@@ -72,6 +72,17 @@ def _gce_metadata(key: str, timeout: float = 1.0) -> Optional[str]:
         return None
 
 
+def local_chip_nodes() -> list:
+    """Device nodes of the TPU chips attached to this host, without
+    loading a TPU runtime: ``/dev/accel*``, or on hosts that expose the
+    chips through VFIO the numbered group nodes of ``/dev/vfio`` (its
+    ``vfio`` entry is the container control node, not a chip)."""
+    import glob
+
+    return sorted(glob.glob("/dev/accel*")
+                  or glob.glob("/dev/vfio/[0-9]*"))
+
+
 class TPUAcceleratorManager(AcceleratorManager):
     """TPU detection + slice topology (reference: tpu.py:70)."""
 
@@ -81,15 +92,13 @@ class TPUAcceleratorManager(AcceleratorManager):
 
     @staticmethod
     def get_current_node_num_accelerators() -> int:
-        import glob
-
         env = os.environ.get("RTPU_TPU_CHIPS")
         if env is not None:
             try:
                 return int(float(env))
             except ValueError:
                 return 0
-        return len(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/*"))
+        return len(local_chip_nodes())
 
     @staticmethod
     def get_current_node_accelerator_type() -> Optional[str]:

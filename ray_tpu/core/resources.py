@@ -171,8 +171,9 @@ def _detect_tpu():
     devices. Deliberately does NOT call jax.devices(): only one process per
     host may own the TPU runtime, and the node daemon must never claim it.
     """
-    import glob
     import os
+
+    from ray_tpu.core.accelerators import local_chip_nodes
 
     env = os.environ.get("RTPU_TPU_CHIPS")
     if env is not None:
@@ -181,7 +182,7 @@ def _detect_tpu():
         except ValueError:
             n = 0.0
         return n, ({LABEL_ACCELERATOR_TYPE: "TPU"} if n else {})
-    chips = glob.glob("/dev/accel*") or glob.glob("/dev/vfio/*")
+    chips = local_chip_nodes()
     if chips:
         return float(len(chips)), {LABEL_ACCELERATOR_TYPE: "TPU"}
     return 0.0, {}

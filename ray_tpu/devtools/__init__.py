@@ -3,7 +3,7 @@
 Every PR so far has shipped post-review fixes for the same bug families
 (lock-ordering hazards, blocking I/O while holding a state lock, sockets
 closed without shutdown under readers writing into shm, dashboard
-innerHTML XSS, jax<0.5-incompatible API calls, swallowed exceptions).
+innerHTML XSS, stray jax mesh/shard_map seams, swallowed exceptions).
 This package codifies those invariants as tooling instead of reviewer
 memory — the same move as the reference's lint-enforced C++ status/ID
 conventions and TSan wiring:

@@ -111,33 +111,34 @@ SOCKET_NAME_RE = re.compile(r"sock", re.IGNORECASE)
 
 # ---------------------------------------------------------- banned APIs
 
-#: jax<0.5 compatibility (this container ships jax<0.5): these calls /
-#: imports silently break it. Use the compat shims instead.
+#: One seam per jax mesh API (written for the installed jax, 0.9):
+#: ambient meshes are entered through ``mesh_context`` and manual
+#: partitioning (``shard_map``) lives in the two ops modules that own
+#: a per-shard kernel — model and parallel code calls those ops.
 #: dotted-call-suffix -> replacement hint.
 BANNED_CALLS = {
     "jax.sharding.set_mesh":
-        "use ray_tpu.parallel.mesh.mesh_context() (jax<0.5 has no "
-        "set_mesh)",
+        "use ray_tpu.parallel.mesh.mesh_context() (the one place the "
+        "ambient mesh is entered)",
     "sharding.set_mesh":
-        "use ray_tpu.parallel.mesh.mesh_context() (jax<0.5 has no "
-        "set_mesh)",
+        "use ray_tpu.parallel.mesh.mesh_context() (the one place the "
+        "ambient mesh is entered)",
 }
 
-#: Module paths whose import is banned (jax<0.5 moved/renamed them).
-#: import-path -> (replacement hint, exempt modules). The exempt module
-#: IS the compat shim — it may import the real thing inside a guarded
-#: fallback.
+#: Module paths whose import is banned outside the exempt modules.
+#: import-path -> (replacement hint, exempt modules).
+_SHARD_MAP_OWNERS = {"ray_tpu.ops.ring_attention", "ray_tpu.ops.attention"}
 BANNED_IMPORTS = {
     "jax.experimental.shard_map": (
-        "import shard_map via the ray_tpu.ops.ring_attention compat "
-        "shim (the jax.experimental path is jax<0.5-only and moves in "
-        "0.5+)",
-        {"ray_tpu.ops.ring_attention"},
+        "shard_map belongs to ray_tpu.ops (ring_attention, attention); "
+        "the jax.experimental path is deprecated — they import "
+        "jax.shard_map",
+        _SHARD_MAP_OWNERS,
     ),
     "jax.shard_map": (
-        "import shard_map via the ray_tpu.ops.ring_attention compat "
-        "shim (top-level jax.shard_map does not exist before jax 0.5)",
-        {"ray_tpu.ops.ring_attention"},
+        "shard_map belongs to ray_tpu.ops (ring_attention, attention): "
+        "call those ops instead of partitioning by hand",
+        _SHARD_MAP_OWNERS,
     ),
 }
 

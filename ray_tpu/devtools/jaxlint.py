@@ -32,8 +32,10 @@ and found by hand in post-review:
   pallas-shape-rules
       inside a ``pl.pallas_call`` kernel body: reductions without
       ``keepdims=True`` (sub-2D intermediate), ``jnp.arange`` (1D
-      iota), or ``reshape`` (cross-lane relayout) — the classic Mosaic
-      lowering failures PR 6 worked around by hand.
+      iota), a float ``broadcasted_iota``, or ``reshape`` (cross-lane
+      relayout) — the classic Mosaic lowering failures PR 6 worked
+      around by hand. The interpreter accepts all of them; the chip's
+      compiler (tests/test_chip_compile.py) is the real guard.
   rng-reinit-per-mesh
       ``jax.random.PRNGKey`` called inside a mesh context in a
       sharded-equivalence module — with jax<0.5 non-partitionable
@@ -694,6 +696,14 @@ class _JaxLinter:
                             f"1D iota (arange) inside Pallas kernel "
                             f"'{label}' — Mosaic requires >=2D; use "
                             "lax.broadcasted_iota", scope=scope)
+                    elif (t == "broadcasted_iota" and sub.args
+                          and "float" in (_dotted(sub.args[0]) or "")):
+                        self._emit(
+                            "pallas-shape-rules", sub,
+                            f"float iota inside Pallas kernel "
+                            f"'{label}' — Mosaic's tpu.iota yields "
+                            "integers only; build an int32 iota and "
+                            "astype", scope=scope)
                     elif t in inv.PALLAS_REDUCTIONS and (
                             d.startswith(("jnp.", "jax.numpy."))
                             or isinstance(sub.func, ast.Attribute)):

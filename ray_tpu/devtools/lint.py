@@ -13,7 +13,7 @@ carries a rule id:
                         body (I/O-serialization locks exempt)
   close-without-shutdown  socket .close() with no earlier shutdown in
                         the same function (recv_into-sink modules only)
-  banned-api            jax<0.5-breaking calls/imports; dashboard
+  banned-api            set_mesh/shard_map outside their one seam; dashboard
                         innerHTML/document.write in JS strings
   swallowed-exception   broad except that neither raises, logs, nor
                         uses the bound exception

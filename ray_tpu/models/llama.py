@@ -239,8 +239,9 @@ def _attention_dispatch(q, k, v, q_pos, kv_pos, cfg, mesh: Optional[Mesh],
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         return ring_attention(q, k, v, q_pos, kv_pos, mesh=mesh)
     if standard_positions:
-        return full_causal_attention(q, k, v)
-    return full_causal_attention(q, k, v, q_positions=q_pos, kv_positions=kv_pos)
+        return full_causal_attention(q, k, v, mesh=mesh)
+    return full_causal_attention(q, k, v, q_positions=q_pos,
+                                 kv_positions=kv_pos, mesh=mesh)
 
 
 def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
@@ -409,7 +410,11 @@ def loss_from_hidden(params: Params, x: jnp.ndarray, tokens: jnp.ndarray,
     the dense and pipeline forwards)."""
     b, s = tokens.shape
     targets = jnp.roll(tokens, -1, axis=1)
-    valid = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
+    # [B, S], not [1, S]: the mean below divides by the number of
+    # valid TOKENS (a broadcast row would count one sequence's worth
+    # and report B times the loss).
+    valid = jnp.broadcast_to(
+        (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :], (b, s))
     if loss_mask is not None:
         valid = valid * jnp.roll(loss_mask, -1, axis=1).astype(jnp.float32)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

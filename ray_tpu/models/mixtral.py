@@ -210,7 +210,7 @@ def _moe_block(x, layer, positions, cfg: MixtralConfig,
     kk = apply_rope(kk, positions, cfg.rope_theta)
     from ray_tpu.ops import full_causal_attention
 
-    attn = full_causal_attention(q, kk, vv)
+    attn = full_causal_attention(q, kk, vv, mesh=mesh)
     x = x + jnp.einsum("bshk,hkd->bsd", attn, layer["wo"]).astype(x.dtype)
 
     h = rms_norm(x, layer["ln_moe"], cfg.norm_eps)
@@ -247,7 +247,8 @@ def loss_fn(params: Params, tokens: jnp.ndarray, cfg: MixtralConfig,
     hidden, aux = forward_hidden(params, tokens, cfg, mesh=mesh)
     b, s = tokens.shape
     targets = jnp.roll(tokens, -1, axis=1)
-    valid = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
+    valid = jnp.broadcast_to(
+        (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :], (b, s))
     logits = jnp.einsum("bsd,dv->bsv", hidden,
                         params["lm_head"]).astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)

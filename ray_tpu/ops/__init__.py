@@ -7,10 +7,11 @@ not the submodules. Dispatch conditions:
 =========================  ===============================  =========================================
 op                         TPU fast path                    dispatch condition
 =========================  ===============================  =========================================
-full_causal_attention      Pallas flash kernel (fwd+bwd)    ``use_fused_kernel``: standard arange
-                                                            positions, seq >= 256 and % 128 == 0,
-                                                            head_dim <= 128 or % 128 == 0; else
-                                                            blockwise scan (seq >= 1024) / dense
+full_causal_attention      Pallas flash kernel (fwd+bwd),   ``use_fused_kernel``: standard arange
+                           shard_mapped over batch and      positions, seq >= 256 and % 128 == 0,
+                           heads when ``mesh`` has > 1      head_dim <= 128 or % 128 == 0; else
+                           device (XLA cannot partition     blockwise scan (seq >= 1024) / dense
+                           a Mosaic kernel)
 causal_attention           (portable dense reference)       always available; position-based masks
 blockwise_attention        (portable online-softmax scan)   seq a multiple of ``block_k``
 decode_attention           Pallas single-query kernel       on TPU, or ``interpret=True`` off-TPU;
@@ -22,9 +23,9 @@ paged_decode_attention     Pallas block-table kernel:       ``LlamaConfig.paged_
                            ceil(len/page) pages/seq         of ``decode_page`` (engine pads). Greedy
                                                             output token-identical to the unpaged
                                                             paths (identity table == contiguous read)
-ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (the ONLY module
-                                                            allowed to import shard_map — rtpu-lint
-                                                            banned-API rule)
+ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (with attention.py
+                                                            the only importers of shard_map —
+                                                            rtpu-lint banned-API rule)
 rms_norm                   (fp32 jnp reference)             always; the fused ops' exactness anchor
 apply_rope                 (fp32 jnp reference)             always
 fused_rms_norm             Pallas one-pass norm kernel      ``LlamaConfig.fused_ops``: kernel on TPU
@@ -32,6 +33,12 @@ fused_rms_norm_residual    + residual-add fold              or under ``interpret
 fused_qk_rope              one kernel for q AND k           elsewhere (same custom VJP both ways,
 fused_swiglu               silu(gate)*up, no temp           so the train path may fuse too)
 =========================  ===============================  =========================================
+
+Every dispatcher asks ``jax.default_backend() == "tpu"``, and on the TPU
+nothing catches a kernel failure to continue on a reference. The
+interpreter passes kernels the chip's compiler refuses:
+``tests/test_chip_compile.py`` compiles each one for a described v5e and
+``chip_smoke.py`` runs each one on the chip against its reference.
 """
 
 from ray_tpu.ops.attention import (

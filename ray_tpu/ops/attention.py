@@ -11,9 +11,13 @@ Shapes follow [batch, seq, heads, head_dim] throughout ("BSHD").
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
+from jax import lax, shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -110,9 +114,6 @@ def blockwise_attention(
     [B, H, Sq, block_k]. Portable (CPU tests, TPU fallback when the Pallas
     kernel does not apply); numerics match `causal_attention`.
     """
-    import jax
-    from jax import lax
-
     b, sq, h, d = q.shape
     sk, kh = k.shape[1], k.shape[2]
     if scale is None:
@@ -159,54 +160,84 @@ def use_fused_kernel(on_tpu: bool, standard: bool, sq: int, d: int) -> bool:
             and (d <= 128 or d % 128 == 0))
 
 
+def _flash_attention(q, k, v, scale: float):
+    """The library Pallas flash kernel on [B,S,H|KH,D] operands (GQA
+    heads repeated here, so under `shard_map` only un-repeated KV
+    crosses the partition boundary)."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        BlockSizes,
+        flash_attention as _tpu_flash,
+    )
+
+    sq, h, kh = q.shape[1], q.shape[2], k.shape[2]
+    if h != kh:
+        k = repeat_kv(k, h // kh)
+        v = repeat_kv(v, h // kh)
+    qt = jnp.transpose(q, (0, 2, 1, 3))
+    kt = jnp.transpose(k, (0, 2, 1, 3))
+    vt = jnp.transpose(v, (0, 2, 1, 3))
+    # The library defaults (block_k_major=128) leave the MXU idle between
+    # tiny grid steps — measured 4x slower than 1024-blocks at Llama
+    # shapes on v5e. Use the largest block <=1024 that divides seq.
+    blk = next(c for c in (1024, 512, 256, 128) if sq % c == 0)
+    bq = bk = min(blk, sq)
+    bs = BlockSizes(
+        block_q=bq, block_k_major=bk, block_k=bk, block_b=1,
+        block_q_major_dkv=bq, block_k_major_dkv=bk, block_k_dkv=bk,
+        block_q_dkv=bq, block_k_major_dq=bk, block_k_dq=bk,
+        block_q_dq=bq,
+    )
+    out = _tpu_flash(qt, kt, vt, causal=True, sm_scale=scale,
+                     block_sizes=bs)
+    return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
+
+
+def _sharded_flash_attention(q, k, v, scale: float, mesh: Mesh):
+    """Mosaic kernels cannot be auto-partitioned: on a multi-device
+    mesh the kernel runs per shard under `shard_map`, batch split over
+    the data axes and heads over ``tp`` (attention is independent per
+    sequence and per head, so no collective is needed)."""
+    tp = mesh.shape.get("tp", 1)
+    if k.shape[2] % tp:
+        # KV heads do not split over tp: repeat first, then every shard
+        # owns whole query heads with their own KV copy.
+        rep = q.shape[2] // k.shape[2]
+        k, v = repeat_kv(k, rep), repeat_kv(v, rep)
+    spec = P(("dp", "fsdp"), None, "tp", None)
+    return shard_map(
+        functools.partial(_flash_attention, scale=scale), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec,
+        # pallas_call's output carries no varying-axes type.
+        check_vma=False,
+    )(q, k, v)
+
+
 def full_causal_attention(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     *,
     q_positions: Optional[jnp.ndarray] = None,
     kv_positions: Optional[jnp.ndarray] = None,
     scale: Optional[float] = None,
+    mesh: Optional[Mesh] = None,
 ) -> jnp.ndarray:
     """Training-path attention dispatcher (full sequence, causal).
 
     TPU: fused Pallas flash kernel (jax.experimental.pallas.ops.tpu) — no
-    [Sq,Sk] materialization, fwd+bwd kernels. Elsewhere / ragged shapes:
-    blockwise online-softmax scan, then dense for short sequences.
+    [Sq,Sk] materialization, fwd+bwd kernels; pass the ``mesh`` the
+    caller's jit partitions over so the kernel is shard_mapped on more
+    than one device. Elsewhere / ragged shapes: blockwise online-softmax
+    scan, then dense for short sequences.
     """
-    import jax
-
     b, sq, h, d = q.shape
     sk = k.shape[1]
     if scale is None:
         scale = d ** -0.5
-    on_tpu = jax.devices()[0].platform == "tpu"
+    on_tpu = jax.default_backend() == "tpu"
     standard = _default_positions(q_positions, kv_positions, b, sq, sk)
     if use_fused_kernel(on_tpu, standard, sq, d):
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            BlockSizes,
-            flash_attention as _tpu_flash,
-        )
-
-        kh = k.shape[2]
-        if h != kh:
-            k = repeat_kv(k, h // kh)
-            v = repeat_kv(v, h // kh)
-        qt = jnp.transpose(q, (0, 2, 1, 3))
-        kt = jnp.transpose(k, (0, 2, 1, 3))
-        vt = jnp.transpose(v, (0, 2, 1, 3))
-        # The library defaults (block_k_major=128) leave the MXU idle between
-        # tiny grid steps — measured 4x slower than 1024-blocks at Llama
-        # shapes on v5e. Use the largest block <=1024 that divides seq.
-        blk = next(c for c in (1024, 512, 256, 128) if sq % c == 0)
-        bq = bk = min(blk, sq)
-        bs = BlockSizes(
-            block_q=bq, block_k_major=bk, block_k=bk, block_b=1,
-            block_q_major_dkv=bq, block_k_major_dkv=bk, block_k_dkv=bk,
-            block_q_dkv=bq, block_k_major_dq=bk, block_k_dq=bk,
-            block_q_dq=bq,
-        )
-        out = _tpu_flash(qt, kt, vt, causal=True, sm_scale=scale,
-                         block_sizes=bs)
-        return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
+        if mesh is not None and mesh.size > 1:
+            return _sharded_flash_attention(q, k, v, scale, mesh)
+        return _flash_attention(q, k, v, scale)
     if q_positions is None:
         q_positions = jnp.broadcast_to(jnp.arange(sq), (b, sq))
     if kv_positions is None:

@@ -29,17 +29,19 @@ via ``LlamaConfig.fused_ops``.
 Kernel-body discipline (now ENFORCED by jax-lint's
 ``pallas-shape-rules`` — ``python -m ray_tpu.devtools.lint --family
 jax``): every intermediate stays >= 2D (reductions carry
-``keepdims=True``), iota is ``lax.broadcasted_iota`` (never a 1D
-``jnp.arange``), and no reshape happens inside a kernel body —
-relayouts belong to the host-side wrappers and BlockSpecs. These are
-the classic Mosaic lowering failures this file originally worked
-around by hand; the linter keeps the next kernel from rediscovering
-them.
+``keepdims=True``), iota is an INTEGER ``lax.broadcasted_iota`` (never
+a 1D ``jnp.arange``, never a float one), and no reshape happens inside
+a kernel body — relayouts belong to the host-side wrappers and
+BlockSpecs, whose last two block dims are multiples of (8, 128) or the
+whole dim. Every kernel here therefore works on a 2D [rows, lanes] view
+of its operands. The interpreter checks none of this:
+``tests/test_chip_compile.py`` asks the chip's compiler.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -48,13 +50,17 @@ from jax import lax
 
 from ray_tpu.ops.norms import rms_norm as rms_norm_reference
 from ray_tpu.ops.rotary import apply_rope as apply_rope_reference
+from ray_tpu.ops.rotary import rope_frequencies
 
-_ROW_BLOCKS = (128, 64, 32, 16, 8, 4, 2, 1)
+_ROW_BLOCKS = (128, 64, 32, 16, 8)
 _COL_BLOCKS = (1024, 512, 256, 128)
 
 
-def _row_block(n: int) -> int:
-    return next(c for c in _ROW_BLOCKS if n % c == 0)
+def _row_block(n: int, cap: int = 128) -> int:
+    """Largest sublane-aligned block (a multiple of 8, at most ``cap``)
+    that divides ``n`` rows; one block spanning all rows when none does
+    (a block dim must be a multiple of 8 or the whole dim)."""
+    return next((c for c in _ROW_BLOCKS if c <= cap and n % c == 0), n)
 
 
 def _col_block(n: int) -> int:
@@ -198,44 +204,66 @@ def fused_rms_norm_residual(x: jnp.ndarray, residual: jnp.ndarray,
 
 # ------------------------------------------------------------------ RoPE
 
-def _rope_kernel(pos_ref, q_ref, k_ref, oq_ref, ok_ref, *, theta: float):
-    # All intermediates stay >= 2D and no cross-lane reshapes happen
-    # (1D vectors and (1,N)->(N,1) relayouts are the classic Mosaic
-    # lowering failures); broadcasting inserts the unit axes instead.
-    d = q_ref.shape[-1]
+def _rope_kernel(pos_ref, inv_ref, q_ref, k_ref, oq_ref, ok_ref, *,
+                 d: int):
+    # Rows are tokens (sublanes), lanes are the flattened (head,
+    # head_dim) axis, so nothing is relaid out inside the kernel: the
+    # [rows, 1] positions broadcast along lanes against the [1, w]
+    # frequency table, and the half-rotation partner of lane j (j +-
+    # d/2 inside its head) comes from two whole-axis lane rolls.
+    from jax.experimental.pallas import tpu as pltpu
+
     half = d // 2
-    # Same formulation as ops.rotary.rope_frequencies: 1 / theta^(2i/d).
-    expo = lax.broadcasted_iota(jnp.float32, (1, 1, half), 2) * (2.0 / d)
-    inv = 1.0 / (theta ** expo)                            # [1, 1, half]
-    ang = pos_ref[...].astype(jnp.float32)[..., None] * inv  # [1,bs,half]
-    cos = jnp.cos(ang)[:, :, None, :]                    # [1,bs,1,half]
-    sin = jnp.sin(ang)[:, :, None, :]
+    ang = pos_ref[...].astype(jnp.float32) * inv_ref[...]     # [bn, w]
+    w = ang.shape[-1]
+    first = lax.broadcasted_iota(jnp.int32, (1, w), 1) % d < half
+    cos = jnp.cos(ang)
+    sin = jnp.where(first, -jnp.sin(ang), jnp.sin(ang))
     for ref, out in ((q_ref, oq_ref), (k_ref, ok_ref)):
-        x = ref[...].astype(jnp.float32)                 # [1,bs,H,D]
-        x1, x2 = x[..., :half], x[..., half:]
-        out[...] = jnp.concatenate(
-            [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-            axis=-1).astype(out.dtype)
+        x = ref[...].astype(jnp.float32)                      # [bn, n]
+        n = x.shape[-1]
+        reps = n // w
+        c = jnp.concatenate([cos] * reps, axis=-1) if reps > 1 else cos
+        s = jnp.concatenate([sin] * reps, axis=-1) if reps > 1 else sin
+        lo = lax.broadcasted_iota(jnp.int32, (1, n), 1) % d < half
+        partner = jnp.where(lo, pltpu.roll(x, n - half, 1),
+                            pltpu.roll(x, half, 1))
+        out[...] = (x * c + partner * s).astype(out.dtype)
 
 
 def _rope_impl(q, k, positions, theta, interpret):
     import jax.experimental.pallas as pl
 
     b, s, h, d = q.shape
-    kh = k.shape[2]
-    bs = _row_block(s)
-    qspec = pl.BlockSpec((1, bs, h, d), lambda bi, si: (bi, si, 0, 0))
-    kspec = pl.BlockSpec((1, bs, kh, d), lambda bi, si: (bi, si, 0, 0))
-    pspec = pl.BlockSpec((1, bs), lambda bi, si: (bi, si))
-    return pl.pallas_call(
-        functools.partial(_rope_kernel, theta=theta),
-        grid=(b, s // bs),
-        in_specs=[pspec, qspec, kspec],
+    nq, nk = h * d, k.shape[2] * d
+    rows = b * s
+    # Cap the f32 working tile at 512 KiB: the kernel holds a handful
+    # of [bn, nq] temporaries in VMEM beside the pipelined blocks.
+    bn = _row_block(rows, cap=max(8, (1 << 17) // nq))
+    # Lanes of the in-kernel cos/sin table: one whole lane tile of
+    # heads (lcm(d, 128)) when both widths are multiples of it — tiling
+    # it out is then a lane-aligned concatenate — else one head.
+    w = d * 128 // math.gcd(d, 128)
+    if nq % w or nk % w:
+        w = d
+    # The same frequencies as the reference, tiled over the heads of
+    # one table width (lane j rotates with frequency j % (d/2)).
+    inv = jnp.tile(rope_frequencies(d, theta), 2 * (w // d)).reshape(1, w)
+    qspec = pl.BlockSpec((bn, nq), lambda i: (i, 0))
+    kspec = pl.BlockSpec((bn, nk), lambda i: (i, 0))
+    oq, ok = pl.pallas_call(
+        functools.partial(_rope_kernel, d=d),
+        grid=(rows // bn,),
+        in_specs=[pl.BlockSpec((bn, 1), lambda i: (i, 0)),
+                  pl.BlockSpec((1, w), lambda i: (0, 0)),
+                  qspec, kspec],
         out_specs=[qspec, kspec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(k.shape, k.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((rows, nq), q.dtype),
+                   jax.ShapeDtypeStruct((rows, nk), k.dtype)],
         interpret=interpret,
-    )(positions, q, k)
+    )(jnp.broadcast_to(positions, (b, s)).reshape(rows, 1),
+      inv, q.reshape(rows, nq), k.reshape(rows, nk))
+    return oq.reshape(q.shape), ok.reshape(k.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -267,9 +295,9 @@ def fused_qk_rope(q: jnp.ndarray, k: jnp.ndarray, positions: jnp.ndarray,
                   theta: float = 500000.0, *, interpret: bool = False):
     """Rotate the q AND k projection outputs in one kernel: q [B,S,H,D],
     k [B,S,KH,D], positions [B,S] int. The cos/sin tables are computed
-    once per position (the unfused path recomputes them per tensor).
-    Returns ``(q_rot, k_rot)``; matches two ``ops.rotary.apply_rope``
-    calls."""
+    once per position and lane tile (the unfused path recomputes them
+    per tensor and head). Returns ``(q_rot, k_rot)``; matches two
+    ``ops.rotary.apply_rope`` calls."""
     return _rope_qk_p(q, k, positions, float(theta), bool(interpret))
 
 

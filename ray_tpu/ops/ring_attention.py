@@ -21,13 +21,8 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
-import jax
-from jax import lax
+from jax import lax, shard_map
 import jax.numpy as jnp
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax in CI images
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import NEG_INF, online_softmax_update

@@ -167,23 +167,17 @@ def constrain(x, names: Sequence[Optional[str]],
     """`with_sharding_constraint` by logical dimension names (no-op outside jit
     over a mesh). Real spec errors (rank mismatch, unknown axis) surface —
     the no-mesh case is detected explicitly, not by matching error text."""
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is None:
-        # Older jax (< 0.5): no ambient-mesh query; constraints only apply
-        # under an explicit set_mesh there, so pass through unsharded.
-        return x
-    mesh = get_abstract_mesh()
-    if mesh is None or getattr(mesh, "empty", False) or not mesh.shape_tuple:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.shape_tuple:
         return x
     return jax.lax.with_sharding_constraint(x, logical_spec(names, rules))
 
 
 def mesh_context(mesh: Mesh):
-    """``jax.sharding.set_mesh(mesh)`` where available (jax >= 0.5); on
-    older jax the physical mesh itself is the ambient-mesh context
-    manager. Use for version-portable `with mesh_context(m):` blocks."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    """``with mesh_context(m):`` enters ``mesh`` as the ambient mesh —
+    the one place the repo calls ``jax.sharding.set_mesh`` (rtpu-lint
+    banned-api keeps it so)."""
+    return jax.sharding.set_mesh(mesh)  # rtpu-lint: disable=banned-api
 
 
 def param_shardings(mesh: Mesh, logical_tree,
@@ -194,12 +188,6 @@ def param_shardings(mesh: Mesh, logical_tree,
         logical_tree,
         is_leaf=lambda x: isinstance(x, tuple),
     )
-
-
-def mfu_denominator(n_devices: int, dtype_flops: float = 197e12) -> float:
-    """Peak bf16 FLOP/s for the mesh (default: v5e = 197 TFLOP/s/chip;
-    v5p = 459e12). Used by bench/MFU reporting."""
-    return n_devices * dtype_flops
 
 
 def largest_pow2_leq(n: int) -> int:

@@ -41,14 +41,7 @@ def _with_mesh_context(mesh: Mesh, fn):
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
-        use_am = getattr(jax.sharding, "use_abstract_mesh", None)
-        if use_am is None:
-            # Older jax (< 0.5): no abstract-mesh context; enter the
-            # physical mesh instead (constrain() passes through there,
-            # but explicit in/out_shardings still place the arrays).
-            with mesh:
-                return fn(*args, **kwargs)
-        with use_am(mesh.abstract_mesh):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             return fn(*args, **kwargs)
 
     return wrapped
@@ -62,10 +55,23 @@ def sharded_init(cfg: llama.LlamaConfig, mesh: Mesh, key: jax.Array,
     p_init = _with_mesh_context(mesh, jax.jit(
         functools.partial(llama.init_params, cfg), out_shardings=shardings))
     params = p_init(key)
-    # Optimizer state mirrors param shapes; XLA propagates the input shardings.
-    opt_state = jax.jit(tx.init)(params)
-    step = jnp.zeros((), jnp.int32)
-    return TrainState(step, params, opt_state)
+    return TrainState(jnp.zeros((), jnp.int32), params,
+                      init_opt_state(tx, params, mesh, shardings))
+
+
+def init_opt_state(tx: optax.GradientTransformation, params: Any,
+                   mesh: Mesh, shardings: Any) -> Any:
+    """``tx.init(params)`` with every param-shaped leaf (Adam's moments)
+    born in its param's sharding and the rest replicated. The zeros
+    have no data dependence on the params, so XLA propagates nothing
+    to them: left alone, each device would hold the WHOLE optimizer
+    state, and the train step would recompile on its second call when
+    its own (sharded) output comes back as input."""
+    replicated = NamedSharding(mesh, P())
+    out = optax.tree_map_params(
+        tx, lambda _, sharding: sharding, jax.eval_shape(tx.init, params),
+        shardings, transform_non_params=lambda _: replicated)
+    return jax.jit(tx.init, out_shardings=out)(params)
 
 
 def make_train_step(

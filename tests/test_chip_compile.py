@@ -1,0 +1,261 @@
+"""The chip's compiler, asked from the CPU sandbox.
+
+The TPU compiler is installed here and compiles for a v5e that is
+DESCRIBED, not attached: every Pallas kernel on the serve/train path at
+Llama-1B and 8B head geometry, the engine's decode and prefill programs
+at full `LLAMA3_1B` size, and the ``fsdp=2 x tp=2`` loss+grad on a mesh
+of the described devices. Nothing runs, so this says nothing about
+results or times (`chip_smoke.py` does, on the chip) — it catches what
+the interpreter cannot: a block shape off the (8, 128) tiling, an op
+Mosaic has no lowering for, a kernel XLA cannot partition, a program
+that does not fit 16 GB.
+
+Only one process may load the TPU library, and pytest-xdist workers
+all import every test file: the topology is therefore described inside
+a fixture (never at import time, in a ``skipif`` or a ``parametrize``
+argument), every test compiles in its own process, and all of these
+tests live in this ONE file so that one worker owns the library.
+"""
+
+import dataclasses
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from ray_tpu import ops
+from ray_tpu.models import llama
+from ray_tpu.parallel.mesh import mesh_2d, param_shardings
+
+# (n_heads, n_kv_heads, head_dim, d_model, d_ff) of Llama-3 1B and 8B.
+GEOMETRY = {"1b": (32, 8, 64, 2048, 8192), "8b": (32, 8, 128, 4096, 14336)}
+BATCH, SEQ = 8, 2048
+# [B, S] of the three programs that call the glue kernels.
+ROPE_SHAPES = {"train": (8, 2048), "prefill": (1, 512), "decode": (8, 1)}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        mp.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out.
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+@pytest.fixture
+def chip(topo, monkeypatch):
+    """One described chip's sharding, with the program's dispatchers
+    steered onto their TPU branch. Traces made under the steering must
+    not outlive it (and CPU traces made earlier must not be reused)."""
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.clear_caches()
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _kernel_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# ------------------------------------------------------------- kernels
+
+@pytest.mark.parametrize("geom", GEOMETRY)
+def test_decode_attention_compiles(chip, geom):
+    h, kh, hd, _, _ = GEOMETRY[geom]
+    c = _compile(
+        functools.partial(ops.decode_attention, layout="bksd", block_s=SEQ),
+        _sds(chip, (BATCH, h, hd)), _sds(chip, (BATCH, kh, SEQ, hd)),
+        _sds(chip, (BATCH, kh, SEQ, hd)), _sds(chip, (BATCH,), jnp.int32))
+    assert _kernel_calls(c) == 1
+
+
+@pytest.mark.parametrize("page", [16, 128])
+@pytest.mark.parametrize("geom", GEOMETRY)
+def test_paged_decode_attention_compiles(chip, geom, page):
+    h, kh, hd, _, _ = GEOMETRY[geom]
+    c = _compile(
+        functools.partial(ops.paged_decode_attention, page_size=page),
+        _sds(chip, (BATCH, h, hd)), _sds(chip, (BATCH, kh, SEQ, hd)),
+        _sds(chip, (BATCH, kh, SEQ, hd)),
+        _sds(chip, (BATCH, SEQ // page), jnp.int32),
+        _sds(chip, (BATCH,), jnp.int32))
+    assert _kernel_calls(c) == 1
+
+
+@pytest.mark.parametrize("geom", GEOMETRY)
+def test_fused_rms_norm_kernels_compile(chip, geom):
+    d = GEOMETRY[geom][3]
+    x, s = _sds(chip, (BATCH, SEQ, d)), _sds(chip, (d,))
+    assert _kernel_calls(_compile(ops.fused_rms_norm, x, s)) == 1
+    assert _kernel_calls(
+        _compile(ops.fused_rms_norm_residual, x, x, s)) == 1
+
+
+@pytest.mark.parametrize("geom", GEOMETRY)
+def test_fused_swiglu_compiles(chip, geom):
+    f = GEOMETRY[geom][4]
+    g = _sds(chip, (BATCH, SEQ, f))
+    assert _kernel_calls(_compile(ops.fused_swiglu, g, g)) == 1
+
+
+@pytest.mark.parametrize("shape", ROPE_SHAPES)
+@pytest.mark.parametrize("geom", GEOMETRY)
+def test_fused_qk_rope_compiles_forward_and_backward(chip, geom, shape):
+    h, kh, hd, _, _ = GEOMETRY[geom]
+    b, s = ROPE_SHAPES[shape]
+    q, k = _sds(chip, (b, s, h, hd)), _sds(chip, (b, s, kh, hd))
+    pos = _sds(chip, (b, s), jnp.int32)
+    assert _kernel_calls(_compile(ops.fused_qk_rope, q, k, pos)) == 1
+
+    def loss(q, k, pos):
+        oq, ok = ops.fused_qk_rope(q, k, pos)
+        return (jnp.sum(oq.astype(jnp.float32))
+                + jnp.sum(ok.astype(jnp.float32) ** 2))
+
+    # The VJP is the same kernel at negated positions (forward + one
+    # backward call; nothing falls back to apply_rope's autodiff).
+    assert _kernel_calls(
+        _compile(jax.grad(loss, argnums=(0, 1)), q, k, pos)) == 2
+
+
+@pytest.mark.parametrize("geom", GEOMETRY)
+def test_flash_attention_compiles_forward_and_backward(chip, geom):
+    h, kh, hd, _, _ = GEOMETRY[geom]
+    q = _sds(chip, (BATCH, SEQ, h, hd))
+    kv = _sds(chip, (BATCH, SEQ, kh, hd))
+    assert _kernel_calls(_compile(ops.full_causal_attention, q, kv, kv)) == 1
+
+    def loss(q, k, v):
+        return jnp.sum(ops.full_causal_attention(q, k, v)
+                       .astype(jnp.float32))
+
+    # Forward + the library's dq and dkv kernels.
+    assert _kernel_calls(
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)) == 3
+
+
+# ------------------------------------------------------ whole programs
+
+_CFG_1B = dataclasses.replace(llama.LLAMA3_1B, max_seq_len=SEQ)
+
+
+def _abstract(sharding, fn, *args):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(fn, *args))
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+def test_engine_decode_chunk_compiles_at_llama3_1b(chip, paged):
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg = dataclasses.replace(_CFG_1B, paged_decode=paged)
+    loop = DecodeLoop(cfg, max_len=SEQ, chunk=8)
+    params = _abstract(chip, functools.partial(llama.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: llama.init_kv_cache(cfg, BATCH, SEQ))
+    vec = _sds(chip, (BATCH,), jnp.int32)
+    c = loop.decode_chunk.lower(
+        params, cache, _sds(chip, (BATCH, 1), jnp.int32), vec, vec, vec,
+        _sds(chip, (BATCH,), jnp.bool_)).compile()
+    # One kernel, in the scanned layer body (a program that does not
+    # fit the chip's 16 GB is refused by the compile itself).
+    assert _kernel_calls(c) == 1
+
+
+def test_engine_prefill_bucket_compiles_at_llama3_1b(chip):
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    loop = DecodeLoop(_CFG_1B, max_len=SEQ, chunk=8)
+    params = _abstract(chip, functools.partial(llama.init_params, _CFG_1B),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: llama.init_kv_cache(_CFG_1B, BATCH, SEQ))
+    scalar = _sds(chip, (), jnp.int32)
+    loop.prefill.lower(params, cache, _sds(chip, (1, 512), jnp.int32),
+                       scalar, scalar).compile()
+
+
+def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip):
+    """Real width, 2 layers, on a mesh of the four described chips: the
+    flash kernel must sit inside a shard_map (XLA cannot partition a
+    Mosaic kernel), and the fsdp/tp collectives must be there."""
+    cfg = dataclasses.replace(_CFG_1B, n_layers=2)
+    mesh = mesh_2d(4, tp=2, devices=list(topo.devices))
+    assert dict(mesh.shape)["fsdp"] == 2 and dict(mesh.shape)["tp"] == 2
+    shapes = jax.eval_shape(functools.partial(llama.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, param_shardings(mesh, llama.param_logical_axes(cfg)))
+    tokens = jax.ShapeDtypeStruct(
+        (BATCH, SEQ), jnp.int32,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"), "sp")))
+
+    def loss(p, t):
+        return llama.loss_fn(p, t, cfg, mesh=mesh)[0]
+
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        c = jax.jit(jax.value_and_grad(loss)).lower(params, tokens).compile()
+    text = c.as_text()
+    # Forward, the remat's forward again, dq and dkv.
+    assert text.count("tpu_custom_call") == 4
+    for collective in ("all-gather", "all-reduce"):
+        assert re.search(rf"\b{collective}(-start)?\(", text), collective
+    # Each device holds a quarter of the weights, not the model.
+    per_device = c.memory_analysis().argument_size_in_bytes
+    total = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(shapes))
+    assert per_device < 0.3 * total
+
+
+# ------------------------------------- the shard_map seam, numerically
+
+def test_sharded_flash_wrapper_matches_unsharded(monkeypatch):
+    """CPU mesh, with the dense reference standing in for the Mosaic
+    kernel: the shard_map over (batch, heads) — GQA heads repeated per
+    shard — must not change the math."""
+    from ray_tpu.ops import attention
+
+    def stand_in(q, k, v, scale):
+        return attention.causal_attention(q, k, v, scale=scale)
+
+    monkeypatch.setattr(attention, "_flash_attention", stand_in)
+    mesh = mesh_2d(4, tp=2, devices=jax.devices()[:4])
+    key = jax.random.PRNGKey(0)
+    q = jax.random.normal(key, (4, 16, 8, 8))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, 4, 8))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (4, 16, 4, 8))
+    for kk, vv in ((k, v), (k[:, :, :1], v[:, :, :1])):  # kh % tp != 0 too
+        got = jax.jit(functools.partial(
+            attention._sharded_flash_attention, scale=8 ** -0.5,
+            mesh=mesh))(q, kk, vv)
+        ref = attention.causal_attention(q, kk, vv)
+        assert jnp.allclose(got, ref, atol=1e-5), float(
+            jnp.max(jnp.abs(got - ref)))

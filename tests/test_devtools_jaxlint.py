@@ -288,6 +288,23 @@ def test_pallas_kernel_disciplined_body_clean():
     assert lint_source(src, "m", "m.py") == []
 
 
+def test_pallas_kernel_float_iota_flagged():
+    # What the v5e compiler refused in the old fused_qk_rope: tpu.iota
+    # yields integers only (the interpreter accepts a float one).
+    src = (
+        "import jax.numpy as jnp\n"
+        "from jax import lax\n"
+        "import jax.experimental.pallas as pl\n"
+        "def _kern(x_ref, o_ref):\n"
+        "    e = lax.broadcasted_iota(jnp.float32, (1, 8), 1)\n"
+        "    o_ref[...] = x_ref[...] * e\n"
+        "def run(x, shape):\n"
+        "    return pl.pallas_call(_kern, out_shape=shape)(x)\n")
+    fs = lint_source(src, "m", "m.py")
+    assert [f.rule for f in fs] == ["pallas-shape-rules"]
+    assert "float iota" in fs[0].message
+
+
 def test_reshape_outside_kernel_clean():
     src = (
         "def host_side(x):\n"

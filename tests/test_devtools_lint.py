@@ -325,12 +325,14 @@ def test_banned_set_mesh_and_shard_map():
     fs = lint_source(src, "ray_tpu.parallel.spmd", "x.py")
     assert [f.rule for f in fs] == ["banned-api"] * 2
     msgs = " ".join(f.message for f in fs)
-    assert "mesh_context" in msgs and "compat shim" in msgs
+    assert "mesh_context" in msgs and "ray_tpu.ops" in msgs
 
 
-def test_shard_map_import_allowed_in_compat_shim():
-    src = "from jax.experimental.shard_map import shard_map\n"
-    assert lint_source(src, "ray_tpu.ops.ring_attention", "x.py") == []
+@pytest.mark.parametrize("module", ["ray_tpu.ops.ring_attention",
+                                    "ray_tpu.ops.attention"])
+def test_shard_map_import_allowed_in_owning_ops(module):
+    src = "from jax import lax, shard_map\n"
+    assert lint_source(src, module, "x.py") == []
 
 
 def test_inner_html_flagged_in_dashboard_strings_only():

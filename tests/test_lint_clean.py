@@ -2,7 +2,7 @@
 across ALL FIVE rule families.
 
 A NEW violation of any codified invariant — concurrency family (lock
-order, blocking-under-lock, close-without-shutdown, banned jax<0.5 /
+order, blocking-under-lock, close-without-shutdown, banned jax mesh /
 dashboard APIs, swallowed exceptions, unjoined daemon threads), jax
 family (closure-captured-array-into-jit, donation-then-read,
 host-sync-in-hot-path, unclamped-dynamic-update-slice,

@@ -102,6 +102,30 @@ def test_2d_train_step_matches_single_device_loss(cfg):
                                llama.param_logical_axes(cfg))
 
 
+def test_sharded_init_shards_optimizer_state_and_step_compiles_once(cfg):
+    """Adam's moments are born in their param's sharding: zeros carry
+    no data dependence, so XLA would otherwise leave the WHOLE optimizer
+    state on every device and the step — whose own output is sharded —
+    would compile a second program on its second call."""
+    mesh = mesh_2d(8, tp=2, devices=jax.devices("cpu")[:8])
+    tx = spmd.default_optimizer(lr=1e-3)
+    with mesh_context(mesh):
+        state = spmd.sharded_init(cfg, mesh, jax.random.key(0), tx)
+        step = spmd.make_train_step(cfg, mesh, tx)
+        tokens = jax.device_put(jnp.zeros((4, 32), jnp.int32),
+                                spmd.data_sharding(mesh))
+        logical = llama.param_logical_axes(cfg)
+        adam = state.opt_state[1][0]
+        spmd.assert_params_sharded(adam.mu, mesh, logical)
+        spmd.assert_params_sharded(adam.nu, mesh, logical)
+        before = jax.tree.map(lambda x: x.sharding, state)
+        state, _ = step(state, tokens)
+        after = jax.tree.map(lambda x: x.sharding, state)
+        assert jax.tree.all(jax.tree.map(
+            lambda a, b, x: a.is_equivalent_to(b, x.ndim),
+            before, after, state))
+
+
 def test_data_sharding_splits_batch_over_fsdp():
     mesh = mesh_2d(8, tp=2, devices=jax.devices("cpu")[:8])
     sh = spmd.data_sharding(mesh)
