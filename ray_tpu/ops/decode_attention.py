@@ -166,5 +166,7 @@ def decode_attention(q, k, v, lengths, *, block_s: int = 2048,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, rep, d), q.dtype),
         interpret=interpret,
+        name="rtpu_decode_attention",
+        metadata={"kernel": "rtpu_decode_attention"},
     )(lengths.astype(jnp.int32), qg, kk, vv)
     return out.reshape(b, h, d)

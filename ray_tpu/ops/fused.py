@@ -115,6 +115,8 @@ def _rms_impl(x, scale, eps, interpret, residual=None):
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
             interpret=interpret,
+            name="rtpu_fused_rms_norm",
+            metadata={"kernel": "rtpu_fused_rms_norm"},
         )(x2, s2)
         return out.reshape(shape)
     r2 = residual.reshape(-1, d)
@@ -126,6 +128,8 @@ def _rms_impl(x, scale, eps, interpret, residual=None):
         out_shape=[jax.ShapeDtypeStruct((n, d), x.dtype),
                    jax.ShapeDtypeStruct((n, d), residual.dtype)],
         interpret=interpret,
+        name="rtpu_fused_rms_norm_residual",
+        metadata={"kernel": "rtpu_fused_rms_norm_residual"},
     )(x2, r2, s2)
     return out.reshape(shape), summed.reshape(shape)
 
@@ -261,6 +265,8 @@ def _rope_impl(q, k, positions, theta, interpret):
         out_shape=[jax.ShapeDtypeStruct((rows, nq), q.dtype),
                    jax.ShapeDtypeStruct((rows, nk), k.dtype)],
         interpret=interpret,
+        name="rtpu_fused_qk_rope",
+        metadata={"kernel": "rtpu_fused_qk_rope"},
     )(jnp.broadcast_to(positions, (b, s)).reshape(rows, 1),
       inv, q.reshape(rows, nq), k.reshape(rows, nk))
     return oq.reshape(q.shape), ok.reshape(k.shape)
@@ -333,6 +339,8 @@ def _swiglu_impl(gate, up, interpret):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n, f), gate.dtype),
         interpret=interpret,
+        name="rtpu_fused_swiglu",
+        metadata={"kernel": "rtpu_fused_swiglu"},
     )(g2, up.reshape(-1, f))
     return out.reshape(shape)
 

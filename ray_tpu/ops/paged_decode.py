@@ -187,6 +187,8 @@ def paged_decode_attention(q, k, v, block_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, rep, d), q.dtype),
         interpret=interpret,
+        name="rtpu_paged_decode_attention",
+        metadata={"kernel": "rtpu_paged_decode_attention"},
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k, v)
     return out.reshape(b, h, d)

@@ -34,8 +34,7 @@ from ray_tpu.devtools import res_debug as _resdbg
 from ray_tpu.serve.engine.decode_loop import DecodeLoop
 from ray_tpu.serve.engine.drafter import PromptLookupDrafter, SpecControl
 from ray_tpu.serve.engine.kv_manager import KVCacheManager, chain_hashes
-from ray_tpu.serve.engine.metrics import (SERVE_TTFT_BREAKDOWN_MS,
-                                          EngineMetrics)
+from ray_tpu.serve.engine.metrics import EngineMetrics, TickClock
 from ray_tpu.serve.engine.scheduler import (EngineRequest, Scheduler,
                                             bucket_for)
 from ray_tpu.util import flight_recorder as _flight
@@ -233,6 +232,9 @@ class InferenceEngine:
         self.prefill_chunk = self.scheduler.prefill_chunk
         self.multi_step = bool(multi_step)
         self.metrics = EngineMetrics(name)
+        # The engine thread's phase clock (engine.tick.* counters and
+        # spans); created here, used by that thread alone.
+        self._tick = TickClock(self.metrics, jax.profiler.TraceAnnotation)
 
         # Fleet KV page tier: evicted prefix blocks spill into a shared
         # page store (shm when a cluster runtime is attached, an
@@ -325,8 +327,15 @@ class InferenceEngine:
                                  stream=True, tenant=tenant,
                                  priority=priority)
         self._queue.put(req)
+        first = True
         while True:
             kind, val = req.stream_queue.get(timeout=timeout)
+            if first and req.first_put_t:
+                # The serve front's own share of TTFT: how long the
+                # first token lay on the stream queue.
+                self.metrics.record_first_deliver(
+                    time.perf_counter() - req.first_put_t)
+            first = False
             if kind == "token":
                 yield val
             elif kind == "done":
@@ -551,6 +560,14 @@ class InferenceEngine:
         raises, so the engine never grows a hidden one."""
         return self._jax.device_put(value)
 
+    @staticmethod
+    def _span(name: str, t0: float, t1: float, req: EngineRequest,
+              attrs: Dict[str, Any]) -> None:
+        """One per-REQUEST span over two `perf_counter` stamps, under
+        the request's trace (callers gate on ``req.trace_ctx``)."""
+        _tracing.emit_span(name, _tracing.wall(t0), _tracing.wall(t1),
+                           parent=req.trace_ctx, attrs=attrs)
+
     def _admit(self) -> None:
         """Match waiting requests to free slots; each admission becomes
         a prefill job (one chunk per tick — a single chunk when
@@ -615,8 +632,7 @@ class InferenceEngine:
                              key=lambda r: (r.priority, -r.arrival_t))
                 if victim.priority >= hp:
                     return False
-        traced = victim.trace_ctx is not None
-        t0w = time.time() if traced else 0.0
+        t0 = time.perf_counter()
         self.scheduler.preempt(victim)
         self._parked.append(victim)
         # RTPU_DEBUG_RES: a parked session pins scheduler + KV residency
@@ -625,13 +641,11 @@ class InferenceEngine:
         _resdbg.note_acquire("parked_kv", key=(id(self), id(victim)),
                              owner=self, note="preempt_park")
         self._preempts += 1
-        if traced:
-            _tracing.emit_span(
-                "engine.preempt_park", t0w, time.time(),
-                parent=victim.trace_ctx,
-                attrs={"priority": victim.priority,
-                       "generated": len(victim.generated),
-                       "remaining": victim.remaining()})
+        if victim.trace_ctx is not None:
+            self._span("engine.preempt_park", t0, time.perf_counter(),
+                       victim, {"priority": victim.priority,
+                                "generated": len(victim.generated),
+                                "remaining": victim.remaining()})
         return True
 
     def _resume_tick(self) -> None:
@@ -664,8 +678,7 @@ class InferenceEngine:
         merges into the original future. Admission runs the normal
         path, so the parked rows come back as a prefix-cache hit or a
         fleet pull (the park/resume KV round-trip)."""
-        traced = orig.trace_ctx is not None
-        t0w = time.time() if traced else 0.0
+        t0 = time.perf_counter()
         cont = EngineRequest(
             list(orig.prompt_ids) + list(orig.generated),
             max_new_tokens=orig.remaining(),
@@ -699,13 +712,11 @@ class InferenceEngine:
         cont.future.add_done_callback(_merge)
         self.scheduler.submit(cont)
         self._resumes += 1
-        if traced:
-            _tracing.emit_span(
-                "engine.preempt_resume", t0w, time.time(),
-                parent=orig.trace_ctx,
-                attrs={"priority": orig.priority,
-                       "resume_prompt": len(cont.prompt_ids),
-                       "remaining": cont.max_new_tokens})
+        if orig.trace_ctx is not None:
+            self._span("engine.preempt_resume", t0, time.perf_counter(),
+                       orig, {"priority": orig.priority,
+                              "resume_prompt": len(cont.prompt_ids),
+                              "remaining": cont.max_new_tokens})
 
     # -------------------------------------------------- fleet KV tier
 
@@ -760,8 +771,7 @@ class InferenceEngine:
         if not todo:
             return
         req = getattr(self.kv, "current_request", None)
-        traced = req is not None and req.trace_ctx is not None
-        t0w = time.time() if traced else 0.0
+        t0 = time.perf_counter()
         pages_k, pages_v, crcs = self.export_pages(
             slot, [i * P for i, _ in todo], tag="kv_spill")
         jobs = []
@@ -771,10 +781,9 @@ class InferenceEngine:
             jobs.append((oid, tuple(resident[i * P:(i + 1) * P]),
                          tuple(chain[:i + 1]), k, v, crc, key))
         self._spill_q.put(jobs)
-        if traced:
-            _tracing.emit_span("engine.kv_spill", t0w, time.time(),
-                               parent=req.trace_ctx,
-                               attrs={"blocks": len(todo), "slot": slot})
+        if req is not None and req.trace_ctx is not None:
+            self._span("engine.kv_spill", t0, time.perf_counter(), req,
+                       {"blocks": len(todo), "slot": slot})
 
     def _spill_loop(self) -> None:
         """Spill worker: pack + store-put the exported pages. Pure host
@@ -819,8 +828,7 @@ class InferenceEngine:
         d0 = adm.cached_len // P
         if max_d <= d0:
             return
-        traced = req.trace_ctx is not None
-        t0w = time.time() if traced else 0.0
+        t0 = time.perf_counter()
         payloads = []
         for d in range(d0 + 1, max_d + 1):
             oid = _kvf.page_object_id(self._fleet_ns, want[d - 1])
@@ -894,12 +902,10 @@ class InferenceEngine:
             self._fleet_stats["kv_fleet_tokens_reused"] += run * P
         for j in range(run):
             self._note_fleet_hash(want[d0 + j])
-        if traced:
-            _tracing.emit_span(
-                "engine.kv_fleet_pull", t0w, time.time(),
-                parent=req.trace_ctx,
-                attrs={"blocks": run, "tokens": run * P,
-                       "slot": adm.slot})
+        if req.trace_ctx is not None:
+            self._span("engine.kv_fleet_pull", t0, time.perf_counter(),
+                       req, {"blocks": run, "tokens": run * P,
+                             "slot": adm.slot})
 
     def _note_fleet_hash(self, h: int) -> None:
         """Record a chain hash this replica can serve from the fleet
@@ -1013,31 +1019,34 @@ class InferenceEngine:
         cached = job.adm.cached_len
         n, bucket = job.adm.chunks[job.idx]
         final = job.idx == len(job.adm.chunks) - 1
-        if job.idx == 0:
-            job.t_pf0 = time.perf_counter()
-        traced = req.trace_ctx is not None
-        t0w = time.time() if traced else 0.0
         try:
-            suffix = req.prompt_ids[job.pos:job.pos + n]
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :n] = suffix
-            logits, self.cache = self.loop.prefill(
-                self.params, self.cache, self._put(padded),
-                self._put(np.int32(slot)),
-                self._put(np.int32(job.pos)))
-            # Per-chunk prefix commit: block occupancy and the slot's
-            # resident chain track the materialized prefix as chunks
-            # land, not the whole prompt up-front.
-            self.kv.commit_prefill(slot, req.prompt_ids[:job.pos + n])
+            with self._tick.phase("prefill_dispatch", slot=slot,
+                                  bucket=bucket, tokens=n):
+                t0 = self._tick.now
+                if job.idx == 0:
+                    job.t_pf0 = t0
+                suffix = req.prompt_ids[job.pos:job.pos + n]
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :n] = suffix
+                logits, self.cache = self.loop.prefill(
+                    self.params, self.cache, self._put(padded),
+                    self._put(np.int32(slot)),
+                    self._put(np.int32(job.pos)))
+                # Per-chunk prefix commit: block occupancy and the
+                # slot's resident chain track the materialized prefix
+                # as chunks land, not the whole prompt up-front.
+                self.kv.commit_prefill(slot, req.prompt_ids[:job.pos + n])
             if final:
-                # First generated token: from the LAST REAL prompt pos
-                # (row n-1 of the final chunk). The ONE counted prefill
-                # sync per admission — intermediate chunks fetch
-                # nothing (np.asarray on the device logits here was the
-                # jax-lint rule's first in-tree catch: an uncounted
-                # implicit sync).
-                first = int(np.argmax(
-                    self._fetch(logits, tag="prefill")[0, n - 1]))
+                # The ONE counted prefill sync per admission —
+                # intermediate chunks fetch nothing (np.asarray on the
+                # device logits here was the jax-lint rule's first
+                # in-tree catch: an uncounted implicit sync). It waits
+                # out whatever the device had queued before this
+                # prefill, then copies the whole [1, bucket, vocab].
+                with self._tick.phase("prefill_fetch", slot=slot,
+                                      bucket=bucket) as attrs:
+                    logits = self._fetch(logits, tag="prefill")
+                    attrs["bytes"] = logits.nbytes
         except BaseException as e:  # noqa: BLE001 — one bad request
             # must not kill the engine thread (every later request
             # would hang on a dead engine). Seed only the PRE-ACQUIRE
@@ -1049,50 +1058,45 @@ class InferenceEngine:
             if req.stream_queue is not None:
                 req.stream_queue.put(("error", e))
             return True
-        if traced:
+        t1 = self._tick.now  # the chunk is dispatched (and, final, fetched)
+        if req.trace_ctx is not None:
             # One span per CHUNK (chunk/chunks attrs), so TTFT
             # decomposition stays accurate under chunked prefill — the
             # gaps between chunk spans are the interleaved decode ticks.
-            _tracing.emit_span(
-                "engine.prefill", t0w, time.time(),
-                parent=req.trace_ctx,
-                attrs={"prefill_tokens": n, "cached_tokens": cached,
-                       "bucket": bucket, "slot": slot,
-                       "chunk": job.idx, "chunks": len(job.adm.chunks)})
+            self._span("engine.prefill", t0, t1, req,
+                       {"prefill_tokens": n, "cached_tokens": cached,
+                        "bucket": bucket, "slot": slot,
+                        "chunk": job.idx, "chunks": len(job.adm.chunks)})
         job.idx += 1
         job.pos += n
         if not final:
             return False
-        req.first_token_t = time.perf_counter()
-        queue_s = max(0.0, job.t_pf0 - req.arrival_t)
-        prefill_s = max(0.0, req.first_token_t - job.t_pf0)
-        SERVE_TTFT_BREAKDOWN_MS.observe(queue_s * 1e3,
-                                        labels={"component": "queue"})
-        SERVE_TTFT_BREAKDOWN_MS.observe(prefill_s * 1e3,
-                                        labels={"component": "prefill"})
-        if self._fleet is not None:
-            self._note_prefill_cost(prefill_s,
-                                    len(req.prompt_ids) - cached)
-        if traced:
-            # Wall-clock span boundaries reconstructed from the
-            # perf_counter intervals measured above (prefill spans
-            # first-chunk dispatch -> first-token fetch, covering any
-            # interleaved decode ticks).
-            now_w = time.time()
-            _tracing.emit_span(
-                "engine.queued", now_w - prefill_s - queue_s,
-                now_w - prefill_s, parent=req.trace_ctx,
-                attrs={"prompt_len": len(req.prompt_ids)})
-        self.metrics.record_admit(req.first_token_t - req.arrival_t,
-                                  len(req.prompt_ids) - cached, cached)
-        req.generated.append(first)
-        if req.stream_queue is not None:
-            req.stream_queue.put(("token", first))
-        if req.handoff:
-            self._finish_handoff(req)
-            return True
-        self.scheduler.activate(req)
-        self._maybe_finish(req, first)
+        with self._tick.phase("prefill_deliver", slot=slot):
+            # First generated token: from the LAST REAL prompt pos (row
+            # n-1 of the final chunk).
+            first = int(np.argmax(logits[0, n - 1]))
+            req.first_token_t = t1
+            queue_s = max(0.0, job.t_pf0 - req.arrival_t)
+            prefill_s = max(0.0, t1 - job.t_pf0)
+            if self._fleet is not None:
+                self._note_prefill_cost(prefill_s,
+                                        len(req.prompt_ids) - cached)
+            if req.trace_ctx is not None:
+                # The request's wait, on its real stamps: arrival to
+                # the first chunk's dispatch.
+                self._span("engine.queued", req.arrival_t, job.t_pf0, req,
+                           {"prompt_len": len(req.prompt_ids)})
+            self.metrics.record_admit(queue_s, prefill_s,
+                                      len(req.prompt_ids) - cached, cached)
+            req.generated.append(first)
+            if req.stream_queue is not None:
+                req.first_put_t = time.perf_counter()
+                req.stream_queue.put(("token", first))
+            if req.handoff:
+                self._finish_handoff(req)
+                return True
+            self.scheduler.activate(req)
+            self._maybe_finish(req, first)
         return True
 
     def _finish_handoff(self, req: EngineRequest) -> None:
@@ -1292,7 +1296,8 @@ class InferenceEngine:
         tokens, which an in-flight chunk would lag by one dispatch.
         """
         if self.drafter is not None:
-            drafts = self._draft_for_roster()
+            with self._tick.phase("decode_dispatch", drafting=True):
+                drafts = self._draft_for_roster()
             if drafts:
                 self._spec_tick(drafts)
                 return
@@ -1360,30 +1365,26 @@ class InferenceEngine:
         roster. Returns the in-flight record _retire_chunk consumes, or
         None on a dispatch failure (roster failed)."""
         active = self.scheduler.active
-        # Chunk-span wall boundaries: computed ONLY when some roster
-        # member is traced — the tracing-off tick is byte-identical (no
-        # extra clock reads, no span dicts).
-        traced_tick = (_tracing.enabled()
-                       and any(r.trace_ctx is not None for r in active))
-        if carry is not None:
-            tok_d, len_d, rem_d, eos_d, done_d = carry["carry"]
-        else:
-            tokens, lengths, remaining, eos_ids, done = \
-                self._roster_arrays(active)
-            tok_d, len_d, rem_d, eos_d, done_d = (
-                self._put(tokens), self._put(lengths),
-                self._put(remaining), self._put(eos_ids),
-                self._put(done))
-        t0w = time.time() if traced_tick else 0.0
-        t0 = time.perf_counter()
-        try:
-            toks_d, n_valid_d, ntok_d, nlen_d, nrem_d, ndone_d, \
-                self.cache = self.loop.decode_chunk(
-                    self.params, self.cache, tok_d, len_d, rem_d,
-                    eos_d, done_d)
-        except BaseException as e:  # noqa: BLE001 — fail all waiters
-            self._fail_roster(e)
-            return None
+        with self._tick.phase("decode_dispatch", slots=len(active),
+                              carried=carry is not None):
+            t0 = self._tick.now
+            if carry is not None:
+                tok_d, len_d, rem_d, eos_d, done_d = carry["carry"]
+            else:
+                tokens, lengths, remaining, eos_ids, done = \
+                    self._roster_arrays(active)
+                tok_d, len_d, rem_d, eos_d, done_d = (
+                    self._put(tokens), self._put(lengths),
+                    self._put(remaining), self._put(eos_ids),
+                    self._put(done))
+            try:
+                toks_d, n_valid_d, ntok_d, nlen_d, nrem_d, ndone_d, \
+                    self.cache = self.loop.decode_chunk(
+                        self.params, self.cache, tok_d, len_d, rem_d,
+                        eos_d, done_d)
+            except BaseException as e:  # noqa: BLE001 — fail all waiters
+                self._fail_roster(e)
+                return None
         return {"outs": (toks_d, n_valid_d),
                 "carry": (ntok_d, nlen_d, nrem_d, eos_d, ndone_d),
                 "roster": self._roster_key(),
@@ -1400,7 +1401,7 @@ class InferenceEngine:
                 # delivered/live_steps < 1.0 shows the frozen-overshoot
                 # waste instead of the old always-1.0 readout.
                 "live_steps": len(active) * self.loop.chunk,
-                "t0": t0, "t0w": t0w, "traced": traced_tick}
+                "t0": t0}
 
     def _retire_chunk(self, rec: Dict[str, Any]) -> bool:
         """The tick's ONE host fetch: land the chunk's tokens, deliver
@@ -1409,12 +1410,15 @@ class InferenceEngine:
         carried its done mask), retire finishes. False on device
         failure."""
         try:
-            # device_get returns host ndarrays: [B, K] ids + [B] valid.
-            chunk_ids, n_valid = self._fetch(rec["outs"])
+            with self._tick.phase("decode_fetch",
+                                  slots=len(rec["reqs"])) as attrs:
+                # device_get returns host ndarrays: [B, K] ids + [B] valid.
+                chunk_ids, n_valid = self._fetch(rec["outs"])
+                attrs["bytes"] = chunk_ids.nbytes + n_valid.nbytes
         except BaseException as e:  # noqa: BLE001 — fail all waiters
             self._fail_roster(e)
             return False
-        now = time.perf_counter()
+        now = self._tick.now
         # TPOT window: a PIPELINED chunk was dispatched one tick ago, so
         # dispatch->fetch would fold the whole intervening host tick
         # (which overlapped device compute) into per-token latency — an
@@ -1424,29 +1428,28 @@ class InferenceEngine:
         # previous retire ended just before this record's dispatch).
         elapsed = now - max(rec["t0"], self._last_retire_t)
         self._last_retire_t = now
-        t1w = time.time() if rec["traced"] else 0.0
         active = self.scheduler.active
         delivered = 0
         n_act = len(active)
-        for req in list(active):
-            n = int(n_valid[req.slot])
-            delivered += n
-            if req.trace_ctx is not None and n:
-                _tracing.emit_span(
-                    "engine.decode_chunk", rec["t0w"], t1w,
-                    parent=req.trace_ctx,
-                    attrs={"tokens": n, "slot": req.slot})
-            for j in range(n):
-                tok = int(chunk_ids[req.slot, j])
-                req.length += 1
-                self.kv.grow(req.slot)  # block-granular occupancy
-                req.generated.append(tok)
-                if req.stream_queue is not None:
-                    req.stream_queue.put(("token", tok))
-                if self._maybe_finish(req, tok):
-                    break  # device froze the slot here; rest are repeats
-        self.metrics.record_chunk(delivered, rec["live_steps"], elapsed)
-        _flight.record("engine_tick", tok=delivered, act=n_act)
+        with self._tick.phase("decode_deliver", slots=n_act) as attrs:
+            for req in list(active):
+                n = int(n_valid[req.slot])
+                delivered += n
+                if req.trace_ctx is not None and n:
+                    self._span("engine.decode_chunk", rec["t0"], now, req,
+                               {"tokens": n, "slot": req.slot})
+                for j in range(n):
+                    tok = int(chunk_ids[req.slot, j])
+                    req.length += 1
+                    self.kv.grow(req.slot)  # block-granular occupancy
+                    req.generated.append(tok)
+                    if req.stream_queue is not None:
+                        req.stream_queue.put(("token", tok))
+                    if self._maybe_finish(req, tok):
+                        break  # device froze the slot here; rest repeat
+            attrs["tokens"] = delivered
+            self.metrics.record_chunk(delivered, rec["live_steps"], elapsed)
+            _flight.record("engine_tick", tok=delivered, act=n_act)
         return True
 
     # -------------------------------------------------------- speculation
@@ -1484,92 +1487,97 @@ class InferenceEngine:
         active = self.scheduler.active
         C, K = self.loop.spec_chunk, self.spec_draft_len
         W = K + 1
-        tokens, lengths, remaining, eos_ids, done = \
-            self._roster_arrays(active)
-        draft_buf = np.zeros((self.max_batch, C, K), np.int32)
-        ndraft = np.zeros((self.max_batch,), np.int32)
-        for slot, cont in drafts.items():
-            # Window rows are packed at stride W = K+1, not K: the only
-            # path to row i is i FULLY accepted windows, and each full
-            # window advances K+1 positions (K drafts + the model's
-            # bonus token). The continuation's prediction for a bonus
-            # position is skipped — the bonus comes from the model's
-            # own argmax, so drafting it would desynchronize every
-            # later row by one position per window (systematic row-1+
-            # rejection on any repetition with period > 1).
-            packed = 0
-            for i in range(C):
-                row = cont[i * (K + 1):i * (K + 1) + K]
-                if not row:
-                    break
-                draft_buf[slot, i, :len(row)] = row
-                packed += len(row)
-            ndraft[slot] = packed
-        for req in active:
-            self.kv.begin_speculation(
-                req.slot, min(C * W, self.max_len - req.length))
-        traced_tick = (_tracing.enabled()
-                       and any(r.trace_ctx is not None for r in active))
-        t0w = time.time() if traced_tick else 0.0
-        t0 = time.perf_counter()
         try:
-            emits_d, counts_d, _len_d, _done_d, self.cache = \
-                self.loop.verify_chunk(
-                    self.params, self.cache, self._put(tokens),
-                    self._put(draft_buf), self._put(ndraft),
-                    self._put(lengths), self._put(remaining),
-                    self._put(eos_ids), self._put(done))
-            # device_get returns host ndarrays: [B,C,W] + [B,C].
-            emits, counts = self._fetch((emits_d, counts_d))
+            with self._tick.phase("decode_dispatch", slots=len(active),
+                                  spec=True):
+                t0 = self._tick.now
+                tokens, lengths, remaining, eos_ids, done = \
+                    self._roster_arrays(active)
+                draft_buf = np.zeros((self.max_batch, C, K), np.int32)
+                ndraft = np.zeros((self.max_batch,), np.int32)
+                for slot, cont in drafts.items():
+                    # Window rows are packed at stride W = K+1, not K:
+                    # the only path to row i is i FULLY accepted
+                    # windows, and each full window advances K+1
+                    # positions (K drafts + the model's bonus token).
+                    # The continuation's prediction for a bonus position
+                    # is skipped — the bonus comes from the model's own
+                    # argmax, so drafting it would desynchronize every
+                    # later row by one position per window (systematic
+                    # row-1+ rejection on any repetition with period
+                    # > 1).
+                    packed = 0
+                    for i in range(C):
+                        row = cont[i * (K + 1):i * (K + 1) + K]
+                        if not row:
+                            break
+                        draft_buf[slot, i, :len(row)] = row
+                        packed += len(row)
+                    ndraft[slot] = packed
+                for req in active:
+                    self.kv.begin_speculation(
+                        req.slot, min(C * W, self.max_len - req.length))
+                emits_d, counts_d, _len_d, _done_d, self.cache = \
+                    self.loop.verify_chunk(
+                        self.params, self.cache, self._put(tokens),
+                        self._put(draft_buf), self._put(ndraft),
+                        self._put(lengths), self._put(remaining),
+                        self._put(eos_ids), self._put(done))
+            with self._tick.phase("decode_fetch",
+                                  slots=len(active)) as attrs:
+                # device_get returns host ndarrays: [B,C,W] + [B,C].
+                emits, counts = self._fetch((emits_d, counts_d))
+                attrs["bytes"] = emits.nbytes + counts.nbytes
         except BaseException as e:  # noqa: BLE001 — fail all waiters
             self._fail_roster(e)
             return
-        elapsed = time.perf_counter() - t0
-        t1w = time.time() if traced_tick else 0.0
+        now = self._tick.now
         live_steps = len(active) * C * W  # token-positions scanned
         delivered = 0
         accepted_total = 0
-        for req in list(active):
-            s = req.slot
-            n = int(counts[s].sum())
-            # Commit the verified rows, roll back the reservation for
-            # the rejected remainder BEFORE delivery: _maybe_finish may
-            # release the slot, and a released slot must carry no
-            # in-flight reservation into the free pool.
-            self.kv.commit_speculation(s, n)
-            delivered += n
-            req_accepted = int(np.maximum(counts[s] - 1, 0).sum())
-            accepted_total += req_accepted
-            if req.trace_ctx is not None and n:
-                _tracing.emit_span(
-                    "engine.decode_chunk", t0w, t1w,
-                    parent=req.trace_ctx,
-                    attrs={"tokens": n, "slot": s, "spec": True,
-                           "spec_accepted": req_accepted,
-                           "drafted": int(ndraft[s])})
-            finished = False
-            for i in range(C):
-                for j in range(int(counts[s, i])):
-                    tok = int(emits[s, i, j])
-                    req.length += 1
-                    req.generated.append(tok)
-                    if req.stream_queue is not None:
-                        req.stream_queue.put(("token", tok))
-                    if self._maybe_finish(req, tok):
-                        finished = True
+        with self._tick.phase("decode_deliver", slots=len(active),
+                              spec=True) as attrs:
+            for req in list(active):
+                s = req.slot
+                n = int(counts[s].sum())
+                # Commit the verified rows, roll back the reservation
+                # for the rejected remainder BEFORE delivery:
+                # _maybe_finish may release the slot, and a released
+                # slot must carry no in-flight reservation into the
+                # free pool.
+                self.kv.commit_speculation(s, n)
+                delivered += n
+                req_accepted = int(np.maximum(counts[s] - 1, 0).sum())
+                accepted_total += req_accepted
+                if req.trace_ctx is not None and n:
+                    self._span("engine.decode_chunk", t0, now, req,
+                               {"tokens": n, "slot": s, "spec": True,
+                                "spec_accepted": req_accepted,
+                                "drafted": int(ndraft[s])})
+                finished = False
+                for i in range(C):
+                    for j in range(int(counts[s, i])):
+                        tok = int(emits[s, i, j])
+                        req.length += 1
+                        req.generated.append(tok)
+                        if req.stream_queue is not None:
+                            req.stream_queue.put(("token", tok))
+                        if self._maybe_finish(req, tok):
+                            finished = True
+                            break
+                    if finished:
                         break
-                if finished:
-                    break
-            if (self.spec_adaptive and not finished
-                    and s in drafts):
-                consumed, acc = self._spec_outcome(
-                    counts[s], int(ndraft[s]), K, W)
-                if consumed:
-                    req.spec.observe(consumed, acc)
-        self.metrics.record_chunk(delivered, live_steps, elapsed)
-        self.metrics.record_spec(int(ndraft.sum()), accepted_total)
-        _flight.record("engine_tick", tok=delivered, act=len(active),
-                       spec=True)
+                if (self.spec_adaptive and not finished
+                        and s in drafts):
+                    consumed, acc = self._spec_outcome(
+                        counts[s], int(ndraft[s]), K, W)
+                    if consumed:
+                        req.spec.observe(consumed, acc)
+            attrs["tokens"] = delivered
+            self.metrics.record_chunk(delivered, live_steps, now - t0)
+            self.metrics.record_spec(int(ndraft.sum()), accepted_total)
+            _flight.record("engine_tick", tok=delivered, act=len(active),
+                           spec=True)
 
     @staticmethod
     def _spec_outcome(counts_row, drafted: int, K: int, W: int):
@@ -1596,16 +1604,21 @@ class InferenceEngine:
         return consumed, accepted
 
     def _engine_loop(self) -> None:
+        tick = self._tick
         while not self._shutdown:
+            tick.lap()
             # tick_guard is a null context unless RTPU_DEBUG_JAX=1 and
             # RTPU_DEBUG_JAX_TRANSFER_GUARD are set; then every tick
             # runs under jax.transfer_guard — implicit device traffic
             # raises instead of silently syncing (all engine dispatch
             # inputs go through the explicit _put/_fetch pair).
             with jax_debug.tick_guard():
-                self._admit()
+                with tick.phase("admit") as attrs:
+                    self._admit()
+                    attrs["prefilling"] = len(self._prefilling)
                 if self.role == "decode":
-                    self._install_tick()
+                    with tick.phase("install"):
+                        self._install_tick()
                 self._prefill_tick()
             self.metrics.record_depths(self.scheduler.queue_depth(),
                                        len(self.scheduler.active),
@@ -1626,7 +1639,8 @@ class InferenceEngine:
                     # Straight into the waiting line (re-putting to the
                     # mailbox would reorder it behind later arrivals and
                     # break FIFO admission); admitted on the next tick.
-                    self.scheduler.submit(self._queue.get(timeout=0.1))
+                    with tick.phase("idle"):
+                        self.scheduler.submit(self._queue.get(timeout=0.1))
                 except queue.Empty:
                     pass
                 continue

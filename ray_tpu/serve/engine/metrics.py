@@ -11,6 +11,11 @@ Two surfaces, one source of truth:
 
 Histogram boundaries are latency-shaped (seconds): TTFT spans prefill
 compiles (first request pays XLA), TPOT sits in the ms range.
+
+`TickClock` times the engine thread itself: always-on seconds per tick
+phase in the snapshot, and — while tracing is on — the same stamps as
+``engine.tick.<phase>`` spans and profiler annotations (one set of
+clock reads, two outputs; engine/README.md "Tick phases").
 """
 
 from __future__ import annotations
@@ -18,9 +23,11 @@ from __future__ import annotations
 import collections
 import itertools
 import threading
+import time
 from typing import Any, Dict, Optional
 
 from ray_tpu.util import metrics as _m
+from ray_tpu.util import tracing as _tracing
 
 _ENGINE_SEQ = itertools.count()
 
@@ -63,16 +70,28 @@ SPEC_ACCEPT_RATE = _m.Gauge(
 SPEC_CHUNKS_TOTAL = _m.Counter(
     "rtpu_llm_spec_chunks_total",
     "decode chunks dispatched through the speculative verify program")
-# TTFT decomposition (labels: component=queue|route|prefill) — the
-# serve-path breakdown the router/SLO PRs are judged on: `queue` is the
-# engine-side wait from arrival to prefill dispatch, `route` the
-# handle-side replica choice, `prefill` the device prefill + first-token
-# fetch. Fed by api.DeploymentHandle (route) and the engine's admission
-# path (queue/prefill); always on — two clock reads per request.
+# TTFT decomposition (labels: component=queue|route|prefill|deliver) —
+# the serve-path breakdown the router/SLO PRs are judged on: `queue` is
+# the engine-side wait from arrival to the first prefill dispatch,
+# `route` the handle-side replica choice, `prefill` first dispatch to
+# the first token on the host (the device prefill, whatever the device
+# had queued before it, and the logits fetch), `deliver` the first
+# token's time on the stream queue until the consumer's thread takes
+# it. Fed by api.DeploymentHandle (route), the engine's admission path
+# (queue/prefill) and generate_stream (deliver); always on. Boundaries
+# resolve 50 ms to 1.5 s, where a loaded replica's components lie.
 SERVE_TTFT_BREAKDOWN_MS = _m.Histogram(
     "rtpu_serve_ttft_breakdown_ms",
     "TTFT component breakdown in milliseconds (component label)",
-    boundaries=[0.1, 0.5, 2, 10, 50, 250, 1000, 5000])
+    boundaries=[1, 2, 5, 10, 20, 50, 100, 150, 200, 300, 400, 500, 750,
+                1000, 1500, 2500, 5000, 10000])
+
+# The engine thread's time, cut into phases (engine/README.md "Tick
+# phases"; `install` is the decode role's own). Counter keys are
+# `tick_<phase>_s`, span and annotation names `engine.tick.<phase>`.
+TICK_PHASES = ("admit", "install", "prefill_dispatch", "prefill_fetch",
+               "prefill_deliver", "decode_dispatch", "decode_fetch",
+               "decode_deliver", "idle")
 
 
 class EngineMetrics:
@@ -97,13 +116,32 @@ class EngineMetrics:
         # a single float the router can compare across replicas without
         # shipping the whole window.
         self._ewma_ttft_s: Optional[float] = None
+        # TTFT, decomposed: sums over `requests` (queue + prefill, the
+        # two halves of every TTFT record_admit sees) and over `streams`
+        # (the serve front's share, written by the consumer's thread).
+        self.queue_wait_s = 0.0
+        self.prefill_wait_s = 0.0
+        self.first_deliver_s = 0.0
+        self.streams = 0
+        # Tick phases: seconds per phase, the loop's wall seconds and
+        # its iterations. Written by the engine thread alone (TickClock)
+        # without the lock; a snapshot reads each float whole.
+        self.tick_s = dict.fromkeys(TICK_PHASES, 0.0)
+        self.tick_loop_s = 0.0
+        self.ticks = 0
 
     # ------------------------------------------------------------ records
 
-    def record_admit(self, ttft_s: float, prefill_tokens: int,
-                     reused_tokens: int) -> None:
+    def record_admit(self, queue_s: float, prefill_s: float,
+                     prefill_tokens: int, reused_tokens: int) -> None:
+        """One admission reached its first token: ``queue_s`` from
+        arrival to the first prefill dispatch, ``prefill_s`` from there
+        to the first token on the host. TTFT is their sum."""
+        ttft_s = queue_s + prefill_s
         with self._lock:
             self.requests += 1
+            self.queue_wait_s += queue_s
+            self.prefill_wait_s += prefill_s
             self.prefill_tokens += prefill_tokens
             self.tokens_generated += 1  # prefill yields the first token
             self._ttfts.append(ttft_s)
@@ -116,6 +154,20 @@ class EngineMetrics:
         PREFILL_TOKENS_TOTAL.inc(prefill_tokens, labels=self._labels)
         if reused_tokens:
             PREFIX_REUSED_TOTAL.inc(reused_tokens, labels=self._labels)
+        SERVE_TTFT_BREAKDOWN_MS.observe(queue_s * 1e3,
+                                        labels={"component": "queue"})
+        SERVE_TTFT_BREAKDOWN_MS.observe(prefill_s * 1e3,
+                                        labels={"component": "prefill"})
+
+    def record_first_deliver(self, seconds: float) -> None:
+        """One stream's first token left the stream queue ``seconds``
+        after the engine thread put it there. Called on the CONSUMER's
+        thread (generate_stream), never on the tick."""
+        with self._lock:
+            self.streams += 1
+            self.first_deliver_s += seconds
+        SERVE_TTFT_BREAKDOWN_MS.observe(seconds * 1e3,
+                                        labels={"component": "deliver"})
 
     def record_chunk(self, tokens: int, live_steps: int,
                      elapsed_s: float) -> None:
@@ -162,8 +214,15 @@ class EngineMetrics:
         return vals[len(vals) // 2] if vals else 0.0
 
     def snapshot(self) -> Dict[str, Any]:
+        tick = {f"tick_{k}_s": v for k, v in self.tick_s.items()}
+        tick.update(tick_loop_s=self.tick_loop_s, ticks=self.ticks)
         with self._lock:
             return {
+                **tick,
+                "queue_wait_s": self.queue_wait_s,
+                "prefill_wait_s": self.prefill_wait_s,
+                "first_deliver_s": self.first_deliver_s,
+                "streams": self.streams,
                 "engine": self.name,
                 "requests": self.requests,
                 "tokens_generated": self.tokens_generated,
@@ -188,3 +247,108 @@ class EngineMetrics:
                 "ttft_ms_ewma": round((self._ewma_ttft_s or 0.0) * 1e3, 3),
                 "tpot_ms_p50": round(self._p50(self._tpots) * 1e3, 3),
             }
+
+
+class _Phase:
+    """One `with` block of a `TickClock`; yields its ``attrs``."""
+
+    __slots__ = ("_clock", "name", "attrs")
+
+    def __init__(self, clock: "TickClock", name: str, attrs: dict):
+        self._clock, self.name, self.attrs = clock, name, attrs
+
+    def __enter__(self) -> dict:
+        self._clock._open(self)
+        return self.attrs
+
+    def __exit__(self, *exc) -> None:
+        self._clock._close()
+
+
+class TickClock:
+    """The engine thread's time, cut into `TICK_PHASES`: one
+    `time.perf_counter()` read per phase boundary, two outputs.
+
+    Always: the seconds between two boundaries are added to
+    ``EngineMetrics.tick_s[phase]``. For a phase that begins while
+    `tracing.enabled()`: the same two stamps become one
+    ``engine.tick.<phase>`` span (parent: this engine's ``serve.engine``
+    root, made at the first traced boundary) and bracket a same-named
+    profiler annotation, so a device profile shows the phases on the
+    host plane with no alignment step.
+
+    The thread is in at most one phase at a time: a phase opened inside
+    another (a preemption that lands the chunk in flight during
+    ``admit``) suspends the outer one, which resumes as a new span when
+    the inner one closes — spans of one engine never overlap. ``now``
+    is the last boundary's stamp, for per-request spans that share it.
+    Engine-thread-only; ``annotation`` is `jax.profiler.TraceAnnotation`
+    (handed in: `util/tracing` and this module import no jax).
+    """
+
+    def __init__(self, metrics: EngineMetrics, annotation):
+        self._metrics = metrics
+        self._annotation = annotation
+        self._open_phases: list = []      # innermost last
+        # The running phase's span record and annotation; None while
+        # tracing is off (decided where the phase starts).
+        self._span: Optional[Dict[str, Any]] = None
+        self._twin = None
+        self._root: Optional[Dict[str, str]] = None
+        self.now = time.perf_counter()
+        self._lap_t = self.now
+
+    def phase(self, name: str, **attrs) -> _Phase:
+        return _Phase(self, name, attrs)
+
+    def lap(self) -> None:
+        """Top of a loop iteration: the loop's wall seconds so far."""
+        t = time.perf_counter()
+        self._metrics.tick_loop_s += t - self._lap_t
+        self._metrics.ticks += 1
+        self._lap_t = t
+
+    def _open(self, phase: _Phase) -> None:
+        t = time.perf_counter()
+        if self._open_phases:
+            self._stop(t)
+        self._open_phases.append(phase)
+        self._start(t)
+
+    def _close(self) -> None:
+        t = time.perf_counter()
+        self._stop(t)
+        self._open_phases.pop()
+        if self._open_phases:
+            self._start(t)
+
+    def _start(self, t: float) -> None:
+        self.now = t
+        if not _tracing.enabled():
+            return
+        wall = _tracing.wall(t)
+        name = "engine.tick." + self._open_phases[-1].name
+        if self._root is None:
+            # parent={}: a trace of its own, whatever span the thread
+            # that first ticks traced happens to be under.
+            self._root = _tracing.emit_span(
+                "serve.engine", wall, wall, parent={},
+                attrs={"engine": self._metrics.name})
+        self._span = _tracing.start_span(name, parent=self._root,
+                                         start=wall)
+        self._twin = self._annotation(name)
+        self._twin.__enter__()
+
+    def _stop(self, t: float) -> None:
+        phase, t0, self.now = self._open_phases[-1], self.now, t
+        self._metrics.tick_s[phase.name] += t - t0
+        if self._twin is not None:
+            self._twin.__exit__(None, None, None)
+            self._twin = None
+        if self._span is not None:
+            # A phase that BEGAN traced is recorded even if tracing has
+            # been switched off since: the last phase of a profiled
+            # stretch would otherwise leave its gap unnamed.
+            self._span["attrs"] = dict(phase.attrs)
+            _tracing.end_span(self._span, end=_tracing.wall(t))
+            self._span = None

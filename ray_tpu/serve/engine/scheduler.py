@@ -47,6 +47,10 @@ class EngineRequest:
     cached_len: int = 0    # prompt prefix served from the prefix cache
     arrival_t: float = 0.0
     first_token_t: float = 0.0
+    # When the engine thread put the first token on ``stream_queue``
+    # (0.0: not yet, or not streamed); generate_stream reads it on the
+    # consumer's thread for the serve front's share of TTFT.
+    first_put_t: float = 0.0
     # Speculative-decoding state (None when the engine runs spec-off):
     # the adaptive draft allowance + lifetime drafted/accepted counters
     # (drafter.SpecControl), attached by the engine at request creation.

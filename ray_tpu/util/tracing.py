@@ -40,10 +40,21 @@ _FLUSH_AT = 64
 # so their spans (e.g. pull-manager per-holder fetches) still reach the
 # head's trace ring.
 _sink: Optional[Callable[[list], None]] = None
+# Spans carry wall-clock seconds; hot paths stamp `time.perf_counter()`
+# (monotone, the clock their counters use) and convert with `wall`.
+# The offset is taken ONCE: every span of this process then shares one
+# mapping, so their order and lengths are the monotone clock's.
+_WALL_OFFSET = time.time() - time.perf_counter()
 
 
 def enabled() -> bool:
     return bool(cfg.tracing_enabled)
+
+
+def wall(perf_t: float) -> float:
+    """A `time.perf_counter()` stamp as wall-clock seconds (the unit of
+    a span's ``start``/``end``)."""
+    return perf_t + _WALL_OFFSET
 
 
 def current() -> Optional[Dict[str, str]]:
@@ -218,21 +229,26 @@ def emit_span(name: str, start: float, end: float,
 
 
 def start_span(name: str, parent: Optional[Dict[str, str]] = None,
-               attrs: Optional[Dict[str, Any]] = None
-               ) -> Optional[Dict[str, Any]]:
+               attrs: Optional[Dict[str, Any]] = None,
+               start: Optional[float] = None) -> Optional[Dict[str, Any]]:
     """Open a manually-managed span (no ContextVar): returns the record,
     finish it with ``end_span``. For request lifecycles that span
-    threads/event loops (the serve proxy)."""
+    threads/event loops (the serve proxy), and for spans that belong to
+    a traced stretch because they BEGAN in it (the engine's tick
+    phases: ``end_span`` records whatever tracing says by then).
+    ``start``/``end`` take the caller's own stamps (wall seconds)."""
     if not enabled():
         return None
-    return _new_rec(name, parent, attrs, time.time(), None, True)
+    return _new_rec(name, parent, attrs,
+                    time.time() if start is None else start, None, True)
 
 
-def end_span(rec: Optional[Dict[str, Any]], ok: bool = True) -> None:
+def end_span(rec: Optional[Dict[str, Any]], ok: bool = True,
+             end: Optional[float] = None) -> None:
     """Close + record a ``start_span`` record. None-safe (tracing off)."""
     if rec is None:
         return
-    rec["end"] = time.time()
+    rec["end"] = time.time() if end is None else end
     if not ok:
         rec["ok"] = False
     _record(rec)

@@ -85,6 +85,17 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _names_kernel(compiled, name: str, instruction: str = "") -> bool:
+    """The kernel's name in what the chip's compiler printed: always as
+    ``kernel_metadata`` (the text a device trace shows for the
+    operation), and as the instruction's own name where the kernel is
+    called directly (``instruction``; under the engine's ``vmap`` over
+    slots XLA names it for the batching loop's body instead)."""
+    text = "".join(compiled.as_text().split())
+    return (f'kernel_metadata={{"kernel":"{name}"}}' in text
+            and f"%{instruction or name}." in text)
+
+
 # ------------------------------------------------------------- kernels
 
 @pytest.mark.parametrize("geom", GEOMETRY)
@@ -95,6 +106,7 @@ def test_decode_attention_compiles(chip, geom):
         _sds(chip, (BATCH, h, hd)), _sds(chip, (BATCH, kh, SEQ, hd)),
         _sds(chip, (BATCH, kh, SEQ, hd)), _sds(chip, (BATCH,), jnp.int32))
     assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_decode_attention")
 
 
 @pytest.mark.parametrize("page", [16, 128])
@@ -108,22 +120,26 @@ def test_paged_decode_attention_compiles(chip, geom, page):
         _sds(chip, (BATCH, SEQ // page), jnp.int32),
         _sds(chip, (BATCH,), jnp.int32))
     assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_paged_decode_attention")
 
 
 @pytest.mark.parametrize("geom", GEOMETRY)
 def test_fused_rms_norm_kernels_compile(chip, geom):
     d = GEOMETRY[geom][3]
     x, s = _sds(chip, (BATCH, SEQ, d)), _sds(chip, (d,))
-    assert _kernel_calls(_compile(ops.fused_rms_norm, x, s)) == 1
-    assert _kernel_calls(
-        _compile(ops.fused_rms_norm_residual, x, x, s)) == 1
+    c = _compile(ops.fused_rms_norm, x, s)
+    assert _kernel_calls(c) == 1 and _names_kernel(c, "rtpu_fused_rms_norm")
+    c = _compile(ops.fused_rms_norm_residual, x, x, s)
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_fused_rms_norm_residual")
 
 
 @pytest.mark.parametrize("geom", GEOMETRY)
 def test_fused_swiglu_compiles(chip, geom):
     f = GEOMETRY[geom][4]
     g = _sds(chip, (BATCH, SEQ, f))
-    assert _kernel_calls(_compile(ops.fused_swiglu, g, g)) == 1
+    c = _compile(ops.fused_swiglu, g, g)
+    assert _kernel_calls(c) == 1 and _names_kernel(c, "rtpu_fused_swiglu")
 
 
 @pytest.mark.parametrize("shape", ROPE_SHAPES)
@@ -133,7 +149,8 @@ def test_fused_qk_rope_compiles_forward_and_backward(chip, geom, shape):
     b, s = ROPE_SHAPES[shape]
     q, k = _sds(chip, (b, s, h, hd)), _sds(chip, (b, s, kh, hd))
     pos = _sds(chip, (b, s), jnp.int32)
-    assert _kernel_calls(_compile(ops.fused_qk_rope, q, k, pos)) == 1
+    c = _compile(ops.fused_qk_rope, q, k, pos)
+    assert _kernel_calls(c) == 1 and _names_kernel(c, "rtpu_fused_qk_rope")
 
     def loss(q, k, pos):
         oq, ok = ops.fused_qk_rope(q, k, pos)
@@ -187,8 +204,15 @@ def test_engine_decode_chunk_compiles_at_llama3_1b(chip, paged):
         params, cache, _sds(chip, (BATCH, 1), jnp.int32), vec, vec, vec,
         _sds(chip, (BATCH,), jnp.bool_)).compile()
     # One kernel, in the scanned layer body (a program that does not
-    # fit the chip's 16 GB is refused by the compile itself).
+    # fit the chip's 16 GB is refused by the compile itself). The
+    # engine vmaps the step over slots: the contiguous kernel, whose
+    # lengths are a scalar-prefetch argument, is then batched by a loop
+    # and its instruction named for the loop's body; its own name stays
+    # in its kernel_metadata, which is what a device trace shows.
     assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_paged_decode_attention" if paged
+                         else "rtpu_decode_attention",
+                         instruction="closed_call")
 
 
 def test_engine_prefill_bucket_compiles_at_llama3_1b(chip):

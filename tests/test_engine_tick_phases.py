@@ -1,0 +1,293 @@
+"""The engine's tick, timed from inside: always-on phase counters, the
+TTFT that decomposes, and — while tracing is on — one
+``engine.tick.<phase>`` span per phase per tick on the counters' clock.
+All on the CPU's tiny engine; times here prove bookkeeping, not speed.
+"""
+
+from __future__ import annotations
+
+import ast
+import pathlib
+import time
+
+import pytest
+
+from ray_tpu.core.config import GLOBAL_CONFIG as cfg
+from ray_tpu.serve.engine import metrics as engine_metrics
+from ray_tpu.serve.engine.metrics import TICK_PHASES, EngineMetrics, TickClock
+from ray_tpu.util import tracing
+
+ENGINE_KW = {"max_batch": 2, "max_len": 64, "prompt_buckets": [8, 16],
+             "decode_chunk": 2}
+TICK_NAMES = {f"engine.tick.{p}" for p in TICK_PHASES}
+PHASE_KEYS = [f"tick_{p}_s" for p in TICK_PHASES]
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
+@pytest.fixture
+def engine():
+    from ray_tpu.serve.llm import LLMEngine
+
+    old = cfg.get("tracing_enabled")
+    cfg.set("tracing_enabled", False)
+    eng = LLMEngine(**ENGINE_KW)
+    yield eng
+    eng.close()
+    cfg.set("tracing_enabled", old)
+    tracing.set_sink(None)
+
+
+@pytest.fixture
+def sink():
+    """Spans of a traced stretch, through the benchmark's own path
+    (`set_sink`, switched on AFTER the engine exists)."""
+    spans = []
+    tracing.flush()
+    tracing.set_sink(spans.extend)
+    cfg.set("tracing_enabled", True)
+    yield spans
+    cfg.set("tracing_enabled", False)
+    tracing.set_sink(None)
+
+
+def _collect(spans):
+    cfg.set("tracing_enabled", False)
+    tracing.flush()
+    return [s for s in spans if s["end"] is not None]
+
+
+def test_phase_counters_are_monotone_and_account_for_the_loop(engine):
+    seen = [engine.stats()]
+    for n in (3, 5, 7):
+        engine.generate(list(range(1, n + 1)), max_new_tokens=6)
+        seen.append(engine.stats())
+    for a, b in zip(seen, seen[1:]):
+        for key in PHASE_KEYS + ["tick_loop_s", "ticks"]:
+            assert b[key] >= a[key], key
+    last = seen[-1]
+    assert last["ticks"] > seen[0]["ticks"]
+    for key in ("tick_admit_s", "tick_prefill_dispatch_s",
+                "tick_prefill_fetch_s", "tick_prefill_deliver_s",
+                "tick_decode_dispatch_s", "tick_decode_fetch_s",
+                "tick_decode_deliver_s"):
+        assert last[key] > 0.0, key
+    assert last["tick_install_s"] == 0.0      # colocated role
+    # The phases and the loop's wall seconds agree: what is left is
+    # loop overhead (and the iteration under way at the snapshot).
+    phases = sum(last[k] for k in PHASE_KEYS)
+    assert phases == pytest.approx(last["tick_loop_s"], rel=0.10)
+
+
+def test_idle_is_a_phase_of_its_own(engine):
+    before = engine.stats()
+    time.sleep(0.35)                # empty roster: blocked on the mailbox
+    after = engine.stats()
+    assert after["tick_idle_s"] - before["tick_idle_s"] >= 0.2
+    assert after["tick_decode_fetch_s"] == before["tick_decode_fetch_s"]
+
+
+def test_ttft_is_the_sum_of_its_two_waits(engine):
+    given = []
+    record = engine.metrics.record_admit
+
+    def spy(queue_s, prefill_s, *rest):
+        given.append((queue_s, prefill_s))
+        return record(queue_s, prefill_s, *rest)
+
+    engine.metrics.record_admit = spy
+    for n in (2, 4, 6, 8):
+        engine.generate(list(range(1, n + 1)), max_new_tokens=3)
+    s = engine.stats()
+    assert s["requests"] == len(given) == 4
+    assert all(q >= 0.0 and p > 0.0 for q, p in given)
+    assert s["queue_wait_s"] == pytest.approx(sum(q for q, _ in given),
+                                              rel=1e-12)
+    assert s["prefill_wait_s"] == pytest.approx(sum(p for _, p in given),
+                                                rel=1e-12)
+    # ... and the TTFT the engine recorded is exactly their sum.
+    assert sum(engine.metrics._ttfts) == pytest.approx(
+        s["queue_wait_s"] + s["prefill_wait_s"], rel=1e-12)
+
+
+def test_first_deliver_moves_once_per_streamed_request(engine):
+    engine.generate([1, 2, 3], max_new_tokens=4)       # not streamed
+    assert engine.stats()["streams"] == 0
+    for i in range(3):
+        toks = list(engine.generate_stream([1, 2, 3, 4 + i],
+                                           max_new_tokens=5))
+        assert len(toks) == 5
+        s = engine.stats()
+        assert s["streams"] == i + 1
+        assert 0.0 < s["first_deliver_s"] < 5.0
+    samples = engine_metrics.SERVE_TTFT_BREAKDOWN_MS
+    assert {"queue", "prefill", "deliver"} <= {
+        dict(k).get("component") for k in samples._counts}
+
+
+def test_traced_ticks_emit_only_phase_names_disjoint_under_one_root(
+        engine, sink):
+    # No request here is made under a trace: tick spans do not depend
+    # on any request of the roster being traced.
+    for n in (3, 9):
+        engine.generate(list(range(1, n + 1)), max_new_tokens=6)
+    time.sleep(0.25)
+    spans = _collect(sink)
+    ticks = [s for s in spans if s["name"].startswith("engine.tick.")]
+    assert {s["name"] for s in ticks} <= TICK_NAMES
+    assert {"engine.tick.admit", "engine.tick.prefill_dispatch",
+            "engine.tick.prefill_fetch", "engine.tick.prefill_deliver",
+            "engine.tick.decode_dispatch", "engine.tick.decode_fetch",
+            "engine.tick.decode_deliver", "engine.tick.idle"} <= {
+                s["name"] for s in ticks}
+    # Nothing else of the engine's: no request carried a trace context.
+    assert {s["name"] for s in spans} <= TICK_NAMES | {"serve.engine"}
+    roots = [s for s in spans if s["name"] == "serve.engine"]
+    assert len(roots) == 1 and roots[0]["parent_id"] == ""
+    assert roots[0]["attrs"]["engine"] == engine.metrics.name
+    for s in ticks:
+        assert s["parent_id"] == roots[0]["span_id"]
+        assert s["trace_id"] == roots[0]["trace_id"]
+    ticks.sort(key=lambda s: s["start"])
+    for a, b in zip(ticks, ticks[1:]):
+        assert a["end"] <= b["start"], (a["name"], b["name"])
+    fetched = [s for s in ticks if s["name"] == "engine.tick.prefill_fetch"]
+    assert all(s["attrs"]["bytes"] > 0 and s["attrs"]["bucket"] in (8, 16)
+               for s in fetched)
+
+
+def test_request_spans_share_the_tick_clock(engine, sink):
+    """`engine.queued` carries the request's real stamps: it starts at
+    arrival, inside the caller's root span, and ends where the first
+    `engine.tick.prefill_dispatch` of that request starts."""
+    with tracing.trace("client") as root:
+        engine.generate([5, 6, 7, 8], max_new_tokens=4)
+    spans = _collect(sink)
+    mine = [s for s in spans if s["trace_id"] == root.trace_id]
+    queued = next(s for s in mine if s["name"] == "engine.queued")
+    prefill = next(s for s in mine if s["name"] == "engine.prefill")
+    client = next(s for s in mine if s["name"] == "client")
+    assert client["start"] - 1e-3 <= queued["start"] <= queued["end"]
+    assert queued["end"] == prefill["start"]
+    dispatch = [s for s in spans
+                if s["name"] == "engine.tick.prefill_dispatch"]
+    assert prefill["start"] in {s["start"] for s in dispatch}
+    chunks = [s for s in mine if s["name"] == "engine.decode_chunk"]
+    fetch_ends = {s["end"] for s in spans
+                  if s["name"] == "engine.tick.decode_fetch"}
+    assert chunks and all(c["end"] in fetch_ends for c in chunks)
+
+
+def test_tracing_off_ticks_are_span_free_and_sync_budget_unchanged(engine):
+    from ray_tpu.util.tracing import _buffer
+
+    before = len(_buffer)
+    out = engine.generate([1, 2, 3, 4], max_new_tokens=6)
+    time.sleep(0.15)                           # an idle tick or two
+    assert out["num_generated"] == 6
+    assert len(_buffer) == before
+    assert engine._tick._root is None          # no root until traced
+    # 1 prefill sync + ceil(5/2) decode-chunk syncs, as before this PR.
+    assert engine.stats()["decode_host_syncs"] == 3
+
+
+# ------------------------------------------------------ the clock alone
+
+class _Annotation:
+    """Stands in for `jax.profiler.TraceAnnotation`."""
+
+    live = []
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        assert not _Annotation.live, "annotations overlap"
+        _Annotation.live.append(self.name)
+
+    def __exit__(self, *exc):
+        _Annotation.log.append(_Annotation.live.pop())
+
+
+def test_a_phase_inside_a_phase_suspends_the_outer_one(sink):
+    """A preemption lands the chunk in flight during `admit`: the
+    fetch's time is not the admission's, and the spans stay disjoint."""
+    _Annotation.log.clear()
+    m = EngineMetrics("clock-test")
+    clock = TickClock(m, _Annotation)
+    clock.lap()
+    with clock.phase("admit"):
+        time.sleep(0.01)
+        with clock.phase("decode_fetch", slots=2) as attrs:
+            time.sleep(0.03)
+            attrs["bytes"] = 24
+        time.sleep(0.01)
+    clock.lap()
+    assert 0.02 <= m.tick_s["admit"] < 0.03 <= m.tick_s["decode_fetch"]
+    assert sum(m.tick_s.values()) == pytest.approx(m.tick_loop_s, rel=0.05)
+    assert m.ticks == 2
+    spans = [s for s in _collect(sink) if s["name"] != "serve.engine"]
+    assert [s["name"] for s in spans] == [
+        "engine.tick.admit", "engine.tick.decode_fetch", "engine.tick.admit"]
+    assert spans[1]["attrs"] == {"slots": 2, "bytes": 24}
+    for a, b in zip(spans, spans[1:]):
+        assert a["end"] == b["start"]          # one stamp per boundary
+    assert _Annotation.log == [s["name"] for s in spans]
+    assert not _Annotation.live
+
+
+def test_a_phase_that_began_traced_is_recorded_after_tracing_goes_off(sink):
+    """The harness switches tracing off at the end of its profiled
+    stretch, in the middle of some phase: that phase still names its
+    gap. One that begins afterwards emits nothing."""
+    _Annotation.log.clear()
+    clock = TickClock(EngineMetrics("edge"), _Annotation)
+    with clock.phase("prefill_fetch", bucket=8):
+        cfg.set("tracing_enabled", False)
+    with clock.phase("decode_fetch"):
+        pass
+    tracing.flush()
+    assert [s["name"] for s in sink] == ["serve.engine",
+                                         "engine.tick.prefill_fetch"]
+    assert sink[1]["end"] > sink[1]["start"]
+    assert _Annotation.log == ["engine.tick.prefill_fetch"]
+
+
+def test_snapshot_carries_flat_keys_a_counter_delta_can_subtract():
+    snap = EngineMetrics("flat").snapshot()
+    for key in PHASE_KEYS + ["tick_loop_s", "queue_wait_s", "prefill_wait_s",
+                             "first_deliver_s"]:
+        assert snap[key] == 0.0 and isinstance(snap[key], float)
+    assert snap["ticks"] == 0 and snap["streams"] == 0
+
+
+def test_ttft_breakdown_boundaries_resolve_a_loaded_replica():
+    bounds = engine_metrics.SERVE_TTFT_BREAKDOWN_MS.boundaries
+    inside = [b for b in bounds if 50 <= b <= 1500]
+    assert len(inside) >= 10 and list(bounds) == sorted(bounds)
+
+
+def test_wall_is_one_offset_for_every_stamp():
+    a = time.perf_counter()
+    # (wall seconds near 2e9 resolve a quarter of a microsecond)
+    assert tracing.wall(a + 2.5) - tracing.wall(a) == pytest.approx(
+        2.5, abs=1e-6)
+    assert abs(tracing.wall(time.perf_counter()) - time.time()) < 0.5
+
+
+def _imports(path):
+    tree = ast.parse((ROOT / path).read_text())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            yield node.module or ""
+
+
+def test_util_tracing_stays_free_of_jax_and_the_tick_of_the_wall_clock():
+    for path in ("ray_tpu/util/tracing.py",
+                 "ray_tpu/serve/engine/metrics.py"):
+        assert not [m for m in _imports(path)
+                    if m == "jax" or m.startswith("jax.")], path
+    assert "time.time()" not in (
+        ROOT / "ray_tpu/serve/engine/core.py").read_text()
