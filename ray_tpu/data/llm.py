@@ -6,7 +6,7 @@ python/ray/llm/_internal/batch/processor/ — stage pipelines of
 preprocess -> tokenize -> generate -> postprocess running over Ray Data
 with stateful engine actors). TPU-first: the generate stage hosts this
 framework's native continuous-batching LLMEngine (serve/llm.py — slot
-pool, bucketed prefill, vmapped decode) in a Data actor pool, so batch
+pool, bucketed prefill, batched in-place decode) in a Data actor pool, so batch
 inference and online serving share one engine implementation.
 
     processor = build_llm_processor(

@@ -258,7 +258,8 @@ JAX_HOT_PATH_ROOTS: dict[str, set[str]] = {
 #: a jnp op) — used by the hot-path rule to track which locals are
 #: device values; syncing one of them is a finding.
 DEVICE_PRODUCER_SUFFIXES = {
-    "decode_chunk", "verify_chunk", "prefill", "decode_step",
+    "decode_chunk", "verify_chunk", "prefill", "prefill_inplace",
+    "decode_step",
 }
 DEVICE_PRODUCER_PREFIXES = ("jnp.", "jax.numpy.")
 

@@ -31,11 +31,14 @@ from jax.sharding import Mesh
 from ray_tpu.ops import (
     apply_rope,
     causal_attention,
+    decode_attention,
+    decode_attention_reference,
     full_causal_attention,
     fused_qk_rope,
     fused_rms_norm,
     fused_rms_norm_residual,
     fused_swiglu,
+    paged_decode_attention,
     ring_attention,
     rms_norm,
 )
@@ -483,3 +486,127 @@ def forward_with_cache(params: Params, tokens: jnp.ndarray,
     x = _norm(x, params["ln_out"], cfg)
     logits = _head_matmul(x, params, cfg)
     return logits, {"k": new_k, "v": new_v}
+
+
+def _write_rows(cache, layer_idx, lengths, rows):
+    """rows [B,KH,D] -> cache[layer_idx, b, :, lengths[b], :] of the
+    [L,B,KH,S,D] cache, in place under a loop that carries it.
+
+    A scatter of D-wide rows into the cache seen as [L*B*KH, S, D] (a
+    free view): that is the form the chip's compiler updates in place
+    in the cache's own layout. Scattered as [KH,D] windows of the 5-D
+    array it re-lays the WHOLE cache out, KH inside S, and back around
+    every step; one dynamic_update_slice a slot stays in place but
+    costs 64 small operations a layer (measured, PERF.md PR 26).
+    ``lengths`` is bounded BY CONTRACT like ``_block``'s cache_index:
+    the engine parks a done or empty slot's write on a row of its own
+    that nothing reads (decode_loop's header), under the cache's
+    extent."""
+    n_layers, b, kh, s, d = cache.shape
+    heads = layer_idx * (b * kh) + jnp.arange(b * kh, dtype=jnp.int32)
+    flat = cache.reshape(n_layers * b * kh, s, d)
+    flat = flat.at[heads, jnp.repeat(lengths.astype(jnp.int32), kh)].set(
+        rows.reshape(b * kh, d).astype(cache.dtype),
+        unique_indices=True, indices_are_sorted=True)
+    return flat.reshape(cache.shape)
+
+
+def _decode_block(x, layer, layer_idx, cache_k, cache_v, lengths,
+                  cfg: LlamaConfig):
+    """One transformer block of the decode step: x [B,1,D], one token a
+    slot, slot b's at position ``lengths[b]``. The whole [L,B,KH,S,D]
+    cache comes in and goes out: slot b's new K and V row is written at
+    ``[layer_idx, b, :, lengths[b]]`` and nothing else of it is touched,
+    so under a loop that carries the cache the update happens in place.
+    ``_block`` is the same arithmetic for [B,T] tokens at ONE
+    ``cache_index`` with a functional cache (prefill, training); the two
+    share the helpers and no cache logic."""
+    fused = bool(cfg.fused_ops)
+    interp = cfg.fused_ops == "interpret"
+    positions = lengths[:, None]
+    h = _norm(x, layer["ln_attn"], cfg)
+    q = _wdot("bsd,dhk->bshk", h, layer["wq"])
+    k = _wdot("bsd,dhk->bshk", h, layer["wk"])
+    v = _wdot("bsd,dhk->bshk", h, layer["wv"])
+    if fused:
+        q, k = fused_qk_rope(q, k, positions, cfg.rope_theta,
+                             interpret=interp)
+    else:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    cache_k = _write_rows(cache_k, layer_idx, lengths, k[:, 0])
+    cache_v = _write_rows(cache_v, layer_idx, lengths, v[:, 0])
+    n_layers, b, kh, s, d = cache_k.shape
+    seen = (lengths + 1).astype(jnp.int32)
+    if cfg.paged_decode:
+        # The cache read IN PLACE as a pool of decode_page-row pages:
+        # layers and slots are both leading axes of the pool, so the
+        # block table picks the layer as it picks the slot (slot-
+        # identity within the layer, as kv_manager keeps prefixes
+        # slot-affine; the indirection is the seam for cross-slot
+        # paging).
+        np_row = s // cfg.decode_page
+        table = layer_idx * (b * np_row) + jnp.arange(
+            b * np_row, dtype=jnp.int32).reshape(b, np_row)
+        attn = paged_decode_attention(
+            q[:, 0], cache_k.reshape(n_layers * b, kh, s, d),
+            cache_v.reshape(n_layers * b, kh, s, d), table, seen,
+            page_size=cfg.decode_page,
+            interpret=cfg.paged_decode == "interpret")
+    elif cfg.use_decode_kernel:
+        # ONE kernel call for all slots, each masked at its own length;
+        # the kernel finds the layer's blocks in the whole cache
+        # (ops/decode_attention.py), its jnp reference off the TPU.
+        attn = decode_attention(
+            q[:, 0], cache_k, cache_v, seen, layer=layer_idx,
+            layout="bksd", block_s=min(2048, s),
+            interpret=cfg.use_decode_kernel == "interpret")
+    else:
+        def of_layer(cache):  # [B,S,KH,D], the reference's layout
+            return lax.dynamic_index_in_dim(
+                cache, layer_idx, 0, keepdims=False).swapaxes(1, 2)
+
+        attn = decode_attention_reference(q[:, 0], of_layer(cache_k),
+                                          of_layer(cache_v), seen)
+    attn_out = _wdot("bshk,hkd->bsd", attn[:, None],
+                     layer["wo"]).astype(x.dtype)
+    if fused:
+        h, x = fused_rms_norm_residual(attn_out, x, layer["ln_mlp"],
+                                       cfg.norm_eps, interpret=interp)
+    else:
+        x = x + attn_out
+        h = rms_norm(x, layer["ln_mlp"], cfg.norm_eps)
+    gate = _wdot("bsd,df->bsf", h, layer["w_gate"])
+    up = _wdot("bsd,df->bsf", h, layer["w_up"])
+    ff = fused_swiglu(gate, up, interpret=interp) if fused \
+        else jax.nn.silu(gate) * up
+    x = x + _wdot("bsf,fd->bsd", ff, layer["w_down"]).astype(x.dtype)
+    return x, cache_k, cache_v
+
+
+def decode_step_with_cache(params: Params, tokens: jnp.ndarray,
+                           cache: Dict[str, jnp.ndarray],
+                           lengths: jnp.ndarray, cfg: LlamaConfig
+                           ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """One decode step for every slot of the engine's cache at once.
+
+    tokens [B, 1] (slot b's last token, at position ``lengths[b]``),
+    lengths [B] -> (logits [B, V] for the next token, the cache with one
+    new row a layer a slot). The layers are a loop that CARRIES the
+    cache (``forward_with_cache`` scans it in and stacks it out, which
+    builds a new array): jitted with the cache donated, the step
+    rewrites ``2 * L * B`` rows of it and copies none."""
+
+    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+
+    def body(carry, layer_and_idx):
+        x, ck, cv = carry
+        layer, layer_idx = layer_and_idx
+        return _decode_block(x, layer, layer_idx, ck, cv, lengths,
+                             cfg), None
+
+    (x, ck, cv), _ = lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    x = _norm(x, params["ln_out"], cfg)
+    return _head_matmul(x, params, cfg)[:, 0], {"k": ck, "v": cv}

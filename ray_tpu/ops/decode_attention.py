@@ -11,10 +11,15 @@ VMEM scratch (the canonical flash pattern from the Pallas guide:
 sequential innermost grid dimension + revisited output block).
 
 Layout (grid = (B, KH, S/block_s), innermost sequential on one core):
-  q    [B, KH, G, D]   one block (1,1,G,D) per (b,kh)
-  k,v  [B, KH, S, D]   one block (1,1,block_s,D) per (b,kh,s)
-  len  [B]             int32, SMEM scalar-prefetch (masks cache tail)
-  out  [B, KH, G, D]   written on the LAST s-block
+  q      [B, KH, G, D]     one block (1,1,G,D) per (b,kh)
+  k,v    [L, B, KH, S, D]  one block (1,1,1,block_s,D) per (b,kh,s) of
+                           layer ``layer`` — the engine's WHOLE cache is
+                           the operand and the layer index picks the
+                           blocks, so no layer is sliced out of it first
+                           (a [B,KH,S,D] cache is the L == 1 case)
+  len    [B]               int32, SMEM scalar-prefetch (masks cache tail)
+  layer  [1]               int32, SMEM scalar-prefetch
+  out    [B, KH, G, D]     written on the LAST s-block
 
 Falls back to a pure-jnp reference implementation off-TPU (and under
 ``interpret=True`` for the CPU test suite, which checks the kernel against
@@ -52,7 +57,7 @@ def decode_attention_reference(q, k, v, lengths):
     return out.reshape(b, h, d)
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, block_s: int, scale: float):
     import jax.experimental.pallas as pl
 
@@ -71,8 +76,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     # preferred_element_type, and the f32 upcasts cost ~1.8x end-to-end
     # (measured 1563us -> 873us on v5e at B8/H32/KH8/S4096/D128).
     q = q_ref[0, 0]                              # [G, D]
-    k = k_ref[0, 0]                              # [block_s, D]
-    v = v_ref[0, 0]
+    k = k_ref[0, 0, 0]                           # [block_s, D]
+    v = v_ref[0, 0, 0]
     length = len_ref[b]
 
     logits = jax.lax.dot_general(
@@ -105,7 +110,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("block_s", "interpret", "layout"))
-def decode_attention(q, k, v, lengths, *, block_s: int = 2048,
+def decode_attention(q, k, v, lengths, *, layer=None,
+                     block_s: int = 2048,
                      interpret: Optional[bool] = None,
                      layout: str = "bskd"):
     """q [B,H,D], lengths [B] int32 -> [B,H,D]. Uses the Pallas kernel on
@@ -114,11 +120,24 @@ def decode_attention(q, k, v, lengths, *, block_s: int = 2048,
     ``layout`` names the cache layout: "bskd" = [B,S,KH,D] (the training
     convention; transposed on entry — a full HBM round trip) or "bksd" =
     [B,KH,S,D] (the engine-native layout this kernel streams directly —
-    store the cache this way for decode-bound serving)."""
+    store the cache this way for decode-bound serving).
+
+    ``layer`` (a traced int32 scalar): k and v are the engine's whole
+    [L,B,KH,S,D] cache and the query attends to that layer of it. The
+    kernel then reads the layer's blocks where they lie; only a cache
+    whose rows ``block_s`` does not divide is sliced (and padded)
+    first, as is the reference's."""
     on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
         interpret = False
-    if not on_tpu and not interpret:
+    kernel = on_tpu or interpret
+    if layer is not None and layout != "bksd":
+        raise ValueError("a layered cache is [L,B,KH,S,D]: layout 'bksd'")
+    if layer is not None and not (kernel and k.shape[3] % block_s == 0):
+        k = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
+        v = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
+        layer = None
+    if not kernel:
         if layout == "bksd":
             k = k.transpose(0, 2, 1, 3)
             v = v.transpose(0, 2, 1, 3)
@@ -128,32 +147,34 @@ def decode_attention(q, k, v, lengths, *, block_s: int = 2048,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    if layout == "bskd":
-        kk = k.transpose(0, 2, 1, 3)  # [B,KH,S,D]
-        vv = v.transpose(0, 2, 1, 3)
-    else:
-        kk, vv = k, v
-    kh, s = kk.shape[1], kk.shape[2]
+    if layer is None:
+        if layout == "bskd":
+            k = k.transpose(0, 2, 1, 3)  # [B,KH,S,D]
+            v = v.transpose(0, 2, 1, 3)
+        if k.shape[2] % block_s:
+            pad = block_s - k.shape[2] % block_s
+            k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        k, v, layer = k[None], v[None], 0    # [1,B,KH,S,D]: layer 0 of one
+    kh, s = k.shape[2], k.shape[3]
     rep = h // kh
-    if s % block_s:
-        pad = block_s - s % block_s
-        kk = jnp.pad(kk, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        vv = jnp.pad(vv, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        s += pad
     qg = q.reshape(b, kh, rep, d)
 
+    def _kv_index(bi, ki, si, lens, layer):
+        return layer[0], bi, ki, si, 0
+
+    def _q_index(bi, ki, si, lens, layer):
+        return bi, ki, 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, kh, s // block_s),
         in_specs=[
-            pl.BlockSpec((1, 1, rep, d), lambda bi, ki, si, lens: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, 1, block_s, d),
-                         lambda bi, ki, si, lens: (bi, ki, si, 0)),
-            pl.BlockSpec((1, 1, block_s, d),
-                         lambda bi, ki, si, lens: (bi, ki, si, 0)),
+            pl.BlockSpec((1, 1, rep, d), _q_index),
+            pl.BlockSpec((1, 1, 1, block_s, d), _kv_index),
+            pl.BlockSpec((1, 1, 1, block_s, d), _kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, d),
-                               lambda bi, ki, si, lens: (bi, ki, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, rep, d), _q_index),
         scratch_shapes=[
             pltpu.VMEM((rep, 1), jnp.float32),   # running max
             pltpu.VMEM((rep, 1), jnp.float32),   # running denom
@@ -168,5 +189,6 @@ def decode_attention(q, k, v, lengths, *, block_s: int = 2048,
         interpret=interpret,
         name="rtpu_decode_attention",
         metadata={"kernel": "rtpu_decode_attention"},
-    )(lengths.astype(jnp.int32), qg, kk, vv)
+    )(lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k, v)
     return out.reshape(b, h, d)

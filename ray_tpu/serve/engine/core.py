@@ -222,7 +222,13 @@ class InferenceEngine:
             # fleet spill/pull tier moves the same pages, so a
             # fleet-enabled colocated engine pads identically.
             cache_rows = -(-cache_rows // prefix_block) * prefix_block
+        # ONE cache buffer for the engine's life: every tick program
+        # takes it donated and hands it back aliased (decode_loop's
+        # header), so ``self.cache`` is rebound at each dispatch and a
+        # donated program that raises costs the buffer (_recover_cache).
+        self._cache_rows = cache_rows
         self.cache = llama.init_kv_cache(self.cfg, max_batch, cache_rows)
+        self._cache_rebuilds = 0
 
         self.kv = KVCacheManager(max_batch, self.max_len,
                                  block_size=prefix_block)
@@ -438,7 +444,8 @@ class InferenceEngine:
                            + self.scheduler.queue_depth()),
                "parked": len(self._parked),
                "preempts": self._preempts,
-               "resumes": self._resumes}
+               "resumes": self._resumes,
+               "cache_rebuilds": self._cache_rebuilds}
         if self.quantize is not None:
             out["weight_bytes"], out["weight_bytes_f32"] = \
                 self._weight_bytes
@@ -589,11 +596,16 @@ class InferenceEngine:
                     and adm.cached_len < len(adm.request.prompt_ids) - 1):
                 try:
                     self._fleet_extend(adm)
-                except Exception:  # rtpu-lint: disable=swallowed-exception — a failed pull is a skipped optimization; recompute covers it
+                except Exception as e:  # noqa: BLE001 — a failed pull is a skipped optimization; recompute covers it
                     # A failed pull/install is a skipped optimization:
                     # rows it may have touched sit past cached_len and
-                    # the suffix prefill overwrites them.
-                    pass
+                    # the suffix prefill overwrites them — unless the
+                    # donated install took the cache with it, and with
+                    # it the reused prefix this admission counts on.
+                    if self._recover_cache(e):
+                        self.scheduler.abort_admission(adm.request)
+                        self._deliver_error([adm.request], e)
+                        continue
             self._prefilling.append(_PrefillJob(adm, pos=adm.cached_len))
 
     # -------------------------------------------- priority preemption
@@ -1009,7 +1021,9 @@ class InferenceEngine:
         their device execution, which is what keeps co-batched TPOT
         flat while a long prompt materializes."""
         for job in list(self._prefilling):
-            if self._advance_prefill(job):
+            if job not in self._prefilling:
+                continue  # failed with the cache an earlier job lost
+            if self._advance_prefill(job) and job in self._prefilling:
                 self._prefilling.remove(job)
 
     def _advance_prefill(self, job: "_PrefillJob") -> bool:
@@ -1028,7 +1042,7 @@ class InferenceEngine:
                 suffix = req.prompt_ids[job.pos:job.pos + n]
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :n] = suffix
-                logits, self.cache = self.loop.prefill(
+                logits, self.cache = self.loop.prefill_inplace(
                     self.params, self.cache, self._put(padded),
                     self._put(np.int32(slot)),
                     self._put(np.int32(job.pos)))
@@ -1053,10 +1067,8 @@ class InferenceEngine:
             # reused prefix: rows this job dispatched are unconfirmed.
             self.scheduler.abort_admission(
                 req, resident=req.prompt_ids[:cached])
-            if not req.future.done():
-                req.future.set_exception(e)
-            if req.stream_queue is not None:
-                req.stream_queue.put(("error", e))
+            self._recover_cache(e)
+            self._deliver_error([req], e)
             return True
         t1 = self._tick.now  # the chunk is dispatched (and, final, fetched)
         if req.trace_ctx is not None:
@@ -1179,10 +1191,8 @@ class InferenceEngine:
                 self._install_one(req, payload)
             except BaseException as e:  # noqa: BLE001 — one bad handoff
                 # must not kill the engine thread
-                if not req.future.done():
-                    req.future.set_exception(e)
-                if req.stream_queue is not None:
-                    req.stream_queue.put(("error", e))
+                self._recover_cache(e)
+                self._deliver_error([req], e)
 
     def _install_one(self, req: EngineRequest,
                      payload: Dict[str, Any]) -> None:
@@ -1277,12 +1287,52 @@ class InferenceEngine:
             done[req.slot] = False
         return tokens, lengths, remaining, eos_ids, done
 
-    def _fail_roster(self, e: BaseException) -> None:
-        for req in self.scheduler.fail_active():
+    @staticmethod
+    def _deliver_error(reqs, e: BaseException) -> None:
+        for req in reqs:
             if not req.future.done():
                 req.future.set_exception(e)
             if req.stream_queue is not None:
                 req.stream_queue.put(("error", e))
+
+    def _fail_roster(self, e: BaseException) -> None:
+        failed = self.scheduler.fail_active()
+        self._recover_cache(e)
+        self._deliver_error(failed, e)
+
+    def _recover_cache(self, e: BaseException) -> bool:
+        """Every handler of a failed cache-writing dispatch (or of the
+        fetch of its results) ends here. The programs take the cache
+        DONATED: one that raised has deleted ``self.cache``, one that
+        failed on the device has left a result that raises when read,
+        and either way every later request would die on it. So if the
+        cache cannot be waited for (a failure path may sync), allocate
+        a new one and fail whoever had rows in the old: the roster,
+        the prefills under way, and — in the KV manager — every
+        resident prefix (a parked session resumes by prefilling again).
+        Waiting requests are untouched. True if the cache was rebuilt."""
+        try:
+            self._jax.block_until_ready(self.cache)
+            return False
+        except Exception:  # rtpu-lint: disable=swallowed-exception — the probe's failure IS the signal; ``e`` is what the callers deliver
+            pass
+        from ray_tpu.models import llama
+
+        # State first, errors last: whoever sees an error delivered
+        # here sees the rebuilt engine behind it.
+        self._inflight = None
+        lost = self.scheduler.fail_active()
+        for job in self._prefilling:
+            if job.adm.request.slot >= 0:  # the failing job's is released
+                self.scheduler.abort_admission(job.adm.request)
+                lost.append(job.adm.request)
+        self._prefilling = []
+        self.kv.forget_resident()
+        self.cache = llama.init_kv_cache(self.cfg, self.max_batch,
+                                         self._cache_rows)
+        self._cache_rebuilds += 1
+        self._deliver_error(lost, e)
+        return True
 
     def _decode_tick(self) -> None:
         """One device chunk for the whole roster + ONE host fetch.

@@ -18,10 +18,15 @@ and ``n_valid`` reports how many of the chunk's tokens were live, so the
 EOS overshoot the old engine paid (up to K-1 wasted host tokens per
 request) is discarded exactly.
 
-Single-token attention inside the step dispatches through
-``models/llama.forward_with_cache`` to the Pallas decode-attention
-kernel (ops/decode_attention.py) on TPU; off-TPU the same code runs the
-masked-attention reference path, so CPU tests cover the identical loop.
+The step is ``models/llama.decode_step_with_cache``: all slots in one
+batch, each slot's new K/V row written at its own length, one call a
+layer to the Pallas decode-attention kernel (ops/decode_attention.py)
+on TPU; off-TPU the same code runs the kernel's jnp reference, so CPU
+tests cover the identical loop. The engine's cache is ONE buffer: the
+chunk, verify, install and tick-prefill programs take it donated and
+return it aliased, so the caller must rebind its reference to the
+result (``self.cache = ...``) and must rebuild the cache if a donated
+call raises — the old array is deleted either way.
 
 Speculative verification (``spec_window`` > 1) adds a SECOND chunk
 program, ``verify_chunk``: each scan iteration forwards a ``[B, W]``
@@ -92,6 +97,9 @@ class DecodeLoop:
         self.prefill = jax_debug.wrap_jit(
             self.prefill, "decode_loop.prefill",
             budget=self.prefill_budget or None)
+        self.prefill_inplace = jax_debug.wrap_jit(
+            self.prefill_inplace, "decode_loop.prefill_inplace",
+            budget=self.prefill_budget or None)
         self.decode_chunk = jax_debug.wrap_jit(
             self.decode_chunk, "decode_loop.decode_chunk", budget=1)
         self.decode_step = jax_debug.wrap_jit(
@@ -111,8 +119,9 @@ class DecodeLoop:
         from ray_tpu.devtools.jax_debug import JitWitness
 
         out = {}
-        for name in ("prefill", "decode_chunk", "decode_step",
-                     "verify_chunk", "export_page", "install_page"):
+        for name in ("prefill", "prefill_inplace", "decode_chunk",
+                     "decode_step", "verify_chunk", "export_page",
+                     "install_page"):
             fn = getattr(self, name, None)
             if isinstance(fn, JitWitness):
                 out[name] = fn.program_count
@@ -151,24 +160,22 @@ class DecodeLoop:
                 cache[k], new_row[k], slot, axis=1) for k in cache}
             return logits, cache
 
+        # Two programs of the one function. ``prefill`` is functional:
+        # the caller's cache lives on and a new one comes back, which
+        # is what a check that prefills the same rows twice wants.
+        # ``prefill_inplace`` is the tick's: the cache is donated and
+        # the slot's rows are rewritten where they lie.
         self.prefill = jax.jit(prefill)
+        self.prefill_inplace = jax.jit(prefill, donate_argnums=(1,))
 
         def step(params, cache, tokens, lengths):
-            """One decode step for every slot: tokens [B,1], lengths [B]."""
-
-            def one(cache_row, tok, idx):
-                # vmap stripped the batch dim; the model wants [L,1,...].
-                row = {k: v[:, None] for k, v in cache_row.items()}
-                logits, new_row = llama.forward_with_cache(
-                    params, tok[None], row, idx, cfg)
-                return logits[0, -1], {k: v[:, 0]
-                                       for k, v in new_row.items()}
-
-            logits, new_cache = jax.vmap(
-                one, in_axes=({"k": 1, "v": 1}, 0, 0),
-                out_axes=(0, {"k": 1, "v": 1}))(cache, tokens, lengths)
-            next_ids = jnp.argmax(logits, axis=-1)
-            return next_ids, new_cache
+            """One decode step for every slot: tokens [B,1], lengths [B].
+            Batched by construction (``llama.decode_step_with_cache``):
+            each slot's one new row a layer is written at its own
+            length and the rest of the cache is carried untouched."""
+            logits, cache = llama.decode_step_with_cache(
+                params, tokens, cache, lengths, cfg)
+            return jnp.argmax(logits, axis=-1), cache
 
         def decode_chunk(params, cache, tokens, lengths, remaining,
                          eos_ids, done):
@@ -211,9 +218,11 @@ class DecodeLoop:
                                            axis=0)
             return toks.T, n_valid, tok, lengths, remaining, done, cache
 
-        self.decode_chunk = jax.jit(decode_chunk)
+        # Donated, like every program the engine binds back to its one
+        # cache (this module's header).
+        self.decode_chunk = jax.jit(decode_chunk, donate_argnums=(1,))
         # Exposed for the equivalence tests: the same single step the
-        # chunk scans over, jitted standalone.
+        # chunk scans over, jitted standalone (functional).
         self.decode_step = jax.jit(step)
 
     def _build_verify(self) -> None:
@@ -319,7 +328,7 @@ class DecodeLoop:
             return (jnp.transpose(toks, (1, 0, 2)), counts.T, lengths,
                     done, cache)
 
-        self.verify_chunk = jax.jit(verify_chunk)
+        self.verify_chunk = jax.jit(verify_chunk, donate_argnums=(1,))
 
     def _build_kv_transfer(self) -> None:
         """KV-page export/install for disaggregated prefill/decode: the
@@ -363,5 +372,5 @@ class DecodeLoop:
             return new
 
         self.export_page = jax.jit(export_page)
-        self.install_page = jax.jit(install_page)
+        self.install_page = jax.jit(install_page, donate_argnums=(0,))
 

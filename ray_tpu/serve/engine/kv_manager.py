@@ -305,6 +305,15 @@ class KVCacheManager:
             self._index.setdefault(h, set()).add(slot)
         self._free.append(slot)
 
+    def forget_resident(self) -> None:
+        """The device rows are gone (the engine rebuilt its cache): no
+        free slot holds a reusable prefix any more. In-use slots keep
+        their bookkeeping until their requests release them."""
+        self._index.clear()
+        for slot in self._free:
+            info = self._slots[slot]
+            info.resident, info.chain = (), ()
+
     def _unindex(self, slot: int) -> None:
         for h in self._slots[slot].chain:
             s = self._index.get(h)

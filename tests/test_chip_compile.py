@@ -85,15 +85,15 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-def _names_kernel(compiled, name: str, instruction: str = "") -> bool:
-    """The kernel's name in what the chip's compiler printed: always as
-    ``kernel_metadata`` (the text a device trace shows for the
-    operation), and as the instruction's own name where the kernel is
-    called directly (``instruction``; under the engine's ``vmap`` over
-    slots XLA names it for the batching loop's body instead)."""
+def _names_kernel(compiled, name: str) -> bool:
+    """The kernel's name in what the chip's compiler printed: as
+    ``kernel_metadata`` and as the instruction's own name, which is
+    what a device trace shows for the operation (``<name>.N``: the
+    engine's step calls the kernel directly, once a layer for all
+    slots, so no batching loop renames it ``closed_call``)."""
     text = "".join(compiled.as_text().split())
     return (f'kernel_metadata={{"kernel":"{name}"}}' in text
-            and f"%{instruction or name}." in text)
+            and f"%{name}." in text)
 
 
 # ------------------------------------------------------------- kernels
@@ -190,41 +190,107 @@ def _abstract(sharding, fn, *args):
         jax.eval_shape(fn, *args))
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
-def test_engine_decode_chunk_compiles_at_llama3_1b(chip, paged):
-    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+# Mistral-7B's widths (benchmark/configs/mistral-7b-v0.3-l16.json) at 2
+# layers — the layer body is scanned, so its HLO is the 16-layer one's —
+# with the serving cells' 32 slots of 1024 rows; and LLAMA3_1B whole.
+_MISTRAL_2L = llama.LlamaConfig(
+    vocab_size=32768, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=8,
+    d_ff=14336, max_seq_len=1024, rope_theta=1e6)
+ENGINES = {"mistral7b_2l": (_MISTRAL_2L, 32, 1024),
+           "llama3_1b": (_CFG_1B, BATCH, SEQ)}
 
-    cfg = dataclasses.replace(_CFG_1B, paged_decode=paged)
-    loop = DecodeLoop(cfg, max_len=SEQ, chunk=8)
+
+def _engine_args(chip, cfg, slots, rows):
     params = _abstract(chip, functools.partial(llama.init_params, cfg),
                        jax.random.PRNGKey(0))
-    cache = _abstract(chip, lambda: llama.init_kv_cache(cfg, BATCH, SEQ))
-    vec = _sds(chip, (BATCH,), jnp.int32)
-    c = loop.decode_chunk.lower(
-        params, cache, _sds(chip, (BATCH, 1), jnp.int32), vec, vec, vec,
-        _sds(chip, (BATCH,), jnp.bool_)).compile()
-    # One kernel, in the scanned layer body (a program that does not
-    # fit the chip's 16 GB is refused by the compile itself). The
-    # engine vmaps the step over slots: the contiguous kernel, whose
-    # lengths are a scalar-prefetch argument, is then batched by a loop
-    # and its instruction named for the loop's body; its own name stays
-    # in its kernel_metadata, which is what a device trace shows.
-    assert _kernel_calls(c) == 1
-    assert _names_kernel(c, "rtpu_paged_decode_attention" if paged
-                         else "rtpu_decode_attention",
-                         instruction="closed_call")
+    cache = _abstract(chip, lambda: llama.init_kv_cache(cfg, slots, rows))
+    return params, cache
 
 
-def test_engine_prefill_bucket_compiles_at_llama3_1b(chip):
+def _lower_decode_chunk(chip, loop, params, cache, slots):
+    vec = _sds(chip, (slots,), jnp.int32)
+    return loop.decode_chunk.lower(
+        params, cache, _sds(chip, (slots, 1), jnp.int32), vec, vec, vec,
+        _sds(chip, (slots,), jnp.bool_)).compile()
+
+
+def _assert_cache_in_place(compiled, cache):
+    """The program rewrites the cache it was given: its output aliases
+    the donated input, and no computation it calls (the step loop, the
+    layer loop, their fusions) copies an array of the cache's shape —
+    a scatter that re-lays the cache out shows as such copies too.
+
+    At a head size of 128 nothing else copies it either, and the
+    temporaries stay below one cache. At 64 (LLAMA3_1B) the chip keeps
+    ``[.., S, 64]`` with S minor (64 would be padded to 128 lanes), the
+    kernel wants D minor, and the ENTRY computation re-lays K and V
+    once a chunk on the way in and out, padded: four copies at the
+    program's edge that only a kernel for that layout removes
+    (PERF.md section 7)."""
+    k = cache["k"]
+    nbytes = 2 * k.size * k.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    shape = "bf16[" + ",".join(map(str, k.shape)) + "]"
+
+    def copies(text):
+        return [line for line in text.splitlines()
+                if shape in line.split("(")[0]
+                and " copy" in line.split("(")[0]]
+
+    called, _, entry = compiled.as_text().partition("\nENTRY ")
+    assert entry and copies(called) == []
+    if k.shape[-1] % 128 == 0:
+        assert copies(entry) == []
+        assert mem.temp_size_in_bytes < nbytes
+    else:
+        assert len(copies(entry)) <= 4
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_engine_decode_chunk_updates_the_cache_in_place(chip, engine):
     from ray_tpu.serve.engine.decode_loop import DecodeLoop
 
-    loop = DecodeLoop(_CFG_1B, max_len=SEQ, chunk=8)
-    params = _abstract(chip, functools.partial(llama.init_params, _CFG_1B),
-                       jax.random.PRNGKey(0))
-    cache = _abstract(chip, lambda: llama.init_kv_cache(_CFG_1B, BATCH, SEQ))
+    cfg, slots, rows = ENGINES[engine]
+    loop = DecodeLoop(cfg, max_len=rows, chunk=8)
+    params, cache = _engine_args(chip, cfg, slots, rows)
+    c = _lower_decode_chunk(chip, loop, params, cache, slots)
+    # One kernel, in the scanned layer body (a program that does not
+    # fit the chip's 16 GB is refused by the compile itself), called
+    # once a layer for all slots and so under its own name.
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_decode_attention")
+    _assert_cache_in_place(c, cache)
+
+
+def test_engine_paged_decode_chunk_compiles_at_llama3_1b(chip):
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg = dataclasses.replace(_CFG_1B, paged_decode=True)
+    loop = DecodeLoop(cfg, max_len=SEQ, chunk=8)
+    params, cache = _engine_args(chip, cfg, BATCH, SEQ)
+    c = _lower_decode_chunk(chip, loop, params, cache, BATCH)
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_paged_decode_attention")
+    _assert_cache_in_place(c, cache)
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_engine_tick_prefill_updates_the_cache_in_place(chip, engine):
+    """The tick's prefill (donated) beside the check's (functional):
+    the same function, so the same program name in a trace."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg, slots, rows = ENGINES[engine]
+    loop = DecodeLoop(cfg, max_len=rows, chunk=8)
+    params, cache = _engine_args(chip, cfg, slots, rows)
     scalar = _sds(chip, (), jnp.int32)
-    loop.prefill.lower(params, cache, _sds(chip, (1, 512), jnp.int32),
-                       scalar, scalar).compile()
+    args = (params, cache, _sds(chip, (1, 512), jnp.int32), scalar, scalar)
+    lowered = loop.prefill_inplace.lower(*args)
+    assert "jit_prefill" in lowered.as_text()[:200]
+    _assert_cache_in_place(lowered.compile(), cache)
+    functional = loop.prefill.lower(*args).compile()
+    assert functional.memory_analysis().alias_size_in_bytes == 0
 
 
 def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip):

@@ -165,7 +165,10 @@ def test_steady_state_decode_programs_and_sync_cadence(debug_jax,
         programs = eng.loop.program_counts()
         assert programs == first
         assert programs["decode_chunk"] == 1
-        assert programs["prefill"] == 2     # both buckets exercised
+        # The tick dispatches the donating program; the functional
+        # twin (checks call it on a live cache) never ran here.
+        assert programs["prefill_inplace"] == 2  # both buckets exercised
+        assert programs["prefill"] == 0
         if workload == "plain":
             assert "verify_chunk" not in programs
         else:
@@ -212,7 +215,7 @@ def test_chunked_paged_engine_declared_schedule(debug_jax):
         # Chunking NARROWS the prefill shape set: every full chunk is
         # the 16-token bucket and every tail (<= chunk) buckets back
         # into it — one program, under the 2-bucket budget.
-        assert programs["prefill"] == 1
+        assert programs["prefill_inplace"] == 1
         assert jax_debug.over_budget_reports() == []
         stats = eng.stats()
         syncs = jax_debug.host_sync_counts()

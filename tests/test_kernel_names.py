@@ -1,8 +1,10 @@
 """Every Pallas kernel of ``ray_tpu/ops`` carries a stable name: the
 ``name=`` of its ``pl.pallas_call`` (Mosaic's ``kernel_name``, the call
 site's named scope) and the same string as ``kernel_metadata``, the one
-field that still identifies the kernel in a device trace when the
-engine ``vmap``s it and XLA names the instruction ``closed_call.N``.
+field that still identifies the kernel in a device trace where a
+batching loop renames the instruction ``closed_call.N`` (the engine's
+``vmap``ped step did, until PR 26; its batched step calls the kernel
+directly and the instruction is ``rtpu_decode_attention.N``).
 Here on the jaxpr; ``tests/test_chip_compile.py`` checks the text the
 chip's compiler produces."""
 
@@ -64,8 +66,8 @@ def test_glue_kernel_carries_its_name(name):
     ("paged_decode", "rtpu_paged_decode_attention"),
 ])
 def test_decode_chunk_carries_its_attention_kernels_name(knob, name):
-    """The engine's own program, ``vmap`` over slots and all: the one
-    kernel of the scanned layer body is the named one."""
+    """The engine's own program: the one kernel of the scanned layer
+    body, called once a layer for all slots, is the named one."""
     from ray_tpu.serve.engine.decode_loop import DecodeLoop
 
     cfg = dataclasses.replace(llama.tiny_config(max_seq_len=64),
