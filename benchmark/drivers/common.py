@@ -1,45 +1,9 @@
-"""What both drivers share: a configuration file's sizes as the
-program's `LlamaConfig`, weights from the seed, the tolerances."""
+"""What both drivers share: the relative error and the refusal. What
+belongs to a model family (its sizes as the program's configuration,
+its weights from the seed, its plain reference) is the configuration's
+builder, ``benchmark/builders/<name>.py``."""
 
 from __future__ import annotations
-
-import functools
-
-
-def llama_config(c: dict, **overrides):
-    """The configuration file's (Hugging Face) keys as a plain
-    `LlamaConfig`: another family through the same code, no width
-    changed, no edit to ``models/``."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models import llama
-
-    head_dim = c.get("head_dim", c["hidden_size"] // c["num_attention_heads"])
-    if head_dim * c["num_attention_heads"] != c["hidden_size"]:
-        raise ValueError("LlamaConfig ties head_dim to hidden_size / heads")
-    if c.get("sliding_window") or c.get("rope_scaling"):
-        raise ValueError("sliding windows and scaled RoPE are not in "
-                         "models/llama.py")
-    return llama.LlamaConfig(
-        vocab_size=c["vocab_size"], d_model=c["hidden_size"],
-        n_layers=c["num_hidden_layers"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        max_seq_len=c["max_position_embeddings"],
-        rope_theta=float(c["rope_theta"]), norm_eps=c["rms_norm_eps"],
-        dtype={"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
-            c["torch_dtype"]],
-        tie_embeddings=c["tie_word_embeddings"], **overrides)
-
-
-def init_params(cfg, seed: int):
-    """The model's weights on the device, in the type they are served
-    in, in ONE jitted call from the seed."""
-    import jax
-
-    from ray_tpu.models import llama
-
-    return jax.jit(functools.partial(llama.init_params, cfg))(
-        jax.random.PRNGKey(seed))
 
 
 def rel_l2(got, ref) -> float:

@@ -5,7 +5,8 @@ owns the chip, entered as a user enters it —
 that the mix's traffic kind (``benchmark/traffic_kinds/``) makes and
 drives.
 
-Set-up: weights from the seed in one jitted call, the engine, one
+Set-up: weights from the seed in one jitted call (the configuration's
+builder makes them, ``benchmark/builders/``), the engine, one
 request per prompt bucket (they pay or load every program the traffic
 uses: one ``prefill`` per bucket and ``decode_chunk``), and on those
 same requests the comparison with the plain reference.
@@ -20,7 +21,10 @@ import numpy as np
 
 from benchmark.drivers import common
 from benchmark.harness import tracing_run
-from benchmark.reference import dense_decoder
+
+# What this driver calls of a configuration's builder (`manifest.check`
+# refuses a configuration whose builder lacks one).
+BUILDER_CALLS = ("config", "init_params", "reference.logits_at")
 
 # Relative L2 error of the engine's bf16 logits (one vocabulary row)
 # against the float32 reference, at the prompt's last position and at
@@ -54,8 +58,10 @@ def _check_prompts(buckets, max_len, vocab, seed):
     return prompts
 
 
-def warm_and_check(handle, engine, params, config, cfg, seed: int) -> dict:
-    """Warm every program and hold the engine to the reference."""
+def warm_and_check(handle, engine, params, config, cfg, seed: int,
+                   reference) -> dict:
+    """Warm every program and hold the engine to ``reference``, the
+    plain reference of the configuration's builder."""
     import jax
     import jax.numpy as jnp
 
@@ -88,7 +94,7 @@ def warm_and_check(handle, engine, params, config, cfg, seed: int) -> dict:
         rows += [(i, len(p) - 1 + j) for j in range(CHECK_TOKENS)]
     # The reference reads the weights the driver made, not whatever the
     # engine keeps of them.
-    ref = dense_decoder.logits_at(params, jnp.asarray(tokens), rows, config)
+    ref = reference.logits_at(params, jnp.asarray(tokens), rows, config)
     ref = np.asarray(ref).reshape(len(prompts), CHECK_TOKENS, -1)
     common.require(np.all(np.isfinite(ref)), "reference logits not finite")
     margins = []
@@ -160,8 +166,8 @@ def bring_up(ctx):
     from ray_tpu import serve
     from ray_tpu.serve.llm import build_llm_deployment
 
-    cfg = common.llama_config(ctx.config)
-    params = common.init_params(cfg, ctx.seed)
+    cfg = ctx.builder.config(ctx.config)
+    params = ctx.builder.init_params(cfg, ctx.seed)
     handle = serve.run(
         build_llm_deployment(engine_kwargs=dict(
             cfg=cfg, params=params, seed=ctx.seed,
@@ -170,7 +176,7 @@ def bring_up(ctx):
     engine = handle._instance.engine
     try:
         checks = warm_and_check(handle, engine, params, ctx.config, cfg,
-                                ctx.seed)
+                                ctx.seed, ctx.builder.reference)
     except BaseException:
         engine.close()
         raise

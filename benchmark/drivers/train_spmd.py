@@ -15,7 +15,11 @@ import time
 
 from benchmark.drivers import common
 from benchmark.harness import tracing_run
-from benchmark.reference import dense_decoder
+
+# What this driver calls of a configuration's builder (`manifest.check`
+# refuses a configuration whose builder lacks one): `spmd.sharded_init`
+# makes the weights, so no `init_params`.
+BUILDER_CALLS = ("config", "reference.loss")
 
 # The system's forward + loss (bf16 weights and activations, chunked
 # cross entropy in float32) against the float32 reference on the SAME
@@ -60,7 +64,7 @@ def run(ctx) -> dict:
     from ray_tpu.parallel.mesh import mesh_2d, mesh_context
 
     config, mix, args = ctx.config, ctx.traffic, ctx.config["driver_args"]
-    cfg = common.llama_config(config)
+    cfg = ctx.builder.config(config)
     vocab = cfg.vocab_size
     mesh = mesh_2d(ctx.chips, tp=min(args["tp"], ctx.chips),
                    devices=jax.devices())
@@ -79,7 +83,7 @@ def run(ctx) -> dict:
 
         first = ctx.kind.batch(mix, ctx.seed, 0, vocab)
         few = jnp.asarray(first[:CHECK_SEQUENCES])
-        ref = float(dense_decoder.loss(state.params, few, config))
+        ref = float(ctx.builder.reference.loss(state.params, few, config))
         same = float(spmd.make_eval_step(cfg, mesh)(
             state.params, jax.device_put(first[:CHECK_SEQUENCES], placed)
         )["loss"])

@@ -35,5 +35,6 @@ def build(root: str, workload: str, *, seed: int, seconds: float,
         cell=cell, config=config, traffic=mix, seed=seed, seconds=seconds,
         trace=trace, chips=cell["chips"], rehearse=rehearse, t_start=t_start,
         cache=compile_cache.configure(), kind=manifest.kind(mix),
+        builder=manifest.builder(config),
         scratch_dir=os.path.join(root, ".bench_tmp", cell["name"]))
     return manifest, ctx, dev

@@ -10,10 +10,14 @@ belongs to ONE of them is a file found by its name:
 - ``benchmark/metrics/<metric>.py``: one reader, ``read(run)`` (a
   metric ``<base>.<cells>`` falls back to ``<base>.py``),
 - ``benchmark/drivers/<driver>.py``: how a kind of configuration comes
-  up (named by the configuration file's ``driver`` key).
+  up (named by the configuration file's ``driver`` key),
+- ``benchmark/builders/<builder>.py``: what belongs to a model family —
+  the program's configuration, the weights, the plain reference (named
+  by the configuration file's optional ``builder`` key; absent, it is
+  ``dense_llama``).
 
-So a later PR adds a cell, a mix or a metric as new files plus one
-entry here, and edits no file that exists. `load` checks the contract's
+So a later PR adds a cell, a mix, a metric or a model family as new
+files plus one entry here, and edits no file that exists. `load` checks the contract's
 limits that can be checked without a run; the driver checks them again.
 """
 
@@ -42,6 +46,13 @@ class ManifestError(ValueError):
 def _need(ok, what) -> None:
     if not ok:
         raise ManifestError(str(what))
+
+
+def _dotted(obj, path: str):
+    """``obj.a.b`` for ``"a.b"``; None where a step is missing."""
+    for name in path.split("."):
+        obj = getattr(obj, name, None)
+    return obj
 
 
 def _line(text, what) -> None:
@@ -96,6 +107,13 @@ class Manifest:
 
     def driver(self, name: str):
         return self._module("drivers", name)
+
+    def builder(self, config: dict):
+        """The builder of a configuration file (its dict): ``config``,
+        ``init_params`` and ``reference`` of its model family. A driver
+        lists what it calls of them as ``BUILDER_CALLS``; `check` looks
+        for those."""
+        return self._module("builders", config.get("builder", "dense_llama"))
 
     def kind(self, mix: dict):
         """The traffic kind of a mix file: how it becomes requests (or
@@ -160,7 +178,12 @@ class Manifest:
             _need((w["config"], w["traffic"]) not in pairs, "pair twice")
             pairs.add((w["config"], w["traffic"]))
             self.kind(self.traffic(w))
-            self.driver(self.config(w)["driver"])
+            config = self.config(w)
+            driver, builder = self.driver(config["driver"]), self.builder(config)
+            for path in getattr(driver, "BUILDER_CALLS", ()):
+                _need(callable(_dotted(builder, path)),
+                      f"builder of {w['config']!r} lacks {path}, which "
+                      f"driver {config['driver']!r} calls")
         _need({w["config"] for w in d["workloads"]} == set(self.configs),
               "a configuration no cell uses")
         n4 = sum(w["chips"] == 4 for w in d["workloads"])

@@ -12,8 +12,9 @@ shape (sessions, bursts, packed documents) adds a kind file and edits
 nothing here.
 
 Every seed gives the SAME multiset of sizes (and, in the open loop, of
-inter-arrival gaps) in another order, with other token ids: seeds
-change which requests meet in a batch, not the work offered.
+inter-arrival gaps, for the lead-in and for the window each) in another
+order, with other token ids: seeds change which requests meet in a
+batch, not the work offered.
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """Median over the timed requests of
-(last token - first token) / (tokens - 1): the steadier statistic that
-stands beside the judged tail ``tpot_p90_ms``."""
+(last token - first token) / (tokens - 1), judged end to end; the
+tail stands beside it as ``tpot_tail_p90_ms``, recorded."""
 
 from benchmark.harness import stats
 

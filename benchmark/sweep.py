@@ -1,6 +1,6 @@
 """Find a serving cell's knee, once, when the cell is defined.
 
-    python3 benchmark/sweep.py --workload mistral7b.chat.steady --seconds 30
+    python3 benchmark/sweep.py --workload mistral7b.chat.steady --seconds 45 --lead-in 20
 
 brings the cell's replica up once and offers its open-loop mix at 1, 2,
 ... requests/s, one lead-in + window + drain each. A rate is SUSTAINED
@@ -8,7 +8,10 @@ if the requests completed per second of the window reach 0.97 of the
 rate offered and fewer requests than the engine has slots wait at the
 window's end. The knee is the highest sustained rate; the cell's
 ``rate_rps`` is then written into its traffic file as 0.8 x knee, by
-hand: the benchmark never searches. Needs the chip, like `run.py`.
+hand: the benchmark never searches. Offer the cell's own window
+(``run_seconds``): near the knee the slots are still filling when a
+shorter one ends, and it reads a sustained rate as missed. Needs the
+chip, like `run.py`.
 """
 
 from __future__ import annotations

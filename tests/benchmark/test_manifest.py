@@ -3,24 +3,36 @@ contract refuses, and takes a cell, a mix and a metric added purely as
 new files plus one entry each."""
 
 import json
+import os
 import shutil
+import subprocess
+import sys
 
 import pytest
 
 from benchmark.harness import manifest
 
 
+# The first three cells; what comes after them is any later PR's to add.
+FIRST_CELLS = {"mistral7b.chat.steady": 1, "smollm2.sft.fsdp2tp2": 4,
+               "mistral7b.chat.flood": 1}
+
+
 def test_the_repo_manifest_loads_and_every_file_is_found():
+    # `load` holds EVERY cell, the later ones too, to `check`: names,
+    # pairs, at most 24 cells, a quarter of them on four chips.
     m = manifest.load()
-    assert list(m.cells) == ["mistral7b.chat.steady", "smollm2.sft.fsdp2tp2",
-                             "mistral7b.chat.flood"]
-    assert [m.cells[c]["chips"] for c in m.cells] == [1, 4, 1]
+    for name, chips in FIRST_CELLS.items():
+        assert m.cell(name)["chips"] == chips
     for cell in m.cells.values():
         cfg = m.config(cell)
         assert cfg["source"] == m.configs[cell["config"]]["source"]
         assert set(cfg["reduced"]) == set(m.configs[cell["config"]]["reduced"])
         assert "assumed" in cfg and "deployment" in cfg
-        assert hasattr(m.driver(cfg["driver"]), "run")
+        driver = m.driver(cfg["driver"])
+        assert hasattr(driver, "run")
+        for call in driver.BUILDER_CALLS:       # what `check` looked for
+            assert callable(manifest._dotted(m.builder(cfg), call))
         kind = m.kind(m.traffic(cell))
         assert hasattr(kind, "batch") or (hasattr(kind, "requests")
                                           and hasattr(kind, "drive"))
@@ -29,6 +41,8 @@ def test_the_repo_manifest_loads_and_every_file_is_found():
     # A metric split by cell shares its base name's reader.
     assert (m.reader("device_idle_pct.train").__code__.co_filename
             .endswith("device_idle_pct.py"))
+    # A configuration file that names no builder is a dense Llama.
+    assert m.builder({}).__name__.endswith("dense_llama")
 
 
 @pytest.fixture
@@ -98,6 +112,112 @@ def test_a_cell_a_mix_and_a_metric_come_in_as_new_files(copy):
     assert m.reader("slo_share_pct")({"ok": 1, "n": 4}) == 25.0
     assert [x["name"] for x in m.metrics_of("smollm2.chat.burst",
                                             "per_layer")] == ["slo_share_pct"]
+
+
+def _reports_like(data, cell, like):
+    """``cell`` joins every metric that lists ``like``: entries, no edit
+    to a file."""
+    for m in data["end_to_end"] + data["per_layer"]:
+        if like in m.get("workloads", ()):
+            m["workloads"].append(cell)
+
+
+def _a_fourth_workload(bench, data):
+    """A further cell of a configuration that is there: a new mix file
+    and one entry."""
+    steady = json.loads((bench / "traffic/chat.steady.json").read_text())
+    (bench / "traffic/chat.light.json").write_text(json.dumps(
+        dict(steady, rate_rps=2.0)))
+    data["workloads"].append(
+        {"name": "mistral7b.chat.light", "config": "mistral-7b-v0.3-l16",
+         "traffic": "chat.light", "chips": 1, "why": "w"})
+    _reports_like(data, "mistral7b.chat.light", "mistral7b.chat.steady")
+
+
+def _a_family_of_its_own(bench, data):
+    """A configuration whose builder and plain reference are new files
+    (stubs over the dense ones; the reference leaves a mark)."""
+    base = json.loads((bench / "configs/mistral-7b-v0.3-l16.json").read_text())
+    (bench / "configs/stub-family.json").write_text(json.dumps(
+        dict(base, name="stub-family", builder="stub_family")))
+    (bench / "builders/stub_family.py").write_text(
+        # By path: in this test's own process ``benchmark`` is the repo's.
+        "import importlib.util, pathlib\n"
+        "from benchmark.builders.dense_llama import config, init_params\n"
+        "_spec = importlib.util.spec_from_file_location('stub_reference',\n"
+        "    pathlib.Path(__file__).parents[1] / 'reference/stub_reference.py')\n"
+        "reference = importlib.util.module_from_spec(_spec)\n"
+        "_spec.loader.exec_module(reference)\n")
+    (bench / "reference/stub_reference.py").write_text(
+        "import pathlib\n"
+        "from benchmark.reference import dense_decoder\n"
+        "def logits_at(params, tokens, rows, c):\n"
+        "    pathlib.Path(__file__).with_suffix('.used').write_text('x')\n"
+        "    return dense_decoder.logits_at(params, tokens, rows, c)\n")
+    data["configs"].append(
+        {"name": "stub-family", "source": base["source"],
+         "file": "benchmark/configs/stub-family.json",
+         "reduced": list(base["reduced"]), "why": "w"})
+    data["workloads"].append(
+        {"name": "stub.chat.steady", "config": "stub-family",
+         "traffic": "chat.steady", "chips": 1, "why": "w"})
+    _reports_like(data, "stub.chat.steady", "mistral7b.chat.steady")
+
+
+@pytest.mark.parametrize("add, cell", [
+    (_a_fourth_workload, "mistral7b.chat.light"),
+    (_a_family_of_its_own, "stub.chat.steady")])
+def test_the_suite_passes_on_a_copy_with_a_further_cell(copy, add, cell):
+    """What a later PR does: new files and manifest entries, no edit.
+    The copy's manifest loads, and every test of ``tests/benchmark/``
+    passes THERE (they read the manifest beside the ``benchmark/`` they
+    import): none enumerates the cells it expects, and the new cell
+    rehearses with the others, traced and not."""
+    root, load = copy
+    m = load(lambda data: add(root / "benchmark", data))
+    assert list(m.cells)[-1] == cell and len(m.cells) == 4
+    assert set(FIRST_CELLS) < set(m.cells)
+    shutil.copytree(manifest.ROOT / "tests/benchmark", root / "tests/benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "tests/__init__.py").write_text("")
+    env = {k: v for k, v in os.environ.items() if not k.startswith("PYTEST_")}
+    env.update(PYTHONPATH=f"{root}{os.pathsep}{manifest.ROOT}",
+               JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", "tests/benchmark", "-q", "-x", "-rA",
+         "-p", "no:cacheprovider", "-k", "not suite_passes_on_a_copy"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=1200)
+    assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-2000:]
+    for trace in (0, 1):
+        assert (f"PASSED tests/benchmark/test_rehearse.py::"
+                f"test_rehearsal_runs_every_cell[{cell}-{trace}]") in out.stdout
+    used = root / "benchmark/reference/stub_reference.used"
+    assert used.exists() == (add is _a_family_of_its_own)
+
+
+@pytest.mark.parametrize("config, ok", [("smollm2-1.7b", True),
+                                        ("mistral-7b-v0.3-l16", False)])
+def test_a_builder_is_held_to_what_its_driver_calls(copy, config, ok):
+    """A family that only trains gives a reference with ``loss`` and no
+    ``logits_at``: the training driver takes it, the serving driver's
+    configuration is refused at `load`, not on the chip."""
+    root, load = copy
+    bench = root / "benchmark"
+    (bench / "builders/train_only.py").write_text(
+        "import types\n"
+        "from benchmark.builders.dense_llama import config\n"
+        "reference = types.SimpleNamespace(loss=lambda params, tokens, c: 0.0)\n")
+    path = bench / f"configs/{config}.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    builder="train_only")))
+    if ok:
+        m = load(lambda d: None)
+        assert m.builder(m.config(m.cell("smollm2.sft.fsdp2tp2"))
+                         ).__name__.endswith("train_only")
+    else:
+        with pytest.raises(manifest.ManifestError, match="init_params"):
+            load(lambda d: None)
 
 
 def _set(path, value):

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.drivers import common
+from benchmark.builders import dense_llama
 from benchmark.reference import dense_decoder
 from ray_tpu.models import llama
 
@@ -37,7 +37,7 @@ def _params(cfg, seed=0):
 @pytest.mark.parametrize("kv_heads", [2, 4], ids=["gqa", "mha"])
 def test_reference_agrees_with_the_system(tied, kv_heads):
     config = _config(tied, kv_heads)
-    cfg = common.llama_config(config, remat=False)
+    cfg = dense_llama.config(config, remat=False)
     assert cfg.tie_embeddings == tied and cfg.n_kv_heads == kv_heads
     params = _params(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 33), 0, 212)
@@ -54,7 +54,7 @@ def test_reference_agrees_with_the_system(tied, kv_heads):
 
 def test_reference_is_causal():
     config = _config(False, 2)
-    params = _params(common.llama_config(config))
+    params = _params(dense_llama.config(config))
     a = jnp.arange(20, dtype=jnp.int32)[None] % 212
     b = a.at[0, 15:].set(3)                      # change only the tail
     la = dense_decoder.logits_at(params, a, [(0, 14), (0, 19)], config)
@@ -71,7 +71,7 @@ def test_tied_embeddings_through_the_sharded_step():
 
     if jax.device_count() < 4:
         pytest.skip("needs four (virtual) devices")
-    cfg = common.llama_config(_config(True, 4))
+    cfg = dense_llama.config(_config(True, 4))
     tx = spmd.default_optimizer(lr=1e-3, warmup=1)
     tokens = np.asarray(
         jax.random.randint(jax.random.PRNGKey(4), (4, 32), 0, 212), np.int32)

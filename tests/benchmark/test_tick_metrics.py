@@ -92,8 +92,9 @@ def test_new_metrics_sit_after_the_old_ones_in_their_cells():
            "front_first_deliver_ms", "engine_host_share_pct",
            "engine_host_share_pct.flood", "decode_attn_ms_per_step",
            "decode_attn_ms_per_step.flood"]
-    assert names[-len(new):] == new
-    assert names[len(names) - len(new) - 1] == "device_idle_pct.train"
+    # ... and before whatever a later PR appends.
+    at = names.index("device_idle_pct.train") + 1
+    assert names[at:at + len(new)] == new
     steady = {m["name"] for m in
               M.metrics_of("mistral7b.chat.steady", "per_layer")}
     flood = {m["name"] for m in
