@@ -1,0 +1,28 @@
+"""Device milliseconds the latent decode-attention kernel takes in one
+decode step: self time on device 0 of the ``rtpu_mla_decode_attention``
+custom calls (``ops/mla_decode.py`` names its ``pl.pallas_call``; the
+step calls it directly, once a layer for all slots, so the trace shows
+``rtpu_mla_decode_attention.N``) over the ``decode_chunk`` program's
+executions in the trace x ``decode_chunk`` steps each."""
+
+import re
+
+KERNEL = re.compile(r"rtpu_mla_decode_attention\.?\d* custom-call "
+                    r".*tpu_custom_call$")
+
+
+def kernel_seconds(run):
+    """(seconds, calls) of the kernel in the traced stretch."""
+    t = run.get("trace") or {}
+    names = [n for n in t.get("op_self_s", {}) if KERNEL.match(n)]
+    return (sum(t["op_self_s"][n] for n in names),
+            sum(t["op_count"][n] for n in names))
+
+
+def read(run):
+    runs = (run.get("trace") or {}).get("program_s", {}).get("decode_chunk")
+    seconds, _ = kernel_seconds(run)
+    if not runs or not seconds:
+        return None
+    chunk = run["config"]["driver_args"]["engine"]["decode_chunk"]
+    return seconds / (len(runs) * chunk) * 1e3

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -92,6 +93,12 @@ class LlamaConfig:
     # non-batch matmul outputs (faster bwd, +O(layers*S*d_ff) HBM).
     remat_policy: str = "nothing"
     tie_embeddings: bool = False
+
+    @property
+    def model(self):
+        """The module the serving engine asks for this family's cache,
+        prefill and decode step (``serve/engine/README.md``)."""
+        return sys.modules[__name__]
 
     @property
     def head_dim(self) -> int:

@@ -1,5 +1,7 @@
 """Mixtral-class sparse-MoE decoder: expert parallelism over the ``ep`` axis.
 
+It DROPS tokens over its fixed capacity and has no serving path (no `forward_with_cache`); the dropless, served expert layer is `models/glm_moe_lite.py`'s.
+
 TPU-first MoE (GShard/Switch pattern — static shapes, one-hot dispatch
 einsums that run on the MXU): top-k routing with a fixed per-expert
 capacity; overflow tokens fall through the residual (standard drop

@@ -23,6 +23,10 @@ paged_decode_attention     Pallas block-table kernel:       ``LlamaConfig.paged_
                            ceil(len/page) pages/seq         of ``decode_page`` (engine pads). Greedy
                                                             output token-identical to the unpaged
                                                             paths (identity table == contiguous read)
+mla_decode_attention       Pallas single-query kernel over  on TPU, or ``interpret=True`` off-TPU;
+                           a LATENT cache: one shared key   jnp reference elsewhere
+                           whose first columns are the
+                           value, each row read once
 ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (with attention.py
                                                             the only importers of shard_map —
                                                             rtpu-lint banned-API rule)
@@ -60,6 +64,10 @@ from ray_tpu.ops.fused import (
     fused_swiglu,
     swiglu_reference,
 )
+from ray_tpu.ops.mla_decode import (
+    mla_decode_attention,
+    mla_decode_attention_reference,
+)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_decode import (
     paged_decode_attention,
@@ -79,6 +87,8 @@ __all__ = [
     "fused_rms_norm",
     "fused_rms_norm_residual",
     "fused_swiglu",
+    "mla_decode_attention",
+    "mla_decode_attention_reference",
     "online_softmax_update",
     "paged_decode_attention",
     "paged_decode_attention_reference",

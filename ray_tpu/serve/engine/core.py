@@ -146,6 +146,28 @@ class InferenceEngine:
         self.role = role
         self._jax = jax
         self.cfg = cfg or llama.tiny_config(max_seq_len=max_len)
+        # The model seam: the configuration's own module provides the
+        # cache, the prefill and the decode step (engine/README.md).
+        self.model = self.cfg.model
+        # Fetched counter -> the attribute its request's span carries.
+        self._span_attr_names = getattr(self.model, "SPAN_ATTRS", {})
+        gate = kv_fleet_min_prefix_blocks
+        if gate is None:
+            from ray_tpu.core.config import GLOBAL_CONFIG as _cfg
+
+            gate = _cfg.serve_kv_fleet_min_prefix_blocks
+        fleet_on = not (isinstance(gate, int) and gate < 0)
+        # What this family's cache cannot do yet is refused here, by
+        # name, never run wrong.
+        asked = {"quantize": quantize is not None,
+                 "paged_decode": bool(paged_decode),
+                 "spec_draft_len": int(spec_draft_len) > 0,
+                 "role": role != "colocated", "kv_fleet": fleet_on}
+        for option, why in getattr(self.model, "ENGINE_REFUSES", {}).items():
+            if asked[option]:
+                raise ValueError(
+                    f"{self.model.__name__} cannot serve with {option} "
+                    f"yet: {why}")
         if paged_decode:
             # The paged kernel's page size IS the KV manager's block
             # granularity — one notion of "block" engine-wide.
@@ -156,10 +178,10 @@ class InferenceEngine:
         # decode_page): the cache padding below must track EITHER spelling
         # or the first decode tick dies on the kernel's page-multiple
         # check.
-        self.paged_decode = self.cfg.paged_decode
+        self.paged_decode = getattr(self.cfg, "paged_decode", False)
         self.params = (params if params is not None
-                       else llama.init_params(self.cfg,
-                                              jax.random.PRNGKey(seed)))
+                       else self.model.init_params(self.cfg,
+                                                   jax.random.PRNGKey(seed)))
         self.quantize = quantize
         if quantize is not None:
             # Weight-only int8 (models/quant.py): decode/verify stream
@@ -186,13 +208,7 @@ class InferenceEngine:
         # spill hook, no extra snapshot keys); 0 = always pull; n>0 =
         # pull only contiguous runs of >= n blocks; "auto" = gate on
         # the measured pull-vs-recompute crossover.
-        gate = kv_fleet_min_prefix_blocks
-        if gate is None:
-            from ray_tpu.core.config import GLOBAL_CONFIG as _cfg
-
-            gate = _cfg.serve_kv_fleet_min_prefix_blocks
         self._fleet_min_blocks = gate
-        fleet_on = not (isinstance(gate, int) and gate < 0)
 
         self.loop = DecodeLoop(self.cfg, max_len=self.max_len,
                                chunk=self.decode_chunk,
@@ -227,7 +243,12 @@ class InferenceEngine:
         # header), so ``self.cache`` is rebound at each dispatch and a
         # donated program that raises costs the buffer (_recover_cache).
         self._cache_rows = cache_rows
-        self.cache = llama.init_kv_cache(self.cfg, max_batch, cache_rows)
+        self.cache = self.model.init_kv_cache(self.cfg, max_batch,
+                                              cache_rows)
+        # Of every layer together: what a token a slot holds costs.
+        self._kv_bytes_per_token = sum(
+            a.nbytes for a in self.cache.values()) // (max_batch
+                                                       * cache_rows)
         self._cache_rebuilds = 0
 
         self.kv = KVCacheManager(max_batch, self.max_len,
@@ -445,7 +466,8 @@ class InferenceEngine:
                "parked": len(self._parked),
                "preempts": self._preempts,
                "resumes": self._resumes,
-               "cache_rebuilds": self._cache_rebuilds}
+               "cache_rebuilds": self._cache_rebuilds,
+               "kv_bytes_per_token": self._kv_bytes_per_token}
         if self.quantize is not None:
             out["weight_bytes"], out["weight_bytes_f32"] = \
                 self._weight_bytes
@@ -1042,10 +1064,13 @@ class InferenceEngine:
                 suffix = req.prompt_ids[job.pos:job.pos + n]
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :n] = suffix
-                logits, self.cache = self.loop.prefill_inplace(
-                    self.params, self.cache, self._put(padded),
-                    self._put(np.int32(slot)),
-                    self._put(np.int32(job.pos)))
+                args = (self._put(padded), self._put(np.int32(slot)),
+                        self._put(np.int32(job.pos)))
+                if self.loop.last_row_only:
+                    # One row of logits comes back, so say which.
+                    args += (self._put(np.int32(n - 1)),)
+                logits, self.cache, *counters = self.loop.prefill_inplace(
+                    self.params, self.cache, *args)
                 # Per-chunk prefix commit: block occupancy and the
                 # slot's resident chain track the materialized prefix
                 # as chunks land, not the whole prompt up-front.
@@ -1056,10 +1081,13 @@ class InferenceEngine:
                 # device logits here was the jax-lint rule's first
                 # in-tree catch: an uncounted implicit sync). It waits
                 # out whatever the device had queued before this
-                # prefill, then copies the whole [1, bucket, vocab].
+                # prefill, then copies the whole [1, bucket, vocab]
+                # (one row of it, and the family's counters with it,
+                # where the program returns those).
                 with self._tick.phase("prefill_fetch", slot=slot,
                                       bucket=bucket) as attrs:
-                    logits = self._fetch(logits, tag="prefill")
+                    logits, *counters = self._fetch((logits, *counters),
+                                                    tag="prefill")
                     attrs["bytes"] = logits.nbytes
         except BaseException as e:  # noqa: BLE001 — one bad request
             # must not kill the engine thread (every later request
@@ -1078,15 +1106,19 @@ class InferenceEngine:
             self._span("engine.prefill", t0, t1, req,
                        {"prefill_tokens": n, "cached_tokens": cached,
                         "bucket": bucket, "slot": slot,
-                        "chunk": job.idx, "chunks": len(job.adm.chunks)})
+                        "chunk": job.idx, "chunks": len(job.adm.chunks),
+                        **(self._span_attrs(counters) if final else {})})
         job.idx += 1
         job.pos += n
         if not final:
             return False
         with self._tick.phase("prefill_deliver", slot=slot):
             # First generated token: from the LAST REAL prompt pos (row
-            # n-1 of the final chunk).
-            first = int(np.argmax(logits[0, n - 1]))
+            # n-1 of the final chunk; the one row there is, for a
+            # family whose tick prefill returns that row alone).
+            first = int(np.argmax(logits[0] if self.loop.last_row_only
+                                  else logits[0, n - 1]))
+            self.metrics.record_model_counters(counters)
             req.first_token_t = t1
             queue_s = max(0.0, job.t_pf0 - req.arrival_t)
             prefill_s = max(0.0, t1 - job.t_pf0)
@@ -1265,6 +1297,11 @@ class InferenceEngine:
                 _tracing.flush()
         return done
 
+    def _span_attrs(self, counters) -> Dict[str, int]:
+        return {self._span_attr_names[name]: int(value)
+                for fetched in counters for name, value in fetched.items()
+                if name in self._span_attr_names}
+
     def _roster_arrays(self, active):
         """Per-slot device inputs for a chunk dispatch (plain or spec)."""
         tokens = np.zeros((self.max_batch, 1), np.int32)
@@ -1316,8 +1353,6 @@ class InferenceEngine:
             return False
         except Exception:  # rtpu-lint: disable=swallowed-exception — the probe's failure IS the signal; ``e`` is what the callers deliver
             pass
-        from ray_tpu.models import llama
-
         # State first, errors last: whoever sees an error delivered
         # here sees the rebuilt engine behind it.
         self._inflight = None
@@ -1328,8 +1363,8 @@ class InferenceEngine:
                 lost.append(job.adm.request)
         self._prefilling = []
         self.kv.forget_resident()
-        self.cache = llama.init_kv_cache(self.cfg, self.max_batch,
-                                         self._cache_rows)
+        self.cache = self.model.init_kv_cache(self.cfg, self.max_batch,
+                                              self._cache_rows)
         self._cache_rebuilds += 1
         self._deliver_error(lost, e)
         return True
@@ -1429,13 +1464,14 @@ class InferenceEngine:
                     self._put(done))
             try:
                 toks_d, n_valid_d, ntok_d, nlen_d, nrem_d, ndone_d, \
-                    self.cache = self.loop.decode_chunk(
+                    self.cache, *counters_d = self.loop.decode_chunk(
                         self.params, self.cache, tok_d, len_d, rem_d,
                         eos_d, done_d)
             except BaseException as e:  # noqa: BLE001 — fail all waiters
                 self._fail_roster(e)
                 return None
-        return {"outs": (toks_d, n_valid_d),
+        # A family's counters ride the chunk's one fetch.
+        return {"outs": (toks_d, n_valid_d, *counters_d),
                 "carry": (ntok_d, nlen_d, nrem_d, eos_d, ndone_d),
                 "roster": self._roster_key(),
                 # Strong refs pin the roster's request objects while
@@ -1463,7 +1499,7 @@ class InferenceEngine:
             with self._tick.phase("decode_fetch",
                                   slots=len(rec["reqs"])) as attrs:
                 # device_get returns host ndarrays: [B, K] ids + [B] valid.
-                chunk_ids, n_valid = self._fetch(rec["outs"])
+                chunk_ids, n_valid, *counters = self._fetch(rec["outs"])
                 attrs["bytes"] = chunk_ids.nbytes + n_valid.nbytes
         except BaseException as e:  # noqa: BLE001 — fail all waiters
             self._fail_roster(e)
@@ -1481,13 +1517,15 @@ class InferenceEngine:
         active = self.scheduler.active
         delivered = 0
         n_act = len(active)
+        self.metrics.record_model_counters(counters)
+        touched = self._span_attrs(counters)
         with self._tick.phase("decode_deliver", slots=n_act) as attrs:
             for req in list(active):
                 n = int(n_valid[req.slot])
                 delivered += n
                 if req.trace_ctx is not None and n:
                     self._span("engine.decode_chunk", rec["t0"], now, req,
-                               {"tokens": n, "slot": req.slot})
+                               {"tokens": n, "slot": req.slot, **touched})
                 for j in range(n):
                     tok = int(chunk_ids[req.slot, j])
                     req.length += 1

@@ -18,11 +18,22 @@ and ``n_valid`` reports how many of the chunk's tokens were live, so the
 EOS overshoot the old engine paid (up to K-1 wasted host tokens per
 request) is discarded exactly.
 
-The step is ``models/llama.decode_step_with_cache``: all slots in one
-batch, each slot's new K/V row written at its own length, one call a
-layer to the Pallas decode-attention kernel (ops/decode_attention.py)
-on TPU; off-TPU the same code runs the kernel's jnp reference, so CPU
-tests cover the identical loop. The engine's cache is ONE buffer: the
+The model comes through ONE seam: ``cfg.model`` is the configuration's
+own model module (``models/llama.py``, ``models/glm_moe_lite.py``), and
+this file asks it for ``forward_with_cache(params, tokens, row,
+cache_index, cfg)`` (the prefill bucket) and ``decode_step_with_cache(
+params, tokens, cache, lengths, cfg)`` (the step: all slots in one
+batch, each slot's new row a layer written at its own length, the
+family's decode-attention kernel on TPU and its jnp reference off it,
+so CPU tests cover the identical loop). Either may return, after its
+two results, a dict of scalar counters that the tick's programs hand on
+(summed over a chunk's steps: a routed family's expert counters ride
+the fetches the tick already makes) and then a dict of what a check
+against a reference reads (each token's chosen experts), which only
+the functional programs return. A module with
+``forward_last_with_cache(.., last, cfg)`` gets a tick prefill that
+returns ONE row of logits. All this file assumes of a cache is a dict
+of arrays with the slot axis second. The engine's cache is ONE buffer: the
 chunk, verify, install and tick-prefill programs take it donated and
 return it aliased, so the caller must rebind its reference to the
 result (``self.cache = ...``) and must rebuild the cache if a donated
@@ -140,42 +151,69 @@ class DecodeLoop:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import llama
-
         cfg = self.cfg
+        model = cfg.model      # the configuration's own model module
         max_len = self.max_len
 
-        def prefill(params, cache, tokens, slot, cache_index):
-            """tokens [1, Pb] written into ``slot``'s rows at
-            [cache_index, cache_index+Pb) — cache_index > 0 is the
-            prefix-cache path (only the uncached suffix re-prefills)."""
+        def in_slot(forward, cache, slot, *args):
+            """``forward`` on ``slot``'s rows of the cache, written
+            back: -> (logits, cache, *whatever else it returns)."""
             row = {k: jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
                    for k, v in cache.items()}
-            logits, new_row = llama.forward_with_cache(
-                params, tokens, row, cache_index, cfg)
+            logits, new_row, *more = forward(row, *args)
             # slot is bounded by contract: the scheduler only admits
             # into slots < max_batch (the cache's axis-1 extent), so
             # the start can never hit XLA's silent clamp.
             cache = {k: jax.lax.dynamic_update_slice_in_dim(  # rtpu-lint: disable=unclamped-dynamic-update-slice
                 cache[k], new_row[k], slot, axis=1) for k in cache}
-            return logits, cache
+            return (logits, cache, *more)
+
+        def prefill(params, cache, tokens, slot, cache_index):
+            """tokens [1, Pb] written into ``slot``'s rows at
+            [cache_index, cache_index+Pb) — cache_index > 0 is the
+            prefix-cache path (only the uncached suffix re-prefills)."""
+            return in_slot(
+                lambda row: model.forward_with_cache(
+                    params, tokens, row, cache_index, cfg), cache, slot)
+
+        def prefill_last(params, cache, tokens, slot, cache_index, last):
+            """The same, for a family whose vocabulary makes a bucket
+            of logits too large to return (let alone fetch): logits
+            [1, V] of row ``last``, the prompt's last real token."""
+            return in_slot(
+                lambda row: model.forward_last_with_cache(
+                    params, tokens, row, cache_index, last, cfg),
+                cache, slot)
 
         # Two programs of the one function. ``prefill`` is functional:
         # the caller's cache lives on and a new one comes back, which
         # is what a check that prefills the same rows twice wants.
         # ``prefill_inplace`` is the tick's: the cache is donated and
-        # the slot's rows are rewritten where they lie.
+        # the slot's rows are rewritten where they lie; of what the
+        # family returns besides, it hands on the counters alone.
         self.prefill = jax.jit(prefill)
-        self.prefill_inplace = jax.jit(prefill, donate_argnums=(1,))
+        self.last_row_only = hasattr(model, "forward_last_with_cache")
+        forward = prefill_last if self.last_row_only else prefill
+
+        def tick_prefill(*args):
+            return forward(*args)[:3]
+
+        # One name in a device trace for every family's tick prefill.
+        tick_prefill.__name__ = prefill.__name__
+        self.prefill_inplace = jax.jit(tick_prefill, donate_argnums=(1,))
+        if self.last_row_only:
+            # The tick's prefill, functional and whole: what a check
+            # holds to a reference (compiled only if one calls it).
+            self.prefill_last = jax.jit(prefill_last)
 
         def step(params, cache, tokens, lengths):
             """One decode step for every slot: tokens [B,1], lengths [B].
-            Batched by construction (``llama.decode_step_with_cache``):
+            Batched by construction (``decode_step_with_cache``):
             each slot's one new row a layer is written at its own
             length and the rest of the cache is carried untouched."""
-            logits, cache = llama.decode_step_with_cache(
-                params, tokens, cache, lengths, cfg)
-            return jnp.argmax(logits, axis=-1), cache
+            logits, cache, *counters = model.decode_step_with_cache(
+                params, tokens, cache, lengths, cfg)[:3]
+            return (jnp.argmax(logits, axis=-1), cache, *counters)
 
         def decode_chunk(params, cache, tokens, lengths, remaining,
                          eos_ids, done):
@@ -187,7 +225,8 @@ class DecodeLoop:
 
             Returns (chunk_tokens [B, K], n_valid [B], next_tokens
             [B, 1], new_lengths [B], new_remaining [B], done [B],
-            cache). chunk_tokens[b, j] for j >= n_valid[b] are frozen
+            cache) and, last, the step's counters summed over the
+            chunk where the family has any. chunk_tokens[b, j] for j >= n_valid[b] are frozen
             repeats of the slot's final token — discard them. The
             trailing carry (next_tokens/lengths/remaining/done) is the
             EXACT input state of the next chunk for an unchanged
@@ -199,7 +238,7 @@ class DecodeLoop:
 
             def body(carry, _):
                 cache, tok, ln, rem, dn = carry
-                nxt, cache = step(params, cache, tok, ln)
+                nxt, cache, *counters = step(params, cache, tok, ln)
                 emit = jnp.where(dn, tok[:, 0], nxt).astype(jnp.int32)
                 ln = jnp.where(dn, ln, ln + 1)
                 rem = jnp.where(dn, rem, rem - 1)
@@ -209,29 +248,38 @@ class DecodeLoop:
                 fin = ((emit == eos_ids) | (rem <= 0)
                        | (ln + 1 >= max_len))
                 new_dn = dn | fin
-                return (cache, emit[:, None], ln, rem, new_dn), (emit, dn)
+                return ((cache, emit[:, None], ln, rem, new_dn),
+                        (emit, dn, *counters))
 
-            (cache, tok, lengths, remaining, done), (toks, was_done) = \
-                jax.lax.scan(body, (cache, tokens, lengths, remaining,
-                                    done), None, length=self.chunk)
+            (cache, tok, lengths, remaining, done), \
+                (toks, was_done, *counters) = jax.lax.scan(
+                    body, (cache, tokens, lengths, remaining, done), None,
+                    length=self.chunk)
             n_valid = self.chunk - jnp.sum(was_done.astype(jnp.int32),
                                            axis=0)
-            return toks.T, n_valid, tok, lengths, remaining, done, cache
+            counters = jax.tree.map(lambda c: jnp.sum(c, axis=0), counters)
+            return (toks.T, n_valid, tok, lengths, remaining, done, cache,
+                    *counters)
 
         # Donated, like every program the engine binds back to its one
         # cache (this module's header).
         self.decode_chunk = jax.jit(decode_chunk, donate_argnums=(1,))
         # Exposed for the equivalence tests: the same single step the
-        # chunk scans over, jitted standalone (functional).
+        # chunk scans over, jitted standalone (functional); and the
+        # family's step whole, its logits and all it returns besides,
+        # for a check against a reference (compiled only if called).
         self.decode_step = jax.jit(step)
+        self.decode_step_whole = jax.jit(
+            lambda params, cache, tokens, lengths:
+            model.decode_step_with_cache(params, tokens, cache, lengths,
+                                         cfg))
 
     def _build_verify(self) -> None:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import llama
-
         cfg = self.cfg
+        model = cfg.model
         max_len = self.max_len
         W = self.spec_window      # window = 1 committed token + K drafts
         K = W - 1
@@ -244,7 +292,7 @@ class DecodeLoop:
 
             def one(cache_row, tok, idx):
                 row = {k: v[:, None] for k, v in cache_row.items()}
-                logits, new_row = llama.forward_with_cache(
+                logits, new_row = model.forward_with_cache(
                     params, tok[None], row, idx, cfg)
                 return logits[0], {k: v[:, 0]
                                    for k, v in new_row.items()}
@@ -346,7 +394,9 @@ class DecodeLoop:
 
         def export_page(cache, slot, start):
             """-> (k_page [L, KH, P, D], v_page) for rows
-            [start, start+P) of ``slot``."""
+            [start, start+P) of ``slot`` of a {k, v} cache of
+            [L, B, KH, S, D] (a family with another cache refuses the
+            roles and the tier that need pages, at the engine)."""
             L, _B, KH, S, D = cache["k"].shape
             start = jnp.clip(start, 0, S - P)
             out = []

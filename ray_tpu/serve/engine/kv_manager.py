@@ -1,7 +1,10 @@
 """Slot/KV-cache manager: block-granular accounting + prefix caching.
 
-The engine's KV cache is one static [L, B, S, KH, D]-class array in HBM
-(models/llama.py init_kv_cache); a "slot" is one batch row. This module
+The engine's cache is whatever its model module makes (``init_kv_cache``
+of ``cfg.model``): a dict of static arrays in HBM whose SECOND axis is
+the slot and each of whose slots holds ``max_len`` rows, one a token —
+K and V per head for llama, one latent row for glm_moe_lite. A "slot"
+is one index of that axis. Nothing here knows the arrays. This module
 owns which request holds which slot, and — the serving win — remembers
 what tokens a FREED slot still has resident so a later request sharing a
 prompt prefix can skip re-prefilling it (vLLM/PagedAttention-style

@@ -126,6 +126,9 @@ class EngineMetrics:
         # Tick phases: seconds per phase, the loop's wall seconds and
         # its iterations. Written by the engine thread alone (TickClock)
         # without the lock; a snapshot reads each float whole.
+        # What the model's own programs count (a routed family's expert
+        # counters), by the names they give, summed as fetched.
+        self.model_counters: Dict[str, float] = {}
         self.tick_s = dict.fromkeys(TICK_PHASES, 0.0)
         self.tick_loop_s = 0.0
         self.ticks = 0
@@ -184,6 +187,17 @@ class EngineMetrics:
             TOKENS_TOTAL.inc(tokens, labels=self._labels)
             TPOT_SECONDS.observe(elapsed_s / tokens, labels=self._labels)
 
+    def record_model_counters(self, counters) -> None:
+        """What a prefill or a chunk's steps counted on the device: a
+        list holding one dict of named scalars, or nothing (a family
+        without counters). They came with a fetch the tick makes
+        anyway."""
+        with self._lock:
+            for fetched in counters:
+                for name, value in fetched.items():
+                    self.model_counters[name] = (
+                        self.model_counters.get(name, 0) + value.item())
+
     def record_spec(self, drafted: int, accepted: int) -> None:
         """One speculative verify chunk: ``drafted`` tokens proposed
         across the roster, ``accepted`` of them verified correct."""
@@ -219,6 +233,7 @@ class EngineMetrics:
         with self._lock:
             return {
                 **tick,
+                **self.model_counters,
                 "queue_wait_s": self.queue_wait_s,
                 "prefill_wait_s": self.prefill_wait_s,
                 "first_deliver_s": self.first_deliver_s,

@@ -293,6 +293,116 @@ def test_engine_tick_prefill_updates_the_cache_in_place(chip, engine):
     assert functional.memory_analysis().alias_size_in_bytes == 0
 
 
+# ------------------------------------- the latent cache and the experts
+
+# GLM-4.7-Flash's widths (benchmark/configs/glm-4.7-flash-l7.json) with
+# the dense layer and 2 of its expert layers (both stacks are scanned,
+# so the HLO is the 7-layer one's), the cell's 32 slots of 4096 rows.
+def _glm_3l():
+    from ray_tpu.models import glm_moe_lite
+
+    return glm_moe_lite, glm_moe_lite.GlmMoeLiteConfig(n_layers=3,
+                                                       max_seq_len=4096)
+
+
+def test_mla_decode_attention_compiles(chip):
+    """The latent row at the width the cache holds it (576 values
+    padded to 640: five whole lane tiles), 20 query heads, blocks of
+    512 rows: one kernel, under its name, the cache read where it lies
+    (no copy, no temporaries)."""
+    from ray_tpu.ops.mla_decode import mla_decode_attention
+
+    glm, cfg = _glm_3l()
+    assert (cfg.cache_row_values, cfg.cache_row_dim) == (576, 640)
+    c = _compile(
+        lambda q, cache, lens, layer: mla_decode_attention(
+            q, cache, lens, layer=layer, v_dim=cfg.kv_lora_rank,
+            scale=cfg.attn_scale),
+        _sds(chip, (32, cfg.n_heads, 640)), _sds(chip, (3, 32, 4096, 640)),
+        _sds(chip, (32,), jnp.int32), _sds(chip, (), jnp.int32))
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_mla_decode_attention")
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_glm_decode_chunk_updates_the_latent_cache_in_place(chip):
+    """The new family's step through the engine's own `decode_chunk`:
+    the latent kernel once in each stack's scanned body, the expert
+    products as the chip compiler's grouped matmul (three a layer), the
+    donated cache aliased and no array of its shape copied."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    glm, cfg = _glm_3l()
+    slots, rows = 32, 4096
+    loop = DecodeLoop(cfg, max_len=rows, chunk=8)
+    params = _abstract(chip, functools.partial(glm.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: glm.init_kv_cache(cfg, slots, rows))
+    c = _lower_decode_chunk(chip, loop, params, cache, slots)
+    text = c.as_text()
+    assert text.count("%rtpu_mla_decode_attention.") >= 2
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = bf16\[128,", text)) == 3
+    kv = cache["kv"]
+    nbytes = kv.size * kv.dtype.itemsize
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    # No layer's experts are sliced out of their stack either (1.2 GB a
+    # layer a step, once): the grouped product reads the stack whole.
+    assert mem.temp_size_in_bytes < 2 ** 28
+    shape = "bf16[" + ",".join(map(str, kv.shape)) + "]"
+    assert not [line for line in text.splitlines()
+                if shape in line.split("(")[0]
+                and " copy" in line.split("(")[0]]
+    # The chunk hands on the step's counters, not what a check reads of
+    # it; the step whole (functional: a check's) compiles too, and
+    # returns its logits and each token's experts.
+    vec = _sds(chip, (slots,), jnp.int32)
+    out = jax.eval_shape(
+        loop.decode_chunk, params, cache, _sds(chip, (slots, 1), jnp.int32),
+        vec, vec, vec, _sds(chip, (slots,), jnp.bool_))
+    assert len(out) == 8 and "experts" not in out[7]
+    whole = (params, cache, _sds(chip, (slots, 1), jnp.int32), vec)
+    loop.decode_step_whole.lower(*whole).compile()
+    logits, _, counters, seen = jax.eval_shape(loop.decode_step_whole, *whole)
+    assert logits.shape == (slots, cfg.vocab_size)
+    assert set(counters) == set(out[7])
+    assert seen["experts"].shape == (cfg.n_moe_layers, slots, 1,
+                                     cfg.n_experts_per_tok)
+
+
+def test_glm_tick_prefill_returns_one_row_of_logits(chip):
+    """The tick's prefill at the largest bucket: [1, vocab] comes back
+    (not 1.27 GB of [1, 4096, vocab]), the flash kernel and the grouped
+    matmul are in it, the cache aliased; under the one name every
+    family's tick prefill has in a trace."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    glm, cfg = _glm_3l()
+    loop = DecodeLoop(cfg, max_len=4096, chunk=8)
+    assert loop.last_row_only
+    params = _abstract(chip, functools.partial(glm.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: glm.init_kv_cache(cfg, 32, 4096))
+    scalar = _sds(chip, (), jnp.int32)
+    lowered = loop.prefill_inplace.lower(
+        params, cache, _sds(chip, (1, 4096), jnp.int32), scalar, scalar,
+        scalar)
+    assert "jit_prefill" in lowered.as_text()[:200]
+    c = lowered.compile()
+    out = jax.eval_shape(loop.prefill_inplace, params, cache,
+                         _sds(chip, (1, 4096), jnp.int32), scalar, scalar,
+                         scalar)
+    assert out[0].shape == (1, cfg.vocab_size)
+    # Beside logits and cache the counters alone: the experts that the
+    # functional twin (`prefill_last`, a check's) returns stay behind.
+    assert len(out) == 3 and "experts" not in out[2]
+    text = c.as_text()
+    assert "%flash_attention" in text and "%ragged-dot-none" in text
+    kv = cache["kv"]
+    assert c.memory_analysis().alias_size_in_bytes >= (
+        kv.size * kv.dtype.itemsize)
+
+
 def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip):
     """Real width, 2 layers, on a mesh of the four described chips: the
     flash kernel must sit inside a shard_map (XLA cannot partition a
