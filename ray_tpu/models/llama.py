@@ -471,14 +471,13 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int,
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
-def forward_with_cache(params: Params, tokens: jnp.ndarray,
+def _hidden_with_cache(params: Params, tokens: jnp.ndarray,
                        cache: Dict[str, jnp.ndarray], cache_index,
-                       cfg: LlamaConfig) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Prefill-chunk or decode-step forward against a KV cache.
-
-    tokens [B, T] written at [cache_index, cache_index+T); returns logits for
-    those T positions plus the updated cache. ``cache_index`` may be traced.
-    """
+                       cfg: LlamaConfig):
+    """tokens [B, T] written at [cache_index, cache_index+T) -> (x
+    [B, T, d] after the final norm, the updated cache): the one body of
+    ``forward_with_cache`` and ``forward_last_with_cache``, which differ
+    in the rows they give the head."""
     b, t = tokens.shape
     positions = cache_index + jnp.broadcast_to(jnp.arange(t), (b, t))
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
@@ -490,9 +489,33 @@ def forward_with_cache(params: Params, tokens: jnp.ndarray,
         return y, new_kv
 
     x, (new_k, new_v) = lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
-    x = _norm(x, params["ln_out"], cfg)
-    logits = _head_matmul(x, params, cfg)
-    return logits, {"k": new_k, "v": new_v}
+    return _norm(x, params["ln_out"], cfg), {"k": new_k, "v": new_v}
+
+
+def forward_with_cache(params: Params, tokens: jnp.ndarray,
+                       cache: Dict[str, jnp.ndarray], cache_index,
+                       cfg: LlamaConfig) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Prefill-chunk or decode-step forward against a KV cache.
+
+    tokens [B, T] written at [cache_index, cache_index+T); returns logits for
+    those T positions plus the updated cache. ``cache_index`` may be traced.
+    """
+    x, cache = _hidden_with_cache(params, tokens, cache, cache_index, cfg)
+    return _head_matmul(x, params, cfg), cache
+
+
+def forward_last_with_cache(params: Params, tokens: jnp.ndarray,
+                            cache: Dict[str, jnp.ndarray], cache_index,
+                            last, cfg: LlamaConfig
+                            ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """The tick's prefill: the same layers, the head for row ``last``
+    alone (the prompt's last real token; ``last`` may be traced) ->
+    (logits [B, V], cache). Rows past ``last`` are bucket padding:
+    causal, so they move nothing the head reads, and their cache rows
+    lie past the slot's length."""
+    x, cache = _hidden_with_cache(params, tokens, cache, cache_index, cfg)
+    row = lax.dynamic_index_in_dim(x, last, axis=1)             # [B, 1, d]
+    return _head_matmul(row, params, cfg)[:, 0], cache
 
 
 def _write_rows(cache, layer_idx, lengths, rows):

@@ -93,7 +93,7 @@ class InferenceEngine:
     interleaved with the roster's decode chunks (Sarathi-style chunked
     prefill, Agrawal et al. 2024): a long prompt no longer stalls
     every co-batched request's TPOT for its whole prefill. Only the
-    final chunk's logits are fetched (still one counted prefill sync
+    final chunk's token is fetched (still one counted prefill sync
     per admission), the KV manager commits the materialized prefix
     chain per chunk, and greedy output is token-identical to the
     unchunked path (same positions, same rows, same math).
@@ -1038,7 +1038,7 @@ class InferenceEngine:
 
     def _prefill_tick(self) -> None:
         """Advance EVERY in-progress prefill by one chunk. Intermediate
-        chunks are dispatch-only (no host fetch — their logits are
+        chunks are dispatch-only (no host fetch — their token is
         never needed); the decode tick that follows interleaves with
         their device execution, which is what keeps co-batched TPOT
         flat while a long prompt materializes."""
@@ -1064,31 +1064,31 @@ class InferenceEngine:
                 suffix = req.prompt_ids[job.pos:job.pos + n]
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :n] = suffix
-                args = (self._put(padded), self._put(np.int32(slot)),
-                        self._put(np.int32(job.pos)))
-                if self.loop.last_row_only:
-                    # One row of logits comes back, so say which.
-                    args += (self._put(np.int32(n - 1)),)
-                logits, self.cache, *counters = self.loop.prefill_inplace(
-                    self.params, self.cache, *args)
+                # The head reads ONE row, the last real token's, and
+                # its argmax is what comes back.
+                token, self.cache, *counters = self.loop.prefill_inplace(
+                    self.params, self.cache, self._put(padded),
+                    self._put(np.int32(slot)), self._put(np.int32(job.pos)),
+                    self._put(np.int32(n - 1)))
                 # Per-chunk prefix commit: block occupancy and the
                 # slot's resident chain track the materialized prefix
                 # as chunks land, not the whole prompt up-front.
                 self.kv.commit_prefill(slot, req.prompt_ids[:job.pos + n])
             if final:
                 # The ONE counted prefill sync per admission —
-                # intermediate chunks fetch nothing (np.asarray on the
-                # device logits here was the jax-lint rule's first
+                # intermediate chunks fetch nothing (np.asarray on a
+                # device array here was the jax-lint rule's first
                 # in-tree catch: an uncounted implicit sync). It waits
                 # out whatever the device had queued before this
-                # prefill, then copies the whole [1, bucket, vocab]
-                # (one row of it, and the family's counters with it,
-                # where the program returns those).
+                # prefill, then copies 4 bytes (and the family's
+                # counters, where the program returns those).
                 with self._tick.phase("prefill_fetch", slot=slot,
                                       bucket=bucket) as attrs:
-                    logits, *counters = self._fetch((logits, *counters),
-                                                    tag="prefill")
-                    attrs["bytes"] = logits.nbytes
+                    fetched = self._fetch((token, *counters), tag="prefill")
+                    token, *counters = fetched
+                    attrs["bytes"] = sum(
+                        a.nbytes for a in self._jax.tree.leaves(fetched))
+                    self.metrics.record_prefill_fetch(attrs["bytes"])
         except BaseException as e:  # noqa: BLE001 — one bad request
             # must not kill the engine thread (every later request
             # would hang on a dead engine). Seed only the PRE-ACQUIRE
@@ -1114,10 +1114,8 @@ class InferenceEngine:
             return False
         with self._tick.phase("prefill_deliver", slot=slot):
             # First generated token: from the LAST REAL prompt pos (row
-            # n-1 of the final chunk; the one row there is, for a
-            # family whose tick prefill returns that row alone).
-            first = int(np.argmax(logits[0] if self.loop.last_row_only
-                                  else logits[0, n - 1]))
+            # n-1 of the final chunk), chosen on the device.
+            first = int(token[0])
             self.metrics.record_model_counters(counters)
             req.first_token_t = t1
             queue_s = max(0.0, job.t_pf0 - req.arrival_t)

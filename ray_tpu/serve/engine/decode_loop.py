@@ -20,24 +20,27 @@ request) is discarded exactly.
 
 The model comes through ONE seam: ``cfg.model`` is the configuration's
 own model module (``models/llama.py``, ``models/glm_moe_lite.py``), and
-this file asks it for ``forward_with_cache(params, tokens, row,
-cache_index, cfg)`` (the prefill bucket) and ``decode_step_with_cache(
-params, tokens, cache, lengths, cfg)`` (the step: all slots in one
-batch, each slot's new row a layer written at its own length, the
-family's decode-attention kernel on TPU and its jnp reference off it,
-so CPU tests cover the identical loop). Either may return, after its
-two results, a dict of scalar counters that the tick's programs hand on
-(summed over a chunk's steps: a routed family's expert counters ride
-the fetches the tick already makes) and then a dict of what a check
-against a reference reads (each token's chosen experts), which only
-the functional programs return. A module with
-``forward_last_with_cache(.., last, cfg)`` gets a tick prefill that
-returns ONE row of logits. All this file assumes of a cache is a dict
-of arrays with the slot axis second. The engine's cache is ONE buffer: the
-chunk, verify, install and tick-prefill programs take it donated and
-return it aliased, so the caller must rebind its reference to the
-result (``self.cache = ...``) and must rebuild the cache if a donated
-call raises — the old array is deleted either way.
+this file asks it for three functions: ``forward_with_cache(params,
+tokens, row, cache_index, cfg)`` (a prefill bucket with the bucket's
+logits: what checks and the verify program read),
+``forward_last_with_cache(params, tokens, row, cache_index, last,
+cfg)`` (the same layers, the head for row ``last`` alone: the tick's
+prefill, of which the tick's program hands back the argmax) and
+``decode_step_with_cache(params, tokens, cache, lengths, cfg)`` (the
+step: all slots in one batch, each slot's new row a layer written at
+its own length, the family's decode-attention kernel on TPU and its
+jnp reference off it, so CPU tests cover the identical loop). Each may
+return, after its two results, a dict of scalar counters that the
+tick's programs hand on (summed over a chunk's steps: a routed family's
+expert counters ride the fetches the tick already makes) and then a
+dict of what a check against a reference reads (each token's chosen
+experts), which only the functional programs return. All this file
+assumes of a cache is a dict of arrays with the slot axis second. The
+engine's cache is ONE buffer: the chunk, verify, install and
+tick-prefill programs take it donated and return it aliased, so the
+caller must rebind its reference to the result (``self.cache = ...``)
+and must rebuild the cache if a donated call raises — the old array is
+deleted either way.
 
 Speculative verification (``spec_window`` > 1) adds a SECOND chunk
 program, ``verify_chunk``: each scan iteration forwards a ``[B, W]``
@@ -177,34 +180,35 @@ class DecodeLoop:
                     params, tokens, row, cache_index, cfg), cache, slot)
 
         def prefill_last(params, cache, tokens, slot, cache_index, last):
-            """The same, for a family whose vocabulary makes a bucket
-            of logits too large to return (let alone fetch): logits
-            [1, V] of row ``last``, the prompt's last real token."""
+            """The same layers, the head for row ``last`` alone (the
+            prompt's last real token): logits [1, V]. A bucket of them
+            is nothing the tick reads, and too large to fetch."""
             return in_slot(
                 lambda row: model.forward_last_with_cache(
                     params, tokens, row, cache_index, last, cfg),
                 cache, slot)
 
-        # Two programs of the one function. ``prefill`` is functional:
-        # the caller's cache lives on and a new one comes back, which
-        # is what a check that prefills the same rows twice wants.
-        # ``prefill_inplace`` is the tick's: the cache is donated and
-        # the slot's rows are rewritten where they lie; of what the
-        # family returns besides, it hands on the counters alone.
-        self.prefill = jax.jit(prefill)
-        self.last_row_only = hasattr(model, "forward_last_with_cache")
-        forward = prefill_last if self.last_row_only else prefill
-
         def tick_prefill(*args):
-            return forward(*args)[:3]
+            """``prefill_last`` as the tick wants it: the row's argmax
+            (int32 [1]: the first index of the largest, as np.argmax
+            has it), the cache, and of what the family returns besides
+            the counters alone."""
+            logits, cache, *counters = prefill_last(*args)[:3]
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), cache,
+                    *counters)
 
-        # One name in a device trace for every family's tick prefill.
+        # ``prefill`` and ``prefill_last`` are functional: the caller's
+        # cache lives on and a new one comes back, which is what a
+        # check that prefills the same rows twice wants (compiled only
+        # if one calls them). ``prefill_inplace`` is the tick's: the
+        # cache is donated, the slot's rows are rewritten where they
+        # lie, and 4 bytes and the counters are all there is to fetch.
+        self.prefill = jax.jit(prefill)
+        self.prefill_last = jax.jit(prefill_last)
+        # The name that device traces and their readers know the tick's
+        # prefill by (`jit_prefill`).
         tick_prefill.__name__ = prefill.__name__
         self.prefill_inplace = jax.jit(tick_prefill, donate_argnums=(1,))
-        if self.last_row_only:
-            # The tick's prefill, functional and whole: what a check
-            # holds to a reference (compiled only if one calls it).
-            self.prefill_last = jax.jit(prefill_last)
 
         def step(params, cache, tokens, lengths):
             """One decode step for every slot: tokens [B,1], lengths [B].

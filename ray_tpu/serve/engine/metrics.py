@@ -123,6 +123,8 @@ class EngineMetrics:
         self.prefill_wait_s = 0.0
         self.first_deliver_s = 0.0
         self.streams = 0
+        # Bytes the prefill syncs fetched, over `requests`.
+        self.prefill_fetch_bytes = 0
         # Tick phases: seconds per phase, the loop's wall seconds and
         # its iterations. Written by the engine thread alone (TickClock)
         # without the lock; a snapshot reads each float whole.
@@ -161,6 +163,12 @@ class EngineMetrics:
                                         labels={"component": "queue"})
         SERVE_TTFT_BREAKDOWN_MS.observe(prefill_s * 1e3,
                                         labels={"component": "prefill"})
+
+    def record_prefill_fetch(self, nbytes: int) -> None:
+        """One admission's counted prefill sync brought ``nbytes`` to
+        the host: the first token and the family's counters."""
+        with self._lock:
+            self.prefill_fetch_bytes += nbytes
 
     def record_first_deliver(self, seconds: float) -> None:
         """One stream's first token left the stream queue ``seconds``
@@ -236,6 +244,7 @@ class EngineMetrics:
                 **self.model_counters,
                 "queue_wait_s": self.queue_wait_s,
                 "prefill_wait_s": self.prefill_wait_s,
+                "prefill_fetch_bytes": self.prefill_fetch_bytes,
                 "first_deliver_s": self.first_deliver_s,
                 "streams": self.streams,
                 "engine": self.name,

@@ -277,20 +277,32 @@ def test_engine_paged_decode_chunk_compiles_at_llama3_1b(chip):
 
 @pytest.mark.parametrize("engine", ENGINES)
 def test_engine_tick_prefill_updates_the_cache_in_place(chip, engine):
-    """The tick's prefill (donated) beside the check's (functional):
-    the same function, so the same program name in a trace."""
+    """The tick's prefill (donated, hands back a token) beside the
+    check's (functional, the bucket's logits): under one program name
+    in a trace. No array of bucket x vocabulary leaves the tick's: its
+    outputs are smaller than the check's by the logits."""
     from ray_tpu.serve.engine.decode_loop import DecodeLoop
 
     cfg, slots, rows = ENGINES[engine]
+    bucket = 512
     loop = DecodeLoop(cfg, max_len=rows, chunk=8)
     params, cache = _engine_args(chip, cfg, slots, rows)
     scalar = _sds(chip, (), jnp.int32)
-    args = (params, cache, _sds(chip, (1, 512), jnp.int32), scalar, scalar)
-    lowered = loop.prefill_inplace.lower(*args)
+    args = (params, cache, _sds(chip, (1, bucket), jnp.int32), scalar, scalar)
+    lowered = loop.prefill_inplace.lower(*args, scalar)
     assert "jit_prefill" in lowered.as_text()[:200]
-    _assert_cache_in_place(lowered.compile(), cache)
+    tick = lowered.compile()
+    _assert_cache_in_place(tick, cache)
+    token, _ = jax.eval_shape(loop.prefill_inplace, *args, scalar)
+    assert (token.shape, token.dtype) == ((1,), jnp.int32)
+    assert f"[1,{bucket},{cfg.vocab_size}]" not in tick.as_text()
     functional = loop.prefill.lower(*args).compile()
     assert functional.memory_analysis().alias_size_in_bytes == 0
+    logits = jax.eval_shape(loop.prefill, *args)[0]
+    assert logits.shape == (1, bucket, cfg.vocab_size)
+    assert (functional.memory_analysis().output_size_in_bytes
+            - tick.memory_analysis().output_size_in_bytes
+            >= 0.99 * logits.size * logits.dtype.itemsize)
 
 
 # ------------------------------------- the latent cache and the experts
@@ -371,15 +383,15 @@ def test_glm_decode_chunk_updates_the_latent_cache_in_place(chip):
 
 
 def test_glm_tick_prefill_returns_one_row_of_logits(chip):
-    """The tick's prefill at the largest bucket: [1, vocab] comes back
-    (not 1.27 GB of [1, 4096, vocab]), the flash kernel and the grouped
+    """The tick's prefill at the largest bucket: the head reads one
+    row and its argmax comes back (not 1.27 GB of [1, 4096, vocab]; the
+    row itself from the check's twin), the flash kernel and the grouped
     matmul are in it, the cache aliased; under the one name every
     family's tick prefill has in a trace."""
     from ray_tpu.serve.engine.decode_loop import DecodeLoop
 
     glm, cfg = _glm_3l()
     loop = DecodeLoop(cfg, max_len=4096, chunk=8)
-    assert loop.last_row_only
     params = _abstract(chip, functools.partial(glm.init_params, cfg),
                        jax.random.PRNGKey(0))
     cache = _abstract(chip, lambda: glm.init_kv_cache(cfg, 32, 4096))
@@ -392,10 +404,15 @@ def test_glm_tick_prefill_returns_one_row_of_logits(chip):
     out = jax.eval_shape(loop.prefill_inplace, params, cache,
                          _sds(chip, (1, 4096), jnp.int32), scalar, scalar,
                          scalar)
-    assert out[0].shape == (1, cfg.vocab_size)
-    # Beside logits and cache the counters alone: the experts that the
-    # functional twin (`prefill_last`, a check's) returns stay behind.
+    assert (out[0].shape, out[0].dtype) == ((1,), jnp.int32)
+    # Beside token and cache the counters alone: the row of logits and
+    # the experts that the functional twin (`prefill_last`, a check's)
+    # returns stay behind.
     assert len(out) == 3 and "experts" not in out[2]
+    row = jax.eval_shape(loop.prefill_last, params, cache,
+                         _sds(chip, (1, 4096), jnp.int32), scalar, scalar,
+                         scalar)
+    assert row[0].shape == (1, cfg.vocab_size) and "experts" in row[3]
     text = c.as_text()
     assert "%flash_attention" in text and "%ragged-dot-none" in text
     kv = cache["kv"]

@@ -225,7 +225,7 @@ def test_tick_programs_donate_and_the_checks_prefill_does_not():
     assert not kept["k"].is_deleted()
     calls = {
         "prefill_inplace": lambda c: loop.prefill_inplace(
-            params, c, prompt, zero, zero)[1],
+            params, c, prompt, zero, zero, jnp.int32(7))[1],
         "decode_chunk": lambda c: loop.decode_chunk(
             params, c, tokens, lengths, remaining, eos, done)[-1],
         "verify_chunk": lambda c: loop.verify_chunk(

@@ -109,6 +109,25 @@ def test_ttft_is_the_sum_of_its_two_waits(engine):
         s["queue_wait_s"] + s["prefill_wait_s"], rel=1e-12)
 
 
+@pytest.mark.parametrize("prefill_chunk", [0, 4], ids=["whole", "chunked"])
+def test_prefill_fetch_bytes_is_a_token_an_admission(prefill_chunk):
+    """The tick's prefill hands back its token (int32: 4 bytes; this
+    family has no counters) in the ONE counted fetch of an admission; a
+    non-final chunk of a chunked prefill fetches nothing."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    eng = LLMEngine(prefill_chunk=prefill_chunk, **ENGINE_KW)
+    try:
+        assert eng.stats()["prefill_fetch_bytes"] == 0
+        for i, n in enumerate((3, 11, 14), start=1):    # 1, 3, 4 chunks
+            eng.generate(list(range(1, n + 1)), max_new_tokens=4)
+            stats = eng.stats()
+            assert stats["requests"] == i
+            assert stats["prefill_fetch_bytes"] == 4 * i
+    finally:
+        eng.close()
+
+
 def test_first_deliver_moves_once_per_streamed_request(engine):
     engine.generate([1, 2, 3], max_new_tokens=4)       # not streamed
     assert engine.stats()["streams"] == 0

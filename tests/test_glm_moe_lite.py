@@ -213,7 +213,7 @@ def test_engine_serves_the_family_end_to_end(tiny):
         cfg=cfg, params=params, **ENGINE)), _local_testing_mode=True)
     engine = handle._instance.engine
     try:
-        assert set(engine.cache) == {"kv"} and engine.loop.last_row_only
+        assert set(engine.cache) == {"kv"}
         rng = np.random.default_rng(0)
         prompts = [[int(t) for t in rng.integers(1, 256, n)]
                    for n in (20, 45, 33)]
@@ -233,6 +233,8 @@ def test_engine_serves_the_family_end_to_end(tiny):
         engine.close()
     # One fetch a prefill, one a chunk of 4: 9 decoded tokens = 3 chunks.
     assert stats["decode_host_syncs"] == 3 * 3
+    # A prefill's fetch: its token and its three counters, 4 bytes each.
+    assert stats["prefill_fetch_bytes"] == 3 * (4 + 3 * 4)
     assert stats["moe_prefill_tokens"] == 20 + 45 + 33
     assert stats["moe_layer_steps"] == 9 * 4 * 2
     assert 0 < stats["moe_expert_hits"] <= stats["moe_layer_steps"] * 8
@@ -261,28 +263,126 @@ def test_engine_refuses_what_the_latent_cache_cannot_do(tiny, option):
 
 
 def test_llama_builds_its_programs_from_its_own_functions(monkeypatch):
-    """The seam did not move the llama family: `DecodeLoop` traces
-    `llama.forward_with_cache` and `llama.decode_step_with_cache`, its
-    tick prefill returns the bucket's logits and its chunk no counters."""
+    """The seam's three functions are the ones `DecodeLoop` traces for
+    the llama family: the check's prefill returns the bucket's logits,
+    the tick's a token (no counters), the chunk its seven results."""
     from ray_tpu.serve.engine.decode_loop import DecodeLoop
 
     cfg = llama.tiny_config(max_seq_len=64)
     assert cfg.model is llama
     called = []
-    for name in ("forward_with_cache", "decode_step_with_cache"):
+    for name in ("forward_with_cache", "forward_last_with_cache",
+                 "decode_step_with_cache"):
         fn = getattr(llama, name)
         monkeypatch.setattr(
             llama, name,
             lambda *a, _fn=fn, _name=name: called.append(_name) or _fn(*a))
     loop = DecodeLoop(cfg, max_len=64, chunk=4)
-    assert not loop.last_row_only
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     cache = llama.init_kv_cache(cfg, 2, 64)
-    out = loop.prefill(params, cache, jnp.zeros((1, 16), jnp.int32),
-                       jnp.int32(0), jnp.int32(0))
+    prompt, zero = jnp.zeros((1, 16), jnp.int32), jnp.int32(0)
+    out = loop.prefill(params, cache, prompt, zero, zero)
     assert len(out) == 2 and out[0].shape == (1, 16, cfg.vocab_size)
+    out = loop.prefill_inplace(params, out[1], prompt, zero, zero,
+                               jnp.int32(15))
+    assert len(out) == 2 and out[0].shape == (1,)
+    assert out[0].dtype == jnp.int32
     vec = jnp.zeros((2,), jnp.int32)
     out = loop.decode_chunk(params, out[1], jnp.zeros((2, 1), jnp.int32), vec,
                             vec + 4, vec - 1, jnp.zeros((2,), bool))
     assert len(out) == 7
-    assert called == ["forward_with_cache", "decode_step_with_cache"]
+    assert called == ["forward_with_cache", "forward_last_with_cache",
+                      "decode_step_with_cache"]
+
+
+# Both families through the one tick prefill: (model module,
+# configuration, params) by name.
+FAMILIES = ["llama", "llama-tied", "glm"]
+BUCKET, MAX_LEN = 16, 64
+
+
+@pytest.fixture(scope="module")
+def families(tiny):
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    out = {"glm": (glm,) + tiny[1:]}
+    for name, tied in (("llama", False), ("llama-tied", True)):
+        cfg = llama.tiny_config(max_seq_len=MAX_LEN, tie_embeddings=tied)
+        out[name] = (llama, cfg, llama.init_params(cfg, jax.random.PRNGKey(7)))
+    return {name: (model, cfg, params,
+                   DecodeLoop(cfg, max_len=MAX_LEN, chunk=4))
+            for name, (model, cfg, params) in out.items()}
+
+
+@pytest.mark.parametrize("cache_index", [0, 24], ids=["fresh", "prefix"])
+@pytest.mark.parametrize("last", [0, 7, BUCKET - 1])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_tick_prefill_is_the_checks_prefill_with_one_row_of_head(
+        families, family, last, cache_index):
+    """`prefill_last`'s row is `prefill`'s ``logits[0, last]`` (one body
+    up to the final norm), after a resident prefix too; the tick's
+    program gives that row's argmax and `prefill_last`'s cache."""
+    model, cfg, params, loop = families[family]
+    slot, put = jnp.int32(1), jnp.int32
+    tokens = _tokens(11, (1, cache_index + BUCKET))
+    cache = model.init_kv_cache(cfg, 2, MAX_LEN)
+    if cache_index:
+        cache = loop.prefill(params, cache, tokens[:, :cache_index], slot,
+                             put(0))[1]
+    # Real tokens up to ``last``, then the bucket's padding.
+    real = jnp.arange(BUCKET) <= last
+    chunk = jnp.where(real, tokens[:, cache_index:], 0)
+    args = (chunk, slot, put(cache_index))
+    whole, want_cache, *_ = loop.prefill(params, cache, *args)
+    row, got_cache, *_ = loop.prefill_last(params, cache, *args, put(last))
+    assert whole.shape == (1, BUCKET, cfg.vocab_size)
+    assert row.shape == (1, cfg.vocab_size)
+    np.testing.assert_allclose(row[0], whole[0, last], rtol=1e-5, atol=1e-5)
+    # The slot's rows up to the last real token: what follows is
+    # padding's, past the slot's length (a routed family gives its
+    # padding to no expert, so those rows may differ).
+    rows = cache_index + last + 1
+    for key in cache:
+        axis = cache[key].ndim - 2              # [.., rows, width]
+        take = lambda a: np.asarray(jnp.take(a, jnp.arange(rows), axis))
+        np.testing.assert_allclose(take(got_cache[key]),
+                                   take(want_cache[key]),
+                                   rtol=1e-5, atol=1e-5)
+    token, ticked, *counters = loop.prefill_inplace(
+        params, jax.tree.map(jnp.copy, cache), *args, put(last))
+    assert token.shape == (1,) and token.dtype == jnp.int32
+    assert int(token[0]) == int(np.argmax(np.asarray(row[0])))
+    for key in cache:
+        np.testing.assert_array_equal(ticked[key], got_cache[key])
+    assert len(counters) == (1 if family == "glm" else 0)
+
+
+PROMPTS = [20, 45, 33]
+
+
+@pytest.mark.parametrize("prefill_chunk", [0, 16], ids=["whole", "chunked"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_engine_tokens_are_the_models_greedy_ones(families, family,
+                                                  prefill_chunk):
+    """End to end, the first token from the tick's device argmax:
+    every token is the argmax after what precedes it (teacher-forced
+    through the family's full forward), with prompts over one and
+    several chunks and a repeated prompt that hits the prefix cache."""
+    from ray_tpu.serve.engine.core import InferenceEngine
+
+    model, cfg, params, _ = families[family]
+    engine = InferenceEngine(cfg, params, prefill_chunk=prefill_chunk,
+                             **ENGINE)
+    try:
+        rng = np.random.default_rng(0)
+        prompts = [[int(t) for t in rng.integers(1, 256, n)] for n in PROMPTS]
+        prompts.append(prompts[1][:40] + [3, 1, 4])
+        for prompt in prompts:
+            out = engine.generate(prompt, max_new_tokens=10)
+            got = out["token_ids"]
+            logits = model.forward(params, jnp.asarray([prompt + got]), cfg)[0]
+            assert got == np.asarray(
+                jnp.argmax(logits[len(prompt) - 1:-1], -1)).tolist()
+    finally:
+        engine.close()
+    assert out["cached_prefix_len"] > 0
