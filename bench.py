@@ -34,7 +34,7 @@ Rows:
   (RMSNorm / rope / SwiGLU, ops/fused.py) and the decode-shaped matmul's
   weight-streaming GB/s at the working dtype vs weight-only int8
   (``baseline_dtype`` names the precision) — so a kernel
-  regression is visible in BENCH_r0N without a full train run.
+  regression is visible without a full train run.
 - llm_decode_tokens_per_s_int8 — the decode bench re-run with
   ``quantize="int8"`` (weight-only int8, models/quant.py) on an
   otherwise identical engine; carries ``speedup_vs_f32``.
@@ -66,9 +66,8 @@ Rows:
   = multi/single — concurrent writers must not fall below one), node-to-
   node pull bandwidth over the scatter-gather transfer path
   (``pull_gbps``), and n-callers x n-actors calls with array args
-  (``actor_args_nn_per_s``). Needs a loadable native store lib
-  (RTPU_SHM_STORE_SO on containers whose glibc rejects the checked-in
-  .so).
+  (``actor_args_nn_per_s``). Needs the native store lib (built from
+  source on first use).
 - data — streaming Dataset executor suite (``--data`` standalone):
   same-window alternating A/B of ``random_shuffle`` with the exchange
   on the channel mesh vs per-task RPC (``data_shuffle_gbps_channel`` /

@@ -1,99 +1,126 @@
-"""Build the native components (g++ -O2 -shared) into ray_tpu/_cpp/*.so.
+"""Build the native store library from its one source, `shm_store.cc`.
 
-Run directly (`python ray_tpu/_cpp/build.py`) or let
-`ray_tpu.core.shm_store.ensure_built()` invoke it lazily on first use.
+`ensure_built()` is the one place that decides whether a compile is
+needed, and `ray_tpu.core.shm_store._load_lib()` calls it on first use.
+The artefact's NAME carries a digest of the source and of the compile
+command (`libshm_store-<digest>.so`), so a library built from other
+source or other flags is simply another file that nobody opens: nothing
+is checked in, nothing can be stale, and there is no switch to rebuild.
 
-NOTE: shm_store.cc layout v2 (sharded arena) changed the mapped segment
-format AND the library ABI (rtpu_store_create gained n_shards,
-rtpu_obj_create gained pref_shard). Any previously built .so — including
-one an RTPU_SHM_STORE_SO override points at — must be rebuilt from the
-current source; the Python client checks rtpu_lib_layout_version() at
-load and refuses stale builds with a clear error. On containers whose
-glibc rejects the checked-in binary, build OUT of tree and point
-RTPU_SHM_STORE_SO at the result (see .claude/skills/verify/SKILL.md).
+Concurrent callers (the xdist workers of a test run; the head, nodes and
+workers of one cluster) compile at most once: an `flock` on a file beside
+the artefact serialises them, `g++` writes to a temporary name in the
+destination and `os.replace` publishes it whole.
+
+Run directly (`python ray_tpu/_cpp/build.py [--sanitize=address|thread]
+[--out-dir DIR]`) to get the path printed; `RTPU_SHM_STORE_SO` names a
+library built elsewhere (a sanitizer build, a read-only install).
 """
 
 from __future__ import annotations
 
 import argparse
+import fcntl
+import hashlib
 import os
 import subprocess
 import sys
+import tempfile
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+SOURCE = os.path.join(HERE, "shm_store.cc")
 
-TARGETS = [
-    ("shm_store.cc", "libshm_store.so", ["-lpthread", "-lrt"]),
-]
+CXX = ["g++", "-O2", "-g", "-std=c++17", "-shared", "-fPIC"]
+LIBS = ["-lpthread", "-lrt"]
 
 #: --sanitize flag -> extra g++ flags. Sanitized builds are for hunting
-#: races/overflows in shm_store.cc under the dataplane tests; they are
-#: slower and must NEVER overwrite the checked-in .so — they build
-#: out-of-tree and are loaded via RTPU_SHM_STORE_SO.
+#: races/overflows in shm_store.cc under the dataplane tests. Their flags
+#: are part of the digest, so the loader never picks one up by itself:
+#: they are loaded via RTPU_SHM_STORE_SO.
 SANITIZERS = {
     "address": ["-fsanitize=address", "-fno-omit-frame-pointer"],
     "thread": ["-fsanitize=thread", "-fno-omit-frame-pointer"],
 }
 
 
-def build(verbose: bool = True, force: bool = False,
-          sanitize: str | None = None,
-          out_dir: str | None = None) -> list[str]:
-    extra: list[str] = []
-    if sanitize is not None:
-        extra = SANITIZERS[sanitize]
-        if out_dir is None:
-            # Default the sanitized artifact out of tree: an in-tree
-            # sanitized .so would both dirty the checked-in binary and
-            # drag libasan/libtsan into every normal cluster boot.
-            out_dir = os.path.join("/tmp", f"rtpu_native_{sanitize}")
-        force = True  # flags changed: mtime shortcut would lie
-    dest = out_dir or HERE
+def _default_dir() -> str:
+    """Beside the source when that is writable (a checkout), else a
+    per-user directory under the system's temporary directory (a
+    read-only install)."""
+    if os.access(HERE, os.W_OK):
+        return HERE
+    return os.path.join(tempfile.gettempdir(), f"rtpu_native_{os.getuid()}")
+
+
+def ensure_built(out_dir: str | None = None, sanitize: str | None = None,
+                 verbose: bool = False) -> str:
+    """Path of the library built from the current `shm_store.cc` with the
+    current flags, compiling it first if no such file exists yet."""
+    flags = CXX + (SANITIZERS[sanitize] if sanitize else [])
+    with open(SOURCE, "rb") as f:
+        digest = hashlib.sha256(
+            f.read() + "\0".join(flags + LIBS).encode()).hexdigest()[:16]
+    dest = out_dir or _default_dir()
     os.makedirs(dest, exist_ok=True)
-    built = []
-    for src, out, libs in TARGETS:
-        src_p = os.path.join(HERE, src)
-        out_p = os.path.join(dest, out)
-        if (not force and os.path.exists(out_p)
-                and os.path.getmtime(out_p) >= os.path.getmtime(src_p)):
-            built.append(out_p)
-            continue
-        cmd = (["g++", "-O2", "-g", "-std=c++17", "-shared", "-fPIC"]
-               + extra + ["-o", out_p, src_p] + libs)
+    out = os.path.join(dest, f"libshm_store-{digest}.so")
+    if os.path.exists(out):
+        return out
+    with open(os.path.join(dest, ".libshm_store.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)  # released when `lock` closes
+        if os.path.exists(out):
+            return out  # another process compiled it while we waited
+        tmp = os.path.join(dest, ".libshm_store.tmp")  # ours: we hold the lock
+        cmd = flags + ["-o", tmp, SOURCE] + LIBS
         if verbose:
             print("+", " ".join(cmd), file=sys.stderr)
-        subprocess.run(cmd, check=True)
-        built.append(out_p)
-    if sanitize is not None and verbose:
-        # dlopen-ing a sanitized .so into a plain python process aborts
-        # ("runtime does not come first in initial library list") unless
-        # the sanitizer runtime is preloaded.
-        rt_lib = {"address": "libasan.so", "thread": "libtsan.so"}[sanitize]
-        preload = subprocess.run(
-            ["g++", f"-print-file-name={rt_lib}"],
-            capture_output=True, text=True).stdout.strip()
-        print(f"sanitized ({sanitize}) build is out-of-tree; run the "
-              f"cluster against it with:\n"
-              f"  export RTPU_SHM_STORE_SO={built[0]}\n"
-              f"  export LD_PRELOAD={preload or rt_lib}",
-              file=sys.stderr)
-    return built
+        try:
+            subprocess.run(cmd, check=True, capture_output=not verbose,
+                           text=True)
+            os.replace(tmp, out)
+        except FileNotFoundError as e:
+            raise OSError(
+                "the native store library must be compiled from "
+                f"{SOURCE} and `g++` was not found; install g++, or set "
+                "RTPU_SHM_STORE_SO to a library built elsewhere with "
+                "`python ray_tpu/_cpp/build.py --out-dir DIR`") from e
+        except subprocess.CalledProcessError as e:
+            raise OSError(
+                f"`{' '.join(cmd)}` failed ({e.returncode}); set "
+                "RTPU_SHM_STORE_SO to a library built elsewhere if this "
+                f"machine cannot compile it:\n{e.stderr or ''}") from e
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+    return out
 
 
 def main() -> None:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--sanitize", choices=sorted(SANITIZERS),
                    help="build with AddressSanitizer/ThreadSanitizer "
-                        "(out-of-tree; load via RTPU_SHM_STORE_SO)")
+                        "(load via RTPU_SHM_STORE_SO)")
     p.add_argument("--out-dir",
-                   help="directory for the built .so (default: in-tree, "
-                        "or /tmp/rtpu_native_<sanitizer> when "
-                        "--sanitize is given)")
-    p.add_argument("--force", action="store_true",
-                   help="rebuild even if the output is newer than the "
-                        "source")
+                   help="directory for the built .so (default: beside the "
+                        "source, or a per-user temporary directory when "
+                        "that is read-only)")
     args = p.parse_args()
-    build(force=args.force, sanitize=args.sanitize, out_dir=args.out_dir)
+    out = ensure_built(out_dir=args.out_dir, sanitize=args.sanitize,
+                       verbose=True)
+    print(out)
+    if args.sanitize:
+        # dlopen-ing a sanitized .so into a plain python process aborts
+        # ("runtime does not come first in initial library list") unless
+        # the sanitizer runtime is preloaded.
+        rt_lib = {"address": "libasan.so", "thread": "libtsan.so"}[
+            args.sanitize]
+        preload = subprocess.run(
+            ["g++", f"-print-file-name={rt_lib}"],
+            capture_output=True, text=True).stdout.strip()
+        print(f"run the cluster against the sanitized ({args.sanitize}) "
+              f"build with:\n"
+              f"  export RTPU_SHM_STORE_SO={out}\n"
+              f"  export LD_PRELOAD={preload or rt_lib}",
+              file=sys.stderr)
 
 
 if __name__ == "__main__":

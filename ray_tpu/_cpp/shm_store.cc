@@ -46,9 +46,9 @@
 //     takeover timer) clears. Both windows are microseconds of C code
 //     with no syscalls besides the mutexes.
 //   - kLayoutVersion is stamped into the mapped header and exported from
-//     the library (rtpu_lib_layout_version) so a stale prebuilt .so — or a
-//     stale RTPU_SHM_STORE_SO override — fails fast at attach instead of
-//     silently corrupting the arena. Rebuild: python ray_tpu/_cpp/build.py
+//     the library (rtpu_lib_layout_version) so a segment another build
+//     created, or a library from outside named by RTPU_SHM_STORE_SO,
+//     fails fast at attach instead of silently corrupting the arena.
 //   - spill_files: lock-free counter of live spill files for this store;
 //     the Python layer checks it before paying unlink/stat syscalls on the
 //     (overwhelmingly common) spill-less delete path.

@@ -201,6 +201,13 @@ class HeadServer:
 
         self._unmet_demand = _collections.deque(
             maxlen=cfg.head_demand_window_max)
+        # Requesters with an entry in the ring. A requester whose pick is
+        # later MET (found capacity free right now) takes its entries out
+        # again: they are no demand any more. Without this a task that
+        # starved for two seconds and then ran stood as unmet demand for
+        # the whole window, and the autoscaler bought a second node for a
+        # task that had finished (see _note_unmet / _note_met).
+        self._unmet_keys: Set[Any] = set()
         # Span sink for distributed tracing (util/tracing.py). Entries
         # are (approx_bytes, span): bounded by COUNT and by BYTES —
         # spans carry user attrs, and a count-only bound let one chatty
@@ -792,6 +799,26 @@ class HeadServer:
                     return n, True
         return None, False
 
+    def _note_unmet(self, demand: Dict[str, Any], demand_key) -> None:
+        ring = self._unmet_demand
+        ring.append((time.monotonic(), demand, demand_key))
+        self._unmet_keys.add(demand_key)
+        if len(self._unmet_keys) > ring.maxlen:
+            # Requesters that starved and never came back: keep only the
+            # keys the ring still holds.
+            self._unmet_keys = {key for _t, _d, key in list(ring)}
+
+    def _note_met(self, demand_key) -> None:
+        """The requester's pick found free capacity: what it failed to get
+        before is met. One set lookup on the pick path; the set is empty
+        whenever nothing starves."""
+        if demand_key in self._unmet_keys:
+            self._unmet_keys.discard(demand_key)
+            ring = self._unmet_demand
+            self._unmet_demand = type(ring)(
+                (e for e in list(ring) if e[2] != demand_key),
+                maxlen=ring.maxlen)
+
     def rpc_pick_node(self, conn, resources: Dict[str, float],
                       strategy: Optional[Dict[str, Any]] = None,
                       exclude: Optional[List[str]] = None,
@@ -863,8 +890,7 @@ class HeadServer:
                     demand = dict(resources)
                     if hard:
                         demand["_labels"] = tuple(sorted(hard.items()))
-                    self._unmet_demand.append(
-                        (time.monotonic(), demand, demand_key))
+                    self._note_unmet(demand, demand_key)
                     return None
 
                 def rank(n):
@@ -905,9 +931,9 @@ class HeadServer:
                 n, saturated = self._pick_first_fit(resources,
                                                     exclude_set)
                 if n is None or saturated:
-                    self._unmet_demand.append(
-                        (time.monotonic(), dict(resources),
-                         demand_key))
+                    self._note_unmet(dict(resources), demand_key)
+                else:
+                    self._note_met(demand_key)
                 if n is None:
                     return None
                 if not input_objects:
@@ -916,14 +942,13 @@ class HeadServer:
         if ranked is None:
             ranked, saturated = self._score_nodes_ex(resources,
                                                      exclude_set)
-            if not ranked:
-                self._unmet_demand.append(
-                    (time.monotonic(), dict(resources), demand_key))
-                return None
-            if saturated:
+            if not ranked or saturated:
                 # Demand exceeds current capacity (autoscaler signal).
-                self._unmet_demand.append(
-                    (time.monotonic(), dict(resources), demand_key))
+                self._note_unmet(dict(resources), demand_key)
+                if not ranked:
+                    return None
+            else:
+                self._note_met(demand_key)
         n = ranked[0]
         if input_objects:
             # In the saturated fallback the lease QUEUES at the picked
@@ -1061,9 +1086,13 @@ class HeadServer:
                                 for k, v in resources.items() if v > 0)):
                     picked = (n.node_id, n.address, n.store_name)
         if picked is None:
-            picked = self.rpc_pick_node(None, resources, strategy, None,
-                                        ("lease_block", owner_addr),
-                                        locality_hint)
+            # The owner's own demand identity (cluster_core's picks use
+            # the same): a block is asked for tasks the owner's picks and
+            # backlog report already stand for, never a demand beside them.
+            picked = self.rpc_pick_node(
+                None, resources, strategy, None,
+                (owner_addr, tuple(sorted(resources.items()))),
+                locality_hint)
         if picked is None:
             return None
         node_id, node_addr, _store = picked
@@ -1962,7 +1991,7 @@ class HeadServer:
                     backlog_shapes.add(tuple(sorted(resources.items())))
                     demands.extend([dict(resources)] * int(count))
             ring: dict = {}
-            for t, d, key in self._unmet_demand:
+            for t, d, key in list(self._unmet_demand):
                 if t >= cutoff:
                     shape = tuple(sorted(d.items()))
                     ring[(key, shape)] = (shape, d)

@@ -549,6 +549,14 @@ class NodeManager:
                     return  # shutdown raced the beat: conn loss expected
                 logger.debug("heartbeat to head failed (%r); "
                              "reconnecting", e)
+                if isinstance(e, TimeoutError):
+                    # A lost frame, not a lost head: send the next beat at
+                    # once (min_gap still spaces them). Waiting out another
+                    # period first made every lost beat cost TWO periods of
+                    # the head's miss window (threshold x period), so three
+                    # lost in a row — 5 % RPC chaos does that within
+                    # minutes — read as a dead node, and its actors died.
+                    self._hb_wake.set()
                 try:
                     self._head.reconnect()
                 except Exception as e2:

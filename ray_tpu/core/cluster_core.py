@@ -1987,7 +1987,7 @@ class ClusterCore:
         try:
             reqs = []
             for s in samples:
-                demand_key = (self.worker_id.hex(),
+                demand_key = (self.owner_addr,
                               tuple(sorted(s.resources.items())))
                 reqs.append((s.resources, s.strategy, [], demand_key,
                              self._locality_hint_for(s)))
@@ -2256,7 +2256,7 @@ class ClusterCore:
         # Demand identity for the head's unmet-demand ring: this
         # submitter + shape. Retries of one starved key stay one demand;
         # distinct submitters register separately.
-        demand_key = (self.worker_id.hex(),
+        demand_key = (self.owner_addr,
                       tuple(sorted(resources.items())))
         for hop in range(4):  # a few spillback hops per attempt
             if hop == 0 and first_pick is not None:

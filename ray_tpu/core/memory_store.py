@@ -118,17 +118,42 @@ class MemoryStore:
         object_ids: List[ObjectID],
         timeout: Optional[float] = None,
     ) -> List[_Record]:
+        """Block until every id is present. Each waiter parks on an event
+        of its OWN, set by the put of the id it waits for (the
+        ``get_async`` callback), not on the store-wide condition: with W
+        waiters on W different ids — a chain of dependent tasks whose
+        workers each ask the owner for one argument — a store-wide
+        ``notify_all`` woke all W on every put, O(W) lock hand-offs per
+        completion and O(W^2) for the chain."""
         deadline = None if timeout is None else time.monotonic() + timeout
-        records: List[_Record] = []
-        with self._cv:
-            for oid in object_ids:
-                while oid not in self._objects:
-                    remaining = None if deadline is None else deadline - time.monotonic()
-                    if remaining is not None and remaining <= 0:
-                        raise GetTimeoutError(f"timed out waiting for {oid}")
-                    self._cv.wait(timeout=remaining)
-                records.append(self._objects[oid])
+        with self._lock:  # one pass for what is already here
+            records = [self._objects.get(oid) for oid in object_ids]
+        for i, rec in enumerate(records):
+            if rec is None:
+                records[i] = self._await(object_ids[i], deadline)
         return records
+
+    def _await(self, oid: ObjectID, deadline: Optional[float]) -> _Record:
+        got: List[_Record] = []
+        arrived = threading.Event()
+
+        def on_put(rec: _Record) -> None:
+            got.append(rec)
+            arrived.set()
+
+        remaining = None if deadline is None else deadline - time.monotonic()
+        if remaining is None or remaining > 0:
+            self.get_async(oid, on_put)  # fires at once if already there
+            arrived.wait(remaining)
+            if not got:
+                self.remove_callback(oid, on_put)
+        if not got:  # a put may have slipped in before the deregistration
+            with self._lock:
+                rec = self._objects.get(oid)
+            if rec is None:
+                raise GetTimeoutError(f"timed out waiting for {oid}")
+            return rec
+        return got[0]
 
     def wait(
         self,

@@ -39,21 +39,21 @@ _LAYOUT_VERSION = 2
 
 def _check_layout_version(lib, so: str) -> None:
     """Refuse a store library whose compiled-in layout disagrees with this
-    client. A stale prebuilt .so (or an RTPU_SHM_STORE_SO override pointing
-    at an old build) must fail LOUDLY at load, not corrupt the arena."""
+    client. The library this module builds itself is named by a digest of
+    its source and cannot disagree; this is the check on a file from
+    outside, named by RTPU_SHM_STORE_SO, which must fail LOUDLY at load,
+    not corrupt the arena."""
     try:
         lib.rtpu_lib_layout_version.restype = ctypes.c_uint64
         got = int(lib.rtpu_lib_layout_version())
     except AttributeError:
         got = 1  # pre-versioning builds exported no version symbol
     if got != _LAYOUT_VERSION:
-        override = os.environ.get("RTPU_SHM_STORE_SO")
-        hint = (f" (RTPU_SHM_STORE_SO points at {override!r} — rebuild "
-                "that file or unset the override)" if override else "")
         raise OSError(
             f"stale shm store library {so!r}: layout version {got}, "
-            f"this client needs {_LAYOUT_VERSION}. Rebuild with "
-            f"`python ray_tpu/_cpp/build.py`{hint}.")
+            f"this client needs {_LAYOUT_VERSION}. Rebuild that file from "
+            "the current source (`python ray_tpu/_cpp/build.py --out-dir "
+            "DIR`) or unset RTPU_SHM_STORE_SO.")
 
 
 def _load_lib():
@@ -61,39 +61,18 @@ def _load_lib():
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB
-        # RTPU_SHM_STORE_SO points at an out-of-tree build of the store
-        # library (e.g. one rebuilt for this machine's glibc) without
-        # touching the checked-in binary; inherited by every spawned
-        # head/node/worker process.
+        # RTPU_SHM_STORE_SO names a library built elsewhere (a sanitizer
+        # build, a read-only install); inherited by every spawned
+        # head/node/worker process. Without it the library is built from
+        # shm_store.cc on first use, under a name that carries the
+        # source's digest (see _cpp/build.py).
         so = os.environ.get("RTPU_SHM_STORE_SO") or ""
         if not so:
-            here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-            so = os.path.join(here, "_cpp", "libshm_store.so")
-        if not os.path.exists(so):
-            from ray_tpu._cpp.build import build
+            from ray_tpu._cpp.build import ensure_built
 
-            build(verbose=False)
-        try:
-            lib = ctypes.CDLL(so)
-            _check_layout_version(lib, so)
-        except OSError as e:
-            # The shipped .so was built against a different libc (e.g.
-            # `GLIBC_2.33 not found`) or from pre-layout-bump source.
-            # Rebuilding from the checked-in source fixes it, but only on
-            # explicit request: an implicit rebuild here would race (every
-            # node process dlopens this path — concurrent g++ runs into
-            # one .so corrupt it).
-            if os.environ.get("RTPU_REBUILD_NATIVE") != "1":
-                raise OSError(
-                    f"{e}\nThe prebuilt libshm_store.so does not match "
-                    "this machine/source; rerun with RTPU_REBUILD_NATIVE=1 "
-                    "(or run `python ray_tpu/_cpp/build.py`) to rebuild it "
-                    "from source.") from e
-            from ray_tpu._cpp.build import build
-
-            build(verbose=False, force=True)
-            lib = ctypes.CDLL(so)
-            _check_layout_version(lib, so)
+            so = ensure_built()
+        lib = ctypes.CDLL(so)
+        _check_layout_version(lib, so)
         lib.rtpu_store_create.restype = ctypes.c_void_p
         lib.rtpu_store_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
                                           ctypes.c_uint64, ctypes.c_uint64,
@@ -207,8 +186,8 @@ class ShmStore:
             raise OSError(
                 f"shm store {name!r} has layout version {seg_ver}, this "
                 f"client needs {_LAYOUT_VERSION}; the creating process ran "
-                "a different build — rebuild everything with "
-                "`python ray_tpu/_cpp/build.py` and restart the cluster.")
+                "a different build — restart the cluster from one "
+                "source tree.")
         self.n_shards = int(self._lib.rtpu_store_n_shards(self._h))
         # Allocation affinity: this process prefers one sub-arena, so the
         # blocks it cycles through stay mapped in ITS page tables (soft
@@ -277,8 +256,7 @@ class ShmStore:
             raise OSError(
                 f"failed to open shm store {name!r} (missing, or created "
                 f"by a build with a different layout version — expected "
-                f"v{_LAYOUT_VERSION}; rebuild with "
-                "`python ray_tpu/_cpp/build.py`)")
+                f"v{_LAYOUT_VERSION})")
         return cls(h, name, owner=False)
 
     def close(self) -> None:

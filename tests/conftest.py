@@ -22,6 +22,14 @@ import jax  # noqa: E402
 # Pin the platform list before any backend is initialized: the suite
 # never runs on a chip, whatever the environment it is started from.
 jax.config.update("jax_platforms", "cpu")
+# And the device count, where a later edit of XLA_FLAGS cannot undo it:
+# a benchmark rehearsal run in-process (`benchmark/harness/context.py`,
+# `rehearse=True`) appends `--xla_force_host_platform_device_count=<the
+# cell's chips>`, and the last flag wins — the xdist worker whose FIRST
+# jax user was tests/benchmark/test_routed_controls.py came up with one
+# CPU device, and `cpu_mesh8` failed for whichever file landed there
+# next (about one whole run in six: PR 31).
+jax.config.update("jax_num_cpu_devices", 8)
 
 import glob  # noqa: E402
 
@@ -57,10 +65,24 @@ for _stale in glob.glob("/dev/shm/rtpu_store_*"):
 
 
 def pytest_configure(config):
-    # Tier-1 CI runs `-m 'not slow'` (ROADMAP): long sweeps opt out of
-    # the 870s budget with this marker and run in the full suite only.
+    # Tier-1 runs `-m 'not slow'` on six xdist workers inside 1,470 s
+    # (ROADMAP, "Tier-1 verify"): long sweeps opt out of that budget
+    # with this marker and run in the full suite only.
     config.addinivalue_line(
         "markers", "slow: long-running sweep excluded from tier-1")
+
+
+@pytest.fixture(scope="session")
+def native_store():
+    """Every fixture that boots a cluster asks for this first: where the
+    store library can neither be found nor built, the session says so
+    once and by name instead of once per cluster test."""
+    from ray_tpu.core import shm_store
+
+    try:
+        return shm_store._load_lib()
+    except OSError as e:
+        pytest.fail(f"native store cannot be built: {e}", pytrace=False)
 
 
 @pytest.fixture
@@ -73,7 +95,7 @@ def local_init():
 
 
 @pytest.fixture
-def cluster_init():
+def cluster_init(native_store):
     import ray_tpu
 
     ray_tpu.init(num_cpus=4)

@@ -12,7 +12,7 @@ from ray_tpu.autoscaler import Autoscaler, AutoscalerConfig, LocalNodeProvider
 
 
 @pytest.fixture
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=2)
     yield rt
     ray_tpu.shutdown()
@@ -196,3 +196,44 @@ def test_bin_packing_absorbs_multiple_demands_per_node(cluster):
     # when the backlog snapshot was taken.
     assert 1 <= len(did["launched"]) <= 3, did
     ray_tpu.get(refs, timeout=120)
+
+
+# ------------------------------------------------ the head's demand ring
+
+
+def test_met_pick_takes_the_requesters_demand_out_of_the_ring():
+    """A requester that starved and is then placed is no demand any more,
+    and a lease block asked by the same owner for the same shape is the
+    same demand, not one beside it. Until PR 31 both stood in the ring
+    for the whole window: the autoscaler read two unmet TPU demands for
+    one task that had already run, found its first slice busy with the
+    idle lease, and bought a second
+    (tests/test_autoscaler_gce.py::test_autoscaler_provisions_tpu_slice_end_to_end)."""
+    from ray_tpu.cluster.head import HeadServer
+
+    head = HeadServer()
+    try:
+        shape = {"TPU": 4.0, "CPU": 1.0}
+        owner = "127.0.0.1:7001"
+        key = (owner, tuple(sorted(shape.items())))
+        for _ in range(3):  # one requester retrying is ONE demand
+            assert head.rpc_pick_node(None, shape, None, None, key) is None
+        # The owner's block request starves on the same identity.
+        assert head._grant_block("b1", owner, shape, None, None, None) is None
+        assert head.rpc_get_demand(None, 20.0)["unmet"] == [shape]
+        # Another owner of the same shape is another demand.
+        other = ("127.0.0.1:7002", key[1])
+        assert head.rpc_pick_node(None, shape, None, None, other) is None
+        assert head.rpc_get_demand(None, 20.0)["unmet"] == [shape, shape]
+
+        head.rpc_register_node(None, "n1", "127.0.0.1:1",
+                               {"TPU": 4.0, "CPU": 8.0}, {}, "store")
+        assert head.rpc_pick_node(None, shape, None, None, key) is not None
+        # Met: the first owner's entries are gone, the other's stand.
+        assert head.rpc_get_demand(None, 20.0)["unmet"] == [shape]
+        assert head._unmet_keys == {other}
+        assert head.rpc_pick_node(None, shape, None, None, other) is not None
+        assert head.rpc_get_demand(None, 20.0)["unmet"] == []
+        assert not head._unmet_keys and not head._unmet_demand
+    finally:
+        head.shutdown()

@@ -16,7 +16,7 @@ from ray_tpu.core.accelerators import (TPUAcceleratorManager,
 
 
 @pytest.fixture
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=2)
     yield rt
     ray_tpu.shutdown()

@@ -278,13 +278,7 @@ def test_retrying_call_outlasts_respawn_window(rpc_pair):
 # --------------------------------------------------------------------------
 
 
-def _node_or_skip(head_addr: str, resources=None):
-    from ray_tpu.core import shm_store
-
-    try:
-        shm_store._load_lib()
-    except OSError as e:
-        pytest.skip(f"native store lib unavailable: {e}")
+def _node(head_addr: str, resources=None):
     from ray_tpu.cluster.node_manager import NodeManager
 
     return NodeManager(head_addr, uuid.uuid4().hex,
@@ -305,7 +299,7 @@ class _FakeProc:
         return 0
 
 
-def test_head_restart_rehydrates_directory_and_reconciles_leases():
+def test_head_restart_rehydrates_directory_and_reconciles_leases(native_store):
     """The two head-restart invariants, driven synchronously:
 
     1. holder-set rehydration — a head that restarts with an empty
@@ -319,7 +313,7 @@ def test_head_restart_rehydrates_directory_and_reconciles_leases():
     from ray_tpu.cluster.node_manager import Lease, WorkerProc
 
     head = HeadServer()
-    nm = _node_or_skip(head.address)
+    nm = _node(head.address)
     try:
         old_inc = head.incarnation
         assert nm._head_incarnation == old_inc
@@ -375,7 +369,7 @@ def test_head_restart_rehydrates_directory_and_reconciles_leases():
             head.shutdown()
 
 
-def test_pull_survives_severed_holder_connection():
+def test_pull_survives_severed_holder_connection(native_store):
     """Mid-pull connection loss to the holder (sever on fetch_object
     chunk 2) must not wedge or corrupt the pull: the retry lap
     re-fetches and the object arrives intact (the test_dataplane
@@ -386,8 +380,8 @@ def test_pull_survives_severed_holder_connection():
     from ray_tpu.cluster.head import HeadServer
 
     head = HeadServer()
-    holder = _node_or_skip(head.address)
-    puller = _node_or_skip(head.address)
+    holder = _node(head.address)
+    puller = _node(head.address)
     old_chunk = cfg.object_transfer_chunk_bytes
     try:
         oid = ObjectID.from_random()
@@ -408,6 +402,47 @@ def test_pull_survives_severed_holder_connection():
         cfg.set("object_transfer_chunk_bytes", old_chunk)
         puller.shutdown()
         holder.shutdown()
+        head.shutdown()
+
+
+def test_lost_heartbeats_cost_the_miss_window_one_period_each(native_store):
+    """Eight heartbeats lost in a row inside a miss window of thirteen
+    periods: the node stays alive. A beat whose reply never comes times
+    out after one period and the next goes out AT ONCE (a gap of
+    0.5 + 8 x 0.5 s here); while the loop also waited out a period
+    before it (until PR 31) each lost beat cost two, eight cost
+    0.5 + 8 x 1.0 s of a 6.5 s window, the head read a dead node and
+    every actor on it died — which 5 % blind RPC chaos did to
+    `test_stress.py::test_cross_node_dag_exact_under_chaos` about one
+    run in seventeen ("node ... died", `ActorDiedError`)."""
+    from ray_tpu.cluster.head import HeadServer
+    from ray_tpu.devtools import chaos
+
+    old = (cfg.health_check_period_ms, cfg.health_check_failure_threshold)
+    cfg.set("health_check_period_ms", 500)
+    cfg.set("health_check_failure_threshold", 13)
+    head = HeadServer()
+    nm = _node(head.address)
+    try:
+        info = head._nodes[nm.node_id]
+        first = info.last_heartbeat
+        _wait_until(lambda: info.last_heartbeat > first, 10,
+                    "no heartbeat reached the head")
+        cfg.set("chaos_plan",
+                "drop_request:role=head:method=heartbeat:count=8")
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < 9.0:  # past the whole miss window
+            assert info.alive, (
+                f"node read as dead {time.monotonic() - t0:.1f} s after "
+                f"its beats began to be lost")
+            time.sleep(0.1)
+        assert chaos.current_plan().rules[0]._fired == 8
+        assert time.monotonic() - info.last_heartbeat < 3.0, "beats resumed"
+    finally:
+        cfg.set("chaos_plan", "")
+        cfg.set("health_check_period_ms", old[0])
+        cfg.set("health_check_failure_threshold", old[1])
+        nm.shutdown()
         head.shutdown()
 
 

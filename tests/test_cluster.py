@@ -17,7 +17,7 @@ from ray_tpu.exceptions import ActorDiedError, TaskError
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4, object_store_memory=256 << 20)
     yield rt
     ray_tpu.shutdown()

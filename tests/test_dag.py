@@ -14,7 +14,7 @@ from ray_tpu.dag import InputNode, MultiOutputNode
 # (handle-scope actor GC is a known gap — reference kills actors when the
 # last handle dies).
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=24)
     yield rt
     ray_tpu.shutdown()

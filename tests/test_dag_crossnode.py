@@ -12,7 +12,7 @@ from ray_tpu.dag.channel import CrossNodeChannel
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=2)
     node = rt.add_node(num_cpus=2)
     deadline = time.time() + 30

@@ -11,7 +11,7 @@ from ray_tpu.util.dask_backend import ray_tpu_dask_get
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=2, ignore_reinit_error=True)
     yield rt
     ray_tpu.shutdown()
@@ -66,14 +66,36 @@ def test_unhashable_tuple_literal(cluster):
     assert ray_tpu_dask_get(dsk, "x") == 2
 
 
-def test_deep_chain_no_recursion_limit(cluster):
-    """Generated graphs chain thousands of tasks; toposort must not
-    recurse. (Values stay local-ish: one task per link.)"""
-    n = 3000
+def _chain(n):
     dsk = {"k0": 0}
     for i in range(1, n):
         dsk[f"k{i}"] = (add, f"k{i-1}", 1)
-    assert ray_tpu_dask_get(dsk, f"k{n-1}") == n - 1
+    return dsk
+
+
+def test_deep_chain_no_recursion_limit(cluster):
+    """Generated graphs chain thousands of tasks; the graph walk must not
+    recurse. The walk is held to that directly, at 3,000 links and well
+    past any recursion limit; the cluster then runs a chain (one task per
+    link, each waiting for the one before) long enough to be deep and
+    short enough to take seconds.
+
+    The cluster used to run all 3,000, in 535 s: every pending link
+    parks a worker's argument fetch on the owner, and every completion
+    woke every one of them (PR 31: core/memory_store.py,
+    tests/test_memory_store.py). 3,000 now take about 7 s; 1,000 are
+    kept here so that a runtime that loses this again costs the suite
+    half a minute, not nine."""
+    import sys
+
+    from ray_tpu.util.dask_backend import _toposort
+
+    for n in (3000, 20 * sys.getrecursionlimit()):
+        order = _toposort(_chain(n))
+        assert order == [f"k{i}" for i in range(n)]
+    n = 1000
+    assert n > sys.getrecursionlimit() - 100
+    assert ray_tpu_dask_get(_chain(n), f"k{n-1}") == n - 1
 
 
 def test_cycle_detection(cluster):

@@ -227,7 +227,7 @@ def test_train_session_iter_device_batches_delegates():
 # ------------------------------------------------------------ cluster tier
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     try:
         rt = ray_tpu.init(num_cpus=4)
     except Exception as e:

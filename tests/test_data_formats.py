@@ -17,7 +17,7 @@ from ray_tpu.data import formats
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=2, ignore_reinit_error=True)
     yield rt
     ray_tpu.shutdown()

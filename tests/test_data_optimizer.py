@@ -16,7 +16,7 @@ from ray_tpu.data._streaming import (InputOperator, LimitOperator,
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4)
     yield rt
     ray_tpu.shutdown()

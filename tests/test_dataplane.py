@@ -2,10 +2,9 @@
 guard) and the scatter-gather RPC framing (zero-copy frames, recv_into
 sinks, chaos tolerance).
 
-Store-backed tests need a loadable native lib; on machines where the
-checked-in .so does not load (glibc mismatch) they skip unless
-RTPU_SHM_STORE_SO points at a local build (see
-.claude/skills/verify/SKILL.md).
+Store-backed tests load the native library, which is built from
+`_cpp/shm_store.cc` on first use; where that cannot be done they fail by
+name (conftest's `native_store`).
 """
 
 from __future__ import annotations
@@ -18,17 +17,8 @@ import time
 import numpy as np
 import pytest
 
+from ray_tpu.core import shm_store
 from ray_tpu.core.config import GLOBAL_CONFIG as cfg
-
-
-def _store_mod_or_skip():
-    from ray_tpu.core import shm_store
-
-    try:
-        shm_store._load_lib()
-    except OSError as e:
-        pytest.skip(f"native store lib unavailable: {e}")
-    return shm_store
 
 
 def _oid(i: int, salt: int = 0):
@@ -42,14 +32,12 @@ def _oid(i: int, salt: int = 0):
 # --------------------------------------------------------------------------
 
 
-def test_layout_version_matches():
-    shm_store = _store_mod_or_skip()
+def test_layout_version_matches(native_store):
     lib = shm_store._load_lib()
     assert int(lib.rtpu_lib_layout_version()) == shm_store._LAYOUT_VERSION
 
 
-def test_open_missing_store_mentions_rebuild():
-    shm_store = _store_mod_or_skip()
+def test_open_missing_store_mentions_rebuild(native_store):
     with pytest.raises(OSError, match="layout version"):
         shm_store.ShmStore.open("/rtpu_test_definitely_missing")
 
@@ -59,8 +47,7 @@ def test_open_missing_store_mentions_rebuild():
 # --------------------------------------------------------------------------
 
 
-def test_sharded_store_basic_and_fallthrough():
-    shm_store = _store_mod_or_skip()
+def test_sharded_store_basic_and_fallthrough(native_store):
     # 640 MB / 8 shards ~= 76 MB sub-arenas (>= the 64 MB floor).
     store = shm_store.ShmStore.create("/rtpu_test_shard", 640 << 20,
                                       prefault=False)
@@ -93,8 +80,7 @@ def test_sharded_store_basic_and_fallthrough():
         store.close()
 
 
-def test_oversized_object_fails_fast_with_shard_hint():
-    shm_store = _store_mod_or_skip()
+def test_oversized_object_fails_fast_with_shard_hint(native_store):
     store = shm_store.ShmStore.create("/rtpu_test_big", 640 << 20,
                                       prefault=False)
     try:
@@ -109,12 +95,11 @@ def test_oversized_object_fails_fast_with_shard_hint():
         store.close()
 
 
-def test_reclaim_pending_never_touches_live_objects():
+def test_reclaim_pending_never_touches_live_objects(native_store):
     """reclaim_pending is the dead-creator rescue: it must refuse sealed
     objects, in-write (allocated) objects, and absent keys — only a true
     PENDING placeholder (unreachable from Python without a mid-create
     crash) is reclaimable."""
-    shm_store = _store_mod_or_skip()
     store = shm_store.ShmStore.create("/rtpu_test_reclaim", 64 << 20,
                                       prefault=False)
     try:
@@ -131,8 +116,7 @@ def test_reclaim_pending_never_touches_live_objects():
         store.close()
 
 
-def test_small_store_collapses_to_one_shard():
-    shm_store = _store_mod_or_skip()
+def test_small_store_collapses_to_one_shard(native_store):
     store = shm_store.ShmStore.create("/rtpu_test_tiny", 64 << 20,
                                       prefault=False)
     try:
@@ -198,7 +182,6 @@ def _hammer_proc(store_name: str, idx: int, n_objects: int, obj_bytes: int,
 
 def _run_hammer(k: int, n_objects: int, obj_bytes: int, capacity: int,
                 name: str):
-    shm_store = _store_mod_or_skip()
     store = shm_store.ShmStore.create(name, capacity, prefault=False)
     try:
         ctx = mp.get_context("spawn")
@@ -227,13 +210,13 @@ def _run_hammer(k: int, n_objects: int, obj_bytes: int, capacity: int,
         store.close()
 
 
-def test_multiprocess_hammer_small():
+def test_multiprocess_hammer_small(native_store):
     """4 processes x 24 x 1 MB through one 640 MB store (no pressure)."""
     _run_hammer(4, 24, 1 << 20, 640 << 20, "/rtpu_test_hammer_s")
 
 
 @pytest.mark.slow
-def test_multiprocess_hammer_spill_pressure():
+def test_multiprocess_hammer_spill_pressure(native_store):
     """4 processes x 60 x 4 MB kept-half through a 640 MB store: live
     bytes approach the arena so the spill path engages; every kept object
     must still read back byte-correct (restore) and every deleted one

@@ -179,18 +179,8 @@ def test_disagg_page_size_mismatch_rejected():
 # ------------------------------------------------------------- serve tier
 
 
-def _cluster_or_skip():
-    from ray_tpu.core import shm_store
-
-    try:
-        shm_store._load_lib()
-    except OSError as e:
-        pytest.skip(f"native store lib unavailable: {e}")
-
-
 @pytest.fixture(scope="module")
-def serve_cluster():
-    _cluster_or_skip()
+def serve_cluster(native_store):
     import ray_tpu
     import ray_tpu.serve as serve
 

@@ -13,7 +13,7 @@ from ray_tpu.jobs import JobStatus, JobSubmissionClient
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4)
     yield rt
     ray_tpu.shutdown()

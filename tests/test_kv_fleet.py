@@ -391,18 +391,8 @@ def test_router_fleet_term_scores_spilled_residency():
 # ----------------------------------------------------------- cluster tier
 
 
-def _cluster_or_skip():
-    from ray_tpu.core import shm_store
-
-    try:
-        shm_store._load_lib()
-    except OSError as e:
-        pytest.skip(f"native store lib unavailable: {e}")
-
-
 @pytest.fixture(scope="module")
-def fleet_cluster():
-    _cluster_or_skip()
+def fleet_cluster(native_store):
     import ray_tpu
     import ray_tpu.serve as serve
 

@@ -302,6 +302,13 @@ def test_owner_skips_blocks_for_strategy_tasks():
 # ------------------------------------------------- directory delta sync
 
 
+def _until(pred, timeout_s: float = 10.0) -> None:
+    """Poll until ``pred()`` holds or the time is up; the caller asserts."""
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline and not pred():
+        time.sleep(0.02)
+
+
 def _wipe_head_directory(head):
     """Simulate what a head restart loses: directory shards + cursors."""
     for sh in head._dir_shards:
@@ -324,10 +331,10 @@ def test_journal_tail_replay_rehydrates_identically():
         nm.rpc_object_batch(None, [("add", o, 10 + i)
                                    for i, o in enumerate(oids)])
         nm.rpc_object_batch(None, [("rm", oids[0], None)])
-        deadline = time.monotonic() + 10
-        while (time.monotonic() < deadline
-               and len(sim.head._object_dir) < 5):
-            time.sleep(0.05)
+        # Both frames applied (six adds, then the remove): waiting for
+        # "at least five" could read the directory between the two.
+        _until(lambda: len(sim.head._object_dir) == 5
+               and oids[0] not in sim.head._object_dir)
         before = sim.head._object_dir
         sizes_before = sim.head._object_sizes
         assert len(before) == 5 and oids[0] not in before
@@ -336,11 +343,12 @@ def test_journal_tail_replay_rehydrates_identically():
         nm._head_dir_cursor = 0
         nm._republish_needed = True
         nm._try_republish()
-        # object_batch is a one-way notify: poll for head-side apply.
-        deadline = time.monotonic() + 10
-        while (time.monotonic() < deadline
-               and sim.head._object_dir != before):
-            time.sleep(0.05)
+        # object_batch is a one-way notify: poll for head-side apply. The
+        # node's own heartbeat may see the wiped cursor meanwhile
+        # ("dir_resync") and ask for the same replay again, so the flag
+        # is held to where it SETTLES, not to an instant.
+        _until(lambda: sim.head._object_dir == before
+               and not nm._republish_needed)
         assert sim.head._object_dir == before
         assert sim.head._object_sizes == sizes_before
         assert not nm._republish_needed
@@ -374,10 +382,8 @@ def test_journal_overflow_falls_back_to_snapshot_rebase():
         nm._head_dir_cursor = 0  # journal floor is way past 1 now
         nm._republish_needed = True
         nm._try_republish()
-        deadline = time.monotonic() + 10
-        while (time.monotonic() < deadline
-               and sim.head._object_dir != before):
-            time.sleep(0.05)
+        _until(lambda: sim.head._object_dir == before
+               and not nm._republish_needed)
         assert sim.head._object_dir == before
         assert not nm._republish_needed
     finally:

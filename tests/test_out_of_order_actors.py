@@ -12,7 +12,7 @@ from ray_tpu.core.config import GLOBAL_CONFIG as cfg
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
     yield rt
     ray_tpu.shutdown()

@@ -1,8 +1,8 @@
 """Weight-only int8 decode (models/quant.py + LLMEngine(quantize)):
 
 - quantize-params mechanics: shapes, dtypes, per-channel scale axes;
-- int8-vs-f32 decode logits within tolerance AND greedy token-identical
-  on the tiny config for short horizons;
+- int8-vs-f32 decode logits within a written tolerance, and greedy tokens
+  identical wherever float32 decides by more than the int8 error;
 - the engine knob end-to-end, including speculative decoding on a
   quantized engine: PR 3's greedy-equivalence invariant (spec on == spec
   off, token for token) must survive quantization — both engines run the
@@ -74,26 +74,41 @@ def test_quantize_rejects_unknown_dtype(tiny_model):
 # ------------------------------------------------- forward equivalence
 
 
+# What weight-only int8 may cost a logit against float32 on the tiny
+# random model: |lq - lf| <= INT8_ATOL + INT8_RTOL * |lf|. A greedy token
+# can be held to the float32 one only where float32 itself decides by
+# more than that: the int8 error moves the two best logits towards each
+# other by at most twice the bound, and inside it a swap is a near-tie,
+# not a fault (the benchmark's `TOL_TOKEN_MARGIN` is the same rule).
+INT8_RTOL, INT8_ATOL = 0.1, 0.15
+
+
+def _int8_bound(lf) -> float:
+    return INT8_ATOL + INT8_RTOL * float(np.max(np.abs(lf)))
+
+
 def test_int8_forward_logits_close_and_greedy_identical(tiny_model):
-    """Short-horizon greedy rollout: int8 logits track f32 within
-    tolerance and the argmax token stream is identical. (The tiny
-    random model has near-tie logits on some prompts where ~0.1 of
-    int8 error legitimately flips an argmax — this fixed prompt/seed
-    pair is one where the streams deterministically agree, making the
-    equivalence a regression guard.)"""
+    """Short-horizon greedy rollout, teacher-forced on the float32
+    stream: int8 logits track f32 within the written tolerance at every
+    step, and the argmax is identical wherever the float32 margin
+    between the two best logits exceeds the int8 error."""
     cfg, params = tiny_model
     qp = quantize_params(params)
     ids = [1, 2, 3, 4, 5]
-    ids_q = list(ids)
+    decided = 0
     for _ in range(8):
-        lf = llama.forward(params, jnp.asarray([ids]), cfg)[0, -1]
-        lq = llama.forward(qp, jnp.asarray([ids_q]), cfg)[0, -1]
-        np.testing.assert_allclose(np.asarray(lq), np.asarray(lf),
-                                   rtol=0.1, atol=0.15)
-        tf, tq = int(jnp.argmax(lf)), int(jnp.argmax(lq))
-        assert tf == tq, (ids, ids_q)
+        lf = np.asarray(llama.forward(params, jnp.asarray([ids]), cfg)[0, -1])
+        lq = np.asarray(llama.forward(qp, jnp.asarray([ids]), cfg)[0, -1])
+        np.testing.assert_allclose(lq, lf, rtol=INT8_RTOL, atol=INT8_ATOL)
+        tf, tq = int(np.argmax(lf)), int(np.argmax(lq))
+        best2 = np.sort(lf)[-2:]
+        if best2[1] - best2[0] > 2 * float(np.max(np.abs(lq - lf))):
+            decided += 1
+            assert tf == tq, ids
+        else:  # a near-tie: int8's choice is one of float32's contenders
+            assert lf[tf] - lf[tq] <= 2 * _int8_bound(lf), ids
         ids.append(tf)
-        ids_q.append(tq)
+    assert decided >= 2, "the rollout held hardly a token to float32"
 
 
 def test_int8_cache_decode_matches_full_forward(tiny_model):
@@ -162,16 +177,29 @@ def test_engine_quantize_knob(tiny_model):
 
 
 def test_engine_int8_greedy_matches_f32_short_horizon(tiny_model):
-    """On the tiny config the int8 logit error does not flip any argmax
-    over short horizons: engine outputs match the f32 engine token for
-    token."""
+    """The int8 engine's greedy tokens are the f32 engine's over short
+    horizons, up to the first float32 near-tie: where the two streams
+    part, the int8 token's float32 logit lies within the int8 error of
+    the best one (after that the two engines read different contexts and
+    are no longer comparable)."""
+    cfg, params = tiny_model
     f32 = make_engine(tiny_model, decode_chunk=4)
     q8 = make_engine(tiny_model, quantize="int8", decode_chunk=4)
     try:
+        agreed = 0
         for prompt in ([1, 2, 3, 4, 5], [9, 8, 7], [5] * 8):
-            a = f32.generate(prompt, max_new_tokens=8)
-            b = q8.generate(prompt, max_new_tokens=8)
-            assert a["token_ids"] == b["token_ids"], prompt
+            a = f32.generate(prompt, max_new_tokens=8)["token_ids"]
+            b = q8.generate(prompt, max_new_tokens=8)["token_ids"]
+            assert len(a) == len(b) == 8
+            k = next((i for i in range(8) if a[i] != b[i]), 8)
+            agreed += k
+            if k == 8:
+                continue
+            lf = np.asarray(llama.forward(
+                params, jnp.asarray([prompt + a[:k]]), cfg)[0, -1])
+            assert int(np.argmax(lf)) == a[k], prompt
+            assert lf[a[k]] - lf[b[k]] <= 2 * _int8_bound(lf), (prompt, k)
+        assert agreed >= 12, "int8 parted from f32 almost at once"
     finally:
         f32.close()
         q8.close()

@@ -12,7 +12,7 @@ from ray_tpu.rllib.replay_buffers import (PrioritizedReplayBuffer,
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4)
     yield rt
     ray_tpu.shutdown()

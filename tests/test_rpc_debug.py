@@ -7,6 +7,7 @@ pairs (no cluster, no store; tier-1 everywhere).
 from __future__ import annotations
 
 import pickle
+import threading
 
 import pytest
 
@@ -39,6 +40,7 @@ class _Handler:
         self.job_counter = 0
         self.kv = {}
         self.releases = 0
+        self.released = threading.Semaphore(0)  # one per lease released
 
     def rpc_ping(self, conn):
         return "pong"
@@ -68,6 +70,7 @@ class _Handler:
 
         def release():
             self.releases += 1
+            self.released.release()
 
         return BufferLease((16, pickle.PickleBuffer(view)), release)
 
@@ -188,8 +191,14 @@ def test_buffer_lease_dup_compared_and_released(witness, pair):
     assert total == 16 and bytes(buf) == b"01234567"
     assert rpc_debug.dup_audit_counts().get("fetch_chunk_local") == 1
     assert rpc_debug.violations() == []
-    # Both deliveries' leases released: the dup's by the witness, the
-    # original's by the response path after the frame went out.
+    # Both deliveries' leases are released: the dup's by the witness, the
+    # original's by the response path AFTER the frame went out — the
+    # borrowed view must outlive the send, so the server releases behind
+    # the reply and the client may hold the reply first. Wait for the
+    # releases themselves, not for the reply.
+    assert h.released.acquire(timeout=10), "dup's lease never released"
+    assert h.released.acquire(timeout=10), "original's lease never released"
+    assert not h.released.acquire(timeout=0.2), "a lease released twice"
     assert h.releases == 2
 
 

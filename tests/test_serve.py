@@ -15,7 +15,7 @@ import ray_tpu.serve as serve
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=24)
     yield rt
     serve.shutdown()

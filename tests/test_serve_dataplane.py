@@ -14,7 +14,7 @@ from ray_tpu import serve
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4)
     yield rt
     serve.shutdown()

@@ -325,15 +325,7 @@ def test_stop_joins_poller():
 
 
 @pytest.fixture(scope="module")
-def cluster():
-    # Cluster boot needs a loadable native store lib; on machines where
-    # the checked-in .so does not load (glibc mismatch) skip like
-    # test_dataplane does unless RTPU_SHM_STORE_SO points at a rebuild.
-    from ray_tpu.core import shm_store
-    try:
-        shm_store._load_lib()
-    except OSError as e:
-        pytest.skip(f"native store lib unavailable: {e}")
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=16)
     yield rt
     serve.shutdown()

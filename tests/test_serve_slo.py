@@ -4,6 +4,7 @@ through the HTTP proxy over the tiny-cpu LLM engine (2 replicas).
 
 import concurrent.futures as cf
 import json
+import math
 import threading
 import time
 import urllib.error
@@ -185,21 +186,13 @@ def test_admitted_ttft_bounded_under_overload():
 
 # ------------------------------------------------------------------- e2e
 
-BUDGET_MS = 300.0
+BUDGET_MS = 60.0
 
 
 @pytest.fixture(scope="module")
-def llm_app():
+def llm_app(native_store):
     from ray_tpu.serve.llm import build_llm_deployment
 
-    # Cluster boot needs a loadable native store lib; skip (like
-    # test_dataplane) when the checked-in .so does not match this
-    # machine's glibc and no RTPU_SHM_STORE_SO rebuild is provided.
-    from ray_tpu.core import shm_store
-    try:
-        shm_store._load_lib()
-    except OSError as e:
-        pytest.skip(f"native store lib unavailable: {e}")
     rt = ray_tpu.init(num_cpus=12, _system_config={
         "serve_slo_ttft_budget_ms": BUDGET_MS,
         "serve_slo_queue_depth": 2,
@@ -256,37 +249,55 @@ def test_routing_policy_does_not_change_outputs(llm_app):
 
 
 def test_overload_sheds_503_and_bounds_admitted_ttft(llm_app):
+    """Closed-loop clients past capacity: the gate sheds observably, and
+    the typical ADMITTED request is served inside the budget, where
+    un-gated every request would sit at the saturation latency.
+
+    The load is sized from the service time measured here, not from a
+    machine remembered: the tiny engine answers in ~15 ms on today's
+    CPU, and the 24 clients x 24 tokens this test was written with
+    (PR 9, "second-plus scale") saturate at ~50 ms — under the old
+    300 ms budget nothing was ever shed."""
     _handle, url = llm_app
+    payload = {"prompt_ids": [1, 2, 3, 4, 5, 6], "max_new_tokens": 56}
+    unloaded = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        assert _post(f"{url}/slollm", payload)[0] == 200
+        unloaded.append((time.perf_counter() - t0) * 1e3)
+    l1_ms = sorted(unloaded)[2]
+    # Un-gated, N closed-loop clients over the 2x2 engine slots sit at
+    # N/4 service times: size N so that is at least 4x the budget.
+    slots = 4
+    clients = max(24, min(96, math.ceil(4 * BUDGET_MS * slots / l1_ms)))
     statuses = []
     lock = threading.Lock()
 
     def client(i):
-        # Long generations make saturation latency (24 clients over
-        # 2x2 engine slots) sit far past the budget.
-        payload = {"prompt_ids": [1 + (i % 7), 2, 3, 4, 5, 6],
-                   "max_new_tokens": 24}
-        for _ in range(6):
-            status, _body = _post(f"{url}/slollm", payload)
+        mine = dict(payload, prompt_ids=[1 + (i % 7), 2, 3, 4, 5, 6])
+        for _ in range(12):
+            status, _body = _post(f"{url}/slollm", mine)
             with lock:
                 statuses.append(status)
+            if status == 503:
+                time.sleep(0.02)  # a shed client backs off
 
-    with cf.ThreadPoolExecutor(24) as pool:
-        list(pool.map(client, range(24)))
+    with cf.ThreadPoolExecutor(clients) as pool:
+        list(pool.map(client, range(clients)))
     with urllib.request.urlopen(f"{url}/-/slo", timeout=10) as r:
         slo = json.load(r)["slollm"]
+    assert set(statuses) <= {200, 503}, (set(statuses), slo)
     assert statuses.count(200) > 0, (statuses, slo)
     # Past-capacity offered load must be OBSERVABLY shed (503 + counter),
     # not absorbed as unbounded queueing.
-    assert statuses.count(503) > 0, (statuses, slo)
-    assert slo["shed_total"] > 0
+    assert statuses.count(503) > 0, (clients, l1_ms, slo)
+    assert slo["shed_total"] >= statuses.count(503)
     assert slo["shed_total"] + slo["admitted_total"] >= len(statuses)
-    # Admitted requests stay near the budget instead of running away
-    # (un-gated, 24 closed-loop clients over 2x2 engine slots at ~24
-    # tokens/request sit at second-plus scale). The e2e bounds are
-    # looser than the unit tier's (test_admitted_ttft_bounded_...):
-    # the window still holds breach samples from the cold-start wave
-    # and the gate's reopen probes ride a real engine on shared CI
-    # CPU. The tight steady-state property is asserted there; here the
-    # claim is "bounded near budget, shed observable".
-    assert slo["p50_ttft_ms"] <= BUDGET_MS * 2.0, slo
-    assert slo["p99_ttft_ms"] <= BUDGET_MS * 8.0, slo
+    assert slo["inflight"] == 0 and slo["queued"] == 0, slo
+    # The typical admitted request is served inside the budget (or, on a
+    # machine slower than the budget, near its unloaded time) — un-gated
+    # it would sit at clients/slots service times, 4x the budget or
+    # more. No bound on p99: the gate is bang-bang, a reopen admits one
+    # burst, and the window may hold it; the steady-state p99 property
+    # is the unit tier's (test_admitted_ttft_bounded_...).
+    assert slo["p50_ttft_ms"] <= max(BUDGET_MS, 3 * l1_ms), (l1_ms, slo)

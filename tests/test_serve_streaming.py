@@ -13,7 +13,7 @@ from ray_tpu import serve
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
     yield rt
     serve.shutdown()

@@ -12,7 +12,7 @@ import ray_tpu.data as rdata
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4)
     yield rt
     ray_tpu.shutdown()

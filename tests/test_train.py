@@ -17,7 +17,7 @@ from ray_tpu.train import (Checkpoint, CheckpointConfig, FailureConfig,
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4)
     yield rt
     ray_tpu.shutdown()

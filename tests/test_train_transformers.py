@@ -11,7 +11,7 @@ transformers = pytest.importorskip("transformers")
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
     yield rt
     ray_tpu.shutdown()

@@ -11,7 +11,7 @@ import ray_tpu.tune as tune
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=8)
     yield rt
     ray_tpu.shutdown()

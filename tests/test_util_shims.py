@@ -9,7 +9,7 @@ import ray_tpu
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(native_store):
     rt = ray_tpu.init(num_cpus=4)
     yield rt
     ray_tpu.shutdown()
