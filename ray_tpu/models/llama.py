@@ -44,6 +44,7 @@ from ray_tpu.ops import (
     rms_norm,
 )
 from ray_tpu.models.quant import QuantTensor
+from ray_tpu.parallel import collective_matmul
 from ray_tpu.parallel.mesh import constrain
 
 Params = Dict[str, Any]
@@ -254,23 +255,63 @@ def _attention_dispatch(q, k, v, q_pos, kv_pos, cfg, mesh: Optional[Mesh],
                                  kv_positions=kv_pos, mesh=mesh)
 
 
+def _tp_ring(seq_len: int, blocks, cfg: LlamaConfig, cache_kv=None) -> bool:
+    """Whether `_block`'s four matmul groups run as collective matmuls
+    (`parallel/collective_matmul.py`): no cache, dense weights, plain XLA
+    around them (``cfg.fused_ops``' Pallas calls cannot be traced inside
+    the ring's `shard_map`, so that option keeps the program it had), and
+    a mesh and shapes that `collective_matmul.ring_size` accepts.
+    ``blocks`` is one layer's weights or the stacked ones (sizes are
+    read from the right)."""
+    if cache_kv is not None or cfg.fused_ops or any(
+            isinstance(w, QuantTensor) for w in blocks.values()):
+        return False
+    return collective_matmul.ring_size(
+        seq_len, blocks["wq"].shape[-2], blocks["wk"].shape[-2],
+        blocks["w_gate"].shape[-1]) > 1
+
+
 def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
            cache_kv=None, cache_index=None, standard_positions: bool = False):
-    """One transformer block. Returns (x, new_kv | None)."""
+    """One transformer block. Returns (x, new_kv | None).
+
+    A whole sequence with no cache, traced under a mesh whose ``tp`` the
+    shapes divide by (`_tp_ring`: read off the mesh and the shapes, never
+    asked for), arrives and leaves sequence-sharded over tp
+    (``res_seq``): the norms and the residual additions run on a shard,
+    the four matmul groups move the other shards themselves under their
+    products, and rotary embedding and SwiGLU are applied to each
+    shard's products as they are made. Every other call multiplies with
+    `_wdot` and leaves its collectives to the partitioner."""
+    ring = _tp_ring(x.shape[1], layer, cfg, cache_kv)
+    stream = ("batch", "res_seq" if ring else "seq", None)
     fused = bool(cfg.fused_ops)
     interp = cfg.fused_ops == "interpret"
+
+    def rope(qkv, pos):
+        q, k, v = qkv
+        if fused:
+            return (*fused_qk_rope(q, k, pos, cfg.rope_theta,
+                                   interpret=interp), v)
+        return (apply_rope(q, pos, cfg.rope_theta),
+                apply_rope(k, pos, cfg.rope_theta), v)
+
+    def swiglu(gate_up):
+        gate, up = gate_up
+        return (fused_swiglu(gate, up, interpret=interp) if fused
+                else jax.nn.silu(gate) * up,)
+
     h = _norm(x, layer["ln_attn"], cfg)
-    q = _wdot("bsd,dhk->bshk", h, layer["wq"])
-    k = _wdot("bsd,dhk->bshk", h, layer["wk"])
-    v = _wdot("bsd,dhk->bshk", h, layer["wv"])
+    wqkv = (layer["wq"], layer["wk"], layer["wv"])
+    if ring:
+        q, k, v = collective_matmul.gather_matmul(
+            "bsd,dhk->bshk", h, wqkv, rowwise=rope, row_args=(positions,))
+    else:
+        q, k, v = (_wdot("bsd,dhk->bshk", h, w) for w in wqkv)
     q = constrain(q, ("batch", "seq", "heads", None))
     k = constrain(k, ("batch", "seq", "kv_heads", None))
-    if fused:
-        q, k = fused_qk_rope(q, k, positions, cfg.rope_theta,
-                             interpret=interp)
-    else:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+    if not ring:
+        q, k, v = rope((q, k, v), positions)
 
     new_kv = None
     if cache_kv is not None:
@@ -332,7 +373,12 @@ def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
         attn = _attention_dispatch(q, k, v, positions, positions, cfg, mesh,
                                    standard_positions=standard_positions)
     attn = constrain(attn, ("batch", "seq", "heads", None))
-    attn_out = _wdot("bshk,hkd->bsd", attn, layer["wo"]).astype(x.dtype)
+    if ring:
+        attn_out = collective_matmul.matmul_scatter(
+            "bshk,hkd->bsd", attn, layer["wo"])
+    else:
+        attn_out = _wdot("bshk,hkd->bsd", attn, layer["wo"])
+    attn_out = attn_out.astype(x.dtype)
     if fused:
         # Residual add folded into the next norm: one pass emits both
         # the normed MLP input and the updated residual stream.
@@ -341,14 +387,20 @@ def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
     else:
         x = x + attn_out
         h = rms_norm(x, layer["ln_mlp"], cfg.norm_eps)
-    x = constrain(x, ("batch", "seq", None))
-    gate = _wdot("bsd,df->bsf", h, layer["w_gate"])
-    up = _wdot("bsd,df->bsf", h, layer["w_up"])
-    ff = fused_swiglu(gate, up, interpret=interp) if fused \
-        else jax.nn.silu(gate) * up
-    ff = constrain(ff, ("batch", "seq", "mlp"))
-    x = x + _wdot("bsf,fd->bsd", ff, layer["w_down"]).astype(x.dtype)
-    return constrain(x, ("batch", "seq", None)), new_kv
+    x = constrain(x, stream)
+    w_mlp = (layer["w_gate"], layer["w_up"])
+    if ring:
+        # SwiGLU's rows stay in ring order: `w_down`'s ring takes them so.
+        (ff,) = collective_matmul.gather_matmul(
+            "bsd,df->bsf", h, w_mlp, rowwise=swiglu, in_sequence=False)
+        down = collective_matmul.matmul_scatter(
+            "bsf,fd->bsd", ff, layer["w_down"])
+    else:
+        (ff,) = swiglu(tuple(_wdot("bsd,df->bsf", h, w) for w in w_mlp))
+        ff = constrain(ff, ("batch", "seq", "mlp"))
+        down = _wdot("bsf,fd->bsd", ff, layer["w_down"])
+    x = x + down.astype(x.dtype)
+    return constrain(x, stream), new_kv
 
 
 def forward(params: Params, tokens: jnp.ndarray, cfg: LlamaConfig,
@@ -379,6 +431,14 @@ def forward_hidden(params: Params, tokens: jnp.ndarray, cfg: LlamaConfig,
     table = constrain(params["embed"], ("vocab", None))
     x = jnp.take(table, tokens, axis=0).astype(cfg.dtype)
     x = constrain(x, ("batch", "seq", None))
+    # Where the blocks run the tp ring (`_tp_ring`), the stream
+    # between them is a sequence shard of tp: a local slice of the rows
+    # just looked up (constrained to it directly, the partitioner gathers
+    # the whole table instead), and ONE gather of the final hidden states
+    # for the head, whose vocab split needs every row.
+    ring = _tp_ring(s, params["blocks"], cfg)
+    if ring:
+        x = constrain(x, ("batch", "res_seq", None))
 
     def body(x, layer):
         y, _ = _block(x, layer, positions, cfg, mesh,
@@ -388,7 +448,8 @@ def forward_hidden(params: Params, tokens: jnp.ndarray, cfg: LlamaConfig,
     if cfg.remat:
         body = jax.checkpoint(body, policy=_remat_policy(cfg))
     x, _ = lax.scan(body, x, params["blocks"])
-    return _norm(x, params["ln_out"], cfg)
+    x = _norm(x, params["ln_out"], cfg)
+    return constrain(x, ("batch", "seq", None)) if ring else x
 
 
 def loss_fn(params: Params, tokens: jnp.ndarray, cfg: LlamaConfig,

@@ -131,6 +131,9 @@ def mesh_2d(n_devices: Optional[int] = None, *, tp: Optional[int] = None,
 DEFAULT_RULES: Dict[str, Optional[object]] = {
     "batch": ("dp", "fsdp"),   # batch dim sharded over all data axes
     "seq": "sp",               # sequence dim sharded for context parallelism
+    # The residual stream between a block's matmul groups where the tp
+    # ring engages (`parallel/collective_matmul.py`).
+    "res_seq": ("sp", "tp"),
     "embed": "fsdp",           # parameters: d_model dim sharded for ZeRO-3
     "heads": "tp",             # attention heads over tensor parallel
     "kv_heads": "tp",

@@ -3,9 +3,16 @@
 This is the TPU-native execution model replacing the reference's per-worker
 torch DDP wiring (reference `train/_internal/backend_executor.py:69` +
 `train/torch/config.py:94-163`): ONE compiled XLA program over a Mesh instead
-of N processes exchanging NCCL messages. Gradient reductions, fsdp
-all-gathers/reduce-scatters, tp collectives, and ring-attention ppermutes are
-all emitted by XLA from sharding annotations.
+of N processes exchanging NCCL messages. Gradient reductions and the fsdp
+all-gathers/reduce-scatters are emitted by XLA from sharding annotations.
+Two families of collectives are written by hand, as `ppermute` rings under
+`shard_map`, because the partitioner emits them blocking: ring attention
+over ``sp`` (`ops/ring_attention.py`) and a block's tensor-parallel sums
+over ``tp``, which travel under the matmuls beside them
+(`parallel/collective_matmul.py`; `models/llama.py:_block` enters it on the
+mesh and the shapes alone). What is left to the partitioner over ``tp`` is
+once a step: the embedding's vocab-sharded lookup, one gather of the final
+hidden states, and the loss's reductions.
 """
 
 from __future__ import annotations

@@ -420,10 +420,23 @@ def test_glm_tick_prefill_returns_one_row_of_logits(chip):
         kv.size * kv.dtype.itemsize)
 
 
+def _computations(text: str):
+    """The compiled module's computations, each a list of lines in
+    scheduled order."""
+    return [c.split("\n") for c in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()",
+                                              text)]
+
+
 def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip):
     """Real width, 2 layers, on a mesh of the four described chips: the
     flash kernel must sit inside a shard_map (XLA cannot partition a
-    Mosaic kernel), and the fsdp/tp collectives must be there."""
+    Mosaic kernel), and the fsdp/tp collectives must be there.
+
+    The tp ring's engagement is decided at compile time, so its witness
+    is the program's text (`parallel/collective_matmul.py`): a layer's
+    tensor-parallel sums are ring hops of half the residual stream, each
+    with a product between its start and its done, and no blocking
+    collective of the whole stream is left in a layer body."""
     cfg = dataclasses.replace(_CFG_1B, n_layers=2)
     mesh = mesh_2d(4, tp=2, devices=list(topo.devices))
     assert dict(mesh.shape)["fsdp"] == 2 and dict(mesh.shape)["tp"] == 2
@@ -450,6 +463,43 @@ def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip):
     per_device = c.memory_analysis().argument_size_in_bytes
     total = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(shapes))
     assert per_device < 0.3 * total
+
+    comps = _computations(text)
+    products = {re.match(r"(?:ENTRY )?%([\w.\-]+)", c[0]).group(1)
+                for c in comps if any(" convolution(" in l for l in c)}
+    stream = f"bf16[{BATCH // 2},{SEQ},{cfg.d_model}]"   # a device's batch
+    hop = f"bf16[{BATCH // 2},{SEQ // 2},{cfg.d_model}]"
+    hops = 0
+    for lines in comps:
+        started = {}
+        for i, line in enumerate(lines):
+            m = re.match(r"\s*%([\w.\-]+) = .*? (collective-permute-"
+                         r"(?:start|done))\((?:%([\w.\-]+)\))?", line)
+            if m and m.group(2).endswith("start"):
+                assert hop in line, line
+                started[m.group(1)] = i
+            elif m:
+                between = lines[started.pop(m.group(3)) + 1:i]
+                assert any(
+                    " convolution(" in l or (
+                        c := re.search(r"calls=%([\w.\-]+)", l)
+                    ) and c.group(1) in products for l in between), (
+                    f"nothing multiplies while {m.group(3)} travels")
+                hops += 1
+        if not any("collective-permute-start(" in l for l in lines):
+            continue
+        # A layer body: the whole stream crosses no link in one piece.
+        for line in lines:
+            assert not re.search(
+                rf"= {re.escape(stream)}\S* (all-reduce|all-gather)"
+                r"(-start)?\(", line), line
+    # 4 forward (q/k/v, wo, gate/up, w_down) + 7 backward: the remat's
+    # first three again and the four transposes. One travelling copy
+    # serves q, k and v in both directions.
+    assert hops == text.count("collective-permute-start(") == 11
+    # The embedding is looked up through its vocab shards, never gathered.
+    assert not re.search(
+        rf"= bf16\[{cfg.vocab_size},{cfg.d_model}\]\S* all-gather", text)
 
 
 # ------------------------------------- the shard_map seam, numerically

@@ -2,7 +2,12 @@
 mesh: `mesh_2d` builds the production training mesh, the logical-axis
 tables place every Llama weight, `assert_params_sharded` proves the
 placement is real (not silently replicated), and the sharded train step
-computes the SAME loss as an unsharded single-device step."""
+computes the SAME loss as an unsharded single-device step — also where
+its tensor-parallel sums travel as collective matmuls
+(`parallel/collective_matmul.py`), which engage on the mesh and the
+shapes alone."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +15,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import llama
-from ray_tpu.parallel import spmd
+from ray_tpu.parallel import collective_matmul, spmd
 from ray_tpu.parallel.mesh import (MeshSpec, make_mesh, mesh_2d,
                                    mesh_context, param_shardings)
 
@@ -100,6 +105,135 @@ def test_2d_train_step_matches_single_device_loss(cfg):
     # buffers must not decay to replicated).
     spmd.assert_params_sharded(state.params, mesh,
                                llama.param_logical_axes(cfg))
+
+
+# name -> (mesh, sequence length, against a cache, config overrides,
+#          whether the ring engages)
+TP_RING_CASES = {
+    "fsdp4_tp2": (MeshSpec(fsdp=4, tp=2), 32, False, {}, True),
+    "fsdp2_tp4": (MeshSpec(fsdp=2, tp=4), 32, False, {}, True),  # 3 hops
+    "fsdp2_sp2_tp2": (MeshSpec(fsdp=2, sp=2, tp=2), 32, False, {}, True),
+    "sequence_not_divisible_by_tp": (
+        MeshSpec(fsdp=4, tp=2), 31, False, {}, False),
+    "with_a_cache": (MeshSpec(fsdp=4, tp=2), 32, True, {}, False),
+    "tp_1": (MeshSpec(fsdp=4, dp=2), 32, False, {}, False),
+    # Pallas calls cannot be traced inside the ring's shard_map.
+    "fused_ops": (MeshSpec(fsdp=4, tp=2), 32, False,
+                  {"fused_ops": "interpret"}, False),
+}
+
+
+def _jaxpr_text(mesh, fn, *args) -> str:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        # A fresh function a trace: tracing caches on identity.
+        text = str(jax.make_jaxpr(lambda *a: fn(*a))(*args))
+    return re.sub(r" at 0x[0-9a-f]+", "", text)
+
+
+@pytest.mark.parametrize("case", TP_RING_CASES)
+def test_tp_ring_engages_on_mesh_and_shapes_alone(case, monkeypatch):
+    """Where tp > 1, no cache and a sequence that divides, the block's
+    four matmul groups move their own shards (`ppermute` in the jaxpr).
+    Everywhere else the jaxpr is, letter for letter, the one traced with
+    the ring switched off: what the serving cells compile does not move
+    by hope. Engaged or not, loss and EVERY gradient leaf of the sharded
+    step are the single device's, remat on."""
+    spec, seq, cached, overrides, engaged = TP_RING_CASES[case]
+    cfg = llama.tiny_config(n_heads=4, n_kv_heads=4, d_ff=128, remat=True,
+                            **overrides)
+    mesh = make_mesh(spec, jax.devices("cpu")[:8])
+    params = llama.init_params(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (8, seq), 0,
+                                cfg.vocab_size)
+    if cached:
+        cache = llama.init_kv_cache(cfg, 8, 64)
+
+        def fn(p, t):
+            return llama.forward_with_cache(p, t, cache, 0, cfg)
+    else:
+        def fn(p, t):
+            return jax.value_and_grad(
+                lambda p: llama.loss_fn(p, t, cfg, mesh=mesh)[0])(p)
+
+    traced = _jaxpr_text(mesh, fn, params, tokens)
+    with monkeypatch.context() as mp:
+        mp.setattr(collective_matmul, "ring_size", lambda *a: 1)
+        plain = _jaxpr_text(mesh, fn, params, tokens)
+    hops = re.compile(r"ppermute\[\s*axis_name=\('tp',\)")
+    assert not hops.search(plain)
+    assert bool(hops.search(traced)) == engaged
+    if not engaged:
+        assert traced == plain
+    if cached:
+        return
+
+    loss_ref, grads_ref = jax.jit(jax.value_and_grad(
+        lambda p: llama.loss_fn(p, tokens, cfg)[0]))(params)
+    tx = spmd.default_optimizer(lr=1e-3)
+    with mesh_context(mesh):
+        p2 = jax.device_put(params, param_shardings(
+            mesh, llama.param_logical_axes(cfg)))
+        t2 = jax.device_put(tokens, spmd.data_sharding(mesh))
+        loss, grads = jax.jit(fn)(p2, t2)
+        state = spmd.TrainState(jnp.zeros((), jnp.int32), p2,
+                                jax.jit(tx.init)(p2))
+        _, metrics = spmd.make_train_step(cfg, mesh, tx)(state, t2)
+    np.testing.assert_allclose(float(loss), float(loss_ref), rtol=2e-4)
+    np.testing.assert_allclose(float(metrics["loss"]), float(loss_ref),
+                               rtol=2e-4)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), want in zip(flat, jax.tree.leaves(grads_ref)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-4,
+            atol=2e-4 * float(jnp.max(jnp.abs(want))),
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_collective_matmul_ring_of_eight_matches_einsum():
+    """The two functions themselves on the longest ring the CPU mesh
+    has (tp = 8, seven hops): a row-wise epilogue fed the rows' own
+    positions, one group assembled in sequence order, one handed to
+    `matmul_scatter` in ring order; values and every gradient are the
+    plain einsums'."""
+    mesh = make_mesh(MeshSpec(tp=8), jax.devices("cpu")[:8])
+    keys = jax.random.split(jax.random.key(0), 4)
+    h = jax.random.normal(keys[0], (2, 32, 16))
+    wa = jax.random.normal(keys[1], (16, 8, 4))
+    wb = jax.random.normal(keys[2], (16, 8, 4))
+    wo = jax.random.normal(keys[3], (8, 4, 16))
+    pos = jnp.broadcast_to(jnp.arange(32.0), (2, 32))
+
+    def rowwise(ab, pos):
+        a, b = ab
+        return (jnp.tanh(a) * b + pos[..., None, None],)
+
+    def ring(h, wa, wb, wo):
+        (seq,) = collective_matmul.gather_matmul(
+            "bsd,dhk->bshk", h, (wa, wb), rowwise=rowwise, row_args=(pos,))
+        (blocks,) = collective_matmul.gather_matmul(
+            "bsd,dhk->bshk", h, (wa, wb), rowwise=rowwise, row_args=(pos,),
+            in_sequence=False)
+        assert len(blocks) == 8 and blocks[0].shape == (2, 4, 8, 4)
+        return (collective_matmul.matmul_scatter("bshk,hkd->bsd", seq, wo)
+                + collective_matmul.matmul_scatter("bshk,hkd->bsd", blocks,
+                                                   wo))
+
+    def plain(h, wa, wb, wo):
+        (y,) = rowwise((jnp.einsum("bsd,dhk->bshk", h, wa),
+                        jnp.einsum("bsd,dhk->bshk", h, wb)), pos)
+        return 2 * jnp.einsum("bshk,hkd->bsd", y, wo)
+
+    def check(fn):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3))
+
+    with mesh_context(mesh):
+        got = jax.jit(check(ring))(*jax.device_put(
+            (h, wa, wb, wo), jax.NamedSharding(mesh, jax.P())))
+    want = check(plain)(h, wa, wb, wo)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-4 * float(jnp.max(jnp.abs(w))))
 
 
 def test_sharded_init_shards_optimizer_state_and_step_compiles_once(cfg):
