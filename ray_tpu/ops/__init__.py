@@ -27,6 +27,12 @@ mla_decode_attention       Pallas single-query kernel over  on TPU, or ``interpr
                            a LATENT cache: one shared key   jnp reference elsewhere
                            whose first columns are the
                            value, each row read once
+gated_delta.gdn_decode     Pallas state step of a gated     on TPU, or ``interpret=True`` off-TPU;
+                           delta-rule layer: every slot's   jnp twin elsewhere. Imported by its one
+                           state of one layer stepped       caller (``models/olmo_hybrid.py``) as
+                           where it lies (aliased)          ``ray_tpu.ops.gated_delta``, not from
+gated_delta.chunk_scan     (jnp chunked WY form under       here: a family the process does not
+                           ``lax.scan``; no kernel yet)     serve costs it no import
 ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (with attention.py
                                                             the only importers of shard_map —
                                                             rtpu-lint banned-API rule)

@@ -245,14 +245,23 @@ class InferenceEngine:
         self._cache_rows = cache_rows
         self.cache = self.model.init_kv_cache(self.cfg, max_batch,
                                               cache_rows)
-        # Of every layer together: what a token a slot holds costs.
+        # Of every layer together: what a token a slot holds costs in
+        # rows, and (a family with SLOT_STATE_KEYS) what a slot holds
+        # besides, whatever its length.
+        state_keys = getattr(self.model, "SLOT_STATE_KEYS", ())
         self._kv_bytes_per_token = sum(
-            a.nbytes for a in self.cache.values()) // (max_batch
-                                                       * cache_rows)
+            a.nbytes for k, a in self.cache.items()
+            if k not in state_keys) // (max_batch * cache_rows)
+        self._state_bytes_per_slot = sum(
+            self.cache[k].nbytes for k in state_keys) // max_batch
         self._cache_rebuilds = 0
 
+        # Such a slot's rows cannot be resumed from without the state
+        # at their end: no prefix is reused (the manager counts what it
+        # would have).
         self.kv = KVCacheManager(max_batch, self.max_len,
-                                 block_size=prefix_block)
+                                 block_size=prefix_block,
+                                 reuse_prefix=not state_keys)
         self.scheduler = Scheduler(self.kv, max_len=self.max_len,
                                    prompt_buckets=self.buckets,
                                    prefill_chunk=prefill_chunk)
@@ -468,6 +477,8 @@ class InferenceEngine:
                "resumes": self._resumes,
                "cache_rebuilds": self._cache_rebuilds,
                "kv_bytes_per_token": self._kv_bytes_per_token}
+        if self._state_bytes_per_slot:
+            out["state_bytes_per_slot"] = self._state_bytes_per_slot
         if self.quantize is not None:
             out["weight_bytes"], out["weight_bytes_f32"] = \
                 self._weight_bytes

@@ -35,7 +35,10 @@ tick's programs hand on (summed over a chunk's steps: a routed family's
 expert counters ride the fetches the tick already makes) and then a
 dict of what a check against a reference reads (each token's chosen
 experts), which only the functional programs return. All this file
-assumes of a cache is a dict of arrays with the slot axis second. The
+assumes of a cache is a dict of arrays with the slot axis second; a
+family that keeps per-slot STATE among them (``SLOT_STATE_KEYS``: a
+linear-attention layer's, which every step overwrites) is handed the
+chunk's live mask as ``live=``, so that only live slots are stepped. The
 engine's cache is ONE buffer: the chunk, verify, install and
 tick-prefill programs take it donated and return it aliased, so the
 caller must rebind its reference to the result (``self.cache = ...``)
@@ -210,13 +213,20 @@ class DecodeLoop:
         tick_prefill.__name__ = prefill.__name__
         self.prefill_inplace = jax.jit(tick_prefill, donate_argnums=(1,))
 
-        def step(params, cache, tokens, lengths):
+        # A family whose cache holds per-slot state (SLOT_STATE_KEYS)
+        # is told which slots are live: a stale row is masked by a
+        # length, a state stepped for a slot that is idle, frozen or
+        # between two chunks of its prefill is not.
+        stateful = bool(getattr(model, "SLOT_STATE_KEYS", ()))
+
+        def step(params, cache, tokens, lengths, live=None):
             """One decode step for every slot: tokens [B,1], lengths [B].
             Batched by construction (``decode_step_with_cache``):
             each slot's one new row a layer is written at its own
             length and the rest of the cache is carried untouched."""
             logits, cache, *counters = model.decode_step_with_cache(
-                params, tokens, cache, lengths, cfg)[:3]
+                params, tokens, cache, lengths, cfg,
+                **({"live": live} if stateful else {}))[:3]
             return (jnp.argmax(logits, axis=-1), cache, *counters)
 
         def decode_chunk(params, cache, tokens, lengths, remaining,
@@ -242,7 +252,7 @@ class DecodeLoop:
 
             def body(carry, _):
                 cache, tok, ln, rem, dn = carry
-                nxt, cache, *counters = step(params, cache, tok, ln)
+                nxt, cache, *counters = step(params, cache, tok, ln, ~dn)
                 emit = jnp.where(dn, tok[:, 0], nxt).astype(jnp.int32)
                 ln = jnp.where(dn, ln, ln + 1)
                 rem = jnp.where(dn, rem, rem - 1)

@@ -71,7 +71,8 @@ class SlotInfo:
 class KVCacheManager:
     """Allocates slots, tracks block occupancy, serves prefix-cache hits."""
 
-    def __init__(self, num_slots: int, max_len: int, block_size: int = 16):
+    def __init__(self, num_slots: int, max_len: int, block_size: int = 16,
+                 reuse_prefix: bool = True):
         if num_slots < 1:
             raise ValueError("need at least one slot")
         if block_size < 1:
@@ -90,6 +91,12 @@ class KVCacheManager:
         self.hits = 0
         self.misses = 0
         self.tokens_reused = 0
+        # False where a slot's rows are not all a request leaves behind
+        # (per-slot state stands at the LAST token, not at a shared
+        # prefix's end): a resident prefix is then found, counted in
+        # ``reuse_vetoed`` and not reused.
+        self.reuse_prefix = reuse_prefix
+        self.reuse_vetoed = 0
         # Fleet KV tier (kv_fleet.py): when the engine sets this, every
         # acquire that is about to destroy still-valid resident rows
         # reports them FIRST — hook(slot, resident, chain, keep_blocks)
@@ -170,6 +177,9 @@ class KVCacheManager:
                 while cached_len > 0 and not fit(cached_len):
                     cached_len -= bs
                 cached_len = max(cached_len, 0)
+        if cached_len > 0 and not self.reuse_prefix:
+            self.reuse_vetoed += 1
+            cached_len = 0
         if cached_len > 0:
             slot = best_slot
             self._free.remove(slot)
@@ -384,7 +394,10 @@ class KVCacheManager:
         return self.hits / total if total else 0.0
 
     def stats(self) -> Dict[str, float]:
+        vetoed = ({} if self.reuse_prefix
+                  else {"prefix_reuse_vetoed": self.reuse_vetoed})
         return {
+            **vetoed,
             "prefix_hits": self.hits,
             "prefix_misses": self.misses,
             "prefix_hit_rate": round(self.hit_rate(), 4),

@@ -526,3 +526,124 @@ def test_sharded_flash_wrapper_matches_unsharded(monkeypatch):
         ref = attention.causal_attention(q, kk, vv)
         assert jnp.allclose(got, ref, atol=1e-5), float(
             jnp.max(jnp.abs(got - ref)))
+
+
+# ------------------------------------- state beside rows in one cache
+
+# Olmo-Hybrid's widths (benchmark/configs/olmo-hybrid-7b-l16.json) at one
+# period of its pattern (the periods are scanned, so the HLO is the
+# 16-layer one's), the cell's 32 slots of 2048 rows.
+def _olmo_1p():
+    from ray_tpu.models import olmo_hybrid
+
+    return olmo_hybrid, olmo_hybrid.OlmoHybridConfig(n_layers=4,
+                                                     max_seq_len=2048)
+
+
+def _copies_of(text: str, cache) -> list:
+    """Copies of an array of any of the cache's shapes in a program."""
+    shapes = [f"{'bf16' if a.dtype == jnp.bfloat16 else 'f32'}"
+              f"[{','.join(map(str, a.shape))}]" for a in cache.values()]
+    return [line for line in text.splitlines()
+            if " copy" in line.split("(")[0]
+            and any(s in line.split("(")[0] for s in shapes)]
+
+
+def test_decode_attention_compiles_at_one_query_head_a_kv_head(chip):
+    """The shared kernel at the hybrid's full layers: 30 KV heads,
+    groups of ONE query head (Mistral's cells run 8 x 4), the layer
+    picked out of the whole [L, B, KH, S, D] cache."""
+    c = _compile(
+        lambda q, k, v, lens, layer: ops.decode_attention(
+            q, k, v, lens, layer=layer, layout="bksd", block_s=2048),
+        _sds(chip, (32, 30, 128)), _sds(chip, (4, 32, 30, 2048, 128)),
+        _sds(chip, (4, 32, 30, 2048, 128)), _sds(chip, (32,), jnp.int32),
+        _sds(chip, (), jnp.int32))
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_decode_attention")
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_gdn_decode_steps_the_state_where_it_lies(chip):
+    """The state step at the published sizes: S^T of 2 heads side by
+    side ([96, 384]: whole tiles, 2,211,840 B a slot a layer, nothing
+    padded), the whole [L, B, ..] array the operand, aliased to the
+    output: one kernel, under its name, no temporaries."""
+    from ray_tpu.ops import gated_delta
+
+    olmo, cfg = _olmo_1p()
+    assert cfg.state_group == 2
+    state = _sds(chip, (12, 32, 15, 96, 384), jnp.float32)
+    assert state.size * 4 == 12 * 32 * 2_211_840
+    f32 = functools.partial(_sds, chip, dtype=jnp.float32)
+    c = jax.jit(gated_delta.gdn_decode, donate_argnums=(0,)).lower(
+        state, _sds(chip, (), jnp.int32), f32((32, 30, 96)),
+        f32((32, 30, 96)), f32((32, 30, 192)), f32((32, 30)),
+        f32((32, 30))).compile()
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_gdn_decode")
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state.size * 4
+    assert mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_olmo_hybrid_decode_chunk_updates_rows_and_state_in_place(chip):
+    """The family's step through the engine's own `decode_chunk`: both
+    kernels in the scanned period under their names (the state step
+    called directly: never ``closed_call``, which the decode-attention
+    reader counts as its own), all four cache arrays aliased, no array
+    of any of their shapes copied, temporaries far under one cache."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    olmo, cfg = _olmo_1p()
+    slots, rows = 32, 2048
+    loop = DecodeLoop(cfg, max_len=rows, chunk=8)
+    params = _abstract(chip, functools.partial(olmo.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: olmo.init_kv_cache(cfg, slots, rows))
+    assert set(cache) == {"k", "v", "state", "conv"}
+    c = _lower_decode_chunk(chip, loop, params, cache, slots)
+    text = c.as_text()
+    assert "%rtpu_gdn_decode." in text
+    assert "%rtpu_decode_attention." in text and "%closed_call" not in text
+    nbytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < 2 ** 28
+    assert _copies_of(text, cache) == []
+    vec = _sds(chip, (slots,), jnp.int32)
+    out = jax.eval_shape(
+        loop.decode_chunk, params, cache, _sds(chip, (slots, 1), jnp.int32),
+        vec, vec, vec, _sds(chip, (slots,), jnp.bool_))
+    assert len(out) == 8 and set(out[7]) == {"gdn_slot_steps"}
+
+
+def test_olmo_hybrid_tick_prefill_resets_the_slot_in_the_program(chip):
+    """The tick's prefill at the largest bucket: the chunked scan and
+    the flash kernel in it, one token and the two counters out, the
+    cache aliased and no array of its shapes copied (the slot's rows,
+    state and conv tail are sliced out and written back)."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    olmo, cfg = _olmo_1p()
+    loop = DecodeLoop(cfg, max_len=2048, chunk=8)
+    params = _abstract(chip, functools.partial(olmo.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: olmo.init_kv_cache(cfg, 32, 2048))
+    scalar = _sds(chip, (), jnp.int32)
+    args = (params, cache, _sds(chip, (1, 2048), jnp.int32), scalar, scalar,
+            scalar)
+    lowered = loop.prefill_inplace.lower(*args)
+    assert "jit_prefill" in lowered.as_text()[:200]
+    c = lowered.compile()
+    out = jax.eval_shape(loop.prefill_inplace, *args)
+    assert (out[0].shape, out[0].dtype) == ((1,), jnp.int32)
+    assert len(out) == 3 and set(out[2]) == {"gdn_prefill_tokens",
+                                             "state_resets"}
+    text = c.as_text()
+    assert "%flash_attention" in text and " while(" in text
+    nbytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < 2 ** 30
+    assert _copies_of(text, cache) == []

@@ -411,3 +411,58 @@ def test_chunked_prefill_emits_per_chunk_spans(tiny_model):
     assert pf[-1]["attrs"]["bucket"] == 8
     queued = [s for s in spans if s["name"] == "engine.queued"]
     assert len(queued) == 1
+
+
+# ---------------------------------------------- what a family's cache refuses
+
+def _olmo_hybrid():
+    from ray_tpu.models import olmo_hybrid
+
+    import jax.numpy as jnp
+
+    return olmo_hybrid.OlmoHybridConfig(
+        vocab_size=64, d_model=24, n_layers=4, n_heads=2, linear_heads=2,
+        linear_key_dim=6, linear_value_dim=10, d_ff=32, max_seq_len=64,
+        dtype=jnp.float32)
+
+
+# family -> its tiny configuration; a further family is a further row.
+FAMILIES = {"olmo_hybrid": _olmo_hybrid}
+OPTIONS = {"quantize": dict(quantize="int8"),
+           "paged_decode": dict(paged_decode=True),
+           "spec_draft_len": dict(spec_draft_len=2),
+           "role": dict(role="prefill"),
+           "kv_fleet": dict(kv_fleet_min_prefix_blocks=0)}
+
+
+@pytest.mark.parametrize("option", OPTIONS)
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_family_refuses_what_its_cache_cannot_serve(family, option):
+    """Every option in the family's `ENGINE_REFUSES` raises at
+    construction, by name and with the family's reason, before any
+    weight is made; without it the engine comes up."""
+    from ray_tpu.serve.engine.core import InferenceEngine
+
+    cfg = FAMILIES[family]()
+    assert option in cfg.model.ENGINE_REFUSES
+    kwargs = dict(max_batch=2, max_len=64, prompt_buckets=[8, 16],
+                  kv_fleet_min_prefix_blocks=-1)
+    with pytest.raises(ValueError,
+                       match=f"cannot serve with {option} yet") as refused:
+        InferenceEngine(cfg, **{**kwargs, **OPTIONS[option]})
+    assert cfg.model.ENGINE_REFUSES[option] in str(refused.value)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_family_with_slot_state_reuses_no_prefix(family):
+    from ray_tpu.serve.engine.core import InferenceEngine
+
+    cfg = FAMILIES[family]()
+    engine = InferenceEngine(cfg, max_batch=2, max_len=64,
+                             prompt_buckets=[8, 16],
+                             kv_fleet_min_prefix_blocks=-1)
+    try:
+        assert cfg.model.SLOT_STATE_KEYS and not engine.kv.reuse_prefix
+        assert "prefix_reuse_vetoed" in engine.stats()
+    finally:
+        engine.close()
