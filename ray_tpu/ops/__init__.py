@@ -34,6 +34,18 @@ gated_delta.gdn_decode     Pallas state step of a gated     on TPU, or ``interpr
                            where it lies (aliased)          ``ray_tpu.ops.gated_delta``, not from
 gated_delta.chunk_scan     (jnp chunked WY form under       here: a family the process does not
                            ``lax.scan``; no kernel yet)     serve costs it no import
+lightning.lightning_decode Pallas state step of a           on TPU, or ``interpret=True`` off-TPU;
+                           lightning (constant-decay)       jnp twin elsewhere. ``ops.lightning`` and
+                           layer, aliased like gdn_decode   ``ops.sparse_attention`` are imported by
+lightning.chunk_scan       (jnp chunked form under          their one caller
+                           ``lax.scan``; no kernel yet)     (``models/minicpm_sala.py``)
+sparse_attention           Pallas single-query kernel over  on TPU, or ``interpret=True`` off-TPU;
+ .sparse_decode_attention  a LIST of 64-row blocks a (slot, jnp gather twin elsewhere. The list is
+                           KV head): a block table made     `select_blocks`' (scores over compressed
+                           anew every step                  keys, pooled to blocks, top-k: XLA)
+ .sparse_prefill_attention (jnp: each query's selection as  always; the loop's trip count follows
+                           a mask, tiles of rows under a    the chunk's last position
+                           ``fori_loop``; no kernel yet)
 ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (with attention.py
                                                             the only importers of shard_map —
                                                             rtpu-lint banned-API rule)

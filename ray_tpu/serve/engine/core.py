@@ -43,15 +43,18 @@ from ray_tpu.util import tracing as _tracing
 
 class _PrefillJob:
     """One admission's prefill progress: ``idx`` chunks of ``adm.chunks``
-    dispatched, next chunk writing at row ``pos``. Engine-thread-only."""
+    dispatched, next chunk writing at row ``pos``; ``counters`` holds
+    what the chunks dispatched so far counted, still on the device (the
+    final chunk's one fetch brings them all). Engine-thread-only."""
 
-    __slots__ = ("adm", "pos", "idx", "t_pf0")
+    __slots__ = ("adm", "pos", "idx", "t_pf0", "counters")
 
     def __init__(self, adm, pos: int):
         self.adm = adm
         self.pos = pos
         self.idx = 0
         self.t_pf0 = 0.0
+        self.counters: list = []
 
 
 class InferenceEngine:
@@ -1081,6 +1084,9 @@ class InferenceEngine:
                     self.params, self.cache, self._put(padded),
                     self._put(np.int32(slot)), self._put(np.int32(job.pos)),
                     self._put(np.int32(n - 1)))
+                # Every chunk's counters ride the final chunk's fetch (a
+                # state family resets its slot in the FIRST chunk).
+                job.counters.extend(counters)
                 # Per-chunk prefix commit: block occupancy and the
                 # slot's resident chain track the materialized prefix
                 # as chunks land, not the whole prompt up-front.
@@ -1095,7 +1101,8 @@ class InferenceEngine:
                 # counters, where the program returns those).
                 with self._tick.phase("prefill_fetch", slot=slot,
                                       bucket=bucket) as attrs:
-                    fetched = self._fetch((token, *counters), tag="prefill")
+                    fetched = self._fetch((token, *job.counters),
+                                          tag="prefill")
                     token, *counters = fetched
                     attrs["bytes"] = sum(
                         a.nbytes for a in self._jax.tree.leaves(fetched))
@@ -1307,9 +1314,15 @@ class InferenceEngine:
         return done
 
     def _span_attrs(self, counters) -> Dict[str, int]:
-        return {self._span_attr_names[name]: int(value)
-                for fetched in counters for name, value in fetched.items()
-                if name in self._span_attr_names}
+        """The named counters of one fetch (a chunked prefill's holds a
+        dict a chunk: summed) as span attributes."""
+        out: Dict[str, int] = {}
+        for fetched in counters:
+            for name, value in fetched.items():
+                attr = self._span_attr_names.get(name)
+                if attr is not None:
+                    out[attr] = out.get(attr, 0) + int(value)
+        return out
 
     def _roster_arrays(self, active):
         """Per-slot device inputs for a chunk dispatch (plain or spec)."""

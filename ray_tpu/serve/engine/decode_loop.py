@@ -286,9 +286,9 @@ class DecodeLoop:
         # for a check against a reference (compiled only if called).
         self.decode_step = jax.jit(step)
         self.decode_step_whole = jax.jit(
-            lambda params, cache, tokens, lengths:
+            lambda params, cache, tokens, lengths, live=None:
             model.decode_step_with_cache(params, tokens, cache, lengths,
-                                         cfg))
+                                         cfg, live))
 
     def _build_verify(self) -> None:
         import jax

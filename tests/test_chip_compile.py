@@ -662,3 +662,140 @@ def test_olmo_hybrid_tick_prefill_resets_the_slot_in_the_program(chip):
     assert mem.alias_size_in_bytes >= nbytes
     assert mem.temp_size_in_bytes < 2 ** 30
     assert _copies_of(text, cache) == []
+
+
+# ---------------------------------------------------- the MiniCPM-SALA cell
+
+def _sala_l16():
+    """The cell's configuration (benchmark/configs/minicpm-sala-l16.json):
+    every published width, 16 layers in the published order."""
+    from ray_tpu.models import minicpm_sala as sala
+
+    s, l = sala.SPARSE, sala.LIGHTNING
+    return sala, sala.MiniCPMSalaConfig(
+        mixer_types=(s,) + (l,) * 6 + (s,) * 2 + (l,) * 4 + (s,) + (l,) * 2,
+        max_seq_len=32768)
+
+
+SALA_SLOTS, SALA_ROWS = 16, 32768
+GIB = 2 ** 30
+
+
+def test_lightning_decode_steps_the_state_where_it_lies(chip):
+    """The lightning state step at the published sizes: 128 x 128 a
+    head, whole tiles as the mathematics has them, the whole [L, B, ..]
+    array the operand, aliased to the output: one kernel, under its
+    name, no temporaries."""
+    from ray_tpu.ops import lightning
+
+    state = _sds(chip, (12, SALA_SLOTS, 32, 128, 128), jnp.float32)
+    assert state.size * 4 == 12 * SALA_SLOTS * 2_097_152
+    f32 = functools.partial(_sds, chip, dtype=jnp.float32)
+    c = jax.jit(lightning.lightning_decode, donate_argnums=(0,)).lower(
+        state, _sds(chip, (), jnp.int32), f32((SALA_SLOTS, 32, 128)),
+        f32((SALA_SLOTS, 32, 128)), f32((SALA_SLOTS, 32, 128)),
+        f32((SALA_SLOTS, 32))).compile()
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_lightning_decode")
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state.size * 4
+    assert mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_sparse_decode_attention_reads_a_block_list(chip):
+    """The block-list kernel at the cell's geometry: 2 KV heads in
+    groups of 16 query heads, a list of 128 blocks of 64 rows a (slot,
+    head) in SMEM, the layer picked out of the whole [L, B, KH, S, D]
+    cache, which stays in HBM."""
+    from ray_tpu.ops import sparse_attention as sa
+
+    cache = (4, SALA_SLOTS, 2, SALA_ROWS, 128)
+    n_list = sa.Selection().list_len(SALA_ROWS)
+    assert n_list == 128
+    vec = _sds(chip, (SALA_SLOTS,), jnp.int32)
+    c = _compile(
+        lambda q, k, v, ids, count, seen, layer: sa.sparse_decode_attention(
+            q, k, v, ids, count, seen, layer=layer, block=64),
+        _sds(chip, (SALA_SLOTS, 32, 128)), _sds(chip, cache),
+        _sds(chip, cache), _sds(chip, (SALA_SLOTS, 2, n_list), jnp.int32),
+        vec, vec, _sds(chip, (), jnp.int32))
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_sparse_decode_attention")
+    assert "vmem_limit_bytes" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def _sala_args(chip):
+    sala, cfg = _sala_l16()
+    params = _abstract(chip, functools.partial(sala.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: sala.init_kv_cache(cfg, SALA_SLOTS,
+                                                       SALA_ROWS))
+    assert set(cache) == {"k", "v", "kc", "state"}
+    # 16 slots x 32,768 rows: 4,096 B of K and V and 128 B of compressed
+    # keys a token, 25.2 MB of state a slot.
+    assert cache["kc"].shape[3] * 16 == cache["k"].shape[3] == SALA_ROWS
+    nbytes = lambda tree: sum(a.size * a.dtype.itemsize
+                              for a in jax.tree.leaves(tree))
+    assert nbytes(params) == 10_078_800_896
+    assert nbytes(cache) == 2_617_245_696
+    return cfg, params, cache, nbytes(params) + nbytes(cache)
+
+
+def test_minicpm_sala_decode_chunk_fits_and_updates_its_cache_in_place(chip):
+    """The cell's `decode_chunk` whole: 16 layers in the published
+    irregular order (six runs, each a scan), both kernels under their
+    names, all four cache arrays aliased and none copied, no stack of
+    weights laid out again (the projections are stored output-major:
+    `minicpm_sala._proj`), and weights + cache + temporaries inside the
+    chip's 16 GiB with room for the check's reference."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg, params, cache, held = _sala_args(chip)
+    loop = DecodeLoop(cfg, max_len=SALA_ROWS, chunk=8)
+    c = _lower_decode_chunk(chip, loop, params, cache, SALA_SLOTS)
+    text = c.as_text()
+    assert "%rtpu_lightning_decode." in text
+    assert "%rtpu_sparse_decode_attention." in text
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= held - 10_078_800_896
+    assert mem.temp_size_in_bytes < 2 ** 28
+    assert held + mem.temp_size_in_bytes < 13 * GIB
+    assert _copies_of(text, cache) == []
+    vec = _sds(chip, (SALA_SLOTS,), jnp.int32)
+    out = jax.eval_shape(
+        loop.decode_chunk, params, cache,
+        _sds(chip, (SALA_SLOTS, 1), jnp.int32), vec, vec, vec,
+        _sds(chip, (SALA_SLOTS,), jnp.bool_))
+    assert len(out) == 8 and set(out[7]) == {
+        "sparse_rows_held", "sparse_rows_selected", "sparse_select_steps",
+        "lightning_state_steps"}
+
+
+def test_minicpm_sala_tick_prefill_chunk_fits_beside_the_cache(chip):
+    """The tick's prefill at the chunk's size (2,048 tokens at any
+    ``cache_index``): the masked attention's loop over the slot's rows
+    and the chunked scan in it, one token and the two counters out, the
+    cache aliased and no array of its shapes copied; its temporaries
+    (the score tiles) leave the chip room."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg, params, cache, held = _sala_args(chip)
+    loop = DecodeLoop(cfg, max_len=SALA_ROWS, chunk=8)
+    scalar = _sds(chip, (), jnp.int32)
+    args = (params, cache, _sds(chip, (1, 2048), jnp.int32), scalar, scalar,
+            scalar)
+    lowered = loop.prefill_inplace.lower(*args)
+    assert "jit_prefill" in lowered.as_text()[:200]
+    c = lowered.compile()
+    out = jax.eval_shape(loop.prefill_inplace, *args)
+    assert (out[0].shape, out[0].dtype) == ((1,), jnp.int32)
+    assert len(out) == 3 and set(out[2]) == {"prefill_chunks",
+                                             "state_resets"}
+    text = c.as_text()
+    assert " while(" in text
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= held - 10_078_800_896
+    assert mem.temp_size_in_bytes < GIB
+    assert held + mem.temp_size_in_bytes < 13 * GIB
+    assert _copies_of(text, cache) == []

@@ -426,8 +426,22 @@ def _olmo_hybrid():
         dtype=jnp.float32)
 
 
+def _minicpm_sala():
+    from ray_tpu.models import minicpm_sala
+    from ray_tpu.ops.sparse_attention import Selection
+
+    import jax.numpy as jnp
+
+    return minicpm_sala.MiniCPMSalaConfig(
+        vocab_size=64, d_model=24, mixer_types=("minicpm4", "lightning-attn"),
+        n_heads=2, n_kv_heads=1, head_dim=8, lightning_heads=2,
+        lightning_head_dim=8, d_ff=32, max_seq_len=64,
+        selection=Selection(kernel=4, stride=2, block=4, window=4, topk=4,
+                            dense_len=16), dtype=jnp.float32)
+
+
 # family -> its tiny configuration; a further family is a further row.
-FAMILIES = {"olmo_hybrid": _olmo_hybrid}
+FAMILIES = {"olmo_hybrid": _olmo_hybrid, "minicpm_sala": _minicpm_sala}
 OPTIONS = {"quantize": dict(quantize="int8"),
            "paged_decode": dict(paged_decode=True),
            "spec_draft_len": dict(spec_draft_len=2),
@@ -466,3 +480,44 @@ def test_a_family_with_slot_state_reuses_no_prefix(family):
         assert "prefix_reuse_vetoed" in engine.stats()
     finally:
         engine.close()
+
+
+def _prefill_counters(cfg) -> dict:
+    """The scalars a family's tick prefill counts, by name."""
+    import jax
+
+    cache = cfg.model.init_kv_cache(cfg, 1, 16)
+    out = jax.eval_shape(
+        lambda p, c: cfg.model.forward_last_with_cache(
+            p, jax.numpy.zeros((1, 8), "int32"), c, 0, 7, cfg)[2],
+        cfg.model.init_params(cfg, jax.random.PRNGKey(0)), cache)
+    return dict.fromkeys(out, 0)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_chunked_prefill_fetches_every_chunks_counters(family):
+    """A family with slot state resets its slot in the FIRST chunk of a
+    prefill; the tick fetches once, after the last: the counters of
+    every chunk ride that fetch, and the request's span sums them."""
+    from ray_tpu.serve.engine.core import InferenceEngine
+
+    cfg = FAMILIES[family]()
+    engine = InferenceEngine(cfg, max_batch=2, max_len=64,
+                             prompt_buckets=[8, 16], prefill_chunk=8,
+                             kv_fleet_min_prefix_blocks=-1)
+    try:
+        for _ in range(2):
+            out = engine.generate(list(range(1, 21)), max_new_tokens=2)
+            assert len(out["token_ids"]) == 2
+        stats = engine.stats()
+    finally:
+        engine.close()
+    assert stats["state_resets"] == 2           # one an admission
+    # ... in one fetch each: the token and three chunks' scalars.
+    assert stats["prefill_fetch_bytes"] == 2 * 4 * (
+        1 + 3 * len(_prefill_counters(cfg)))
+    if "prefill_chunks" in cfg.model.SPAN_ATTRS:
+        assert stats["prefill_chunks"] == 2 * 3     # 8 + 8 + 4 tokens
+    assert engine._span_attrs([{"state_resets": 1}, {"state_resets": 0}]) \
+        == {"state_reset": 1}
+
