@@ -45,16 +45,21 @@ class _PrefillJob:
     """One admission's prefill progress: ``idx`` chunks of ``adm.chunks``
     dispatched, next chunk writing at row ``pos``; ``counters`` holds
     what the chunks dispatched so far counted, still on the device (the
-    final chunk's one fetch brings them all). Engine-thread-only."""
+    final chunk's one fetch brings them all). ``t0`` is the last
+    chunk's dispatch stamp; ``token`` the first generated token, on the
+    device, once the FINAL chunk is dispatched and until
+    ``_land_prefill`` fetches it. Engine-thread-only."""
 
-    __slots__ = ("adm", "pos", "idx", "t_pf0", "counters")
+    __slots__ = ("adm", "pos", "idx", "t_pf0", "t0", "counters", "token")
 
     def __init__(self, adm, pos: int):
         self.adm = adm
         self.pos = pos
         self.idx = 0
         self.t_pf0 = 0.0
+        self.t0 = 0.0
         self.counters: list = []
+        self.token = None
 
 
 class InferenceEngine:
@@ -102,15 +107,25 @@ class InferenceEngine:
     unchunked path (same positions, same rows, same math).
 
     ``multi_step`` (default on, plain-decode path only) double-buffers
-    decode dispatch: each tick enqueues chunk N+1 from chunk N's
-    device-carried state BEFORE fetching chunk N's tokens, so the
-    per-tick host sync overlaps the next chunk's device execution.
-    Exactly one host sync per FETCHED chunk either way (the witness
-    budget is unchanged); at most one trailing chunk per burst is
-    dispatched wastefully (every roster member already frozen on
-    device) and dropped unfetched. Disabled automatically while
-    speculation drafts (drafts are proposed from host-visible tokens,
-    which an in-flight chunk would lag by one dispatch).
+    decode dispatch: each tick enqueues chunk N+1 BEFORE fetching chunk
+    N's tokens, WHATEVER the roster did in between. The roster's decode
+    state (tokens, lengths, budgets, EOS ids, done mask) lives on the
+    device across a roster change: a slot still held by the request it
+    was dispatched with takes chunk N's carry (a finish inside chunk N
+    stays frozen), a request activated since takes the host's values,
+    a request whose prefill was dispatched this tick takes its first
+    token from the prefill's own device output, and a slot nobody
+    holds is parked and done (``_dispatch_chunk``). Chunk N's fetch
+    and delivery and the admissions' first-token fetches then run
+    under chunk N+1's device time. Exactly one host sync per FETCHED
+    chunk and one per admission either way (the witness budget is
+    unchanged); at most one trailing chunk per burst is dispatched
+    wastefully (every roster member already frozen on device) and
+    dropped unfetched. Disabled automatically while speculation drafts
+    (drafts are proposed from host-visible tokens, which an in-flight
+    chunk would lag by one dispatch); ``multi_step=False`` and the
+    speculative engine dispatch, fetch and deliver a chunk in one
+    tick, and fetch a first token where its prefill is dispatched.
 
     ``paged_decode`` routes decode attention through the paged
     block-table kernel (``ops/paged_decode.py``): the block-granular
@@ -270,6 +285,9 @@ class InferenceEngine:
                                    prefill_chunk=prefill_chunk)
         self.prefill_chunk = self.scheduler.prefill_chunk
         self.multi_step = bool(multi_step)
+        # The pipelined chunk schedule (_pipelined_tick) is the
+        # drafter-free engine's; drafts need host-visible tokens.
+        self._pipelined = self.multi_step and self.drafter is None
         self.metrics = EngineMetrics(name)
         # The engine thread's phase clock (engine.tick.* counters and
         # spans); created here, used by that thread alone.
@@ -664,12 +682,14 @@ class InferenceEngine:
         if victim.priority >= hp:
             return False
         if self._inflight is not None:
-            # Land the in-flight decode chunk BEFORE recycling a slot.
-            # _retire_chunk delivers by slot to whoever is active at
-            # fetch time; the done-mask guard only covers FINISHED
-            # slots (frozen on device), so a chunk dispatched with the
-            # victim in its roster would otherwise hand the victim's
-            # tokens to the preemptor admitted into the same slot.
+            # Land the in-flight decode chunk BEFORE recycling a slot:
+            # it was dispatched with the victim in its roster, and
+            # _retire_chunk delivers a slot's tokens only to the
+            # request the chunk was dispatched with while it still
+            # holds the slot — parked first, the victim would lose
+            # them (and prefill them again on resume). With nothing in
+            # flight the next chunk is built from the host's values,
+            # the preemptor's among them.
             prev, self._inflight = self._inflight, None
             if not self._retire_chunk(prev):
                 return False
@@ -1050,31 +1070,47 @@ class InferenceEngine:
             return 1 << 30
         return co
 
-    def _prefill_tick(self) -> None:
+    def _prefill_tick(self) -> List[_PrefillJob]:
         """Advance EVERY in-progress prefill by one chunk. Intermediate
         chunks are dispatch-only (no host fetch — their token is
         never needed); the decode tick that follows interleaves with
         their device execution, which is what keeps co-batched TPOT
-        flat while a long prompt materializes."""
+        flat while a long prompt materializes.
+
+        On the pipelined schedule a FINAL chunk is dispatch-only too:
+        its token stays on the device and the jobs are handed back, for
+        ``_pipelined_tick`` to put into the chunk it dispatches and to
+        land (``_land_prefill``) after it — the device is never left
+        waiting for the host to read a first token. Every other
+        schedule lands each where it is dispatched and returns
+        nothing."""
+        landing: List[_PrefillJob] = []
         for job in list(self._prefilling):
             if job not in self._prefilling:
                 continue  # failed with the cache an earlier job lost
-            if self._advance_prefill(job) and job in self._prefilling:
-                self._prefilling.remove(job)
+            if not self._dispatch_prefill(job):
+                continue
+            if self._pipelined:
+                landing.append(job)
+            else:
+                self._land_prefill(job)
+        return landing
 
-    def _advance_prefill(self, job: "_PrefillJob") -> bool:
-        """Dispatch one prefill chunk; returns True when the job is
-        finished (activated into the decode roster, or aborted)."""
+    def _dispatch_prefill(self, job: "_PrefillJob") -> bool:
+        """Dispatch one prefill chunk, no host sync. True when it was
+        the job's FINAL chunk: ``job.token`` is then the first
+        generated token, on the device, and the job stays in
+        ``_prefilling`` until ``_land_prefill`` has fetched it. A chunk
+        that raises aborts its admission alone."""
         req, slot = job.adm.request, job.adm.slot
-        cached = job.adm.cached_len
         n, bucket = job.adm.chunks[job.idx]
         final = job.idx == len(job.adm.chunks) - 1
         try:
             with self._tick.phase("prefill_dispatch", slot=slot,
                                   bucket=bucket, tokens=n):
-                t0 = self._tick.now
+                job.t0 = self._tick.now
                 if job.idx == 0:
-                    job.t_pf0 = t0
+                    job.t_pf0 = job.t0
                 suffix = req.prompt_ids[job.pos:job.pos + n]
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :n] = suffix
@@ -1091,45 +1127,80 @@ class InferenceEngine:
                 # slot's resident chain track the materialized prefix
                 # as chunks land, not the whole prompt up-front.
                 self.kv.commit_prefill(slot, req.prompt_ids[:job.pos + n])
-            if final:
-                # The ONE counted prefill sync per admission —
-                # intermediate chunks fetch nothing (np.asarray on a
-                # device array here was the jax-lint rule's first
-                # in-tree catch: an uncounted implicit sync). It waits
-                # out whatever the device had queued before this
-                # prefill, then copies 4 bytes (and the family's
-                # counters, where the program returns those).
-                with self._tick.phase("prefill_fetch", slot=slot,
-                                      bucket=bucket) as attrs:
-                    fetched = self._fetch((token, *job.counters),
-                                          tag="prefill")
-                    token, *counters = fetched
-                    attrs["bytes"] = sum(
-                        a.nbytes for a in self._jax.tree.leaves(fetched))
-                    self.metrics.record_prefill_fetch(attrs["bytes"])
         except BaseException as e:  # noqa: BLE001 — one bad request
             # must not kill the engine thread (every later request
-            # would hang on a dead engine). Seed only the PRE-ACQUIRE
-            # reused prefix: rows this job dispatched are unconfirmed.
-            self.scheduler.abort_admission(
-                req, resident=req.prompt_ids[:cached])
-            self._recover_cache(e)
-            self._deliver_error([req], e)
-            return True
-        t1 = self._tick.now  # the chunk is dispatched (and, final, fetched)
-        if req.trace_ctx is not None:
-            # One span per CHUNK (chunk/chunks attrs), so TTFT
-            # decomposition stays accurate under chunked prefill — the
-            # gaps between chunk spans are the interleaved decode ticks.
-            self._span("engine.prefill", t0, t1, req,
-                       {"prefill_tokens": n, "cached_tokens": cached,
-                        "bucket": bucket, "slot": slot,
-                        "chunk": job.idx, "chunks": len(job.adm.chunks),
-                        **(self._span_attrs(counters) if final else {})})
+            # would hang on a dead engine).
+            self._abort_prefill(job, e)
+            return False
+        if final:
+            job.token = token
+        else:
+            self._prefill_span(job, job.idx, self._tick.now, ())
         job.idx += 1
         job.pos += n
-        if not final:
-            return False
+        return final
+
+    def _abort_prefill(self, job: "_PrefillJob", e: BaseException) -> None:
+        """A prefill chunk (or the fetch of its token) failed: this
+        admission fails, alone unless the cache went with it. Seed only
+        the PRE-ACQUIRE reused prefix: rows this job dispatched are
+        unconfirmed."""
+        req = job.adm.request
+        if job in self._prefilling:
+            self._prefilling.remove(job)
+        self.scheduler.abort_admission(
+            req, resident=req.prompt_ids[:job.adm.cached_len])
+        self._recover_cache(e)
+        self._deliver_error([req], e)
+
+    def _prefill_span(self, job: "_PrefillJob", idx: int, t1: float,
+                      counters) -> None:
+        """One span per CHUNK (chunk/chunks attrs), so TTFT
+        decomposition stays accurate under chunked prefill — the gaps
+        between chunk spans are the interleaved decode ticks. The final
+        chunk's ends with its fetch and carries the counters."""
+        req = job.adm.request
+        if req.trace_ctx is None:
+            return
+        n, bucket = job.adm.chunks[idx]
+        self._span("engine.prefill", job.t0, t1, req,
+                   {"prefill_tokens": n, "cached_tokens": job.adm.cached_len,
+                    "bucket": bucket, "slot": job.adm.slot,
+                    "chunk": idx, "chunks": len(job.adm.chunks),
+                    **self._span_attrs(counters)})
+
+    def _land_prefill(self, job: "_PrefillJob") -> None:
+        """The ONE counted prefill sync of an admission, and what its
+        first token sets off: TTFT bookkeeping, the token onto the
+        stream, the request into the decode roster (or its handoff).
+        On the pipelined schedule this runs AFTER the chunk the request
+        joins is dispatched (the device took the token from the
+        prefill's own output), so the wait here is under device work."""
+        if job not in self._prefilling:
+            return  # failed with the cache since its dispatch
+        req, slot = job.adm.request, job.adm.slot
+        cached = job.adm.cached_len
+        try:
+            # Intermediate chunks fetch nothing (np.asarray on a device
+            # array here was the jax-lint rule's first in-tree catch: an
+            # uncounted implicit sync). This waits out whatever the
+            # device had queued before the prefill, then copies 4 bytes
+            # (and the family's counters, where the program returns
+            # those).
+            with self._tick.phase("prefill_fetch", slot=slot,
+                                  bucket=job.adm.chunks[-1][1]) as attrs:
+                fetched = self._fetch((job.token, *job.counters),
+                                      tag="prefill")
+                token, *counters = fetched
+                attrs["bytes"] = sum(
+                    a.nbytes for a in self._jax.tree.leaves(fetched))
+                self.metrics.record_prefill_fetch(attrs["bytes"])
+        except BaseException as e:  # noqa: BLE001 — one bad request
+            self._abort_prefill(job, e)
+            return
+        self._prefilling.remove(job)
+        t1 = self._tick.now  # the first token is on the host
+        self._prefill_span(job, len(job.adm.chunks) - 1, t1, counters)
         with self._tick.phase("prefill_deliver", slot=slot):
             # First generated token: from the LAST REAL prompt pos (row
             # n-1 of the final chunk), chosen on the device.
@@ -1154,10 +1225,9 @@ class InferenceEngine:
                 req.stream_queue.put(("token", first))
             if req.handoff:
                 self._finish_handoff(req)
-                return True
+                return
             self.scheduler.activate(req)
             self._maybe_finish(req, first)
-        return True
 
     def _finish_handoff(self, req: EngineRequest) -> None:
         """Prefill role: resolve the request with a KV handoff payload
@@ -1324,8 +1394,12 @@ class InferenceEngine:
                     out[attr] = out.get(attr, 0) + int(value)
         return out
 
-    def _roster_arrays(self, active):
-        """Per-slot device inputs for a chunk dispatch (plain or spec)."""
+    def _roster_arrays(self, active, joining=()):
+        """Per-slot host inputs for a chunk dispatch (plain or spec):
+        ``active`` requests with what the host knows of them, and
+        ``joining`` prefill jobs whose first token is still on the
+        device — their row and ``done`` are the device's to fill
+        (``loop.roster_join``), the rest is known here."""
         tokens = np.zeros((self.max_batch, 1), np.int32)
         # The scan's static shape steps EVERY slot, so inactive slots
         # still write one KV row per step. Park those writes on the LAST
@@ -1344,6 +1418,12 @@ class InferenceEngine:
             if req.eos_id is not None:
                 eos_ids[req.slot] = req.eos_id
             done[req.slot] = False
+        for job in joining:
+            req = job.adm.request
+            lengths[req.slot] = len(req.prompt_ids)
+            remaining[req.slot] = req.max_new_tokens - 1
+            if req.eos_id is not None:
+                eos_ids[req.slot] = req.eos_id
         return tokens, lengths, remaining, eos_ids, done
 
     @staticmethod
@@ -1354,8 +1434,16 @@ class InferenceEngine:
             if req.stream_queue is not None:
                 req.stream_queue.put(("error", e))
 
-    def _fail_roster(self, e: BaseException) -> None:
+    def _fail_roster(self, e: BaseException, joining=()) -> None:
+        """A chunk failed: everyone it was (or would have been)
+        dispatched with fails — the roster, and the ``joining`` jobs
+        whose first token it took from the device."""
         failed = self.scheduler.fail_active()
+        for job in joining:
+            if job in self._prefilling:
+                self._prefilling.remove(job)
+                self.scheduler.abort_admission(job.adm.request)
+                failed.append(job.adm.request)
         self._recover_cache(e)
         self._deliver_error(failed, e)
 
@@ -1391,7 +1479,7 @@ class InferenceEngine:
         self._deliver_error(lost, e)
         return True
 
-    def _decode_tick(self) -> None:
+    def _decode_tick(self, landing: List[_PrefillJob]) -> None:
         """One device chunk for the whole roster + ONE host fetch.
 
         With speculation enabled, ticks where prompt lookup proposed at
@@ -1400,7 +1488,8 @@ class InferenceEngine:
         workload on which lookup never bites costs nothing over
         speculation-off. Multi-step double-buffering applies only to
         the drafter-free engine: drafts are proposed from host-visible
-        tokens, which an in-flight chunk would lag by one dispatch.
+        tokens, which an in-flight chunk would lag by one dispatch
+        (``landing`` is empty off that schedule: ``_prefill_tick``).
         """
         if self.drafter is not None:
             with self._tick.phase("decode_dispatch", drafting=True):
@@ -1408,10 +1497,8 @@ class InferenceEngine:
             if drafts:
                 self._spec_tick(drafts)
                 return
-            self._plain_tick()
-            return
-        if self.multi_step:
-            self._pipelined_tick()
+        if self._pipelined:
+            self._pipelined_tick(landing)
         else:
             self._plain_tick()
 
@@ -1423,103 +1510,119 @@ class InferenceEngine:
         if rec is not None:
             self._retire_chunk(rec)
 
-    def _pipelined_tick(self) -> None:
-        """Multi-step schedule: with an unchanged roster, enqueue chunk
-        N+1 from chunk N's device-carried state BEFORE fetching chunk
-        N — the one host sync per tick then overlaps chunk N+1's device
-        execution instead of serializing ahead of it. Roster churn
-        (admissions, finishes discovered at the last fetch) falls back
-        to fetch-then-dispatch for that tick; device-side freezing
-        keeps an in-flight chunk correct across finishes either way
-        (a slot the host retires was already done on device — its
-        carried mask emits nothing, so the trailing chunk of a burst
-        delivers zero tokens and is dropped unfetched)."""
-        prev = self._inflight
-        nxt = None
-        if (prev is not None and prev["roster"] == self._roster_key()
-                and self._roster_outlives_chunk()):
-            nxt = self._dispatch_chunk(carry=prev)
+    def _pipelined_tick(self, landing: List[_PrefillJob]) -> None:
+        """Multi-step schedule: enqueue chunk N+1 BEFORE fetching chunk
+        N, whatever the roster did since N was dispatched — the roster's
+        decode state lives on the device across the change
+        (``_dispatch_chunk``), so the tick's host work (chunk N's fetch
+        and delivery, the admissions' first tokens) runs under chunk
+        N+1's device time instead of ahead of it. Device-side freezing
+        keeps the chunk in flight correct across finishes (a slot the
+        host retires was already done on device: its carried mask emits
+        nothing, so the trailing chunk of a burst delivers zero tokens
+        and is dropped unfetched). The order below is the device's own:
+        chunk N, the tick's prefills, chunk N+1 — so each fetch finds
+        its program done or running, never queued behind a later one."""
+        prev, self._inflight = self._inflight, None
+        joining = [j for j in landing if not j.adm.request.handoff]
+        if self._roster_outlives_chunk(prev, joining):
+            # Bound before the retire: a device failure found there
+            # drops it with the cache (_recover_cache).
+            self._inflight = self._dispatch_chunk(prev, joining)
         if prev is not None:
-            self._inflight = None
-            if not self._retire_chunk(prev):
-                return  # device failure: roster failed, nxt is doomed
-        if nxt is None and self.scheduler.active:
-            nxt = self._dispatch_chunk()
-        self._inflight = nxt
+            self._retire_chunk(prev)
+        for job in landing:
+            self._land_prefill(job)
 
-    def _roster_key(self):
-        return tuple((id(r), r.slot) for r in self.scheduler.active)
-
-    def _roster_outlives_chunk(self) -> bool:
-        """True when some active request can still be live AFTER the
-        in-flight chunk lands (its budget and row cap — both known
-        host-side — survive another ``chunk`` tokens). When nobody can,
-        the speculative next chunk would be all-frozen by construction:
+    def _roster_outlives_chunk(self, prev, joining) -> bool:
+        """True when some request can still be live AFTER the in-flight
+        chunk ``prev`` lands: one that joined the roster since (it is
+        not in that chunk at all), or one whose budget and row cap —
+        both known host-side — survive another ``chunk`` tokens. When
+        nobody can, the next chunk would be all-frozen by construction:
         skip it instead of burning a whole wasted dispatch per burst
         (short generations — budget <= chunk — would otherwise pay ~2x
         decode compute for zero tokens). EOS is the one early stop the
         host can't predict; an EOS-ended burst still wastes at most one
         trailing chunk."""
-        k = self.loop.chunk
-        return any(r.remaining() > k and r.length + k + 1 < self.max_len
-                   for r in self.scheduler.active)
-
-    def _dispatch_chunk(self, carry: Optional[Dict[str, Any]] = None):
-        """Enqueue one decode chunk (no host sync). ``carry`` pipelines
-        the previous chunk's device-carried state (tokens/lengths/
-        remaining/done stay on device; eos never changes for a fixed
-        roster); without it the inputs are rebuilt host-side from the
-        roster. Returns the in-flight record _retire_chunk consumes, or
-        None on a dispatch failure (roster failed)."""
         active = self.scheduler.active
-        with self._tick.phase("decode_dispatch", slots=len(active),
-                              carried=carry is not None):
+        if joining or prev is None:
+            return bool(joining or active)
+        k = self.loop.chunk
+        held = prev["held"]
+        return any(held.get(r.slot) is not r
+                   or (r.remaining() > k and r.length + k + 1 < self.max_len)
+                   for r in active)
+
+    def _dispatch_chunk(self, prev: Optional[Dict[str, Any]] = None,
+                        joining=()):
+        """Enqueue one decode chunk (no host sync). Without ``prev``
+        the inputs are the host's (``_roster_arrays``). With the record
+        of the chunk in flight they are MERGED on the device, slot by
+        slot, from what the host knows now:
+
+        - the request ``prev`` was dispatched with: what that chunk
+          carries (token, length, budget, ``done`` — a slot that
+          finished inside it stays frozen);
+        - a request activated since: the host's values;
+        - a ``joining`` prefill job: the host's, but the token, which
+          the prefill's own output provides, and ``done`` by the scan's
+          rules on it;
+        - nobody (freed, failed, parked): ``done`` and the parked row.
+
+        Returns the in-flight record _retire_chunk consumes, or None on
+        a dispatch failure (roster and joiners failed)."""
+        active = self.scheduler.active
+        roster = active + [j.adm.request for j in joining]
+        carried = prev is not None
+        with self._tick.phase("decode_dispatch", slots=len(roster),
+                              carried=carried):
             t0 = self._tick.now
-            if carry is not None:
-                tok_d, len_d, rem_d, eos_d, done_d = carry["carry"]
-            else:
-                tokens, lengths, remaining, eos_ids, done = \
-                    self._roster_arrays(active)
-                tok_d, len_d, rem_d, eos_d, done_d = (
-                    self._put(tokens), self._put(lengths),
-                    self._put(remaining), self._put(eos_ids),
-                    self._put(done))
+            held = prev["held"] if carried else {}
+            kept = [r.slot for r in active if held.get(r.slot) is r]
+            fresh = [r for r in active if held.get(r.slot) is not r]
+            state = tuple(self._put(a)
+                          for a in self._roster_arrays(fresh, joining))
             try:
+                if carried:
+                    keep = np.zeros((self.max_batch,), bool)
+                    keep[kept] = True
+                    state = self.loop.roster_merge(
+                        self._put(keep), prev["carry"], state)
+                for job in joining:
+                    tok_d, done_d = self.loop.roster_join(
+                        *state, self._put(np.int32(job.adm.slot)),
+                        job.token)
+                    state = (tok_d, *state[1:4], done_d)
                 toks_d, n_valid_d, ntok_d, nlen_d, nrem_d, ndone_d, \
                     self.cache, *counters_d = self.loop.decode_chunk(
-                        self.params, self.cache, tok_d, len_d, rem_d,
-                        eos_d, done_d)
+                        self.params, self.cache, *state)
             except BaseException as e:  # noqa: BLE001 — fail all waiters
-                self._fail_roster(e)
+                self._fail_roster(e, joining)
                 return None
+        self.metrics.record_dispatch(carried)
         # A family's counters ride the chunk's one fetch.
         return {"outs": (toks_d, n_valid_d, *counters_d),
-                "carry": (ntok_d, nlen_d, nrem_d, eos_d, ndone_d),
-                "roster": self._roster_key(),
-                # Strong refs pin the roster's request objects while
-                # this record lives: the key above compares id()s, and
-                # a finished request's id could otherwise be recycled
-                # for a newly admitted one in the same slot — a false
-                # "unchanged roster" that would pipeline the new
-                # request against a carry that has its slot frozen.
-                "reqs": list(active),
-                # Device utilization denominator: every slot live at
-                # dispatch is scanned for the full chunk (static
-                # shapes) whether or not it freezes mid-chunk —
-                # delivered/live_steps < 1.0 shows the frozen-overshoot
-                # waste instead of the old always-1.0 readout.
-                "live_steps": len(active) * self.loop.chunk,
+                "carry": (ntok_d, nlen_d, nrem_d, state[3], ndone_d),
+                # Who the chunk was dispatched with, by slot. The next
+                # dispatch carries a slot only for the SAME request, and
+                # the retire delivers only to it; the strong refs keep a
+                # finished request's identity from being recycled for a
+                # newly admitted one in the same slot while this record
+                # lives.
+                "held": {r.slot: r for r in roster},
                 "t0": t0}
 
     def _retire_chunk(self, rec: Dict[str, Any]) -> bool:
         """The tick's ONE host fetch: land the chunk's tokens, deliver
-        to whoever is still active (a slot whose request finished —
-        or was recycled — since dispatch reports n_valid 0: the device
-        carried its done mask), retire finishes. False on device
+        to whoever it was dispatched with and still holds the slot (a
+        request that finished since reports n_valid 0 — the device
+        carried its done mask; one that failed or was parked since has
+        let go of its slot), retire finishes. False on device
         failure."""
         try:
             with self._tick.phase("decode_fetch",
-                                  slots=len(rec["reqs"])) as attrs:
+                                  slots=len(rec["held"])) as attrs:
                 # device_get returns host ndarrays: [B, K] ids + [B] valid.
                 chunk_ids, n_valid, *counters = self._fetch(rec["outs"])
                 attrs["bytes"] = chunk_ids.nbytes + n_valid.nbytes
@@ -1536,30 +1639,39 @@ class InferenceEngine:
         # previous retire ended just before this record's dispatch).
         elapsed = now - max(rec["t0"], self._last_retire_t)
         self._last_retire_t = now
-        active = self.scheduler.active
+        holders = [(slot, req) for slot, req in rec["held"].items()
+                   if req.slot == slot]
         delivered = 0
-        n_act = len(active)
         self.metrics.record_model_counters(counters)
         touched = self._span_attrs(counters)
-        with self._tick.phase("decode_deliver", slots=n_act) as attrs:
-            for req in list(active):
-                n = int(n_valid[req.slot])
+        with self._tick.phase("decode_deliver",
+                              slots=len(holders)) as attrs:
+            for slot, req in holders:
+                n = int(n_valid[slot])
                 delivered += n
                 if req.trace_ctx is not None and n:
                     self._span("engine.decode_chunk", rec["t0"], now, req,
-                               {"tokens": n, "slot": req.slot, **touched})
+                               {"tokens": n, "slot": slot, **touched})
                 for j in range(n):
-                    tok = int(chunk_ids[req.slot, j])
+                    tok = int(chunk_ids[slot, j])
                     req.length += 1
-                    self.kv.grow(req.slot)  # block-granular occupancy
+                    self.kv.grow(slot)  # block-granular occupancy
                     req.generated.append(tok)
                     if req.stream_queue is not None:
                         req.stream_queue.put(("token", tok))
                     if self._maybe_finish(req, tok):
                         break  # device froze the slot here; rest repeat
             attrs["tokens"] = delivered
-            self.metrics.record_chunk(delivered, rec["live_steps"], elapsed)
-            _flight.record("engine_tick", tok=delivered, act=n_act)
+            # Device utilization denominator: a slot that was live at
+            # the chunk's first step is scanned for the full chunk
+            # (static shapes) whether or not it freezes mid-chunk —
+            # delivered/live_steps < 1.0 shows the frozen-overshoot
+            # waste. Counted from what the device reports, not from the
+            # roster at dispatch: a slot carried into the chunk already
+            # frozen was never live in it.
+            live_steps = self.loop.chunk * int(np.count_nonzero(n_valid))
+            self.metrics.record_chunk(delivered, live_steps, elapsed)
+            _flight.record("engine_tick", tok=delivered, act=len(holders))
         return True
 
     # -------------------------------------------------------- speculation
@@ -1729,11 +1841,11 @@ class InferenceEngine:
                 if self.role == "decode":
                     with tick.phase("install"):
                         self._install_tick()
-                self._prefill_tick()
+                landing = self._prefill_tick()
             self.metrics.record_depths(self.scheduler.queue_depth(),
                                        len(self.scheduler.active),
                                        self.kv.hit_rate())
-            if not self.scheduler.active:
+            if not self.scheduler.active and not landing:
                 if self._prefilling or self._install_waiting:
                     continue  # keep chunked prefills / installs advancing
                 # A burst just drained: the multi-step trailing chunk
@@ -1755,4 +1867,4 @@ class InferenceEngine:
                     pass
                 continue
             with jax_debug.tick_guard():
-                self._decode_tick()
+                self._decode_tick(landing)

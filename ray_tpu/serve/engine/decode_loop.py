@@ -123,6 +123,10 @@ class DecodeLoop:
             self.decode_chunk, "decode_loop.decode_chunk", budget=1)
         self.decode_step = jax_debug.wrap_jit(
             self.decode_step, "decode_loop.decode_step", budget=1)
+        self.roster_merge = jax_debug.wrap_jit(
+            self.roster_merge, "decode_loop.roster_merge", budget=1)
+        self.roster_join = jax_debug.wrap_jit(
+            self.roster_join, "decode_loop.roster_join", budget=1)
         if self.spec_window > 1:
             self.verify_chunk = jax_debug.wrap_jit(
                 self.verify_chunk, "decode_loop.verify_chunk", budget=1)
@@ -139,8 +143,8 @@ class DecodeLoop:
 
         out = {}
         for name in ("prefill", "prefill_inplace", "decode_chunk",
-                     "decode_step", "verify_chunk", "export_page",
-                     "install_page"):
+                     "decode_step", "roster_merge", "roster_join",
+                     "verify_chunk", "export_page", "install_page"):
             fn = getattr(self, name, None)
             if isinstance(fn, JitWitness):
                 out[name] = fn.program_count
@@ -289,6 +293,38 @@ class DecodeLoop:
             lambda params, cache, tokens, lengths, live=None:
             model.decode_step_with_cache(params, tokens, cache, lengths,
                                          cfg, live))
+
+        def roster_merge(keep, carried, fresh):
+            """A chunk's five inputs (tokens, lengths, remaining,
+            eos_ids, done) ACROSS a roster change: a slot of ``keep``
+            [B] takes what the chunk in flight carries for it (its
+            request is the one that chunk was dispatched with, so the
+            device already holds its state, a freeze inside that chunk
+            included); every other slot takes what the host wrote
+            (``fresh``: a request activated since, or the parked row
+            and ``done`` of a slot nobody holds). The engine never
+            needs the chunk in flight on the host to dispatch the
+            next."""
+            return tuple(
+                jnp.where(keep.reshape(keep.shape + (1,) * (c.ndim - 1)),
+                          c, f) for c, f in zip(carried, fresh))
+
+        def roster_join(tokens, lengths, remaining, eos_ids, done, slot,
+                        token):
+            """A request joins the roster with a first token the host
+            has not seen: ``token`` int32 [1], as the tick's prefill
+            hands it back, goes into ``slot``'s row, and the slot is
+            done from the start under the scan's own termination rules
+            (the host wrote its length, budget AFTER this token, and
+            EOS id)."""
+            tok = token[0]
+            fin = ((tok == eos_ids[slot]) | (remaining[slot] <= 0)
+                   | (lengths[slot] + 1 >= max_len))
+            return (tokens.at[slot, 0].set(tok), done.at[slot].set(fin))
+
+        # Two programs of a few [B] selects; no cache, nothing donated.
+        self.roster_merge = jax.jit(roster_merge)
+        self.roster_join = jax.jit(roster_join)
 
     def _build_verify(self) -> None:
         import jax

@@ -107,6 +107,11 @@ class EngineMetrics:
         self.prefill_tokens = 0
         self.host_syncs = 0        # decode-loop device fetches
         self.decode_steps = 0      # live slot-steps advanced on device
+        # Plain decode chunks dispatched, and how many of them went out
+        # AHEAD of the previous chunk's fetch (the pipelined schedule).
+        # One writer, the engine thread; no lock (like ``tick_s``).
+        self.chunks_dispatched = 0
+        self.chunks_carried = 0
         self.spec_drafted = 0      # draft tokens proposed
         self.spec_accepted = 0     # draft tokens verified + accepted
         self.spec_chunks = 0       # chunks through the verify program
@@ -195,6 +200,13 @@ class EngineMetrics:
             TOKENS_TOTAL.inc(tokens, labels=self._labels)
             TPOT_SECONDS.observe(elapsed_s / tokens, labels=self._labels)
 
+    def record_dispatch(self, carried: bool) -> None:
+        """One plain decode chunk enqueued; ``carried``: while the
+        previous one was still unfetched, its inputs merged on the
+        device from that chunk's carry."""
+        self.chunks_dispatched += 1
+        self.chunks_carried += carried
+
     def record_model_counters(self, counters) -> None:
         """What a prefill or a chunk's steps counted on the device: a
         list holding one dict of named scalars, or nothing (a family
@@ -253,6 +265,8 @@ class EngineMetrics:
                 "prefill_tokens": self.prefill_tokens,
                 "decode_host_syncs": self.host_syncs,
                 "decode_steps": self.decode_steps,
+                "decode_chunks_dispatched": self.chunks_dispatched,
+                "decode_chunks_carried": self.chunks_carried,
                 # decode tokens delivered per device token-position
                 # scanned (first tokens come from prefill, so they're
                 # excluded): < 1.0 when slots freeze mid-chunk or

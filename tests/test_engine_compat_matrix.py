@@ -225,6 +225,241 @@ def test_multi_step_roster_churn_under_concurrency(tiny_model):
     assert want[True] == want[False]
 
 
+# ---------------------------- the pipelined schedule across a changed roster
+
+def _tiny_llama_128():
+    from ray_tpu.models import llama
+
+    return llama.tiny_config(max_seq_len=128)
+
+
+def _family_cfg(family):
+    import dataclasses
+
+    if family == "llama":
+        return _tiny_llama_128()
+    return dataclasses.replace(FAMILIES[family](), max_seq_len=128)
+
+
+class _Churn:
+    """One engine under a roster that changes all the time, every
+    schedule decision made on the engine thread so that two runs see
+    the same arrivals: requests are put in order while the first
+    admission waits behind a gate, and a second wave is put from inside
+    the third decode fetch. ``log`` holds ``d`` per decode-chunk
+    dispatch and ``f`` per decode fetch; ``done_in`` the ``done`` mask
+    each dispatch was handed."""
+
+    def __init__(self, cfg, params=None, *, multi_step, max_batch=2,
+                 second_wave=(), **kw):
+        from ray_tpu.serve.engine.core import InferenceEngine
+
+        kw.setdefault("prefill_chunk", 8)
+        self.eng = eng = InferenceEngine(
+            cfg, params, max_batch=max_batch, max_len=128,
+            prompt_buckets=[8, 16], decode_chunk=4, prefix_block=8,
+            multi_step=multi_step, kv_fleet_min_prefix_blocks=-1, **kw)
+        self.log, self.done_in, self.reqs = [], [], []
+        self.gate = threading.Event()
+        self.on_fetch = {3: lambda: self.put(second_wave)}
+        fetches = [0]
+        inner_dispatch, inner_fetch, admit = (
+            eng.loop.decode_chunk, eng._fetch, eng._admit)
+
+        def dispatch(params, cache, tokens, lengths, remaining, eos, done):
+            self.log.append("d")
+            self.done_in.append([bool(x) for x in jax.device_get(done)])
+            return inner_dispatch(params, cache, tokens, lengths,
+                                  remaining, eos, done)
+
+        def fetch(tree, tag="decode"):
+            if tag == "decode":
+                self.log.append("f")
+                fetches[0] += 1
+                self.on_fetch.pop(fetches[0], lambda: None)()
+            return inner_fetch(tree, tag)
+
+        def gated_admit():
+            self.gate.wait(60)
+            admit()
+
+        eng.loop.decode_chunk, eng._fetch, eng._admit = (
+            dispatch, fetch, gated_admit)
+
+    def put(self, requests):
+        for prompt, n, *rest in requests:
+            req = self.eng._make_request(prompt, n, *(rest or [None]))
+            self.reqs.append(req)
+            self.eng._queue.put(req)
+
+    def run(self, requests):
+        try:
+            self.put(requests)
+            self.gate.set()
+            outs = [r.future.result(timeout=300)["token_ids"]
+                    for r in list(self.reqs)]
+            # The second wave was put while the first ran.
+            outs += [r.future.result(timeout=300)["token_ids"]
+                     for r in self.reqs[len(outs):]]
+            return outs, self.eng.stats()
+        finally:
+            self.eng.close()
+
+
+# The anchor outlives everyone, so some request can always outlive the
+# chunk in flight and both schedules run the same number of chunks;
+# the others turn their slots over beside it, two of them on one prompt
+# (prefix reuse where the family and the slot allow it) that prefills
+# in three chunks.
+ANCHOR = ([1, 2, 3], 120)
+LONG = list(range(2, 22))
+FIRST_WAVE = [ANCHOR, ([4] * 3, 5), ([5] * 3, 13), ([6] * 3, 7), (LONG, 9)]
+SECOND_WAVE = [(LONG, 11), ([7] * 3, 6), ([8] * 5, 2)]
+CHURN_CASES = [("llama", 2), ("llama", 3), ("llama", 4),
+               ("olmo_hybrid", 2), ("minicpm_sala", 3)]
+
+
+@pytest.mark.parametrize("family,max_batch", CHURN_CASES)
+def test_pipelined_dispatch_leads_every_fetch_under_churn(family,
+                                                          max_batch):
+    """Admissions and finishes change the roster on nearly every tick;
+    chunk N+1 is still dispatched before chunk N is fetched on EVERY
+    tick that has a chunk in flight, the tokens are the serial
+    schedule's, and no sync is added. A family with slot state gives
+    the same tokens too: a slot frozen in the carried mask is not
+    stepped."""
+    cfg = _family_cfg(family)
+    params = cfg.model.init_params(cfg, jax.random.PRNGKey(7))
+    runs = {}
+    for ms in (False, True):
+        churn = _Churn(cfg, params, multi_step=ms, max_batch=max_batch,
+                       second_wave=SECOND_WAVE)
+        runs[ms] = (*churn.run(FIRST_WAVE), churn.log)
+    (want, serial, serial_log), (got, piped, log) = runs[False], runs[True]
+    assert [len(o) for o in got] == [n for _, n in FIRST_WAVE + SECOND_WAVE]
+    assert got == want
+    n = piped["decode_chunks_dispatched"]
+    assert serial_log == ["d", "f"] * serial["decode_chunks_dispatched"]
+    assert log == ["d"] + ["d", "f"] * (n - 1) + ["f"]
+    assert piped["decode_host_syncs"] <= serial["decode_host_syncs"]
+    assert serial["decode_chunks_carried"] == 0
+    assert piped["decode_chunks_carried"] == n - 1 > 0.9 * n
+    assert piped["requests"] == serial["requests"] == 8
+    # The roster did change: a request ended or joined at nearly every
+    # chunk boundary of the churn, far more often than it stood still.
+    assert piped["prefix_tokens_reused"] == serial["prefix_tokens_reused"]
+    if (family, max_batch) == ("llama", 2):
+        # One slot beside the anchor's: the repeat follows its twin.
+        assert piped["prefix_tokens_reused"] == 16
+
+
+def _first_token(cfg, params, prompt):
+    from ray_tpu.serve.engine.core import InferenceEngine
+
+    eng = InferenceEngine(cfg, params, max_batch=1, max_len=128,
+                          prompt_buckets=[8, 16],
+                          kv_fleet_min_prefix_blocks=-1)
+    try:
+        return eng.generate(prompt, max_new_tokens=1)["token_ids"][0]
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("case", ["first_token_is_eos", "budget_of_one"])
+def test_a_request_that_ends_at_its_first_token_joins_frozen(case):
+    """Its first token is on the DEVICE when the chunk it joins is
+    dispatched: the device applies the finish rules to it, so the slot
+    is done in that chunk's input, emits nothing, and the request ends
+    with its one token while the neighbour decodes on."""
+    cfg = _tiny_llama_128()
+    params = cfg.model.init_params(cfg, jax.random.PRNGKey(7))
+    prompt = [9, 8, 7, 6]
+    first = _first_token(cfg, params, prompt)
+    short = ((prompt, 12, first) if case == "first_token_is_eos"
+             else (prompt, 1))
+    runs = {}
+    for ms in (False, True):
+        churn = _Churn(cfg, params, multi_step=ms, max_batch=2)
+        runs[ms] = (*churn.run([([1, 2, 3], 30), short, ([5] * 3, 6)]),
+                    churn.done_in)
+    (want, _, _), (got, stats, done_in) = runs[False], runs[True]
+    assert got == want and got[1] == [first]
+    # Slot 1 joined the first chunk done, with slot 0 live beside it
+    # (the host had seen neither token), and was handed to the third
+    # request only after that.
+    assert done_in[0] == [False, True]
+    assert len(got[2]) == 6
+    assert stats["decode_chunks_carried"] >= stats[
+        "decode_chunks_dispatched"] - 1
+
+
+def test_a_preempted_slot_is_not_carried_into_the_next_chunk():
+    """A request parked while a chunk is in flight: the chunk is landed
+    first (its tokens are the victim's), the slot goes to the preemptor
+    with the HOST's values, never the victim's carry, and both end with
+    the tokens an undisturbed engine gives them."""
+    from ray_tpu.serve.engine.core import InferenceEngine
+
+    cfg = _tiny_llama_128()
+    params = cfg.model.init_params(cfg, jax.random.PRNGKey(7))
+    low, high = ([3, 1, 4, 1, 5], 40), ([2, 7, 1, 8], 12)
+    alone = InferenceEngine(cfg, params, max_batch=1, max_len=128,
+                            prompt_buckets=[8, 16], decode_chunk=4,
+                            kv_fleet_min_prefix_blocks=-1)
+    try:
+        want = [alone.generate(*r)["token_ids"] for r in (low, high)]
+    finally:
+        alone.close()
+    eng = InferenceEngine(cfg, params, max_batch=1, max_len=128,
+                          prompt_buckets=[8, 16], decode_chunk=4,
+                          kv_fleet_min_prefix_blocks=-1)
+    try:
+        stream = eng.generate_stream(*low, priority=0)
+        got_low = [next(stream) for _ in range(6)]   # chunks in flight
+        got_high = eng.generate(*high, priority=5)["token_ids"]
+        got_low += list(stream)
+        stats = eng.stats()
+    finally:
+        eng.close()
+    assert stats["preempts"] == 1 and stats["resumes"] == 1
+    assert [got_low, got_high] == want
+
+
+def test_live_steps_are_counted_from_what_the_device_reports():
+    """`decode_steps` counts, at the retire, chunk x (slots the chunk
+    found live): on the serial schedule that is the roster at dispatch
+    x chunk, which is what was counted before; on the pipelined one a
+    slot carried in already frozen no longer counts."""
+    cfg = _tiny_llama_128()
+    params = cfg.model.init_params(cfg, jax.random.PRNGKey(7))
+    seen = {}
+    for ms in (False, True):
+        churn = _Churn(cfg, params, multi_step=ms, max_batch=3,
+                       second_wave=SECOND_WAVE)
+        at_retire = []
+        record = churn.eng.metrics.record_chunk
+
+        def spy(tokens, live_steps, elapsed, _r=record, _o=at_retire):
+            _o.append(live_steps)
+            return _r(tokens, live_steps, elapsed)
+
+        churn.eng.metrics.record_chunk = spy
+        _, stats = churn.run(FIRST_WAVE)
+        at_dispatch = [4 * d.count(False) for d in churn.done_in]
+        seen[ms] = (at_dispatch, at_retire, stats)
+    at_dispatch, at_retire, stats = seen[False]
+    assert at_retire == at_dispatch and sum(at_retire) == stats[
+        "decode_steps"]
+    # Pipelined: the mask handed to the device says who is live, and
+    # the retire counts exactly those (the host's roster at dispatch
+    # would have counted slots the device had already frozen).
+    at_dispatch, at_retire, stats = seen[True]
+    assert at_retire == at_dispatch and sum(at_retire) == stats[
+        "decode_steps"]
+    assert stats["tokens_generated"] == seen[False][2]["tokens_generated"]
+    assert 0.0 < stats["decode_utilization"] <= 1.0
+
+
 # ----------------------------------------------------- chunked prefill
 
 

@@ -209,6 +209,92 @@ def test_tracing_off_ticks_are_span_free_and_sync_budget_unchanged(engine):
     assert engine.stats()["decode_host_syncs"] == 3
 
 
+# ------------------------------- the pipelined schedule under the same clock
+
+def _stream_pair(eng, warm: int = 5):
+    """Two streams decoding side by side, `warm` tokens in: the tick
+    has a chunk in flight and dispatches the next ahead of its fetch."""
+    streams = [eng.generate_stream([3 + i, 1, 4, 1, 5], max_new_tokens=50)
+               for i in range(2)]
+    for stream in streams:
+        for _ in range(warm):
+            assert isinstance(next(stream), int)
+    return streams
+
+
+def test_phases_account_for_the_loop_with_the_deliveries_under_a_chunk(
+        engine):
+    """First tokens are fetched AFTER the chunk they join is dispatched
+    and a chunk is delivered after the next is: every second is still
+    in exactly one phase, and a changed roster is still carried."""
+    before = engine.stats()
+    streams = _stream_pair(engine)
+    engine.generate([9, 9, 9], max_new_tokens=1)    # waits for a slot
+    for stream in streams:
+        assert len(list(stream)) == 45
+    time.sleep(0.15)
+    s = engine.stats()
+    phases = sum(s[k] - before[k] for k in PHASE_KEYS)
+    assert phases == pytest.approx(s["tick_loop_s"] - before["tick_loop_s"],
+                                   rel=0.10)
+    assert s["decode_chunks_carried"] >= s["decode_chunks_dispatched"] - 2
+    # One prefill sync an admission, one sync a fetched chunk.
+    assert s["requests"] == 3
+    assert s["decode_host_syncs"] <= s["decode_chunks_dispatched"]
+
+
+@pytest.mark.parametrize("fault", ["fetch_of_the_chunk_in_flight",
+                                   "early_dispatch_after_donation"])
+def test_a_device_failure_between_dispatch_and_retire_fails_the_roster_once(
+        engine, fault):
+    """Chunk N+1 is dispatched before chunk N is retired. Whichever of
+    the two the device fails in, the roster is failed ONCE, each request
+    hears of it once, the cache is rebuilt only if it went, and the
+    engine serves the next request as a fresh one would."""
+    want = engine.generate([2, 7, 1, 8], max_new_tokens=7)["token_ids"]
+    streams = _stream_pair(engine)
+    failed = []
+    fail_roster = engine._fail_roster
+
+    def spy(e, *rest):
+        failed.append(e)
+        return fail_roster(e, *rest)
+
+    engine._fail_roster = spy
+    if fault == "fetch_of_the_chunk_in_flight":
+        inner = engine._fetch
+
+        def fetch(tree, tag="decode"):
+            if tag == "decode" and not failed:
+                # The next chunk is out already: that is the schedule.
+                assert engine._inflight is not None
+                raise RuntimeError("injected device failure")
+            return inner(tree, tag)
+
+        engine._fetch = fetch
+    else:
+        inner = engine.loop.decode_chunk
+
+        def dispatch(params, cache, *args):
+            if not failed:
+                inner(params, cache, *args)      # takes the cache with it
+                raise RuntimeError("injected device failure")
+            return inner(params, cache, *args)
+
+        engine.loop.decode_chunk = dispatch
+    for stream in streams:
+        with pytest.raises(RuntimeError, match="injected"):
+            list(stream)
+    assert len(failed) == 1
+    stats = engine.stats()
+    assert stats["active"] == 0 and stats["free_slots"] == 2
+    assert stats["cache_rebuilds"] == (
+        1 if fault == "early_dispatch_after_donation" else 0)
+    assert engine.generate([2, 7, 1, 8], max_new_tokens=7)["token_ids"] \
+        == want
+    assert engine.stats()["cache_rebuilds"] == stats["cache_rebuilds"]
+
+
 # ------------------------------------------------------ the clock alone
 
 class _Annotation:

@@ -245,6 +245,39 @@ def test_transfer_guard_clean_engine_tick(debug_jax, monkeypatch):
         eng.close()
 
 
+def test_transfer_guard_clean_pipelined_tick_across_roster_changes(
+        debug_jax, monkeypatch):
+    """The drafter-free engine merges the next chunk's inputs ON THE
+    DEVICE (the carry of the chunk in flight, the host's arrays through
+    `_put`, a joining request's first token from its prefill's output):
+    no implicit transfer under the guard, one compiled program each for
+    the merge and the join whatever the roster does, and the sync
+    budget of the serial schedule — one per fetched chunk, one per
+    admission."""
+    import concurrent.futures as cf
+
+    monkeypatch.setenv("RTPU_DEBUG_JAX_TRANSFER_GUARD", "disallow")
+    eng = _engine(max_batch=2)
+    try:
+        with cf.ThreadPoolExecutor(6) as pool:
+            futs = [pool.submit(eng.generate, [i + 1] * (3 + 4 * i), n)
+                    for i, n in enumerate((22, 5, 9, 13, 7, 1))]
+            assert [f.result(timeout=300)["num_generated"]
+                    for f in futs] == [22, 5, 9, 13, 7, 1]
+        programs = eng.loop.program_counts()
+        assert programs["decode_chunk"] == 1
+        assert programs["roster_merge"] == 1
+        assert programs["roster_join"] == 1
+        assert jax_debug.over_budget_reports() == []
+        stats = eng.stats()
+        assert stats["decode_chunks_carried"] > 0
+        syncs = jax_debug.host_sync_counts()
+        assert syncs.get("engine.decode", 0) == stats["decode_host_syncs"]
+        assert syncs.get("engine.prefill", 0) == stats["requests"] == 6
+    finally:
+        eng.close()
+
+
 def test_flag_off_engine_is_unwrapped(monkeypatch):
     monkeypatch.delenv("RTPU_DEBUG_JAX", raising=False)
     eng = _engine()
