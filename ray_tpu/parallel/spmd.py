@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_tpu.devtools import jax_debug
 from ray_tpu.models import llama
 from ray_tpu.parallel.mesh import logical_spec, param_shardings
+from ray_tpu.util import compile_cache
 
 
 class TrainState(NamedTuple):
@@ -58,12 +59,14 @@ def sharded_init(cfg: llama.LlamaConfig, mesh: Mesh, key: jax.Array,
                  tx: optax.GradientTransformation) -> TrainState:
     """Initialize params directly INTO their shards (no host-side full copy —
     required for models larger than one host's HBM)."""
-    shardings = param_shardings(mesh, llama.param_logical_axes(cfg))
-    p_init = _with_mesh_context(mesh, jax.jit(
-        functools.partial(llama.init_params, cfg), out_shardings=shardings))
-    params = p_init(key)
-    return TrainState(jnp.zeros((), jnp.int32), params,
-                      init_opt_state(tx, params, mesh, shardings))
+    with compile_cache.phase("spmd.sharded_init"):
+        shardings = param_shardings(mesh, llama.param_logical_axes(cfg))
+        p_init = _with_mesh_context(mesh, jax.jit(
+            functools.partial(llama.init_params, cfg),
+            out_shardings=shardings))
+        params = p_init(key)
+        return TrainState(jnp.zeros((), jnp.int32), params,
+                          init_opt_state(tx, params, mesh, shardings))
 
 
 def init_opt_state(tx: optax.GradientTransformation, params: Any,
@@ -101,17 +104,19 @@ def make_train_step(
     # python scalar in the state) is the most expensive silent bug a
     # training loop can have. The RTPU_DEBUG_JAX witness reports it;
     # off, wrap_jit returns the jitted step untouched.
-    return _with_mesh_context(mesh, jax_debug.wrap_jit(
-        jax.jit(step_fn, donate_argnums=(0,)), "spmd.train_step",
-        budget=1))
+    with compile_cache.phase("spmd.make_train_step"):
+        return _with_mesh_context(mesh, jax_debug.wrap_jit(
+            jax.jit(step_fn, donate_argnums=(0,)), "spmd.train_step",
+            budget=1))
 
 
 def make_eval_step(cfg: llama.LlamaConfig, mesh: Mesh):
     def eval_fn(params, tokens):
         loss, metrics = llama.loss_fn(params, tokens, cfg, mesh=mesh)
         return metrics
-    return _with_mesh_context(mesh, jax_debug.wrap_jit(
-        jax.jit(eval_fn), "spmd.eval_step", budget=1))
+    with compile_cache.phase("spmd.make_eval_step"):
+        return _with_mesh_context(mesh, jax_debug.wrap_jit(
+            jax.jit(eval_fn), "spmd.eval_step", budget=1))
 
 
 def sharding_summary(params: Any, logical_tree: Any) -> Dict[str, str]:

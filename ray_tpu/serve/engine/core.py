@@ -19,6 +19,7 @@ class.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import queue
 import threading
@@ -37,6 +38,7 @@ from ray_tpu.serve.engine.kv_manager import KVCacheManager, chain_hashes
 from ray_tpu.serve.engine.metrics import EngineMetrics, TickClock
 from ray_tpu.serve.engine.scheduler import (EngineRequest, Scheduler,
                                             bucket_for)
+from ray_tpu.util import compile_cache as _compile_cache
 from ray_tpu.util import flight_recorder as _flight
 from ray_tpu.util import tracing as _tracing
 
@@ -60,6 +62,20 @@ class _PrefillJob:
         self.t0 = 0.0
         self.counters: list = []
         self.token = None
+
+
+def _timed_init(init):
+    """The constructor under the compile account's ``engine.init``
+    phase (`util/compile_cache.py`): its wall seconds less the compiles
+    booked inside it, kept for ``stats()["engine_init_s"]``."""
+
+    @functools.wraps(init)
+    def wrapped(self, *args, **kwargs):
+        with _compile_cache.phase("engine.init") as whole:
+            init(self, *args, **kwargs)
+        self._init_s = whole.own_s
+
+    return wrapped
 
 
 class InferenceEngine:
@@ -137,6 +153,7 @@ class InferenceEngine:
     granularity) and the cache allocation is padded to a page multiple.
     """
 
+    @_timed_init
     def __init__(self, cfg=None, params=None, *, max_batch: int = 4,
                  max_len: int = 512,
                  prompt_buckets: Optional[List[int]] = None,
@@ -197,20 +214,22 @@ class InferenceEngine:
         # or the first decode tick dies on the kernel's page-multiple
         # check.
         self.paged_decode = getattr(self.cfg, "paged_decode", False)
-        self.params = (params if params is not None
-                       else self.model.init_params(self.cfg,
-                                                   jax.random.PRNGKey(seed)))
         self.quantize = quantize
-        if quantize is not None:
-            # Weight-only int8 (models/quant.py): decode/verify stream
-            # half the weight bytes per step; every engine program
-            # (prefill, decode_chunk, verify_chunk) reads the same
-            # quantized pytree through forward_with_cache unchanged.
-            from ray_tpu.models.quant import (quantize_params,
-                                              quantized_weight_bytes)
+        with _compile_cache.phase("engine.weights"):
+            self.params = (params if params is not None
+                           else self.model.init_params(
+                               self.cfg, jax.random.PRNGKey(seed)))
+            if quantize is not None:
+                # Weight-only int8 (models/quant.py): decode/verify
+                # stream half the weight bytes per step; every engine
+                # program (prefill, decode_chunk, verify_chunk) reads
+                # the same quantized pytree through forward_with_cache
+                # unchanged.
+                from ray_tpu.models.quant import (quantize_params,
+                                                  quantized_weight_bytes)
 
-            self.params = quantize_params(self.params, dtype=quantize)
-            self._weight_bytes = quantized_weight_bytes(self.params)
+                self.params = quantize_params(self.params, dtype=quantize)
+                self._weight_bytes = quantized_weight_bytes(self.params)
         self.max_batch = max_batch
         self.max_len = min(max_len, self.cfg.max_seq_len)
         self.decode_chunk = max(1, int(decode_chunk))
@@ -228,14 +247,13 @@ class InferenceEngine:
         # the measured pull-vs-recompute crossover.
         self._fleet_min_blocks = gate
 
-        self.loop = DecodeLoop(self.cfg, max_len=self.max_len,
-                               chunk=self.decode_chunk,
-                               spec_window=self.spec_draft_len + 1,
-                               spec_chunk=spec_chunk,
-                               prefill_budget=len(self.buckets),
-                               kv_page=(prefix_block
-                                        if (role != "colocated" or fleet_on)
-                                        else 0))
+        with _compile_cache.phase("engine.decode_loop"):
+            self.loop = DecodeLoop(
+                self.cfg, max_len=self.max_len, chunk=self.decode_chunk,
+                spec_window=self.spec_draft_len + 1, spec_chunk=spec_chunk,
+                prefill_budget=len(self.buckets),
+                kv_page=(prefix_block
+                         if (role != "colocated" or fleet_on) else 0))
         # Verify windows span spec_draft_len+1 rows; the scratch strip
         # past max_len absorbs parked/overrun writes so they can never
         # clamp back onto resident rows (decode_loop docstring). Row
@@ -261,8 +279,9 @@ class InferenceEngine:
         # header), so ``self.cache`` is rebound at each dispatch and a
         # donated program that raises costs the buffer (_recover_cache).
         self._cache_rows = cache_rows
-        self.cache = self.model.init_kv_cache(self.cfg, max_batch,
-                                              cache_rows)
+        with _compile_cache.phase("engine.cache"):
+            self.cache = self.model.init_kv_cache(self.cfg, max_batch,
+                                                  cache_rows)
         # Of every layer together: what a token a slot holds costs in
         # rows, and (a family with SLOT_STATE_KEYS) what a slot holds
         # besides, whatever its length.
@@ -508,6 +527,19 @@ class InferenceEngine:
             out["compiled_programs"] = programs
         out.update(self.kv.stats())
         out.update(self.metrics.snapshot())
+        # Set-up (engine/README.md "Set-up and compilation"): this
+        # engine's constructor, and the PROCESS's compile account where
+        # an entry script turned it on.
+        out["engine_init_s"] = self._init_s
+        account = _compile_cache.account()
+        if account is not None:
+            t = account.totals()
+            out.update(compile_requests=t["requests"],
+                       compile_hits=t["hits"],
+                       compile_trace_s=t["trace_s"],
+                       compile_lower_s=t["lower_s"],
+                       compile_backend_s=t["compile_s"],
+                       compile_cache_load_s=t["cache_load_s"])
         if self._fleet is not None:
             with self._fleet_lock:
                 out.update(self._fleet_stats)
@@ -1123,6 +1155,7 @@ class InferenceEngine:
                 # Every chunk's counters ride the final chunk's fetch (a
                 # state family resets its slot in the FIRST chunk).
                 job.counters.extend(counters)
+                self.metrics.record_prefill_chunk(n)
                 # Per-chunk prefix commit: block occupancy and the
                 # slot's resident chain track the materialized prefix
                 # as chunks land, not the whole prompt up-front.

@@ -112,6 +112,10 @@ class EngineMetrics:
         # One writer, the engine thread; no lock (like ``tick_s``).
         self.chunks_dispatched = 0
         self.chunks_carried = 0
+        # Prefill programs dispatched and the REAL tokens they carried
+        # (``prefill_tokens`` counts a whole prompt, at its last chunk).
+        self.prefill_chunks_dispatched = 0
+        self.prefill_chunk_tokens = 0
         self.spec_drafted = 0      # draft tokens proposed
         self.spec_accepted = 0     # draft tokens verified + accepted
         self.spec_chunks = 0       # chunks through the verify program
@@ -207,6 +211,12 @@ class EngineMetrics:
         self.chunks_dispatched += 1
         self.chunks_carried += carried
 
+    def record_prefill_chunk(self, tokens: int) -> None:
+        """One prefill program enqueued, carrying ``tokens`` real
+        prompt tokens (bucket padding left out)."""
+        self.prefill_chunks_dispatched += 1
+        self.prefill_chunk_tokens += tokens
+
     def record_model_counters(self, counters) -> None:
         """What a prefill or a chunk's steps counted on the device: a
         list holding one dict of named scalars, or nothing (a family
@@ -267,6 +277,8 @@ class EngineMetrics:
                 "decode_steps": self.decode_steps,
                 "decode_chunks_dispatched": self.chunks_dispatched,
                 "decode_chunks_carried": self.chunks_carried,
+                "prefill_chunks_dispatched": self.prefill_chunks_dispatched,
+                "prefill_chunk_tokens": self.prefill_chunk_tokens,
                 # decode tokens delivered per device token-position
                 # scanned (first tokens come from prefill, so they're
                 # excluded): < 1.0 when slots freeze mid-chunk or

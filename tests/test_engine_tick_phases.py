@@ -128,6 +128,42 @@ def test_prefill_fetch_bytes_is_a_token_an_admission(prefill_chunk):
         eng.close()
 
 
+def test_prompt_tokens_are_counted_a_chunk_at_its_dispatch(sink):
+    """A three-chunk prompt raises ``prefill_chunk_tokens`` by each
+    chunk's REAL tokens where the chunk is dispatched (bucket padding
+    left out) and ``prefill_tokens`` once, by the whole prompt, when
+    the last chunk lands; the ``engine.tick.prefill_dispatch`` spans
+    carry the same as ``tokens``."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    eng = LLMEngine(prefill_chunk=4, **ENGINE_KW)
+    seen = []
+    record = eng.metrics.record_prefill_chunk
+
+    def recording(tokens):
+        record(tokens)
+        seen.append((tokens, eng.metrics.prefill_chunk_tokens,
+                     eng.metrics.prefill_chunks_dispatched,
+                     eng.metrics.prefill_tokens))
+
+    eng.metrics.record_prefill_chunk = recording
+    try:
+        before = eng.stats()
+        assert before["prefill_chunk_tokens"] == 0
+        assert before["prefill_chunks_dispatched"] == 0
+        # (the scheduler rounds a chunk of 4 up to the smallest bucket)
+        eng.generate(list(range(1, 20)), max_new_tokens=4)   # 8 + 8 + 3
+        after = eng.stats()
+    finally:
+        eng.close()
+    assert seen == [(8, 8, 1, 0), (8, 16, 2, 0), (3, 19, 3, 0)]
+    assert after["prefill_tokens"] == 19 == after["prefill_chunk_tokens"]
+    assert after["prefill_chunks_dispatched"] == 3
+    dispatched = [s["attrs"]["tokens"] for s in _collect(sink)
+                  if s["name"] == "engine.tick.prefill_dispatch"]
+    assert dispatched == [8, 8, 3]
+
+
 def test_first_deliver_moves_once_per_streamed_request(engine):
     engine.generate([1, 2, 3], max_new_tokens=4)       # not streamed
     assert engine.stats()["streams"] == 0
@@ -158,8 +194,10 @@ def test_traced_ticks_emit_only_phase_names_disjoint_under_one_root(
             "engine.tick.decode_dispatch", "engine.tick.decode_fetch",
             "engine.tick.decode_deliver", "engine.tick.idle"} <= {
                 s["name"] for s in ticks}
-    # Nothing else of the engine's: no request carried a trace context.
-    assert {s["name"] for s in spans} <= TICK_NAMES | {"serve.engine"}
+    # Nothing else of the engine's: no request carried a trace context
+    # (a process with a compile account adds ITS ``compile.<program>``).
+    assert {s["name"] for s in spans if not s["name"].startswith("compile.")
+            } <= TICK_NAMES | {"serve.engine"}
     roots = [s for s in spans if s["name"] == "serve.engine"]
     assert len(roots) == 1 and roots[0]["parent_id"] == ""
     assert roots[0]["attrs"]["engine"] == engine.metrics.name
