@@ -227,26 +227,38 @@ def phase_kernels(sz: Sizes, seed: int, rec: Recorder) -> None:
           rope_grads(rope_ref)(q, k, wq, wk), _TOL_ELEMENTWISE)
 
     # Flash path of full_causal_attention, forward and backward,
-    # against the portable online-softmax scan.
-    def attn_ref(q, k, v):
-        return ops.blockwise_attention(q, k, v, q_positions=pos,
-                                       kv_positions=pos,
-                                       block_k=min(512, s))
+    # against the portable online-softmax scan: at the model's geometry,
+    # and at the train cell's device shard (`smollm2.sft.fsdp2tp2`: 16
+    # sequences of 2,048, 16 heads of 64 with a KV head each), whose
+    # blocks `ops.attention.flash_block_sizes` chose from a sweep.
+    def attention_case(name, q, k, v):
+        s, hd = q.shape[1], q.shape[3]
+        pos = jnp.broadcast_to(jnp.arange(s), q.shape[:2])
 
-    _require(it or ops.use_fused_kernel(True, True, s, hd),
-             f"no flash kernel at seq {s}, head_dim {hd}")
-    wo = rnd(10, q.shape, jnp.float32)
+        def attn_ref(q, k, v):
+            return ops.blockwise_attention(q, k, v, q_positions=pos,
+                                           kv_positions=pos,
+                                           block_k=min(512, s))
 
-    def attn_grads(fn):
-        return jax.jit(jax.grad(
-            lambda q, k, v, wo: jnp.sum(fn(q, k, v) * wo),
-            argnums=(0, 1, 2)))
+        _require(it or ops.use_fused_kernel(True, True, s, hd),
+                 f"no flash kernel at seq {s}, head_dim {hd}")
+        wo = rnd(10, q.shape, jnp.float32)
 
-    check("flash_attention", jax.jit(ops.full_causal_attention)(q, k, v),
-          jax.jit(attn_ref)(q, k, v), _TOL_ATTENTION)
-    check("flash_attention_bwd",
-          attn_grads(ops.full_causal_attention)(q, k, v, wo),
-          attn_grads(attn_ref)(q, k, v, wo), _TOL_ATTENTION)
+        def attn_grads(fn):
+            return jax.jit(jax.grad(
+                lambda q, k, v, wo: jnp.sum(fn(q, k, v) * wo),
+                argnums=(0, 1, 2)))
+
+        check(name, jax.jit(ops.full_causal_attention)(q, k, v),
+              jax.jit(attn_ref)(q, k, v), _TOL_ATTENTION)
+        check(name + "_bwd",
+              attn_grads(ops.full_causal_attention)(q, k, v, wo),
+              attn_grads(attn_ref)(q, k, v, wo), _TOL_ATTENTION)
+
+    attention_case("flash_attention", q, k, v)
+    shard = (b, s, 4, hd) if it else (16, 2048, 16, 64)
+    attention_case("flash_attention_hd64_shard",
+                   *(rnd(20 + i, shard) for i in range(3)))
 
     # The two decode kernels on the engine-native [B, KH, S, D] cache.
     qd = rnd(11, (b, h, hd))

@@ -7,10 +7,12 @@ not the submodules. Dispatch conditions:
 =========================  ===============================  =========================================
 op                         TPU fast path                    dispatch condition
 =========================  ===============================  =========================================
-full_causal_attention      Pallas flash kernel (fwd+bwd),   ``use_fused_kernel``: standard arange
-                           shard_mapped over batch and      positions, seq >= 256 and % 128 == 0,
-                           heads when ``mesh`` has > 1      head_dim <= 128 or % 128 == 0; else
-                           device (XLA cannot partition     blockwise scan (seq >= 1024) / dense
+full_causal_attention      Pallas flash kernels (fwd, dkv,  ``use_fused_kernel``: standard arange
+                           dq: ``ops/flash_attention.py``,  positions, seq >= 256 and % 128 == 0,
+                           blocks from head size and seq),  head_dim <= 128 or % 128 == 0; else
+                           shard_mapped over batch and      blockwise scan (seq >= 1024) / dense
+                           heads when ``mesh`` has > 1
+                           device (XLA cannot partition
                            a Mosaic kernel)
 causal_attention           (portable dense reference)       always available; position-based masks
 blockwise_attention        (portable online-softmax scan)   seq a multiple of ``block_k``

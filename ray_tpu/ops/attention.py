@@ -1,10 +1,11 @@
 """Causal grouped-query attention: dispatcher + portable paths.
 
-`full_causal_attention` dispatches to the fused Pallas TPU flash kernel
-(jax.experimental.pallas.ops.tpu.flash_attention, with block sizes tuned
-for Llama shapes — see `use_fused_kernel`); the blockwise online-softmax
-scan below is the portable path (CPU tests, ragged shapes), and
-`ops/ring_attention.py` covers sequence parallelism over the ``sp`` axis.
+`full_causal_attention` dispatches to the Pallas TPU flash kernels of
+`ops/flash_attention.py` (forward, dkv, dq; blocks chosen from the head
+size and the sequence by `flash_block_sizes`, gate `use_fused_kernel`);
+the blockwise online-softmax scan below is the portable path (CPU
+tests, ragged shapes), and `ops/ring_attention.py` covers sequence
+parallelism over the ``sp`` axis.
 
 Shapes follow [batch, seq, heads, head_dim] throughout ("BSHD").
 """
@@ -153,21 +154,70 @@ def _default_positions(q_positions, kv_positions, b, sq, sk) -> bool:
 
 
 def use_fused_kernel(on_tpu: bool, standard: bool, sq: int, d: int) -> bool:
-    """The fused-flash dispatch gate, exposed for tests: the kernel accepts
-    any head_dim <= 128 (lane-padded internally) or an exact multiple of
-    128 — Llama-class head_dim=64/128 both qualify."""
+    """The fused-flash dispatch gate, exposed for tests: the kernels take
+    any head_dim <= 128 or an exact multiple of 128 — Llama-class
+    head_dim=64/128 both qualify. A head size under 128 is NOT padded in
+    HBM (the trace shows ``bf16[16,16,2048,64]`` operands): it half-fills
+    the 128-wide MXU in both products, which is why the kernels' compute
+    roofline share at head size 64 cannot pass about 50 %."""
     return (on_tpu and standard and sq >= 256 and sq % 128 == 0
             and (d <= 128 or d % 128 == 0))
 
 
+# (head size, sequence) whose blocks a sweep on the chip chose: PERF.md
+# section 6, PR 38 (TPU v5e, bare kernels, device time from a trace).
+SWEPT = frozenset((hd, sq) for hd in (64, 128, 256)
+                  for sq in (1024, 2048, 4096))
+
+
+def flash_block_sizes(hd: int, sq: int):
+    """The `BlockSizes` of the three flash kernels (forward, dkv, dq) for
+    one head size and sequence length.
+
+    Where the sweep measured: 512 x 512 tiles inside major blocks of the
+    whole sequence, to 2,048 rows. The kernels skip a masked TILE, so a
+    large major block costs no masked work and saves grid steps (K and V
+    of a head stay resident), and 512 is where a tile's fixed work (the
+    running max, sum and accumulator rescaled) stops mattering while the
+    diagonal still cuts little: at head size 64 and sequence 2,048 the
+    forward takes 2.40 ms a call with these blocks, 3.00 with 1,024 for
+    every field (12 of 16 tiles computed where the causal half needs 10)
+    and 4.24 with 256-wide tiles in a 1,024 major block; the library's
+    kernels, which skip by the major block alone, took 3.30 at 1,024 for
+    every field (their best) and 7.40 at 256, 128 being their default.
+    The same shape of blocks won at head sizes 128 and 256. A major
+    block is not longer than 2,048 rows: a kernel's code grows with the
+    tiles it unrolls, and 36 of them ran 3.8 times slower than 14.
+    Elsewhere: the largest of 1,024 / 512 / 256 / 128 that divides the
+    sequence for every field, which is what every shape had before the
+    sweep.
+    """
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+
+    one = next(c for c in (1024, 512, 256, 128) if sq % c == 0)
+    if (hd, sq) in SWEPT:
+        tile, major = 512, min(sq, 2048)
+        # The backward holds more of a major block in VMEM than the
+        # forward (q, o, do, a statistic, k, v, dk, dv), and twice the
+        # bytes a row at head size 256.
+        major_dq = major if hd <= 128 else 1024
+        # dkv unrolls rows x columns of tiles, and a sequence of more
+        # than one major block needs them a second time, unmasked.
+        major_dkv = major_dq if sq <= 2048 else 1024
+    else:
+        tile = major = major_dq = major_dkv = one
+    return BlockSizes(
+        block_q=tile, block_k_major=major, block_k=tile, block_b=1,
+        block_q_major_dkv=major_dkv, block_q_dkv=tile,
+        block_k_major_dkv=major_dkv, block_k_dkv=tile,
+        block_q_dq=tile, block_k_major_dq=major_dq, block_k_dq=tile)
+
+
 def _flash_attention(q, k, v, scale: float):
-    """The library Pallas flash kernel on [B,S,H|KH,D] operands (GQA
-    heads repeated here, so under `shard_map` only un-repeated KV
-    crosses the partition boundary)."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes,
-        flash_attention as _tpu_flash,
-    )
+    """The Pallas flash kernels on [B,S,H|KH,D] operands (GQA heads
+    repeated here, so under `shard_map` only un-repeated KV crosses the
+    partition boundary)."""
+    from ray_tpu.ops.flash_attention import flash_attention
 
     sq, h, kh = q.shape[1], q.shape[2], k.shape[2]
     if h != kh:
@@ -176,19 +226,8 @@ def _flash_attention(q, k, v, scale: float):
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
-    # The library defaults (block_k_major=128) leave the MXU idle between
-    # tiny grid steps — measured 4x slower than 1024-blocks at Llama
-    # shapes on v5e. Use the largest block <=1024 that divides seq.
-    blk = next(c for c in (1024, 512, 256, 128) if sq % c == 0)
-    bq = bk = min(blk, sq)
-    bs = BlockSizes(
-        block_q=bq, block_k_major=bk, block_k=bk, block_b=1,
-        block_q_major_dkv=bq, block_k_major_dkv=bk, block_k_dkv=bk,
-        block_q_dkv=bq, block_k_major_dq=bk, block_k_dq=bk,
-        block_q_dq=bq,
-    )
-    out = _tpu_flash(qt, kt, vt, causal=True, sm_scale=scale,
-                     block_sizes=bs)
+    out = flash_attention(qt, kt, vt, scale,
+                          flash_block_sizes(q.shape[3], sq))
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
 
 

@@ -163,20 +163,39 @@ def test_fused_qk_rope_compiles_forward_and_backward(chip, geom, shape):
         _compile(jax.grad(loss, argnums=(0, 1)), q, k, pos)) == 2
 
 
-@pytest.mark.parametrize("geom", GEOMETRY)
+# (batch, heads, kv heads, head size) at SEQ: the two Llama geometries
+# and a device's share of a layer of `smollm2.sft.fsdp2tp2` (32
+# sequences over dp x fsdp = 2, 32 heads of 64 over tp = 2).
+FLASH_SHAPES = {"1b": (BATCH, 32, 8, 64), "8b": (BATCH, 32, 8, 128),
+                "smollm2_shard": (16, 16, 16, 64)}
+# A row statistic broadcast along lanes in HBM on its way to a backward
+# kernel (the library's dq wrapper wrote `di` out `block_k_major` lanes
+# wide, f32[16,16,2048,1024]: 2.1 GB a layer; and `l`, `m`, `di` at 128).
+_STAT_BROADCAST = re.compile(r"f32\[\d+,\d+,2048,\d+\]\S* broadcast\(")
+
+
+@pytest.mark.parametrize("geom", FLASH_SHAPES)
 def test_flash_attention_compiles_forward_and_backward(chip, geom):
-    h, kh, hd, _, _ = GEOMETRY[geom]
-    q = _sds(chip, (BATCH, SEQ, h, hd))
-    kv = _sds(chip, (BATCH, SEQ, kh, hd))
-    assert _kernel_calls(_compile(ops.full_causal_attention, q, kv, kv)) == 1
+    b, h, kh, hd = FLASH_SHAPES[geom]
+    q = _sds(chip, (b, SEQ, h, hd))
+    kv = _sds(chip, (b, SEQ, kh, hd))
+    c = _compile(ops.full_causal_attention, q, kv, kv)
+    assert _kernel_calls(c) == 1 and "%flash_attention." in c.as_text()
 
     def loss(q, k, v):
         return jnp.sum(ops.full_causal_attention(q, k, v)
                        .astype(jnp.float32))
 
-    # Forward + the library's dq and dkv kernels.
-    assert _kernel_calls(
-        _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)) == 3
+    # Forward + the dkv and dq kernels (`ops/flash_attention.py`),
+    # under the names a device trace shows them by.
+    c = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    text = c.as_text()
+    assert _kernel_calls(c) == 3
+    for name in ("flash_mha_bwd_dkv", "flash_mha_bwd_dq"):
+        assert f"%{name}." in text, name
+    # The one statistic the backward reads is the forward kernel's own
+    # output, and `di` is made inside the kernels.
+    assert not _STAT_BROADCAST.findall(text)
 
 
 # ------------------------------------------------------ whole programs

@@ -131,17 +131,20 @@ def _jaxpr_text(mesh, fn, *args) -> str:
 
 
 @pytest.mark.parametrize("case", TP_RING_CASES)
-def test_tp_ring_engages_on_mesh_and_shapes_alone(case, monkeypatch):
+def test_tp_ring_engages_on_mesh_and_shapes_alone(case, monkeypatch,
+                                                  cpu_mesh8):
     """Where tp > 1, no cache and a sequence that divides, the block's
     four matmul groups move their own shards (`ppermute` in the jaxpr).
     Everywhere else the jaxpr is, letter for letter, the one traced with
     the ring switched off: what the serving cells compile does not move
     by hope. Engaged or not, loss and EVERY gradient leaf of the sharded
-    step are the single device's, remat on."""
+    step are the single device's, remat on. (`cpu_mesh8`: the sharded
+    step must not be LOADED from a persistent compile cache that another
+    test of the worker switched on; a whole run lost a worker here.)"""
     spec, seq, cached, overrides, engaged = TP_RING_CASES[case]
     cfg = llama.tiny_config(n_heads=4, n_kv_heads=4, d_ff=128, remat=True,
                             **overrides)
-    mesh = make_mesh(spec, jax.devices("cpu")[:8])
+    mesh = make_mesh(spec, cpu_mesh8)
     params = llama.init_params(cfg, jax.random.key(0))
     tokens = jax.random.randint(jax.random.key(1), (8, seq), 0,
                                 cfg.vocab_size)
