@@ -70,6 +70,11 @@ from ray_tpu.ops import (
     mla_decode_attention,
     rms_norm,
 )
+from ray_tpu.ops.grouped_experts import (
+    EXPERT_STACKS,  # noqa: F401 — the names of a layer's expert matrices
+    grouped_swiglu,
+    split_expert_stacks,
+)
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -238,52 +243,20 @@ def _swiglu(x, w_gate, w_up, w_down):
     return jnp.einsum("tf,fd->td", jax.nn.silu(gate) * up, w_down)
 
 
-EXPERT_STACKS = ("w_gate", "w_up", "w_down")
-
-
-def expert_stacks(moe: Params) -> Params:
-    """The expert layers' matrices as ONE run of groups, [Lm * E, ..]: a
-    free view of the stacked parameters. The grouped product takes the
-    whole of it and finds a layer's experts by their group sizes (all
-    other groups are empty), so no layer's 1.2 GB is sliced out of the
-    stack first: a slice feeding a kernel is a copy, and at decode those
-    copies took more of the step than everything else in it (v5e
-    trace, PR 29). The layer scans close over this, and scan the rest."""
-    return {k: moe[k].reshape((-1,) + moe[k].shape[2:])
-            for k in EXPERT_STACKS}
-
-
 def moe_ffn(x, layer, stacks, layer_idx, cfg: GlmMoeLiteConfig, valid=None):
     """x [T, d] -> (y [T, d], experts [T, k], load [E]); ``layer`` holds
     the router and the shared expert of expert layer ``layer_idx``,
-    ``stacks`` every expert layer's experts (`expert_stacks`).
+    ``stacks`` every expert layer's experts (`split_expert_stacks`).
 
-    Dropless: the T*k chosen pairs are sorted by expert and each group
-    multiplied by its expert's matrices; ``load[e]`` is the group's
-    size. ``valid`` [T] (a prefill bucket's real tokens) keeps padding
-    out of every group: such pairs sort last, past the groups' total,
-    and their rows are zeroed."""
-    t, d = x.shape
-    k, e = cfg.n_experts_per_tok, cfg.n_experts
+    Dropless: the T*k chosen pairs go through the grouped product that
+    every routed family shares (``ops/grouped_experts.py``: sorted by
+    expert, each group multiplied by its expert's matrices; ``load[e]``
+    is the group's size; ``valid`` [T], a prefill bucket's real tokens,
+    keeps padding out of every group). This family's own: `route`, the
+    gates' weighted sum and the shared expert."""
     experts, gates = route(x, layer["router"], layer["router_bias"], cfg)
-    flat = experts.reshape(t * k)
-    if valid is not None:
-        flat = jnp.where(jnp.repeat(valid, k), flat, e)
-    order = jnp.argsort(flat, stable=True)
-    load = jnp.sum(flat[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :],
-                   axis=0, dtype=jnp.int32)
-    n_groups = stacks["w_gate"].shape[0]
-    # layer_idx < n_groups / e by construction (the scan's own index).
-    sizes = lax.dynamic_update_slice(  # rtpu-lint: disable=unclamped-dynamic-update-slice
-        jnp.zeros((n_groups,), jnp.int32), load, (layer_idx * e,))
-    xs = jnp.take(x, order // k, axis=0)                     # [T*k, d]
-    hidden = (jax.nn.silu(lax.ragged_dot(xs, stacks["w_gate"], sizes))
-              * lax.ragged_dot(xs, stacks["w_up"], sizes))
-    ys = lax.ragged_dot(hidden, stacks["w_down"], sizes)     # [T*k, d]
-    if valid is not None:
-        ys = jnp.where((jnp.take(flat, order) < e)[:, None], ys, 0)
-    back = jnp.argsort(order)                # pair i sits at row back[i]
-    y = jnp.take(ys, back, axis=0).reshape(t, k, d)
+    y, load = grouped_swiglu(x, experts, stacks, layer_idx, cfg.n_experts,
+                             valid)
     y = jnp.einsum("tkd,tk->td", y.astype(F32), gates).astype(x.dtype)
     shared = _swiglu(x, layer["ws_gate"], layer["ws_up"], layer["ws_down"])
     return y + shared, experts, load
@@ -404,13 +377,6 @@ def _decode_block(x, layer, moe, layer_idx, cache, lengths,
     return x + y.astype(x.dtype), cache, experts, load
 
 
-def _split(moe: Params):
-    """The expert layers' parameters as (what the grouped products read
-    whole, what the layer scan slices a layer at a time)."""
-    return expert_stacks(moe), {k: v for k, v in moe.items()
-                                if k not in EXPERT_STACKS}
-
-
 # The engine's seam --------------------------------------------------------
 
 def init_kv_cache(cfg: GlmMoeLiteConfig, batch: int, max_len: int,
@@ -429,7 +395,7 @@ def _prefill(params, tokens, cache, cache_index, cfg, valid):
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     nd = cfg.n_dense_layers
 
-    stacks, scanned = _split(params["moe"])
+    stacks, scanned = split_expert_stacks(params["moe"])
 
     def body(x, xs):
         layer, rows, idx = xs
@@ -514,7 +480,7 @@ def decode_step_with_cache(params: Params, tokens: jnp.ndarray,
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     nd = cfg.n_dense_layers
 
-    stacks, scanned = _split(params["moe"])
+    stacks, scanned = split_expert_stacks(params["moe"])
 
     def body(carry, xs):
         x, kv = carry

@@ -48,6 +48,11 @@ sparse_attention           Pallas single-query kernel over  on TPU, or ``interpr
  .sparse_prefill_attention (jnp: each query's selection as  always; the loop's trip count follows
                            a mask, tiles of rows under a    the chunk's last position
                            ``fori_loop``; no kernel yet)
+grouped_experts            (``lax.ragged_dot`` x 3 between a  always; the chip's compiler has a grouped
+ .grouped_swiglu           stable sort by expert and its      matmul for ``ragged_dot``, elsewhere it is
+                           inverse: dropless, any k)          a masked dense product. Imported by its
+                                                            callers (``models/glm_moe_lite.py``,
+                                                            ``models/zaya.py``)
 ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (with attention.py
                                                             the only importers of shard_map —
                                                             rtpu-lint banned-API rule)

@@ -818,3 +818,99 @@ def test_minicpm_sala_tick_prefill_chunk_fits_beside_the_cache(chip):
     assert mem.temp_size_in_bytes < GIB
     assert held + mem.temp_size_in_bytes < 13 * GIB
     assert _copies_of(text, cache) == []
+
+
+# ------------------------------------------------------------ the ZAYA1 cell
+
+ZAYA_SLOTS, ZAYA_ROWS = 64, 2048
+
+
+def _zaya_args(chip):
+    """The cell's configuration (benchmark/configs/zaya1-8b-l16.json):
+    every published width, 16 layers, 64 slots of 2,048 rows."""
+    from ray_tpu.models import zaya
+
+    cfg = zaya.ZayaConfig(n_layers=16, max_seq_len=ZAYA_ROWS)
+    params = _abstract(chip, functools.partial(zaya.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: zaya.init_kv_cache(cfg, ZAYA_SLOTS,
+                                                       ZAYA_ROWS))
+    assert set(cache) == {"k", "v", "tail"}
+    # 1,024 B of K and V a token a layer; a tail of 2,688 float32 (21
+    # whole lane tiles) a slot a layer.
+    assert cache["k"].shape == (16, ZAYA_SLOTS, 2, ZAYA_ROWS, 128)
+    assert cache["tail"].shape == (16, ZAYA_SLOTS, 2688)
+    nbytes = lambda tree: sum(a.size * a.dtype.itemsize
+                              for a in jax.tree.leaves(tree))
+    assert nbytes(params) == 7_748_146_304
+    assert nbytes(cache) == 2_158_493_696
+    return cfg, params, cache, nbytes(params) + nbytes(cache)
+
+
+def test_zaya_decode_chunk_fits_and_updates_rows_and_tail_in_place(chip):
+    """The cell's `decode_chunk` whole: one scan over the 16 layers, the
+    decode-attention kernel in it under its name (called directly, 4
+    query heads a KV head), the experts as the chip compiler's grouped
+    matmul (three a layer, 64 rows: one expert a token), rows and tail
+    aliased and none copied, no layer's experts sliced out of their
+    stack, and weights + cache + temporaries inside the chip with room
+    for the check's two further caches."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg, params, cache, held = _zaya_args(chip)
+    loop = DecodeLoop(cfg, max_len=ZAYA_ROWS, chunk=8)
+    c = _lower_decode_chunk(chip, loop, params, cache, ZAYA_SLOTS)
+    text = c.as_text()
+    assert "%rtpu_decode_attention." in text and "%closed_call" not in text
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = bf16\[64,", text)) == 3
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= held - 7_748_146_304
+    assert mem.temp_size_in_bytes < 2 ** 28
+    assert held + mem.temp_size_in_bytes < 10.5 * GIB
+    assert _copies_of(text, cache) == []
+    vec = _sds(chip, (ZAYA_SLOTS,), jnp.int32)
+    out = jax.eval_shape(
+        loop.decode_chunk, params, cache,
+        _sds(chip, (ZAYA_SLOTS, 1), jnp.int32), vec, vec, vec,
+        _sds(chip, (ZAYA_SLOTS,), jnp.bool_))
+    assert len(out) == 8 and set(out[7]) == {
+        "moe_layer_steps", "moe_expert_hits", "moe_decode_load_max",
+        "decode_attn_rows", "decode_attn_rows_streamed"}
+    whole = (params, cache, _sds(chip, (ZAYA_SLOTS, 1), jnp.int32), vec)
+    logits, _, counters, seen = jax.eval_shape(loop.decode_step_whole, *whole)
+    assert logits.shape == (ZAYA_SLOTS, cfg.vocab_size)
+    assert set(counters) == set(out[7])
+    assert seen["experts"].shape == (16, ZAYA_SLOTS, 1, 1)
+    assert seen["router_p"].shape == (16, ZAYA_SLOTS, 1, 16)
+    assert seen["router_in"].shape == (16, ZAYA_SLOTS, 1, 2048)
+
+
+def test_zaya_tick_prefill_resets_the_tail_in_the_program(chip):
+    """The tick's prefill at the largest bucket: the flash kernel and
+    the grouped matmul in it, one token and the counters out (the
+    262,272-column row of logits stays behind), the cache aliased and
+    no array of its shapes copied."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg, params, cache, held = _zaya_args(chip)
+    loop = DecodeLoop(cfg, max_len=ZAYA_ROWS, chunk=8)
+    scalar = _sds(chip, (), jnp.int32)
+    args = (params, cache, _sds(chip, (1, 512), jnp.int32), scalar, scalar,
+            scalar)
+    lowered = loop.prefill_inplace.lower(*args)
+    assert "jit_prefill" in lowered.as_text()[:200]
+    c = lowered.compile()
+    out = jax.eval_shape(loop.prefill_inplace, *args)
+    assert (out[0].shape, out[0].dtype) == ((1,), jnp.int32)
+    assert len(out) == 3 and set(out[2]) == {
+        "moe_prefill_tokens", "moe_prefill_load_max",
+        "moe_prefill_load_mean", "state_resets"}
+    row = jax.eval_shape(loop.prefill_last, *args)
+    assert row[0].shape == (1, cfg.vocab_size)
+    assert set(row[3]) == {"experts", "router_in", "router_p"}
+    text = c.as_text()
+    assert "%flash_attention" in text and "%ragged-dot-none" in text
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= held - 7_748_146_304
+    assert mem.temp_size_in_bytes < GIB
+    assert _copies_of(text, cache) == []

@@ -316,7 +316,7 @@ LONG = list(range(2, 22))
 FIRST_WAVE = [ANCHOR, ([4] * 3, 5), ([5] * 3, 13), ([6] * 3, 7), (LONG, 9)]
 SECOND_WAVE = [(LONG, 11), ([7] * 3, 6), ([8] * 5, 2)]
 CHURN_CASES = [("llama", 2), ("llama", 3), ("llama", 4),
-               ("olmo_hybrid", 2), ("minicpm_sala", 3)]
+               ("olmo_hybrid", 2), ("minicpm_sala", 3), ("zaya", 3)]
 
 
 @pytest.mark.parametrize("family,max_batch", CHURN_CASES)
@@ -675,8 +675,20 @@ def _minicpm_sala():
                             dense_len=16), dtype=jnp.float32)
 
 
+def _zaya():
+    from ray_tpu.models import zaya
+
+    import jax.numpy as jnp
+
+    return zaya.ZayaConfig(
+        vocab_size=64, d_model=24, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=8, rotary_dim=4, n_experts=4, moe_d_ff=16, router_d=8,
+        max_seq_len=64, dtype=jnp.float32)
+
+
 # family -> its tiny configuration; a further family is a further row.
-FAMILIES = {"olmo_hybrid": _olmo_hybrid, "minicpm_sala": _minicpm_sala}
+FAMILIES = {"olmo_hybrid": _olmo_hybrid, "minicpm_sala": _minicpm_sala,
+            "zaya": _zaya}
 OPTIONS = {"quantize": dict(quantize="int8"),
            "paged_decode": dict(paged_decode=True),
            "spec_draft_len": dict(spec_draft_len=2),
