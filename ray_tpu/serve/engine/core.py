@@ -25,8 +25,8 @@ import queue
 import threading
 import time
 import zlib
-from collections import OrderedDict
-from typing import Any, Dict, List, Optional
+from collections import OrderedDict, deque
+from typing import Any, Deque, Dict, List, Optional
 
 import numpy as np
 
@@ -41,6 +41,11 @@ from ray_tpu.serve.engine.scheduler import (EngineRequest, Scheduler,
 from ray_tpu.util import compile_cache as _compile_cache
 from ray_tpu.util import flight_recorder as _flight
 from ray_tpu.util import tracing as _tracing
+
+
+# The listening wait (_listen) looks at the chunk in flight at least
+# this often: what a wrong estimate of that chunk's end can cost.
+_LISTEN_SLICE_S = 0.002
 
 
 class _PrefillJob:
@@ -363,6 +368,20 @@ class InferenceEngine:
         self._preempts = 0
         self._resumes = 0
         self._last_retire_t = 0.0  # TPOT cadence anchor (see _retire_chunk)
+        # What the listening wait (_listen) derives its deadline from,
+        # all measured by the tick itself: the stamp at which the last
+        # fetch returned IF it had to wait for the device (the program
+        # queued behind the fetched one began then; None when the fetch
+        # found its result ready, so nothing is known), the device
+        # seconds of the last few chunks whose start and end were both
+        # seen that way, and the host seconds of the last few carried
+        # dispatches. ``_unfetched_ahead``: a prefill chunk that no
+        # fetch will stamp the end of was dispatched since the last
+        # decode chunk, so the next chunk's start will not be seen.
+        self._fetch_blocked_t: Optional[float] = None
+        self._chunk_s: Deque[float] = deque(maxlen=8)
+        self._dispatch_s: Deque[float] = deque(maxlen=8)
+        self._unfetched_ahead = False
         self._queue: "queue.Queue[EngineRequest]" = queue.Queue()
         # Decode role: KV-page install jobs handed over from prefill
         # replicas. Device work happens on the engine thread (installs
@@ -645,7 +664,13 @@ class InferenceEngine:
         the RTPU_DEBUG_JAX witness (per tag), so the one-sync-per-chunk
         invariant is assertable, not aspirational."""
         jax_debug.note_host_sync(f"engine.{tag}")
-        return self._jax.device_get(tree)  # rtpu-lint: disable=host-sync-in-hot-path — this IS the counted sync
+        waits = not all(a.is_ready() for a in self._jax.tree.leaves(tree))
+        out = self._jax.device_get(tree)  # rtpu-lint: disable=host-sync-in-hot-path — this IS the counted sync
+        # A fetch that had to wait returns when the device ends that
+        # program and begins the next in its queue: the one stamp of
+        # the device's own clock the host gets (see _listen_deadline).
+        self._fetch_blocked_t = time.perf_counter() if waits else None
+        return out
 
     def _put(self, value):
         """Explicit host->device placement for dispatch inputs: under
@@ -1168,6 +1193,7 @@ class InferenceEngine:
         if final:
             job.token = token
         else:
+            self._unfetched_ahead = True
             self._prefill_span(job, job.idx, self._tick.now, ())
         job.idx += 1
         job.pos += n
@@ -1555,17 +1581,122 @@ class InferenceEngine:
         nothing, so the trailing chunk of a burst delivers zero tokens
         and is dropped unfetched). The order below is the device's own:
         chunk N, the tick's prefills, chunk N+1 — so each fetch finds
-        its program done or running, never queued behind a later one."""
+        its program done or running, never queued behind a later one.
+
+        Chunk N+1 goes out LATE in chunk N's time where the thread has
+        something better to do than sit in chunk N's fetch: ``_listen``
+        waits on the mailbox first, and an arrival heard there has its
+        prefill on the device behind chunk N alone (its job joins
+        ``landing``)."""
+        listened = self._listen(landing)
         prev, self._inflight = self._inflight, None
         joining = [j for j in landing if not j.adm.request.handoff]
         if self._roster_outlives_chunk(prev, joining):
             # Bound before the retire: a device failure found there
             # drops it with the cache (_recover_cache).
             self._inflight = self._dispatch_chunk(prev, joining)
+            if listened and self._ran_dry(prev, landing):
+                self.metrics.record_listen_late()
         if prev is not None:
             self._retire_chunk(prev)
         for job in landing:
             self._land_prefill(job)
+
+    def _admits_at_once(self) -> bool:
+        """An arrival would be admitted the moment it is heard: nobody
+        waits ahead of it (FIFO holds) and a slot is free. The decode
+        role's arrivals come by another queue."""
+        return (self.kv.free_slots() > 0 and not self._parked
+                and not self.scheduler.queue_depth()
+                and self.role != "decode")
+
+    def _listen_deadline(self, rec: Dict[str, Any]) -> Optional[float]:
+        """When the wait for arrivals must end for chunk N+1 to reach
+        the device before chunk N (``rec``) leaves it: N's start, plus
+        the SHORTEST of the last chunks' device times, less a margin of
+        their scatter (longest less shortest) and the LONGEST of the
+        last carried dispatches' host times. All measured by the tick
+        (``_fetch``, ``_retire_chunk``, ``_dispatch_chunk``); None when
+        any of it is unknown — the last fetch found its result ready,
+        no chunk has been timed, a program no fetch stamps lay ahead of
+        ``rec`` — and the tick then keeps the order it always had."""
+        began = self._fetch_blocked_t
+        if (began is None or not rec["timed"] or not self._chunk_s
+                or not self._dispatch_s):
+            return None
+        shortest = min(self._chunk_s)
+        margin = max(self._chunk_s) - shortest + max(self._dispatch_s)
+        return began + shortest - margin
+
+    @staticmethod
+    def _chunk_done(rec: Dict[str, Any]) -> bool:
+        """Chunk ``rec`` has left the device. No sync, no transfer: the
+        ``_fetch`` count and the transfer guard see nothing."""
+        return rec["outs"][0].is_ready()
+
+    def _ran_dry(self, prev: Optional[Dict[str, Any]],
+                 landing: List[_PrefillJob]) -> bool:
+        """Asked when a chunk has just been dispatched: the program
+        ahead of it (the tick's last prefill, else chunk ``prev``) had
+        left the device already, which has stood idle since."""
+        if landing:
+            return landing[-1].token.is_ready()
+        return prev is not None and self._chunk_done(prev)
+
+    def _listen(self, landing: List[_PrefillJob]) -> bool:
+        """While the chunk in flight runs and an arrival could be
+        admitted at once, the thread waits on its MAILBOX, not in that
+        chunk's fetch: an arrival heard here is admitted and its first
+        prefill chunk dispatched now, behind the chunk in flight alone
+        (a final chunk's job joins ``landing``, as if the top of the
+        tick had dispatched it), where it would have waited for the top
+        of the next tick and then behind a chunk that had only begun.
+        At the deadline the tick goes on: the device's order is still
+        chunk N, the prefills, chunk N+1.
+
+        A wrong estimate costs one slice: the wait does not begin, and
+        ends, when the chunk is done (``_chunk_done``). Booked as
+        ``decode_fetch`` — the thread waits for the device here as it
+        does there — with the admissions and dispatches heard inside it
+        under their own phases. True if the thread listened at all."""
+        rec = self._inflight
+        if rec is None or not self._admits_at_once():
+            return False
+        deadline = self._listen_deadline(rec)
+        if (deadline is None or deadline <= time.perf_counter()
+                or self._chunk_done(rec)):
+            return False
+        with self._tick.phase("decode_fetch", listening=True) as attrs:
+            attrs["heard"] = 0
+            while (self._inflight is rec and not self._shutdown
+                   and not self._chunk_done(rec)):
+                left = deadline - time.perf_counter()
+                if left <= 0:
+                    break
+                try:
+                    req = self._queue.get(
+                        timeout=min(left, _LISTEN_SLICE_S))
+                except queue.Empty:
+                    continue
+                attrs["heard"] += self._hear(req, landing)
+                if not self._admits_at_once():
+                    break
+        return True
+
+    def _hear(self, req: EngineRequest, landing: List[_PrefillJob]) -> int:
+        """Admit one arrival straight from the mailbox (the waiting
+        line was empty, so FIFO holds) and dispatch its first prefill
+        chunk. Returns the admissions made (0 if the slot raced away)."""
+        self.scheduler.submit(req)
+        first = len(self._prefilling)
+        with self._tick.phase("admit", listening=True):
+            self._run_admissions()
+        jobs = self._prefilling[first:]
+        for job in jobs:
+            self.metrics.record_heard()
+            if self._dispatch_prefill(job):
+                landing.append(job)
+        return len(jobs)
 
     def _roster_outlives_chunk(self, prev, joining) -> bool:
         """True when some request can still be live AFTER the in-flight
@@ -1634,8 +1765,16 @@ class InferenceEngine:
                 self._fail_roster(e, joining)
                 return None
         self.metrics.record_dispatch(carried)
+        if carried:
+            self._dispatch_s.append(self._tick.now - t0)
+        # The chunk begins where the program ahead of it ends; a fetch
+        # will show that moment if that program is the chunk in flight
+        # or a prefill whose token the tick lands.
+        timed = carried and not self._unfetched_ahead
+        self._unfetched_ahead = False
         # A family's counters ride the chunk's one fetch.
         return {"outs": (toks_d, n_valid_d, *counters_d),
+                "timed": timed,
                 "carry": (ntok_d, nlen_d, nrem_d, state[3], ndone_d),
                 # Who the chunk was dispatched with, by slot. The next
                 # dispatch carries a slot only for the SAME request, and
@@ -1653,6 +1792,7 @@ class InferenceEngine:
         carried its done mask; one that failed or was parked since has
         let go of its slot), retire finishes. False on device
         failure."""
+        began = self._fetch_blocked_t
         try:
             with self._tick.phase("decode_fetch",
                                   slots=len(rec["held"])) as attrs:
@@ -1662,6 +1802,11 @@ class InferenceEngine:
         except BaseException as e:  # noqa: BLE001 — fail all waiters
             self._fail_roster(e)
             return False
+        ended = self._fetch_blocked_t
+        if rec["timed"] and began is not None and ended is not None:
+            # Both ends seen by a fetch that waited: device seconds,
+            # whatever the host did (or compiled) in between.
+            self._chunk_s.append(ended - began)
         now = self._tick.now
         # TPOT window: a PIPELINED chunk was dispatched one tick ago, so
         # dispatch->fetch would fold the whole intervening host tick

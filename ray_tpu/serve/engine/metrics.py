@@ -58,6 +58,14 @@ PREFIX_REUSED_TOTAL = _m.Counter(
 HOST_SYNCS_TOTAL = _m.Counter(
     "rtpu_llm_decode_host_syncs_total",
     "device->host fetches issued by the decode loop (one per chunk)")
+ADMISSIONS_HEARD_TOTAL = _m.Counter(
+    "rtpu_llm_admissions_heard_total",
+    "admissions whose first prefill chunk was dispatched from the tick's "
+    "listening wait, behind the decode chunk in flight")
+LISTEN_DEADLINE_LATE_TOTAL = _m.Counter(
+    "rtpu_llm_listen_deadline_late_total",
+    "ticks whose listening wait outran what it waited for: the next "
+    "decode chunk was dispatched to a device that had run dry")
 SPEC_DRAFTED_TOTAL = _m.Counter(
     "rtpu_llm_spec_drafted_total",
     "draft tokens proposed by prompt-lookup speculation")
@@ -112,6 +120,12 @@ class EngineMetrics:
         # One writer, the engine thread; no lock (like ``tick_s``).
         self.chunks_dispatched = 0
         self.chunks_carried = 0
+        # The listening wait (core.py ``_listen``): admissions whose
+        # first prefill chunk it dispatched (against ``requests``), and
+        # ticks on which it ended too late: the next chunk was
+        # dispatched to a device that had run dry, and idled.
+        self.admissions_heard = 0
+        self.listen_deadline_late = 0
         # Prefill programs dispatched and the REAL tokens they carried
         # (``prefill_tokens`` counts a whole prompt, at its last chunk).
         self.prefill_chunks_dispatched = 0
@@ -211,6 +225,16 @@ class EngineMetrics:
         self.chunks_dispatched += 1
         self.chunks_carried += carried
 
+    def record_heard(self) -> None:
+        """One admission made from the listening wait."""
+        self.admissions_heard += 1
+        ADMISSIONS_HEARD_TOTAL.inc(labels=self._labels)
+
+    def record_listen_late(self) -> None:
+        """One tick whose listening wait left the device to run dry."""
+        self.listen_deadline_late += 1
+        LISTEN_DEADLINE_LATE_TOTAL.inc(labels=self._labels)
+
     def record_prefill_chunk(self, tokens: int) -> None:
         """One prefill program enqueued, carrying ``tokens`` real
         prompt tokens (bucket padding left out)."""
@@ -277,6 +301,8 @@ class EngineMetrics:
                 "decode_steps": self.decode_steps,
                 "decode_chunks_dispatched": self.chunks_dispatched,
                 "decode_chunks_carried": self.chunks_carried,
+                "admissions_heard": self.admissions_heard,
+                "listen_deadline_late": self.listen_deadline_late,
                 "prefill_chunks_dispatched": self.prefill_chunks_dispatched,
                 "prefill_chunk_tokens": self.prefill_chunk_tokens,
                 # decode tokens delivered per device token-position
