@@ -225,10 +225,14 @@ def init_params(cfg: GlmMoeLiteConfig, key: jax.Array) -> Params:
 
 # Experts ------------------------------------------------------------------
 
-def route(x, router, bias, cfg: GlmMoeLiteConfig):
+def route(x, router, bias, cfg: GlmMoeLiteConfig, precision=None):
     """x [T, d] -> (experts [T, k] int32, gates [T, k] float32): chosen
-    on ``s + b``, weighted by ``s``."""
+    on ``s + b``, weighted by ``s``. ``precision`` is the router
+    product's (None: the backend's default, which on the TPU rounds a
+    float32 router to bf16; a family that states a float32 router asks
+    for `lax.Precision.HIGHEST`)."""
     s = jax.nn.sigmoid(jnp.einsum("td,de->te", x, router,
+                                  precision=precision,
                                   preferred_element_type=F32))
     _, experts = lax.top_k(s + bias.astype(F32), cfg.n_experts_per_tok)
     gates = jnp.take_along_axis(s, experts, axis=-1)

@@ -48,11 +48,20 @@ sparse_attention           Pallas single-query kernel over  on TPU, or ``interpr
  .sparse_prefill_attention (jnp: each query's selection as  always; the loop's trip count follows
                            a mask, tiles of rows under a    the chunk's last position
                            ``fori_loop``; no kernel yet)
+row_select                 Pallas scoring-and-selection of  on TPU, or ``interpret=True`` off-TPU;
+ .select_decode_rows       single rows for a decode step:   jnp twin elsewhere. Imported by its one
+                           a slot's index keys streamed to  caller (``models/dots3_note.py``). The
+                           its length, the exact top-k of   mask it writes is ``mla_decode_attention``'s
+                           the scores found with no sort    ``keep``
+ .top_rows, .index_scores  (jnp: a chunk's scores tile by   always; the prefill's selection as a mask
+                           tile, the exact top-k as a mask)  over rows
 grouped_experts            (``lax.ragged_dot`` x 3 between a  always; the chip's compiler has a grouped
  .grouped_swiglu           stable sort by expert and its      matmul for ``ragged_dot``, elsewhere it is
                            inverse: dropless, any k)          a masked dense product. Imported by its
                                                             callers (``models/glm_moe_lite.py``,
-                                                            ``models/zaya.py``)
+                                                            ``models/zaya.py``,
+                                                            ``models/dots3_note.py``, which holds
+                                                            a SHARE of the experts: ``held``)
 ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (with attention.py
                                                             the only importers of shard_map —
                                                             rtpu-lint banned-API rule)

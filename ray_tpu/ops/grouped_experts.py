@@ -1,5 +1,6 @@
 """The grouped product of a dropless expert layer, for every routed
-family (``models/glm_moe_lite.py``, ``models/zaya.py``): each token's
+family (``models/glm_moe_lite.py``, ``models/zaya.py``,
+``models/dots3_note.py``): each token's
 chosen experts are given, the (token, expert) pairs are sorted by
 expert and each group multiplied by its own expert's SwiGLU matrices
 with ``jax.lax.ragged_dot`` (the chip's compiler has a grouped-matmul
@@ -7,7 +8,9 @@ kernel for it; elsewhere it is a masked dense product, fine at test
 sizes). No capacity: no pair is dropped however skewed the routing.
 What a family keeps for itself is its router (`route`: which experts,
 with what weights) and whatever it adds to the sum (a shared expert, a
-scaling factor).
+scaling factor). A chip that holds a SHARE of a layer's experts (expert
+parallelism, its one-chip half) says which (``held``): the router keeps
+its width, pairs on absent experts go to no group.
 """
 
 from __future__ import annotations
@@ -41,18 +44,31 @@ def split_expert_stacks(layers):
 
 
 def grouped_swiglu(x, experts, stacks, layer_idx, n_experts: int,
-                   valid=None):
+                   valid=None, held=None):
     """x [T, d], experts [T, k] int32 (each token's chosen experts of
-    expert layer ``layer_idx``), ``stacks`` every expert layer's experts
-    (`expert_stacks`) -> (y [T, k, d]: each pair's expert applied to its
-    token, load [E] int32: each group's size).
+    expert layer ``layer_idx``, among the router's ``n_experts``),
+    ``stacks`` every expert layer's experts (`expert_stacks`) -> (y
+    [T, k, d]: each pair's expert applied to its token, load [E] int32:
+    each group's size).
 
     ``valid`` [T] (a prefill bucket's real tokens) keeps padding out of
     every group: such pairs sort last, past the groups' total, and
-    their rows are zeroed."""
+    their rows are zeroed.
+
+    ``held`` = (first, count): this chip's share of an expert-parallel
+    layer. The stacks hold experts ``first .. first + count - 1`` of
+    every layer and no others; the router still ranks all ``n_experts``
+    and a pair on an absent expert goes to no group, as padding does
+    (its row comes back zero: what the absent chips would add is left
+    out, no code stands in for them). ``load`` is then over the
+    ``count`` held experts. Absent, every expert is held."""
     t, d = x.shape
     k, e = experts.shape[1], n_experts
     flat = experts.reshape(t * k)
+    if held is not None:
+        first, e = held
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < e), flat, e)
     if valid is not None:
         flat = jnp.where(jnp.repeat(valid, k), flat, e)
     order = jnp.argsort(flat, stable=True)
@@ -66,7 +82,7 @@ def grouped_swiglu(x, experts, stacks, layer_idx, n_experts: int,
     hidden = (jax.nn.silu(lax.ragged_dot(xs, stacks["w_gate"], sizes))
               * lax.ragged_dot(xs, stacks["w_up"], sizes))
     ys = lax.ragged_dot(hidden, stacks["w_down"], sizes)     # [T*k, d]
-    if valid is not None:
+    if valid is not None or held is not None:
         ys = jnp.where((jnp.take(flat, order) < e)[:, None], ys, 0)
     back = jnp.argsort(order)                # pair i sits at row back[i]
     return jnp.take(ys, back, axis=0).reshape(t, k, d), load

@@ -23,6 +23,14 @@ slice). Blocks past ``lengths[b]`` are not read: the index map parks
 them on the slot's last valid block (no fresh copy, as the paged
 kernel's does) and their compute is skipped.
 
+``keep`` [B, S] (optional) says which of a slot's rows under its length
+the query attends to at all: a family whose queries CHOOSE their rows
+(a learned top-k over single rows), or whose rows are a ring that keeps
+only the last few hundred (a window), hands the kernel its choice as a
+mask and the rows are read whole under it, where they lie. ``name`` is
+what the call is known by in a device trace: a family that runs the
+kernel at two geometries in one step tells them apart by it.
+
 Off the TPU the jnp reference runs (``interpret=True`` runs the kernel
 under the Pallas interpreter, for the CPU tests).
 """
@@ -39,11 +47,14 @@ NEG_INF = -1e30
 
 
 def mla_decode_attention_reference(q, kv, lengths, *, v_dim: int,
-                                   scale: float):
-    """q [B,H,Dk], kv [B,S,Dk], lengths [B] -> [B,H,v_dim]."""
+                                   scale: float, keep=None):
+    """q [B,H,Dk], kv [B,S,Dk], lengths [B], keep [B,S] | None ->
+    [B,H,v_dim] (0 where a slot attends to no row)."""
     logits = jnp.einsum("bhd,bsd->bhs", q, kv,
                         preferred_element_type=jnp.float32) * scale
     mask = jnp.arange(kv.shape[1])[None, :] < lengths[:, None]
+    if keep is not None:
+        mask = mask & (keep > 0)
     logits = jnp.where(mask[:, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     probs = jnp.where(mask[:, None, :], probs, 0.0)
@@ -52,9 +63,12 @@ def mla_decode_attention_reference(q, kv, lengths, *, v_dim: int,
     return out.astype(q.dtype)
 
 
-def _mla_kernel(len_ref, layer_ref, q_ref, kv_ref, o_ref, m_ref, l_ref,
-                acc_ref, *, block_s: int, v_dim: int, scale: float):
+def _mla_kernel(len_ref, layer_ref, q_ref, kv_ref, *rest, block_s: int,
+                v_dim: int, scale: float, kept: bool):
     import jax.experimental.pallas as pl
+
+    keep_ref = rest[0] if kept else None
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
 
     b = pl.program_id(0)
     s_idx = pl.program_id(1)
@@ -77,11 +91,18 @@ def _mla_kernel(len_ref, layer_ref, q_ref, kv_ref, o_ref, m_ref, l_ref,
             preferred_element_type=jnp.float32) * scale   # [H, block_s]
         positions = s_idx * block_s + jax.lax.broadcasted_iota(
             jnp.int32, logits.shape, 1)
-        logits = jnp.where(positions < length, logits, NEG_INF)
+        valid = positions < length
+        if kept:
+            valid = valid & (keep_ref[0] > 0)        # [1, block_s]
+        logits = jnp.where(valid, logits, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
         correction = jnp.exp(m_prev - m_new)
         p = jnp.exp(logits - m_new)
+        if kept:
+            # A block may hold no kept row: its running maximum is still
+            # the floor, and exp(floor - floor) is one, not nothing.
+            p = jnp.where(valid, p, 0.0)
         l_ref[...] = l_ref[...] * correction + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
             p.astype(rows.dtype), rows[:, :v_dim], (((1,), (0,)), ((), ())),
@@ -96,21 +117,23 @@ def _mla_kernel(len_ref, layer_ref, q_ref, kv_ref, o_ref, m_ref, l_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("v_dim", "scale", "block_s",
-                                             "interpret"))
+                                             "interpret", "name"))
 def mla_decode_attention(q, cache, lengths, *, layer, v_dim: int,
                          scale: float, block_s: int = 512,
-                         interpret: Optional[bool] = None):
+                         interpret: Optional[bool] = None, keep=None,
+                         name: str = "rtpu_mla_decode_attention"):
     """q [B,H,Dk], cache [L,B,S,Dk], lengths [B] int32, ``layer`` a
-    traced int32 scalar -> [B,H,v_dim]: the Pallas kernel on the TPU
-    (or under ``interpret``), the jnp reference elsewhere and where
-    ``block_s`` does not divide the cache's rows."""
+    traced int32 scalar, ``keep`` [B,S] (optional; > 0: the row is
+    attended to) -> [B,H,v_dim]: the Pallas kernel on the TPU (or under
+    ``interpret``), the jnp reference elsewhere and where ``block_s``
+    does not divide the cache's rows."""
     on_tpu = jax.default_backend() == "tpu"
     n_layers, b, s, dk = cache.shape
     block_s = min(block_s, s)
     if not ((on_tpu or interpret) and s % block_s == 0):
         kv = jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
         return mla_decode_attention_reference(q, kv, lengths, v_dim=v_dim,
-                                              scale=scale)
+                                              scale=scale, keep=keep)
 
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -126,11 +149,20 @@ def mla_decode_attention(q, cache, lengths, *, layer, v_dim: int,
     def _q_index(bi, si, lens, layer):
         return bi, 0, 0
 
+    def _keep_index(bi, si, lens, layer):
+        return bi, 0, _kv_index(bi, si, lens, layer)[2]
+
+    kept = keep is not None
+    in_specs = [pl.BlockSpec((1, h, dk), _q_index),
+                pl.BlockSpec((1, 1, block_s, dk), _kv_index)]
+    operands = [q, cache]
+    if kept:
+        in_specs.append(pl.BlockSpec((1, 1, block_s), _keep_index))
+        operands.append(keep.astype(jnp.float32).reshape(b, 1, s))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, s // block_s),
-        in_specs=[pl.BlockSpec((1, h, dk), _q_index),
-                  pl.BlockSpec((1, 1, block_s, dk), _kv_index)],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, h, v_dim), _q_index),
         scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),      # running max
                         pltpu.VMEM((h, 1), jnp.float32),      # running denom
@@ -138,11 +170,11 @@ def mla_decode_attention(q, cache, lengths, *, layer, v_dim: int,
     )
     return pl.pallas_call(
         functools.partial(_mla_kernel, block_s=block_s, v_dim=v_dim,
-                          scale=scale),
+                          scale=scale, kept=kept),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, v_dim), q.dtype),
         interpret=bool(interpret),
-        name="rtpu_mla_decode_attention",
-        metadata={"kernel": "rtpu_mla_decode_attention"},
+        name=name,
+        metadata={"kernel": name},
     )(lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-      q, cache)
+      *operands)

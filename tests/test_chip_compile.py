@@ -914,3 +914,139 @@ def test_zaya_tick_prefill_resets_the_tail_in_the_program(chip):
     assert mem.alias_size_in_bytes >= held - 7_748_146_304
     assert mem.temp_size_in_bytes < GIB
     assert _copies_of(text, cache) == []
+
+
+# ------------------------------------------------------- the dots3-note cell
+
+DOTS3_SLOTS, DOTS3_ROWS = 16, 32768
+
+
+def _dots3_args(chip):
+    """The cell's configuration (benchmark/configs/
+    dots3-note-prev-l5-ep8.json) through its builder: every published
+    width, 5 layers, 32 of 256 experts held, 16 slots of 32,768 rows."""
+    import json
+
+    from benchmark.builders import dots3_note as builder
+    from benchmark.harness import manifest
+    from ray_tpu.models import dots3_note
+
+    with open(manifest.BENCH_DIR / "configs"
+              / "dots3-note-prev-l5-ep8.json") as f:
+        cfg = builder.config(json.load(f))
+    params = _abstract(chip, functools.partial(dots3_note.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: dots3_note.init_kv_cache(
+        cfg, DOTS3_SLOTS, DOTS3_ROWS))
+    # Three kinds of entry: a latent row of 1,280 B and an index key of
+    # 256 B a token a full layer; 640 rows of 2,304 B a slot a sliding
+    # layer, whatever the slot's length.
+    assert cache["kv"].shape == (2, DOTS3_SLOTS, DOTS3_ROWS, 640)
+    assert cache["ik"].shape == (2, DOTS3_SLOTS, DOTS3_ROWS, 128)
+    assert cache["win"].shape == (3, DOTS3_SLOTS, 640, 1152)
+    nbytes = lambda tree: sum(a.size * a.dtype.itemsize
+                              for a in jax.tree.leaves(tree))
+    assert nbytes(params) == 8_186_107_904
+    assert nbytes(cache) == 1_681_391_616
+    return cfg, params, cache, nbytes(params) + nbytes(cache)
+
+
+def test_dsa_select_scores_and_chooses_in_one_kernel(chip):
+    """A decode step's choice of rows at the published sizes: 64 index
+    heads of 128 over a slot's 32,768 index keys, blocks of 2,048 rows,
+    the scores and both searches in fast memory: one kernel, under its
+    name, the index keys read where they lie."""
+    from ray_tpu.ops import row_select
+
+    c = row_select.select_decode_rows.lower(
+        _sds(chip, (DOTS3_SLOTS, 64, 128)),
+        _sds(chip, (DOTS3_SLOTS, 64), jnp.float32),
+        _sds(chip, (2, DOTS3_SLOTS, DOTS3_ROWS, 128)),
+        _sds(chip, (DOTS3_SLOTS,), jnp.int32),
+        layer=_sds(chip, (), jnp.int32), k=2048).compile()
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_dsa_select")
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_mla_decode_attention_compiles_at_the_window_layers_width(chip):
+    """The latent kernel at its second geometry: 64 heads over rows of
+    1,088 values padded to 1,152 (nine whole lane tiles), the ring of
+    640 rows in one block, under the window's mask and the name the
+    family gives it."""
+    from ray_tpu.ops.mla_decode import mla_decode_attention
+
+    c = _compile(
+        lambda q, cache, lens, layer, keep: mla_decode_attention(
+            q, cache, lens, layer=layer, v_dim=1024, scale=256 ** -0.5,
+            block_s=640, keep=keep, name="rtpu_swa_decode_attention"),
+        _sds(chip, (DOTS3_SLOTS, 64, 1152)),
+        _sds(chip, (3, DOTS3_SLOTS, 640, 1152)),
+        _sds(chip, (DOTS3_SLOTS,), jnp.int32), _sds(chip, (), jnp.int32),
+        _sds(chip, (DOTS3_SLOTS, 640), jnp.bool_))
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_swa_decode_attention")
+
+
+def test_dots3_decode_chunk_fits_and_updates_its_three_entries_in_place(chip):
+    """The cell's `decode_chunk` whole: the layers as three scans in
+    published order, the selection kernel and the latent kernel under
+    its two names, the grouped matmul over the 128 pairs a step (those
+    on absent experts in no group), latent rows, index keys and rings
+    aliased and none copied, and weights + cache + temporaries inside
+    the chip with room for the check's cache and reference."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg, params, cache, held = _dots3_args(chip)
+    loop = DecodeLoop(cfg, max_len=DOTS3_ROWS, chunk=8)
+    c = _lower_decode_chunk(chip, loop, params, cache, DOTS3_SLOTS)
+    text = c.as_text()
+    for kernel in ("rtpu_dsa_select", "rtpu_dsa_decode_attention",
+                   "rtpu_swa_decode_attention"):
+        assert f"%{kernel}." in text
+    # Two runs of expert layers (full, then sliding), three products each.
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = bf16\[128,", text)) == 6
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= held - 8_186_107_904
+    assert mem.temp_size_in_bytes < 2 ** 29
+    assert held + mem.temp_size_in_bytes < 11 * GIB
+    assert _copies_of(text, cache) == []
+    vec = _sds(chip, (DOTS3_SLOTS,), jnp.int32)
+    out = jax.eval_shape(
+        loop.decode_chunk, params, cache,
+        _sds(chip, (DOTS3_SLOTS, 1), jnp.int32), vec, vec, vec,
+        _sds(chip, (DOTS3_SLOTS,), jnp.bool_))
+    assert len(out) == 8 and set(out[7]) == {
+        "dsa_rows_visible", "dsa_rows_selected", "dsa_rows_attended",
+        "dsa_queries_selected", "window_rows_read", "moe_layer_steps",
+        "moe_expert_hits", "moe_pairs_routed", "moe_pairs_held"}
+
+
+def test_dots3_tick_prefill_chunk_fits_beside_the_cache(chip):
+    """The tick's prefill at the chunk's size (2,048 queries at any
+    ``cache_index``, each with its own 2,048 rows): the scoring and the
+    masked attention's loops over the slot's rows, one token and the
+    counters out, the three entries aliased and none copied; its
+    temporaries (the score array, the tiles) leave the chip room."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg, params, cache, held = _dots3_args(chip)
+    loop = DecodeLoop(cfg, max_len=DOTS3_ROWS, chunk=8)
+    scalar = _sds(chip, (), jnp.int32)
+    args = (params, cache, _sds(chip, (1, 2048), jnp.int32), scalar, scalar,
+            scalar)
+    lowered = loop.prefill_inplace.lower(*args)
+    assert "jit_prefill" in lowered.as_text()[:200]
+    c = lowered.compile()
+    out = jax.eval_shape(loop.prefill_inplace, *args)
+    assert (out[0].shape, out[0].dtype) == ((1,), jnp.int32)
+    assert len(out) == 3 and set(out[2]) == {
+        "prefill_chunks", "dsa_queries_selected", "moe_pairs_routed",
+        "moe_pairs_held"}
+    text = c.as_text()
+    assert " while(" in text and "%ragged-dot-none" in text
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= held - 8_186_107_904
+    assert mem.temp_size_in_bytes < 2 * GIB
+    assert held + mem.temp_size_in_bytes < 12.5 * GIB
+    assert _copies_of(text, cache) == []

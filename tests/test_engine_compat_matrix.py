@@ -686,9 +686,30 @@ def _zaya():
         max_seq_len=64, dtype=jnp.float32)
 
 
+def _dots3_note():
+    from ray_tpu.models import dots3_note
+
+    import jax.numpy as jnp
+
+    geometry = dots3_note.LatentGeometry
+    return dots3_note.Dots3NoteConfig(
+        vocab_size=64, d_model=24,
+        layer_types=(dots3_note.FULL, dots3_note.FULL, dots3_note.SLIDING),
+        full=geometry(2, 12, 8, 4, 4, 4, 1e4),
+        sliding=geometry(2, 12, 12, 4, 4, 4, 1e4), index_heads=2,
+        index_head_dim=8, index_topk=4, window=3, d_ff=32,
+        moe_d_ff=16, n_experts=8, held_experts=(2, 2), n_experts_per_tok=2,
+        max_seq_len=64, dtype=jnp.float32)
+
+
 # family -> its tiny configuration; a further family is a further row.
 FAMILIES = {"olmo_hybrid": _olmo_hybrid, "minicpm_sala": _minicpm_sala,
-            "zaya": _zaya}
+            "zaya": _zaya, "dots3_note": _dots3_note}
+# A family whose per-slot entry is valid by the query's position alone (a
+# ring of last rows): nothing is reset when a slot changes owner, so it
+# neither counts `state_resets` nor stamps them on a span. Every other
+# family here MUST do both.
+NOT_RESET_WITH_THE_SLOT = {"dots3_note"}
 OPTIONS = {"quantize": dict(quantize="int8"),
            "paged_decode": dict(paged_decode=True),
            "spec_draft_len": dict(spec_draft_len=2),
@@ -759,12 +780,16 @@ def test_a_chunked_prefill_fetches_every_chunks_counters(family):
         stats = engine.stats()
     finally:
         engine.close()
-    assert stats["state_resets"] == 2           # one an admission
+    if family in NOT_RESET_WITH_THE_SLOT:
+        assert "state_resets" not in _prefill_counters(cfg)
+        assert "state_resets" not in cfg.model.SPAN_ATTRS
+    else:
+        assert stats["state_resets"] == 2       # one an admission
+        assert engine._span_attrs([{"state_resets": 1},
+                                   {"state_resets": 0}]) == {"state_reset": 1}
     # ... in one fetch each: the token and three chunks' scalars.
     assert stats["prefill_fetch_bytes"] == 2 * 4 * (
         1 + 3 * len(_prefill_counters(cfg)))
     if "prefill_chunks" in cfg.model.SPAN_ATTRS:
         assert stats["prefill_chunks"] == 2 * 3     # 8 + 8 + 4 tokens
-    assert engine._span_attrs([{"state_resets": 1}, {"state_resets": 0}]) \
-        == {"state_reset": 1}
 
