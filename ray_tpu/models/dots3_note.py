@@ -58,7 +58,11 @@ and is called under two names (``rtpu_dsa_decode_attention``,
 mask of a full layer is made by one kernel of its own
 (``rtpu_dsa_select``: scores and the exact top rows).
 PERF.md (PR 42) has the reasons and what a gather or a row-list kernel
-would change.
+would change. A prefill chunk's full layers attend under their mask in
+a third kernel (``rtpu_dsa_prefill_attention``, ``ops/dsa_prefill.py``:
+the slot's latent rows expanded a tile at a time, a block of scores in
+fast memory; PR 43); the selection that makes the mask is ``jnp``
+(`row_select.index_scores`, `select_rows`).
 
 What the engine's seam asks: `init_params`, `init_kv_cache`,
 `forward_with_cache`, `forward_last_with_cache` (the tick's prefill:
@@ -82,6 +86,7 @@ import jax.numpy as jnp
 from ray_tpu.models.glm_moe_lite import route
 from ray_tpu.ops import apply_rope, mla_decode_attention, rms_norm
 from ray_tpu.ops import row_select
+from ray_tpu.ops.dsa_prefill import dsa_prefill_attention
 from ray_tpu.ops.grouped_experts import grouped_swiglu, split_expert_stacks
 
 Params = Dict[str, Any]
@@ -436,48 +441,6 @@ def _index_parts(h, c_q, layer, positions, cfg: Dots3NoteConfig):
     return q, k, w * (cfg.index_heads * cfg.index_head_dim) ** -0.5
 
 
-def masked_latent_attention(q, rows, keep, layer, g: LatentGeometry,
-                            rows_seen, *, kv_tile: int = 512):
-    """A chunk of queries of ONE slot under a mask over rows. q
-    [T,H,qk] (rotated), rows [S,W] (the slot's cache rows of this
-    layer, the chunk's own written), keep [T,S] bool (every query
-    keeps at least one row) -> [T,H,v] float32. Expanded MLA a tile of
-    rows at a time, online softmax; tiles that begin at or past
-    ``rows_seen`` are not read."""
-    t, h = q.shape[:2]
-    s = rows.shape[0]
-    kv_tile = min(kv_tile, s)
-    if s % kv_tile:
-        raise ValueError(f"tiles of {kv_tile} rows do not divide {s}")
-
-    def tile(i, carry):
-        m, l, acc = carry
-        start = i * kv_tile
-        k_t, v_t = _expand(lax.dynamic_slice_in_dim(rows, start, kv_tile, 0),
-                           layer, g)
-        logits = jnp.einsum("thk,shk->hts", q, k_t,
-                            preferred_element_type=F32) * g.scale
-        mask = lax.dynamic_slice_in_dim(keep, start, kv_tile, axis=1)[None]
-        logits = jnp.where(mask, logits, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(logits, -1, keepdims=True))
-        correction = jnp.exp(m - m_new)
-        p = jnp.where(mask, jnp.exp(logits - m_new), 0.0)
-        l = l * correction + jnp.sum(p, -1, keepdims=True)
-        acc = acc * correction + jnp.einsum(
-            "hts,shv->htv", p.astype(v_t.dtype), v_t,
-            preferred_element_type=F32)
-        return m_new, l, acc
-
-    n_tiles = jnp.minimum(
-        lax.div(jnp.asarray(rows_seen, jnp.int32) + (kv_tile - 1), kv_tile),
-        s // kv_tile)
-    m, l, acc = lax.fori_loop(
-        0, n_tiles, tile,
-        (jnp.full((h, t, 1), NEG_INF, F32), jnp.zeros((h, t, 1), F32),
-         jnp.zeros((h, t, g.v_head_dim), F32)))
-    return (acc / jnp.maximum(l, 1e-30)).transpose(1, 0, 2)
-
-
 def select_rows(scores, positions, cfg: Dots3NoteConfig, rows_seen):
     """scores [T,S] float32 of a chunk's queries at ``positions`` [T],
     no row at or past ``rows_seen`` visible -> [T,S] bool: every row up
@@ -508,13 +471,14 @@ def _full_prefill_block(x, layer, kv_l, ik_l, cache_index, positions,
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     rows_seen = cache_index + x.shape[1]
 
-    def one(q, q_i, w_i, pos, kv_s, ik_s):
+    def choose(q_i, w_i, pos, ik_s):
         scores = row_select.index_scores(q_i, w_i, ik_s, rows_seen)
-        keep = select_rows(scores, pos, cfg, rows_seen)
-        return masked_latent_attention(q, kv_s, keep, layer, g,
-                                       rows_seen), keep
+        return select_rows(scores, pos, cfg, rows_seen)
 
-    attn, keep = jax.vmap(one)(q, q_i, w_i, positions, kv_l, ik_l)
+    keep = jax.vmap(choose)(q_i, w_i, positions, ik_l)
+    attn = dsa_prefill_attention(
+        q, kv_l, keep, layer["w_uk"], layer["w_uv"], rows_seen,
+        scale=g.scale, interpret=cfg.interpret_decode_kernel)
     return _gate_and_out(x, h, attn, layer), kv_l, ik_l, keep
 
 
@@ -771,6 +735,8 @@ def _prefill(params, tokens, cache, cache_index, last, cfg: Dots3NoteConfig):
         "prefill_chunks": jnp.asarray(b, jnp.int32),
         "dsa_queries_selected": cfg.n_full_layers * jnp.sum(
             real + 1 > cfg.index_topk, dtype=jnp.int32),
+        "dsa_prefill_rows_attended": cfg.n_full_layers * jnp.sum(
+            jnp.minimum(real + 1, cfg.index_topk), dtype=jnp.int32),
         "moe_pairs_routed": (cfg.n_moe_layers * cfg.n_experts_per_tok
                              * jnp.asarray(n_real, jnp.int32)),
         "moe_pairs_held": jnp.sum(moe["held"]).astype(jnp.int32)}

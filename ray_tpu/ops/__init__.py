@@ -55,6 +55,14 @@ row_select                 Pallas scoring-and-selection of  on TPU, or ``interpr
                            the scores found with no sort    ``keep``
  .top_rows, .index_scores  (jnp: a chunk's scores tile by   always; the prefill's selection as a mask
                            tile, the exact top-k as a mask)  over rows
+dsa_prefill                Pallas kernel of a CHUNK of      on TPU where the widths are whole lanes, or
+ .dsa_prefill_attention    queries over a slot's latent     ``interpret=True`` off-TPU; jnp twin
+                           rows under a mask over rows:     elsewhere (the same tiles of rows, their
+                           rows expanded to keys and        scores through HBM). Imported by its one
+                           values a tile once a group of    caller (``models/dots3_note.py``); the mask
+                           heads, a block of scores kept    is `row_select`'s selection, so no row the
+                           in fast memory, tiles past the   queries chose is dropped and none added
+                           rows written not read
 grouped_experts            (``lax.ragged_dot`` x 3 between a  always; the chip's compiler has a grouped
  .grouped_swiglu           stable sort by expert and its      matmul for ``ragged_dot``, elsewhere it is
                            inverse: dropless, any k)          a masked dense product. Imported by its

@@ -969,6 +969,27 @@ def test_dsa_select_scores_and_chooses_in_one_kernel(chip):
     assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+@pytest.mark.parametrize("queries", [512, 1024])
+def test_dsa_prefill_attention_compiles_at_the_shorter_buckets(chip, queries):
+    """The full layers' prefill attention at the published sizes and the
+    buckets a prompt's last chunk takes (the tick's whole prefill below
+    holds 2,048): 128 heads over one slot's 32,768 latent rows of 640
+    lanes where they lie, the mask as int8, ONE kernel under its name
+    and no [heads, queries, rows] array beside it."""
+    from ray_tpu.ops.dsa_prefill import dsa_prefill_attention
+
+    c = dsa_prefill_attention.lower(
+        _sds(chip, (1, queries, 128, 192)), _sds(chip, (1, DOTS3_ROWS, 640)),
+        _sds(chip, (1, queries, DOTS3_ROWS), jnp.bool_),
+        _sds(chip, (512, 128, 128)), _sds(chip, (512, 128, 128)),
+        _sds(chip, (), jnp.int32), scale=192 ** -0.5).compile()
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_dsa_prefill_attention")
+    # The padded queries, the mask as int8 and the output: no scores.
+    assert c.memory_analysis().temp_size_in_bytes < queries * (
+        128 * 256 * 2 + DOTS3_ROWS + 128 * 128 * 4) * 1.1
+
+
 def test_mla_decode_attention_compiles_at_the_window_layers_width(chip):
     """The latent kernel at its second geometry: 64 heads over rows of
     1,088 values padded to 1,152 (nine whole lane tiles), the ring of
@@ -1024,10 +1045,12 @@ def test_dots3_decode_chunk_fits_and_updates_its_three_entries_in_place(chip):
 
 def test_dots3_tick_prefill_chunk_fits_beside_the_cache(chip):
     """The tick's prefill at the chunk's size (2,048 queries at any
-    ``cache_index``, each with its own 2,048 rows): the scoring and the
-    masked attention's loops over the slot's rows, one token and the
-    counters out, the three entries aliased and none copied; its
-    temporaries (the score array, the tiles) leave the chip room."""
+    ``cache_index``, each with its own 2,048 rows): the scoring's loop
+    over the slot's rows, the masked attention as ONE kernel under its
+    name (no [128, 2048, 512] float32 tile of scores among the
+    temporaries), one token and the counters out, the three entries
+    aliased and none copied; its temporaries (the score array, the
+    mask) leave the chip room."""
     from ray_tpu.serve.engine.decode_loop import DecodeLoop
 
     cfg, params, cache, held = _dots3_args(chip)
@@ -1041,10 +1064,12 @@ def test_dots3_tick_prefill_chunk_fits_beside_the_cache(chip):
     out = jax.eval_shape(loop.prefill_inplace, *args)
     assert (out[0].shape, out[0].dtype) == ((1,), jnp.int32)
     assert len(out) == 3 and set(out[2]) == {
-        "prefill_chunks", "dsa_queries_selected", "moe_pairs_routed",
-        "moe_pairs_held"}
+        "prefill_chunks", "dsa_queries_selected",
+        "dsa_prefill_rows_attended", "moe_pairs_routed", "moe_pairs_held"}
     text = c.as_text()
     assert " while(" in text and "%ragged-dot-none" in text
+    assert "%rtpu_dsa_prefill_attention." in text
+    assert "f32[1,128,2048,128]" not in text and "f32[128,2048," not in text
     mem = c.memory_analysis()
     assert mem.alias_size_in_bytes >= held - 8_186_107_904
     assert mem.temp_size_in_bytes < 2 * GIB

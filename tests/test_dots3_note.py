@@ -153,26 +153,40 @@ def test_prefill_then_decode_through_the_three_entries(tiny, interpret):
                                    (64, 126, 150)],
                          ids=["three-chunks", "two-chunks", "short-middle",
                               "ring-wrapped"])
-def test_a_prompt_prefilled_in_chunks_equals_one_prefill(tiny, edges):
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["jnp", "pallas-interpret"])
+def test_a_prompt_prefilled_in_chunks_equals_one_prefill(tiny, edges,
+                                                         interpret):
     """The selection past ``index_topk`` and the window both reach back
     across a chunk's edge: through `forward_last_with_cache`, each chunk
     padded to a bucket of 16, 32 or 64, the last row's logits and the
     three entries equal one prefill's; the last case's third chunk
-    wraps the ring of 128 rows inside itself."""
+    wraps the ring of 128 rows inside itself. The chunks with the full
+    layers' attention as the kernel (interpreted) or its jnp twin, the
+    one prefill always the twin: the mask each real query's attention
+    ran under (``seen["rows"]``) and the prefill kernel's count are the
+    same rows either way."""
     c, cfg, params = tiny
     total = edges[-1]
     tokens = _tokens(3, (1, total))
     cache = dots3_note.init_kv_cache(cfg, 1, 192)
-    whole, want, _, _ = _prefill(params, tokens, cache, 0, cfg=cfg)
-    start = 0
+    whole, want, one, seen = _prefill(params, tokens, cache, 0, cfg=cfg)
+    cfg = dataclasses.replace(cfg, interpret_decode_kernel=interpret)
+    start, attended = 0, 0
     for end in edges:
         n = end - start
         bucket = next(b for b in (16, 32, 64) if n <= b)
         padded = jnp.zeros((1, bucket), tokens.dtype).at[:, :n].set(
             tokens[:, start:end])
-        logits, cache, counters, _ = _prefill_last(
+        logits, cache, counters, chunk = _prefill_last(
             params, padded, cache, start, n - 1, cfg=cfg)
+        assert (np.asarray(chunk["rows"][:, :, :n])
+                == np.asarray(seen["rows"][:, :, start:end])).all()
+        attended += int(counters["dsa_prefill_rows_attended"])
         start = end
+    # 2 full layers; a query at position p keeps min(p + 1, 8) rows.
+    assert attended == int(one["dsa_prefill_rows_attended"]) == 2 * sum(
+        min(p + 1, 8) for p in range(total)) == int(seen["rows"].sum())
     np.testing.assert_allclose(logits[0], whole[0, total - 1], **TOL)
     for k in ("kv", "ik"):
         np.testing.assert_allclose(cache[k][:, :, :total],
