@@ -27,9 +27,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 from jax import lax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ray_tpu.ops import (
+    FLASH_SAVED,
     apply_rope,
     causal_attention,
     decode_attention,
@@ -90,10 +92,23 @@ class LlamaConfig:
     # "interpret" = run the kernels under the Pallas interpreter off-TPU
     # (equivalence-test escape hatch); False = the plain unfused ops.
     fused_ops: Any = False
-    # jax.checkpoint policy name: "nothing" = full per-layer remat (lowest
-    # HBM — backward recomputes the block from its input), "dots" = save
-    # non-batch matmul outputs (faster bwd, +O(layers*S*d_ff) HBM).
-    remat_policy: str = "nothing"
+    # jax.checkpoint policy name: what a layer keeps for its backward
+    # beside its input, in bytes of a batch B of S tokens over the whole
+    # mesh (D = d_model, H = n_heads, KH = n_kv_heads, F = d_ff; bf16):
+    # "nothing" = 0: the backward recomputes the whole block;
+    # "attention" = 2·B·S·D·(2 + KH/H) + 4·B·H·S: q and k after rope, the
+    #   flash kernel's output (each with a row's heads side by side, so
+    #   that head size 64 is not padded to 128 lanes) and its row
+    #   statistic (`_SAVED`): the backward runs neither q's and k's
+    #   products and rope nor the forward kernel again. At B 32, S 2048,
+    #   D 2048, H = KH = 32 on four chips 203 MB a layer a device, and
+    #   the step program 15.2 GB for 24 layers where "nothing" is 9.7. A
+    #   user of "nothing" at the memory's limit sets it back. Off the TPU
+    #   there is no kernel, and q and k alone are kept;
+    # "dots" = every matmul's output, 2·B·S·(5·D + 2·F) with KH = H:
+    #   872 MB a layer a device there, 21 GB, which is why it is not
+    #   the default.
+    remat_policy: str = "attention"
     tie_embeddings: bool = False
 
     @property
@@ -202,13 +217,25 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
 
 # Forward ------------------------------------------------------------------
 
-def _remat_policy(cfg: LlamaConfig):
-    if cfg.remat_policy == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    if cfg.remat_policy != "nothing":
+# `checkpoint_name`s that `remat_policy="attention"` keeps: q and k after
+# rope (`_block`) and what the flash kernels' backward reads of their
+# forward (named where the residuals are made, `ops/flash_attention.py`).
+_Q_ROPE, _K_ROPE = "q_rope", "k_rope"
+_SAVED = (_Q_ROPE, _K_ROPE, *FLASH_SAVED)
+
+
+def _remat_policy(cfg):
+    """The `jax.checkpoint` policy ``cfg.remat_policy`` names (Mixtral's
+    and the pipeline's layer bodies ask here too)."""
+    policies = {
+        "nothing": jax.checkpoint_policies.nothing_saveable,
+        "attention": jax.checkpoint_policies.save_only_these_names(*_SAVED),
+        "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    }
+    if cfg.remat_policy not in policies:
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}; "
-                         "expected 'nothing' or 'dots'")
-    return jax.checkpoint_policies.nothing_saveable
+                         f"expected one of {sorted(policies)}")
+    return policies[cfg.remat_policy]
 
 
 
@@ -254,6 +281,13 @@ def _attention_dispatch(q, k, v, q_pos, kv_pos, cfg, mesh: Optional[Mesh],
         return full_causal_attention(q, k, v, mesh=mesh)
     return full_causal_attention(q, k, v, q_positions=q_pos,
                                  kv_positions=kv_pos, mesh=mesh)
+
+
+def _named(x, name: str):
+    """``x`` [B, S, H, D] under a `checkpoint_name`, its heads side by
+    side ([B, S, H·D]): what a policy keeps of it is then not padded to
+    the chip's 128-lane tiles at head size 64."""
+    return checkpoint_name(x.reshape(*x.shape[:2], -1), name).reshape(x.shape)
 
 
 def _tp_ring(seq_len: int, blocks, cfg: LlamaConfig, cache_kv=None) -> bool:
@@ -313,6 +347,7 @@ def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
     k = constrain(k, ("batch", "seq", "kv_heads", None))
     if not ring:
         q, k, v = rope((q, k, v), positions)
+    q, k = _named(q, _Q_ROPE), _named(k, _K_ROPE)
 
     new_kv = None
     if cache_kv is not None:

@@ -47,6 +47,8 @@ class MixtralConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = True
+    # `llama._remat_policy`'s names. Llama's default is "attention"; no
+    # chip run has measured it here, so this one stays.
     remat_policy: str = "nothing"
 
     @property

@@ -89,6 +89,7 @@ interpreter passes kernels the chip's compiler refuses:
 """
 
 from ray_tpu.ops.attention import (
+    FLASH_SAVED,
     blockwise_attention,
     causal_attention,
     full_causal_attention,
@@ -121,6 +122,7 @@ from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rotary import apply_rope, rope_frequencies
 
 __all__ = [
+    "FLASH_SAVED",
     "apply_rope",
     "blockwise_attention",
     "causal_attention",

@@ -213,6 +213,15 @@ def flash_block_sizes(hd: int, sq: int):
         block_q_dq=tile, block_k_major_dq=major_dq, block_k_dq=tile)
 
 
+# `checkpoint_name`s of what the flash backward kernels read of their
+# forward: its output and its row statistic, named where the custom
+# VJP's residuals are made (`ops/flash_attention.py`). A `jax.checkpoint`
+# policy that keeps them runs no forward kernel in the backward pass
+# (`models/llama.py` `_remat_policy`). Here, not beside the kernels, so
+# that naming them imports no Pallas.
+FLASH_SAVED = ("flash_out", "flash_lse")
+
+
 def _flash_attention(q, k, v, scale: float):
     """The Pallas flash kernels on [B,S,H|KH,D] operands (GQA heads
     repeated here, so under `shard_map` only un-repeated KV crosses the

@@ -13,12 +13,16 @@ itself (`_causal_regions`): one wholly above it is not computed, one
 wholly below it is computed without a mask, and the major blocks stay
 large (K and V of a head resident, few grid steps).
 
-The forward leaves ONE statistic a row, ``m + log l``, 128 lanes wide,
-and the backward takes it and ``di`` as they are: nothing is sliced to a
-column and broadcast again between the calls (the library's dq wrapper
-writes ``di`` out ``block_k_major`` lanes wide: 2.1 GB a call at the
-train cell's shapes). Products take the operands' dtype and accumulate
-in float32; the softmax is float32 throughout.
+The forward leaves ONE statistic a row, ``m + log l``, as a
+``[B, H, 1, S]`` float32 row (4 bytes a query: it is what a train step
+KEEPS a layer beside ``o``, `FLASH_SAVED`), and the backward kernels
+take it as it is and turn their block of it into a column in fast
+memory, as they make ``di`` there: nothing is sliced or broadcast in
+HBM between the calls (the library's dq wrapper writes ``di`` out
+``block_k_major`` lanes wide: 2.1 GB a call at the train cell's
+shapes; 128 lanes of the statistic were 268 MB). Products take the
+operands' dtype and accumulate in float32; the softmax is float32
+throughout.
 
 Operands are [batch, heads, seq, head_dim]; `ops/attention.py` owns the
 layout, the block choice and the dispatch. The trace names are
@@ -35,13 +39,26 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.attention import FLASH_SAVED
 
 LANES = 128
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
+
+
+def _as_row(x):
+    """A [rows, 128] lane-replicated statistic as a [1, rows] row."""
+    return x.T[:1]
+
+
+def _as_column(x):
+    """A [1, rows] row as a [rows, 128] lane-replicated statistic."""
+    return jnp.broadcast_to(x, (LANES, x.shape[1])).T
 
 
 def _lanes(x, n: int):
@@ -158,17 +175,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float,
         l = l_sc[...]
         o_ref[0, 0] = (acc_sc[...] / _lanes(l, d)).astype(o_ref.dtype)
         if lse_ref:
-            lse_ref[0][0, 0] = m_sc[...] + jnp.log(l)
+            lse_ref[0][0, 0] = _as_row(m_sc[...] + jnp.log(l))
 
 
-def _backward_rows(q_ref, o_ref, do_ref, rows, q_scale: float):
-    """(q, do, di) of a block of rows; ``di`` = sum(o * do) a row, the
-    softmax gradient's correction, as a column (it needs no pass of its
-    own over o and do in HBM, nor 128 lanes of it written out)."""
+def _backward_rows(q_ref, lse_ref, o_ref, do_ref, rows, q_scale: float):
+    """(q, do, di, lse) of a block of rows; ``di`` = sum(o * do) a row,
+    the softmax gradient's correction, as a column (it needs no pass of
+    its own over o and do in HBM, nor 128 lanes of it written out), and
+    the forward's statistic turned from its row into 128 lanes."""
     o, do = o_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
     di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=1,
                  keepdims=True)
-    return _scaled(q_ref[0, 0, rows, :], q_scale), do, di
+    return (_scaled(q_ref[0, 0, rows, :], q_scale), do, di,
+            _as_column(lse_ref[0, 0, :, rows]))
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, lse_ref, o_ref, do_ref, dq_ref, dq_sc, *,
@@ -184,11 +203,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, lse_ref, o_ref, do_ref, dq_ref, dq_sc, *,
     q_scale, s_scale = _split_scale(scale)
 
     def tile(_, start, offset, rows):
-        q, do, di = rows
+        q, do, di, lse = rows
         k = k_ref[0, 0, pl.ds(start, block_k), :]
         v = v_ref[0, 0, pl.ds(start, block_k), :]
         s = _scores(q, k, s_scale, offset)
-        p = jnp.exp(s - _lanes(lse_ref[0, 0], block_k))
+        p = jnp.exp(s - _lanes(lse, block_k))
         dp = lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
         ds = (dp - di) * p
         dq_sc[...] += lax.dot(ds.astype(k.dtype), k,
@@ -196,8 +215,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, lse_ref, o_ref, do_ref, dq_ref, dq_sc, *,
 
     _causal_regions(
         qi * bq - ki * bkm, seq, bq, bkm, _row_tiles(bq, bkm, block_k),
-        lambda _: _backward_rows(q_ref, o_ref, do_ref, slice(None), q_scale),
-        tile)
+        lambda _: _backward_rows(q_ref, lse_ref, o_ref, do_ref, slice(None),
+                                 q_scale), tile)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _store():
@@ -220,12 +239,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, lse_ref, o_ref, do_ref, dk_ref, dv_ref,
     q_scale, s_scale = _split_scale(scale)
 
     def tile(q_start, k_start, offset, rows):
-        q, do, di = rows
+        q, do, di, lse = rows
         cols = pl.ds(k_start, block_k)
         k, v = k_ref[0, 0, cols, :], v_ref[0, 0, cols, :]
         s = _scores(q, k, s_scale, offset)
-        p = jnp.exp(s - _lanes(
-            lse_ref[0, 0, pl.ds(q_start, block_q), :], block_k))
+        p = jnp.exp(s - _lanes(lse, block_k))
         dv_sc[cols, :] += lax.dot(p.T.astype(do.dtype), do,
                                   preferred_element_type=jnp.float32)
         dp = lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
@@ -239,7 +257,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, lse_ref, o_ref, do_ref, dk_ref, dv_ref,
          for q_start in range(0, bqm, block_q)
          for k_start in range(0, bkm, block_k)],
         lambda q_start: _backward_rows(
-            q_ref, o_ref, do_ref, pl.ds(q_start, block_q), q_scale), tile)
+            q_ref, lse_ref, o_ref, do_ref, pl.ds(q_start, block_q), q_scale),
+        tile)
 
     @pl.when(qi == pl.num_programs(3) - 1)
     def _store():
@@ -265,9 +284,12 @@ def _row_specs(bq: int, bkm: int, d: int):
     def q_map(bi, hi, qi, ki):
         return bi, hi, qi, 0
 
+    def stat_map(bi, hi, qi, ki):
+        return bi, hi, 0, qi
+
     return (pl.BlockSpec((1, 1, bq, d), q_map),
             pl.BlockSpec((1, 1, bkm, d), kv_map),
-            pl.BlockSpec((1, 1, bq, LANES), q_map))
+            pl.BlockSpec((1, 1, 1, bq), stat_map))
 
 
 def _call(kernel, scope, grid, in_specs, out_specs, out_shape, scratch,
@@ -302,8 +324,7 @@ def _forward(q, k, v, scale, blocks, interpret, residuals: bool):
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     if residuals:
         out_specs.append(lm_spec)
-        out_shape.append(
-            jax.ShapeDtypeStruct((b, h, s, LANES), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32))
     out = _call(
         functools.partial(_fwd_kernel, scale=scale, block_k=bk, seq=s),
         None, (b, h, s // bq, s // bkm),
@@ -342,12 +363,15 @@ def _backward_dkv(q, k, v, lse, o, do, scale, blocks, interpret):
         # block of rows that does is read once and waited on.
         return bi, hi, jnp.maximum(qi, (ki * bkm) // bqm), 0
 
+    def stat_map(bi, hi, ki, qi):
+        return bi, hi, 0, q_map(bi, hi, ki, qi)[2]
+
     def kv_map(bi, hi, ki, qi):
         return bi, hi, ki, 0
 
     q_spec = pl.BlockSpec((1, 1, bqm, d), q_map)
     kv_spec = pl.BlockSpec((1, 1, bkm, d), kv_map)
-    lm_spec = pl.BlockSpec((1, 1, bqm, LANES), q_map)
+    lm_spec = pl.BlockSpec((1, 1, 1, bqm), stat_map)
     dkv = jax.ShapeDtypeStruct(k.shape, k.dtype)
     return _call(
         functools.partial(_dkv_kernel, scale=scale, block_q=bq, block_k=bk,
@@ -367,6 +391,18 @@ def _flash_mha(q, k, v, scale, blocks, interpret):
 
 def _flash_mha_fwd(q, k, v, scale, blocks, interpret):
     o, lse = _forward(q, k, v, scale, blocks, interpret, residuals=True)
+    # The output is named with a row's heads side by side, [B, S, H·D]
+    # (the layout the caller wants it in anyway): kept as the kernel's
+    # [B, H, S, D] it would take twice its bytes at head size 64, the
+    # chip's tiles being 128 lanes wide. The residuals ARE the named
+    # values, so a policy that keeps them feeds the backward kernels
+    # from what it kept; with no policy the transposes cancel.
+    b, h, s, d = o.shape
+    out_name, lse_name = FLASH_SAVED
+    kept = checkpoint_name(o.transpose(0, 2, 1, 3).reshape(b, s, h * d),
+                           out_name)
+    lse = checkpoint_name(lse, lse_name)
+    o = kept.reshape(b, s, h, d).transpose(0, 2, 1, 3)
     return o, (q, k, v, o, lse)
 
 

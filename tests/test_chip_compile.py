@@ -446,17 +446,9 @@ def _computations(text: str):
                                               text)]
 
 
-def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip):
-    """Real width, 2 layers, on a mesh of the four described chips: the
-    flash kernel must sit inside a shard_map (XLA cannot partition a
-    Mosaic kernel), and the fsdp/tp collectives must be there.
-
-    The tp ring's engagement is decided at compile time, so its witness
-    is the program's text (`parallel/collective_matmul.py`): a layer's
-    tensor-parallel sums are ring hops of half the residual stream, each
-    with a product between its start and its done, and no blocking
-    collective of the whole stream is left in a layer body."""
-    cfg = dataclasses.replace(_CFG_1B, n_layers=2)
+def _fsdp2_tp2_loss_and_grad(topo, cfg, batch):
+    """(compiled loss-and-gradient on the four described chips, the
+    parameters' shapes)."""
     mesh = mesh_2d(4, tp=2, devices=list(topo.devices))
     assert dict(mesh.shape)["fsdp"] == 2 and dict(mesh.shape)["tp"] == 2
     shapes = jax.eval_shape(functools.partial(llama.init_params, cfg),
@@ -465,7 +457,7 @@ def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip):
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         shapes, param_shardings(mesh, llama.param_logical_axes(cfg)))
     tokens = jax.ShapeDtypeStruct(
-        (BATCH, SEQ), jnp.int32,
+        (batch, SEQ), jnp.int32,
         sharding=NamedSharding(mesh, P(("dp", "fsdp"), "sp")))
 
     def loss(p, t):
@@ -473,9 +465,40 @@ def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip):
 
     with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         c = jax.jit(jax.value_and_grad(loss)).lower(params, tokens).compile()
+    return c, shapes
+
+
+# policy: (Mosaic kernels, ring hops) a layer body pair. "nothing":
+# forward, the remat's forward again, dq and dkv; 4 hops forward
+# (q/k/v, wo, gate/up, w_down) + 7 backward, the remat's first three
+# again and the four transposes (one travelling copy serves q, k and v
+# in both directions). "attention" keeps q and k after rope, the
+# kernel's output and its statistic: the backward runs the forward
+# kernel no second time, and of the remat's q/k/v ring v's product
+# alone (the hop stays: the transposed products need the other
+# shard's rows of the normed stream, which nobody kept).
+_REMAT_PROGRAMS = {"attention": (3, 11), "nothing": (4, 11)}
+
+
+@pytest.mark.parametrize("policy", _REMAT_PROGRAMS)
+def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip,
+                                                           policy):
+    """Real width, 2 layers, on a mesh of the four described chips: the
+    flash kernel must sit inside a shard_map (XLA cannot partition a
+    Mosaic kernel), and the fsdp/tp collectives must be there.
+
+    The tp ring's engagement is decided at compile time, so its witness
+    is the program's text (`parallel/collective_matmul.py`): a layer's
+    tensor-parallel sums are ring hops of half the residual stream, each
+    with a product between its start and its done, and no blocking
+    collective of the whole stream is left in a layer body. So is what
+    the remat policy keeps: the kernels and hops left in the backward."""
+    assert llama.LlamaConfig().remat_policy == "attention"
+    cfg = dataclasses.replace(_CFG_1B, n_layers=2, remat_policy=policy)
+    kernels, ring_hops = _REMAT_PROGRAMS[policy]
+    c, shapes = _fsdp2_tp2_loss_and_grad(topo, cfg, BATCH)
     text = c.as_text()
-    # Forward, the remat's forward again, dq and dkv.
-    assert text.count("tpu_custom_call") == 4
+    assert text.count("tpu_custom_call") == kernels
     for collective in ("all-gather", "all-reduce"):
         assert re.search(rf"\b{collective}(-start)?\(", text), collective
     # Each device holds a quarter of the weights, not the model.
@@ -512,13 +535,50 @@ def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip):
             assert not re.search(
                 rf"= {re.escape(stream)}\S* (all-reduce|all-gather)"
                 r"(-start)?\(", line), line
-    # 4 forward (q/k/v, wo, gate/up, w_down) + 7 backward: the remat's
-    # first three again and the four transposes. One travelling copy
-    # serves q, k and v in both directions.
-    assert hops == text.count("collective-permute-start(") == 11
+    assert hops == text.count("collective-permute-start(") == ring_hops
     # The embedding is looked up through its vocab shards, never gathered.
     assert not re.search(
         rf"= bf16\[{cfg.vocab_size},{cfg.d_model}\]\S* all-gather", text)
+
+
+def _program_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def test_attention_policy_keeps_four_arrays_a_layer_a_device(topo, chip):
+    """`smollm2.sft.fsdp2tp2`'s widths, depth and a device's share of
+    its batch (32 x 2,048 over fsdp 2): what the default policy holds
+    over "nothing" is, a layer, q, k and the kernel's output with a
+    row's heads side by side (bf16 [16, 2048, 1024], 67 MB each: as
+    [16, 16, 2048, 64] the chip's 128-lane tiles would make each 134)
+    and the statistic as ONE float32 a row (2 MB, not 268 MB of
+    lanes)."""
+    cfg = dataclasses.replace(_CFG_1B, n_layers=24, n_kv_heads=32,
+                              vocab_size=49152, tie_embeddings=True)
+    held, stacked = {}, {}
+    for policy in ("attention", "nothing"):
+        c, _ = _fsdp2_tp2_loss_and_grad(
+            topo, dataclasses.replace(cfg, remat_policy=policy), 32)
+        held[policy] = _program_bytes(c)
+        # What the forward's layer loop stacks for the backward's.
+        loop = next(l for l in c.as_text().split("\n") if " while(" in l)
+        stacked[policy] = sorted(
+            s for s in re.findall(r"\w+\[24,[\d,]+\]", loop.split(" while(")[0])
+            if s.count(",") >= 3 and "2048" in s)
+    stream, named, stat = ("bf16[24,16,1024,2048]", "bf16[24,16,2048,1024]",
+                           "f32[24,16,16,1,2048]")
+    assert stacked["nothing"] == [stream]
+    assert stacked["attention"] == sorted([stream, named, named, named, stat])
+    # The whole program grows by what is kept and by a sixth more: the
+    # compiler's working set of the layer in flight is another one
+    # (237 MB a layer where 203 are kept, and a padded stack would be
+    # 404).
+    a_layer = (held["attention"] - held["nothing"]) / 24
+    kept = 3 * 16 * 2048 * 1024 * 2 + 16 * 16 * 2048 * 4
+    assert kept == 203_423_744
+    assert kept <= a_layer < 1.25 * kept, (a_layer, kept)
 
 
 # ------------------------------------- the shard_map seam, numerically

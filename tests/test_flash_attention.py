@@ -18,8 +18,10 @@ import pytest
 from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
 
 from ray_tpu.ops import attention
-from ray_tpu.ops.attention import causal_attention, flash_block_sizes
-from ray_tpu.ops.flash_attention import LANES, flash_attention
+from ray_tpu.ops.attention import (FLASH_SAVED, causal_attention,
+                                   flash_block_sizes)
+from ray_tpu.ops.flash_attention import (LANES, _flash_mha_fwd,
+                                         flash_attention)
 
 HEAD_SIZES, SEQS = (64, 128, 256), (256, 512, 1024, 2048, 4096)
 
@@ -133,3 +135,53 @@ def test_a_block_off_the_tiling_is_refused_by_name(block):
     bad = _blocks((block, block, block), (128,) * 4, (128,) * 3)
     with pytest.raises(ValueError, match="block_q"):
         flash_attention(q, k, v, scale, bad, True)
+
+
+# ----------------------------------- what the backward reads, and keeps
+
+@pytest.mark.parametrize("case", ["hd64_wide_tiles", "hd128_tall_tiles"])
+def test_the_row_statistic_is_one_float_a_row(case):
+    """The forward leaves ``log sum exp`` of a row's visible scores as a
+    [B, H, 1, S] row (the kernel turns its 128 equal lanes into it):
+    what a train step keeps a layer is 4 bytes a query."""
+    q, k, v, _, scale, blocks = _operands(case)
+    o, (_, _, _, kept, lse) = _flash_mha_fwd(q, k, v, scale, blocks, True)
+    b, h, s, _ = q.shape
+    assert kept is o and (lse.shape, lse.dtype) == ((b, h, 1, s), jnp.float32)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    visible = jnp.tril(jnp.ones((s, s), bool))
+    want = jax.nn.logsumexp(jnp.where(visible, scores, -jnp.inf), axis=-1)
+    assert jnp.allclose(lse[:, :, 0], want, atol=2e-5)
+
+
+def pallas_kernels(jaxpr) -> list:
+    """The kernel function's name of every `pallas_call` in a jaxpr and
+    the jaxprs its equations hold (`tests/test_model_llama.py` counts a
+    layer's with it too)."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["jaxpr"].debug_info.func_name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += pallas_kernels(sub)
+    return names
+
+
+@pytest.mark.parametrize("policy,forwards", [
+    (jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED), 1),
+    (jax.checkpoint_policies.nothing_saveable, 2)], ids=["named", "nothing"])
+def test_keeping_the_named_residuals_runs_no_forward_kernel_again(
+        policy, forwards):
+    """The custom VJP's residuals ARE the values it names: a checkpoint
+    that keeps the two names feeds dkv and dq from what it kept, and
+    the gradients are the ones of no checkpoint at all, to the bit."""
+    q, k, v, w, scale, blocks = _operands("chosen_hd64")
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, scale, blocks, True) * w)
+
+    kept = jax.grad(jax.checkpoint(loss, policy=policy), argnums=(0, 1, 2))
+    assert sorted(pallas_kernels(jax.make_jaxpr(kept)(q, k, v).jaxpr)) == sorted(
+        ["_fwd_kernel"] * forwards + ["_dkv_kernel", "_dq_kernel"])
+    for g, r in zip(kept(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)):
+        assert jnp.array_equal(g, r)

@@ -13,6 +13,7 @@ from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel import spmd
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+from tests.test_flash_attention import pallas_kernels
 
 
 @pytest.fixture(scope="module")
@@ -174,3 +175,78 @@ def test_fused_kernel_gate_covers_llama_head_dims():
     assert not use_fused_kernel(True, False, 2048, 64)    # packed positions
     assert not use_fused_kernel(False, True, 2048, 64)    # CPU
     assert not use_fused_kernel(True, True, 2048, 192)    # unpadded mid dim
+
+
+# ------------------------------------------------- what the remat keeps
+
+REMAT_CASES = {"default": {}, "nothing": {"remat_policy": "nothing"},
+               "no_remat": {"remat": False}}
+
+
+@pytest.fixture
+def flash_on_cpu(monkeypatch):
+    """The flash kernels under the Pallas interpreter where the chip
+    would run them (`ops/attention.py` asks the backend, and imports
+    the kernels' entry at each call)."""
+    from ray_tpu.ops import attention, flash_attention as kernels
+
+    monkeypatch.setattr(attention, "use_fused_kernel",
+                        lambda on_tpu, standard, sq, d: standard)
+    monkeypatch.setattr(
+        kernels, "flash_attention",
+        lambda q, k, v, scale, blocks: kernels._flash_mha(
+            q, k, v, scale, blocks, True))
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _remat_cfg(case: str) -> llama.LlamaConfig:
+    return llama.tiny_config(d_model=128, n_heads=2, n_kv_heads=1,
+                             max_seq_len=256, **{"remat": True,
+                                                 **REMAT_CASES[case]})
+
+
+def _loss_and_grad(cfg, differentiate=jax.value_and_grad):
+    params = llama.init_params(cfg, jax.random.key(3))
+    tokens = jax.random.randint(jax.random.key(4), (2, 256), 0,
+                                cfg.vocab_size)
+    return differentiate(lambda p: llama.loss_fn(p, tokens, cfg)[0]), params
+
+
+def test_default_policy_keeps_the_attention_names():
+    assert llama.LlamaConfig().remat_policy == "attention"
+    assert llama._SAVED == ("q_rope", "k_rope", "flash_out", "flash_lse")
+
+
+@pytest.mark.parametrize("case", ["nothing", "no_remat"])
+def test_what_the_remat_keeps_changes_no_bit(flash_on_cpu, case):
+    """Saved and recomputed values come from the same operations: the
+    loss and every gradient leaf of the default policy equal full
+    recomputation's and no recomputation's."""
+    f, params = _loss_and_grad(_remat_cfg("default"))
+    loss, grads = jax.jit(f)(params)
+    f, params = _loss_and_grad(_remat_cfg(case))
+    other_loss, other = jax.jit(f)(params)
+    assert jnp.isfinite(loss) and loss == other_loss
+    same = jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)), grads,
+                        other)
+    assert all(jax.tree.leaves(same)), same
+
+
+@pytest.mark.parametrize("case,forwards", [("default", 1), ("nothing", 2)])
+def test_forward_kernels_in_a_layers_gradient(flash_on_cpu, case, forwards):
+    """The layers are one scanned body: under the default policy the
+    gradient's program holds the forward kernel once (the forward
+    pass's; the backward reads what was kept), under "nothing" twice."""
+    f, params = _loss_and_grad(_remat_cfg(case), jax.grad)
+    kernels = pallas_kernels(jax.make_jaxpr(f)(params).jaxpr)
+    assert sorted(kernels) == sorted(
+        ["_fwd_kernel"] * forwards + ["_dkv_kernel", "_dq_kernel"])
+
+
+def test_unknown_remat_policy_is_refused_by_name():
+    cfg = llama.tiny_config(remat=True, remat_policy="everything")
+    f, params = _loss_and_grad(cfg)
+    with pytest.raises(ValueError, match="unknown remat_policy 'everything'"):
+        f(params)

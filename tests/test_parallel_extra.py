@@ -69,6 +69,32 @@ def test_pipeline_train_step_decreases_loss(pp2_mesh):
     assert losses[-1] < losses[0], losses
 
 
+def test_pipeline_stage_body_differentiates_under_the_default_policy(
+        pp2_mesh):
+    """The stage body takes `LlamaConfig.remat_policy`'s default through
+    `llama._remat_policy`: keeping the named q and k under the rotor's
+    `vmap` changes no gradient."""
+    pcfg = pipeline.PipelineConfig(stages=2, microbatches=4)
+    tokens = jnp.asarray(
+        np.random.default_rng(2).integers(0, 256, (4, 32)), jnp.int32)
+
+    def grads(**remat):
+        cfg = llama.tiny_config(n_layers=4, **remat)
+        staged = pipeline.stage_params(
+            llama.init_params(cfg, jax.random.PRNGKey(0)), 2)
+        with mesh_context(pp2_mesh):
+            return jax.jit(jax.grad(
+                lambda p: pipeline.pipeline_loss_fn(
+                    p, tokens, cfg, pcfg, mesh=pp2_mesh)[0]))(staged)
+
+    assert llama.tiny_config(remat=True).remat_policy == "attention"
+    kept, plain = grads(remat=True), grads(remat=False)
+    for name, g in kept["blocks"].items():
+        assert jnp.isfinite(g).all() and jnp.any(g != 0), name
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=1e-5, atol=1e-6), kept, plain)
+
+
 def test_pipeline_validation_errors():
     cfg = llama.tiny_config(n_layers=4)
     with pytest.raises(ValueError, match="not divisible"):
