@@ -253,14 +253,11 @@ def _bench_8b_proxy(devices, kind: str) -> dict:
                        f"{last_err!r:.300}")
 
 
-def _bench_decode(quantize: str = None, paged: bool = False) -> dict:
+def _bench_decode(quantize: str = None) -> dict:
     """Steady-state decode throughput of the native LLM engine
     (``quantize="int8"`` measures the weight-only-quantized engine on
     the identical workload — the decode path is weight-bandwidth bound,
-    so halving the weight bytes is the headline lever; ``paged=True``
-    routes decode attention through the paged block-table kernel,
-    which streams only the pages covering each sequence's valid rows
-    instead of the whole cache extent)."""
+    so halving the weight bytes is the headline lever)."""
     import threading
 
     import numpy as np
@@ -268,13 +265,12 @@ def _bench_decode(quantize: str = None, paged: bool = False) -> dict:
     from ray_tpu.models import llama
     from ray_tpu.serve.llm import LLMEngine
 
-    cfg = dataclasses.replace(llama.LLAMA3_1B, max_seq_len=512,
-                              use_decode_kernel=True)
+    cfg = dataclasses.replace(llama.LLAMA3_1B, max_seq_len=512)
     max_batch, new_tokens, seconds = 8, 48, 8.0
     # decode_chunk=8: one host sync per 8 tokens.
     engine = LLMEngine(cfg, max_batch=max_batch, max_len=256,
                        prompt_buckets=[32], decode_chunk=8,
-                       quantize=quantize, paged_decode=paged)
+                       quantize=quantize)
     rng = np.random.default_rng(0)
 
     hi = min(1000, cfg.vocab_size - 1)
@@ -308,8 +304,7 @@ def _bench_decode(quantize: str = None, paged: bool = False) -> dict:
     if client_errors and not sum(counts):
         raise RuntimeError(f"all decode clients failed: {client_errors[0]}")
     tps = sum(counts) / elapsed
-    metric = ("llm_decode_tokens_per_s_paged" if paged
-              else "llm_decode_tokens_per_s_int8" if quantize == "int8"
+    metric = ("llm_decode_tokens_per_s_int8" if quantize == "int8"
               else "llm_decode_tokens_per_s")
     row = {"metric": metric, "value": round(tps, 1),
            "unit": "tokens/s",
@@ -317,8 +312,6 @@ def _bench_decode(quantize: str = None, paged: bool = False) -> dict:
            "max_batch": max_batch}
     if quantize:
         row["quantize"] = quantize
-    if paged:
-        row["paged_decode"] = True
     if client_errors:
         # Dead clients deflate throughput: a plausible-but-wrong number
         # must carry the evidence (module invariant).
@@ -342,8 +335,7 @@ def _bench_engine() -> dict:
     from ray_tpu.models import llama
     from ray_tpu.serve.llm import LLMEngine
 
-    cfg = dataclasses.replace(llama.LLAMA3_1B, max_seq_len=512,
-                              use_decode_kernel=True)
+    cfg = dataclasses.replace(llama.LLAMA3_1B, max_seq_len=512)
     max_batch, new_tokens, seconds = 8, 48, 8.0
     # Build THIS engine under the RTPU_DEBUG_JAX witness: the row
     # records the steady-state compiled-program counts (program creep =
@@ -463,8 +455,7 @@ def _bench_engine_spec() -> list:
     from ray_tpu.models import llama
     from ray_tpu.serve.llm import LLMEngine
 
-    cfg = dataclasses.replace(llama.LLAMA3_1B, max_seq_len=512,
-                              use_decode_kernel=True)
+    cfg = dataclasses.replace(llama.LLAMA3_1B, max_seq_len=512)
     max_batch, new_tokens, seconds = 8, 160, 8.0
     # A constant-token prompt is the distilled repetitive workload: the
     # generation locks into repetition loops the drafter tracks.
@@ -555,8 +546,7 @@ def _bench_engine_mixed() -> list:
     from ray_tpu.models import llama
     from ray_tpu.serve.llm import LLMEngine
 
-    cfg = dataclasses.replace(llama.LLAMA3_1B, max_seq_len=512,
-                              use_decode_kernel=True)
+    cfg = dataclasses.replace(llama.LLAMA3_1B, max_seq_len=512)
     seconds = 8.0
     long_prompt_len, decode_new = 200, 48
     rng = np.random.default_rng(3)
@@ -651,12 +641,10 @@ def _bench_engine_mixed() -> list:
 
 def engine_child_main() -> None:
     """Standalone engine suite (``bench.py --engine``): engine row, the
-    paged-decode row, the speculative-decoding on/off pair, and the
-    mixed long-prompt sweep (chunked prefill on/off), one JSON row
-    each."""
+    speculative-decoding on/off pair, and the mixed long-prompt sweep
+    (chunked prefill on/off), one JSON row each."""
     _chip_devices()
     print(json.dumps(_bench_engine()), flush=True)
-    print(json.dumps(_bench_decode(paged=True)), flush=True)
     for row in _bench_engine_spec():
         print(json.dumps(row), flush=True)
     for row in _bench_engine_mixed():
@@ -843,13 +831,6 @@ def child_main() -> None:
         row_q["speedup_vs_f32"] = round(
             row_q["value"] / row_dec["value"], 3)
     print(json.dumps(row_q), flush=True)
-
-    # --- row 3c: same decode workload, paged block-table kernel --------
-    row_p = _bench_decode(paged=True)
-    if row_dec.get("value") and row_p.get("value"):
-        row_p["speedup_vs_unpaged"] = round(
-            row_p["value"] / row_dec["value"], 3)
-    print(json.dumps(row_p), flush=True)
 
     # --- row 4: engine suite (decode + TTFT + prefix-cache) -------------
     print(json.dumps(_bench_engine()), flush=True)
@@ -3396,11 +3377,6 @@ def main() -> int:
     if "error" not in decq and decq.get("value"):
         merged["llm_decode_tokens_per_s_int8"] = decq.get("value")
         merged["llm_decode_int8_speedup"] = decq.get("speedup_vs_f32")
-    decp = by_metric.get("llm_decode_tokens_per_s_paged", {})
-    if "error" not in decp and decp.get("value"):
-        merged["llm_decode_tokens_per_s_paged"] = decp.get("value")
-        merged["llm_decode_paged_speedup"] = \
-            decp.get("speedup_vs_unpaged")
     ops_merged = _merge_ops_rows(
         [r for r in rows if r.get("metric") in ("ops_microbench",
                                                 "decode_matmul_gbps")])

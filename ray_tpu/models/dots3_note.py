@@ -98,9 +98,6 @@ FULL, SLIDING = "full_attention", "sliding_attention"
 # reason; `InferenceEngine` refuses them at construction.
 ENGINE_REFUSES = {
     "quantize": "models/quant.py quantizes llama's weight tree only",
-    "paged_decode": "ops/paged_decode.py reads K and V pages of one "
-                    "width; here are latent rows of two widths, index "
-                    "keys and a ring",
     "spec_draft_len": "verify_chunk vmaps forward_with_cache over llama's "
                       "{k, v} cache, and a rejected draft has already "
                       "overwritten a ring row",
@@ -177,7 +174,7 @@ class Dots3NoteConfig:
     dtype: Any = jnp.bfloat16
     # Run the decode kernel under the Pallas interpreter off the TPU
     # (tests); otherwise the kernel on the TPU, its jnp reference off it.
-    interpret_decode_kernel: bool = False
+    interpret_kernels: bool = False
 
     def __post_init__(self):
         if any(k not in (FULL, SLIDING) for k in self.layer_types):
@@ -478,7 +475,7 @@ def _full_prefill_block(x, layer, kv_l, ik_l, cache_index, positions,
     keep = jax.vmap(choose)(q_i, w_i, positions, ik_l)
     attn = dsa_prefill_attention(
         q, kv_l, keep, layer["w_uk"], layer["w_uv"], rows_seen,
-        scale=g.scale, interpret=cfg.interpret_decode_kernel)
+        scale=g.scale, interpret=cfg.interpret_kernels)
     return _gate_and_out(x, h, attn, layer), kv_l, ik_l, keep
 
 
@@ -603,12 +600,12 @@ def _full_decode_block(x, layer, idx, kv, ik, lengths, live,
     keep = row_select.select_decode_rows(
         q_i[:, 0], w_i[:, 0], ik,
         jnp.where(live, lengths, -1).astype(jnp.int32), layer=idx,
-        k=cfg.index_topk, interpret=cfg.interpret_decode_kernel)
+        k=cfg.index_topk, interpret=cfg.interpret_kernels)
     o_lat = mla_decode_attention(
         _absorbed_queries(q_nope[:, 0], q_rope[:, 0], layer, g), kv,
         jnp.where(live, lengths + 1, 0).astype(jnp.int32), layer=idx,
         v_dim=g.kv_lora_rank, scale=g.scale, keep=keep,
-        interpret=cfg.interpret_decode_kernel,
+        interpret=cfg.interpret_kernels,
         name="rtpu_dsa_decode_attention")
     o = jnp.einsum("bhr,rhv->bhv", o_lat, layer["w_uv"])
     return (_gate_and_out(x, h, o[:, None], layer), kv, ik,
@@ -639,7 +636,7 @@ def _sliding_decode_block(x, layer, idx, win, lengths, live,
         _absorbed_queries(q_nope[:, 0], q_rope[:, 0], layer, g), win,
         jnp.where(live, ring, 0).astype(jnp.int32), layer=idx,
         v_dim=g.kv_lora_rank, scale=g.scale, keep=keep, block_s=ring,
-        interpret=cfg.interpret_decode_kernel,
+        interpret=cfg.interpret_kernels,
         name="rtpu_swa_decode_attention")
     o = jnp.einsum("bhr,rhv->bhv", o_lat, layer["w_uv"])
     return (_gate_and_out(x, h, o[:, None], layer), win,

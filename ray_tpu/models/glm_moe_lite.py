@@ -83,9 +83,6 @@ F32 = jnp.float32
 # reason; `InferenceEngine` refuses them at construction.
 ENGINE_REFUSES = {
     "quantize": "models/quant.py quantizes llama's weight tree only",
-    "paged_decode": "ops/paged_decode.py reads K and V pages of one "
-                    "width; the latent cache is one array whose value is "
-                    "part of its key",
     "spec_draft_len": "verify_chunk vmaps forward_with_cache over llama's "
                       "{k, v} cache",
     "role": "export_page/install_page carry k_page and v_page",
@@ -122,7 +119,7 @@ class GlmMoeLiteConfig:
     dtype: Any = jnp.bfloat16
     # Run the decode kernel under the Pallas interpreter off the TPU
     # (tests); otherwise the kernel on the TPU, its jnp reference off it.
-    interpret_decode_kernel: bool = False
+    interpret_kernels: bool = False
 
     def __post_init__(self):
         if self.qk_head_dim != self.v_head_dim:
@@ -373,7 +370,7 @@ def _decode_block(x, layer, moe, layer_idx, cache, lengths,
     o_lat = mla_decode_attention(
         q, cache, (lengths + 1).astype(jnp.int32), layer=layer_idx,
         v_dim=cfg.kv_lora_rank, scale=cfg.attn_scale,
-        interpret=cfg.interpret_decode_kernel)
+        interpret=cfg.interpret_kernels)
     o = jnp.einsum("bhr,rhv->bhv", o_lat, layer["w_uv"])
     x = x + jnp.einsum("bhv,hvd->bd", o, layer["w_o"])[:, None].astype(x.dtype)
     h = rms_norm(x, layer["ln_mlp"], cfg.norm_eps)

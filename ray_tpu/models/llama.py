@@ -35,14 +35,12 @@ from ray_tpu.ops import (
     apply_rope,
     causal_attention,
     decode_attention,
-    decode_attention_reference,
     decode_step_rows,
     full_causal_attention,
     fused_qk_rope,
     fused_rms_norm,
     fused_rms_norm_residual,
     fused_swiglu,
-    paged_decode_attention,
     ring_attention,
     rms_norm,
 )
@@ -66,24 +64,11 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    # Single-token decode steps dispatch to the hand-written Pallas
-    # decode-attention kernel on TPU (ops/decode_attention.py — measured
-    # faster than the XLA-fused path at serving shapes). False = always
-    # use the generic masked-attention path; "interpret" = run the same
-    # kernel glue under the Pallas interpreter off-TPU (test coverage for
-    # the dispatch itself).
-    use_decode_kernel: Any = True
-    # Paged decode attention (ops/paged_decode.py): single-token decode
-    # reads the block-granular KV cache IN PLACE through a block-table
-    # index — only ceil(length/page) pages stream per sequence, vs the
-    # whole cache extent for the contiguous kernel. True = Pallas kernel
-    # on TPU / jnp gather reference elsewhere; "interpret" = the kernel
-    # under the Pallas interpreter off-TPU (test escape hatch); False =
-    # never. Takes precedence over ``use_decode_kernel`` for decode
-    # steps. The cache's row extent must be a multiple of
-    # ``decode_page`` (the engine pads its allocation).
-    paged_decode: Any = False
-    decode_page: int = 16
+    # The tests' hook, one name on every served family's configuration:
+    # the decode step's attention kernel (ops/decode_attention.py, which
+    # itself picks kernel on the TPU and jnp twin elsewhere) runs under
+    # the Pallas interpreter off the TPU.
+    interpret_kernels: bool = False
     # Fused Pallas kernels for the per-layer glue (ops/fused.py):
     # RMSNorm(+residual), rotary folded over the QK projection outputs,
     # and SwiGLU each become one VMEM pass instead of several XLA HBM
@@ -363,46 +348,12 @@ def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
         cv = lax.dynamic_update_slice(  # rtpu-lint: disable=unclamped-dynamic-update-slice
             cv, v.swapaxes(1, 2).astype(cv.dtype), (0, 0, cache_index, 0))
         new_kv = (ck, cv)
-        if k.shape[1] == 1 and cfg.paged_decode:
-            # Paged decode step: the cache is read IN PLACE as a pool of
-            # decode_page-row pages through a block table. The table here
-            # is slot-identity (each sequence's pages are its own rows,
-            # in order — kv_manager keeps prefixes slot-affine), so the
-            # paged read is bit-equal to the contiguous one; the
-            # indirection is the seam for cross-slot paging.
-            from ray_tpu.ops import paged_decode_attention
-
-            page = cfg.decode_page
-            bq, s_cache = x.shape[0], ck.shape[2]
-            np_row = s_cache // page
-            table = jnp.arange(bq * np_row,
-                               dtype=jnp.int32).reshape(bq, np_row)
-            lengths = jnp.broadcast_to(cache_index + 1, (bq,))
-            attn = paged_decode_attention(
-                q[:, 0], ck, cv, table, lengths.astype(jnp.int32),
-                page_size=page,
-                interpret=cfg.paged_decode == "interpret")[:, None]
-        elif (k.shape[1] == 1 and cfg.use_decode_kernel
-                and (jax.default_backend() == "tpu"
-                     or cfg.use_decode_kernel == "interpret")):
-            # Serving decode step: one query over the cache prefix — the
-            # Pallas kernel streams the native-layout cache directly
-            # (ops/decode_attention.py). "interpret" runs the same glue
-            # under the Pallas interpreter off-TPU (test escape hatch).
-            from ray_tpu.ops import decode_attention
-
-            lengths = jnp.broadcast_to(cache_index + 1, (x.shape[0],))
-            attn = decode_attention(
-                q[:, 0], ck, cv, lengths.astype(jnp.int32), layout="bksd",
-                interpret=cfg.use_decode_kernel == "interpret")[:, None]
-        else:
-            kv_len = ck.shape[2]
-            kv_pos = jnp.broadcast_to(jnp.arange(kv_len),
-                                      (x.shape[0], kv_len))
-            kv_mask = kv_pos < (cache_index + k.shape[1])
-            attn = causal_attention(q, ck.swapaxes(1, 2), cv.swapaxes(1, 2),
-                                    q_positions=positions,
-                                    kv_positions=kv_pos, kv_mask=kv_mask)
+        kv_len = ck.shape[2]
+        kv_pos = jnp.broadcast_to(jnp.arange(kv_len), (x.shape[0], kv_len))
+        kv_mask = kv_pos < (cache_index + k.shape[1])
+        attn = causal_attention(q, ck.swapaxes(1, 2), cv.swapaxes(1, 2),
+                                q_positions=positions,
+                                kv_positions=kv_pos, kv_mask=kv_mask)
     else:
         attn = _attention_dispatch(q, k, v, positions, positions, cfg, mesh,
                                    standard_positions=standard_positions)
@@ -663,37 +614,12 @@ def _decode_block(x, layer, layer_idx, cache_k, cache_v, lengths, seen,
         k = apply_rope(k, positions, cfg.rope_theta)
     cache_k = _write_rows(cache_k, layer_idx, lengths, k[:, 0])
     cache_v = _write_rows(cache_v, layer_idx, lengths, v[:, 0])
-    n_layers, b, kh, s, d = cache_k.shape
-    if cfg.paged_decode:
-        # The cache read IN PLACE as a pool of decode_page-row pages:
-        # layers and slots are both leading axes of the pool, so the
-        # block table picks the layer as it picks the slot (slot-
-        # identity within the layer, as kv_manager keeps prefixes
-        # slot-affine; the indirection is the seam for cross-slot
-        # paging).
-        np_row = s // cfg.decode_page
-        table = layer_idx * (b * np_row) + jnp.arange(
-            b * np_row, dtype=jnp.int32).reshape(b, np_row)
-        attn = paged_decode_attention(
-            q[:, 0], cache_k.reshape(n_layers * b, kh, s, d),
-            cache_v.reshape(n_layers * b, kh, s, d), table, seen,
-            page_size=cfg.decode_page,
-            interpret=cfg.paged_decode == "interpret")
-    elif cfg.use_decode_kernel:
-        # ONE kernel call for all slots, each masked at its own length;
-        # the kernel finds the layer's blocks in the whole cache
-        # (ops/decode_attention.py), its jnp reference off the TPU.
-        attn = decode_attention(
-            q[:, 0], cache_k, cache_v, seen, layer=layer_idx,
-            layout="bksd",
-            interpret=cfg.use_decode_kernel == "interpret")
-    else:
-        def of_layer(cache):  # [B,S,KH,D], the reference's layout
-            return lax.dynamic_index_in_dim(
-                cache, layer_idx, 0, keepdims=False).swapaxes(1, 2)
-
-        attn = decode_attention_reference(q[:, 0], of_layer(cache_k),
-                                          of_layer(cache_v), seen)
+    # ONE kernel call for all slots, each masked at its own length; the
+    # kernel finds the layer's blocks in the whole cache, and
+    # ops/decode_attention.py decides kernel or jnp twin from the platform.
+    attn = decode_attention(
+        q[:, 0], cache_k, cache_v, seen, layer=layer_idx, layout="bksd",
+        interpret=cfg.interpret_kernels)
     attn_out = _wdot("bshk,hkd->bsd", attn[:, None],
                      layer["wo"]).astype(x.dtype)
     if fused:
@@ -732,8 +658,6 @@ def decode_step_with_cache(params: Params, tokens: jnp.ndarray,
 
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     seen, counters = decode_step_rows(lengths, live, cache["k"])
-    if cfg.paged_decode:    # another kernel's blocks
-        del counters["decode_attn_rows_streamed"]
 
     def body(carry, layer_and_idx):
         x, ck, cv = carry

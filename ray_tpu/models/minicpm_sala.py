@@ -103,8 +103,6 @@ SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
 SLOT_STATE_KEYS = ("state",)
 ENGINE_REFUSES = {
     "quantize": "models/quant.py quantizes llama's weight tree only",
-    "paged_decode": "ops/paged_decode.py pages K and V rows; the "
-                    "compressed keys and the state have no pages",
     "spec_draft_len": "a rejected draft would have stepped the state and "
                       "completed windows: verify needs a snapshot",
     "role": "export_page/install_page carry k_page and v_page, not the "

@@ -88,8 +88,6 @@ F32 = jnp.float32
 SLOT_STATE_KEYS = ("state", "conv")
 ENGINE_REFUSES = {
     "quantize": "models/quant.py quantizes llama's weight tree only",
-    "paged_decode": "ops/paged_decode.py pages K and V rows; a slot's "
-                    "state has no rows to page",
     "spec_draft_len": "a rejected draft would have stepped the state: "
                       "verify needs a snapshot to roll back to",
     "role": "export_page/install_page carry k_page and v_page, not the "

@@ -97,8 +97,6 @@ _HIGHEST = lax.Precision.HIGHEST
 SLOT_STATE_KEYS = ("tail",)
 ENGINE_REFUSES = {
     "quantize": "models/quant.py quantizes llama's weight tree only",
-    "paged_decode": "ops/paged_decode.py pages K and V rows; a slot's "
-                    "tail has no rows to page",
     "spec_draft_len": "a rejected draft would have stepped the tail: "
                       "verify needs a snapshot to roll back to",
     "role": "export_page/install_page carry k_page and v_page, not the "
@@ -133,7 +131,7 @@ class ZayaConfig:
     dtype: Any = jnp.bfloat16
     # Run the decode kernel under the Pallas interpreter off the TPU
     # (tests); otherwise the kernel on the TPU, its jnp reference off it.
-    interpret_decode_kernel: bool = False
+    interpret_kernels: bool = False
 
     def __post_init__(self):
         if (self.cca_time0, self.cca_time1) != (2, 2):
@@ -413,7 +411,7 @@ def _decode_block(x, layer, stacks, layer_idx, cache_k, cache_v, tails,
     cache_v = _write_rows(cache_v, layer_idx, lengths, v)
     attn = decode_attention(
         q, cache_k, cache_v, seen_rows, layer=layer_idx, layout="bksd",
-        interpret=cfg.interpret_decode_kernel)
+        interpret=cfg.interpret_kernels)
     x = x + _mm("bc,cd->bd", attn.reshape(x.shape[0], -1),
                 layer["w_o"])
     y, expert, load, seen = moe_ffn(x, layer, stacks, layer_idx, cfg)

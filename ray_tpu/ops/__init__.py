@@ -19,13 +19,6 @@ blockwise_attention        (portable online-softmax scan)   seq a multiple of ``
 decode_attention           Pallas single-query kernel:      on TPU, or ``interpret=True`` off-TPU;
                            streams the blocks of rows that  jnp reference elsewhere
                            begin under each slot's length
-paged_decode_attention     Pallas block-table kernel:       ``LlamaConfig.paged_decode`` (engine knob
-                           reads the paged KV cache IN      ``paged_decode=True``): kernel on TPU or
-                           PLACE through the table's        under ``interpret``; jnp gather reference
-                           index map, streaming only        elsewhere. Cache rows must be a multiple
-                           ceil(len/page) pages/seq         of ``decode_page`` (engine pads). Greedy
-                                                            output token-identical to the unpaged
-                                                            paths (identity table == contiguous read)
 mla_decode_attention       Pallas single-query kernel over  on TPU, or ``interpret=True`` off-TPU;
                            a LATENT cache: one shared key   jnp reference elsewhere
                            whose first columns are the
@@ -114,10 +107,6 @@ from ray_tpu.ops.mla_decode import (
     mla_decode_attention_reference,
 )
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.paged_decode import (
-    paged_decode_attention,
-    paged_decode_attention_reference,
-)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rotary import apply_rope, rope_frequencies
 
@@ -137,8 +126,6 @@ __all__ = [
     "mla_decode_attention",
     "mla_decode_attention_reference",
     "online_softmax_update",
-    "paged_decode_attention",
-    "paged_decode_attention_reference",
     "repeat_kv",
     "ring_attention",
     "rms_norm",

@@ -20,8 +20,8 @@ the online-softmax carry in VMEM scratch. Each valid row is read ONCE:
 the block is loaded once and serves both the score product (all Dk
 columns) and the value product (its first v_dim columns, a lane-aligned
 slice). Blocks past ``lengths[b]`` are not read: the index map parks
-them on the slot's last valid block (no fresh copy, as the paged
-kernel's does) and their compute is skipped.
+them on the slot's last valid block (no fresh copy) and their compute
+is skipped.
 
 ``keep`` [B, S] (optional) says which of a slot's rows under its length
 the query attends to at all: a family whose queries CHOOSE their rows

@@ -18,7 +18,6 @@ class.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import math
 import queue
@@ -147,15 +146,6 @@ class InferenceEngine:
     chunk would lag by one dispatch); ``multi_step=False`` and the
     speculative engine dispatch, fetch and deliver a chunk in one
     tick, and fetch a first token where its prefill is dispatched.
-
-    ``paged_decode`` routes decode attention through the paged
-    block-table kernel (``ops/paged_decode.py``): the block-granular
-    KV cache is read IN PLACE via a slot-identity block table —
-    bit-equal to the contiguous read, streaming only the pages that
-    cover each sequence's valid rows. True = Pallas kernel on TPU /
-    jnp gather reference elsewhere; "interpret" = Pallas interpreter
-    off-TPU. The page size is ``prefix_block`` (the KV manager's block
-    granularity) and the cache allocation is padded to a page multiple.
     """
 
     @_timed_init
@@ -171,7 +161,6 @@ class InferenceEngine:
                  quantize: Optional[str] = None,
                  prefill_chunk: int = 0,
                  multi_step: bool = True,
-                 paged_decode: Any = False,
                  role: str = "colocated",
                  seed: int = 0,
                  kv_fleet_min_prefix_blocks: Any = None,
@@ -200,25 +189,18 @@ class InferenceEngine:
         # What this family's cache cannot do yet is refused here, by
         # name, never run wrong.
         asked = {"quantize": quantize is not None,
-                 "paged_decode": bool(paged_decode),
                  "spec_draft_len": int(spec_draft_len) > 0,
                  "role": role != "colocated", "kv_fleet": fleet_on}
         for option, why in getattr(self.model, "ENGINE_REFUSES", {}).items():
+            if option not in asked:
+                raise ValueError(
+                    f"{self.model.__name__}.ENGINE_REFUSES names "
+                    f"{option!r}, which is no option of this engine "
+                    f"(it knows {sorted(asked)})")
             if asked[option]:
                 raise ValueError(
                     f"{self.model.__name__} cannot serve with {option} "
                     f"yet: {why}")
-        if paged_decode:
-            # The paged kernel's page size IS the KV manager's block
-            # granularity — one notion of "block" engine-wide.
-            self.cfg = dataclasses.replace(self.cfg,
-                                           paged_decode=paged_decode,
-                                           decode_page=prefix_block)
-        # A cfg-level LlamaConfig.paged_decode counts too (its own
-        # decode_page): the cache padding below must track EITHER spelling
-        # or the first decode tick dies on the kernel's page-multiple
-        # check.
-        self.paged_decode = getattr(self.cfg, "paged_decode", False)
         self.quantize = quantize
         with _compile_cache.phase("engine.weights"):
             self.params = (params if params is not None
@@ -264,12 +246,6 @@ class InferenceEngine:
         # clamp back onto resident rows (decode_loop docstring). Row
         # accounting everywhere else still uses the logical max_len.
         cache_rows = self.max_len + self.loop.scratch_rows
-        if self.paged_decode:
-            # The paged kernel reads the cache as whole pages; pad the
-            # allocation to a page multiple (padded rows sit past the
-            # scratch strip — never written, masked out by lengths).
-            page = self.cfg.decode_page
-            cache_rows = -(-cache_rows // page) * page
         if role != "colocated" or fleet_on:
             # KV-page export/install moves whole pages: pad the
             # allocation so the tail page of a max-length prompt never

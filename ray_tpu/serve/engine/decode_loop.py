@@ -437,8 +437,7 @@ class DecodeLoop:
         pages and fetches them in a single sync), the decode engine
         installs received pages into its own cache at the same rows.
         Page size == the KV manager's block size, so a "page" here is
-        exactly the block the hash chain and the paged-decode kernel
-        already agree on."""
+        exactly the block of the hash chain."""
         import jax
         import jax.numpy as jnp
 

@@ -678,9 +678,8 @@ def build_llm_deployment(name: str = "llm", *, num_replicas: int = 1,
     ``engine_kwargs`` flow straight into the ``LLMEngine`` constructor —
     including the speculative-decoding knobs (``spec_draft_len``,
     ``spec_ngram_max``, ``spec_adaptive``), ``quantize="int8"``,
-    ``prefill_chunk`` (chunked prefill), ``paged_decode`` (block-table
-    decode attention) and ``multi_step`` (double-buffered decode
-    dispatch).
+    ``prefill_chunk`` (chunked prefill) and ``multi_step``
+    (double-buffered decode dispatch).
 
     ``disaggregated=True`` deploys TWO pools instead of one:
     ``<name>`` (prefill-role replicas — admission + chunked prefill

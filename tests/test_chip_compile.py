@@ -109,20 +109,6 @@ def test_decode_attention_compiles(chip, geom):
     assert _names_kernel(c, "rtpu_decode_attention")
 
 
-@pytest.mark.parametrize("page", [16, 128])
-@pytest.mark.parametrize("geom", GEOMETRY)
-def test_paged_decode_attention_compiles(chip, geom, page):
-    h, kh, hd, _, _ = GEOMETRY[geom]
-    c = _compile(
-        functools.partial(ops.paged_decode_attention, page_size=page),
-        _sds(chip, (BATCH, h, hd)), _sds(chip, (BATCH, kh, SEQ, hd)),
-        _sds(chip, (BATCH, kh, SEQ, hd)),
-        _sds(chip, (BATCH, SEQ // page), jnp.int32),
-        _sds(chip, (BATCH,), jnp.int32))
-    assert _kernel_calls(c) == 1
-    assert _names_kernel(c, "rtpu_paged_decode_attention")
-
-
 @pytest.mark.parametrize("geom", GEOMETRY)
 def test_fused_rms_norm_kernels_compile(chip, geom):
     d = GEOMETRY[geom][3]
@@ -279,18 +265,6 @@ def test_engine_decode_chunk_updates_the_cache_in_place(chip, engine):
     # once a layer for all slots and so under its own name.
     assert _kernel_calls(c) == 1
     assert _names_kernel(c, "rtpu_decode_attention")
-    _assert_cache_in_place(c, cache)
-
-
-def test_engine_paged_decode_chunk_compiles_at_llama3_1b(chip):
-    from ray_tpu.serve.engine.decode_loop import DecodeLoop
-
-    cfg = dataclasses.replace(_CFG_1B, paged_decode=True)
-    loop = DecodeLoop(cfg, max_len=SEQ, chunk=8)
-    params, cache = _engine_args(chip, cfg, BATCH, SEQ)
-    c = _lower_decode_chunk(chip, loop, params, cache, BATCH)
-    assert _kernel_calls(c) == 1
-    assert _names_kernel(c, "rtpu_paged_decode_attention")
     _assert_cache_in_place(c, cache)
 
 

@@ -81,36 +81,6 @@ def test_zero_length_slot_attends_nothing():
                                rtol=2e-3, atol=2e-3)
 
 
-def test_llama_decode_dispatch_glue_interpret():
-    """The MODEL-side integration (llama._block's q slice / lengths /
-    native-layout call) under the Pallas interpreter on CPU: a dispatch
-    bug here would otherwise only surface as wrong tokens on real TPU."""
-    import dataclasses
-
-    from ray_tpu.models import llama
-
-    cfg_k = dataclasses.replace(llama.tiny_config(max_seq_len=64),
-                                use_decode_kernel="interpret")
-    cfg_x = dataclasses.replace(cfg_k, use_decode_kernel=False)
-    params = llama.init_params(cfg_k, jax.random.PRNGKey(0))
-    cache_k = llama.init_kv_cache(cfg_k, 2, 64)
-    cache_x = llama.init_kv_cache(cfg_x, 2, 64)
-    prompt = jnp.asarray([[5, 9, 3, 7], [2, 8, 1, 4]], jnp.int32)
-    lk, cache_k = llama.forward_with_cache(params, prompt, cache_k, 0, cfg_k)
-    lx, cache_x = llama.forward_with_cache(params, prompt, cache_x, 0, cfg_x)
-    np.testing.assert_allclose(np.asarray(lk), np.asarray(lx), rtol=2e-4,
-                               atol=2e-4)  # prefill identical path
-    tok = jnp.argmax(lk[:, -1], -1)[:, None].astype(jnp.int32)
-    for step in range(3):
-        lk, cache_k = llama.forward_with_cache(params, tok, cache_k,
-                                               4 + step, cfg_k)
-        lx, cache_x = llama.forward_with_cache(params, tok, cache_x,
-                                               4 + step, cfg_x)
-        np.testing.assert_allclose(np.asarray(lk), np.asarray(lx),
-                                   rtol=2e-3, atol=2e-3)
-        tok = jnp.argmax(lk[:, -1], -1)[:, None].astype(jnp.int32)
-
-
 def test_bfloat16_inputs():
     q, k, v, lengths = _inputs(b=1, h=4, kh=2, s=256, d=64,
                                dtype=jnp.bfloat16)

@@ -30,16 +30,16 @@ CACHE = 6       # where a chunk's results hold the cache (counters follow)
 
 HEADS = {"gqa": dict(n_heads=4, n_kv_heads=2),
          "mha": dict(n_heads=4, n_kv_heads=4)}
-# How the step reaches attention: the dispatcher's jnp reference, the
-# Pallas kernel under the interpreter (whole cache + layer index), no
-# kernel at all, the paged kernel's gather reference and the paged
-# kernel interpreted (the block table picks the layer), int8 weights.
+# How the step reaches attention: the dispatcher's jnp twin (what it
+# picks off the TPU), the Pallas kernel under the interpreter at
+# tiny_config's head size 16 (the layer sliced and padded to lanes a
+# call), the same at head size 128 with rows the block divides (the
+# kernel reads the layer's blocks where they lie in the whole cache: the
+# form the Mistral, Olmo and ZAYA cells run), int8 weights.
 ROUTES = {
     "contiguous": {},
-    "interpret": dict(use_decode_kernel="interpret"),
-    "no_kernel": dict(use_decode_kernel=False),
-    "paged": dict(paged_decode=True, decode_page=16),
-    "paged_interpret": dict(paged_decode="interpret", decode_page=16),
+    "interpret": dict(interpret_kernels=True),
+    "interpret_inplace": dict(interpret_kernels=True, d_model=512),
     "int8": {},
 }
 
@@ -169,7 +169,8 @@ def test_decode_chunk_stops_at_a_slots_eos(heads):
     assert int(np.asarray(got[1])[0]) <= 2
 
 
-@pytest.mark.parametrize("route", ["contiguous", "interpret", "paged"])
+@pytest.mark.parametrize("route", ["contiguous", "interpret",
+                                   "interpret_inplace"])
 @pytest.mark.parametrize("heads", HEADS)
 def test_decoded_rows_equal_the_functional_prefills(heads, route):
     """What the in-place step leaves in a live slot's rows is what the

@@ -109,7 +109,7 @@ def test_prefill_then_decode_through_the_three_entries(tiny, interpret):
     tiles) that WRAPS at step 128; every step's logits against the
     reference's full forward pass, slot 0 not live and left as it was."""
     c, cfg, params = tiny
-    cfg = dataclasses.replace(cfg, interpret_decode_kernel=interpret)
+    cfg = dataclasses.replace(cfg, interpret_kernels=interpret)
     n, total, rows = 120, 136, 192
     tokens = _tokens(2, (1, total))
     want = np.asarray(reference.logits_at(
@@ -171,7 +171,7 @@ def test_a_prompt_prefilled_in_chunks_equals_one_prefill(tiny, edges,
     tokens = _tokens(3, (1, total))
     cache = dots3_note.init_kv_cache(cfg, 1, 192)
     whole, want, one, seen = _prefill(params, tokens, cache, 0, cfg=cfg)
-    cfg = dataclasses.replace(cfg, interpret_decode_kernel=interpret)
+    cfg = dataclasses.replace(cfg, interpret_kernels=interpret)
     start, attended = 0, 0
     for end in edges:
         n = end - start
@@ -401,9 +401,9 @@ def test_the_engine_serves_the_family_through_its_seam(tiny):
     assert engine._span_attrs([{"dsa_queries_selected": 3,
                                 "moe_pairs_held": 5}]) == {
         "queries_selected": 3, "expert_pairs_held": 5}
-    with pytest.raises(ValueError, match="cannot serve with paged_decode"):
+    with pytest.raises(ValueError, match="cannot serve with quantize"):
         InferenceEngine(cfg, params, max_batch=1, max_len=64,
-                        paged_decode=True, kv_fleet_min_prefix_blocks=-1)
+                        quantize="int8", kv_fleet_min_prefix_blocks=-1)
 
 
 def test_the_configuration_file_keeps_every_published_width():

@@ -2,13 +2,11 @@
 behavior.
 
 The engine's compounding performance knobs — speculative decoding
-(PR 3), weight-only int8 (PR 6), chunked prefill, the paged decode
-kernel, and multi-step double-buffered ticks — all share ONE
-correctness contract: greedy output is token-identical to the plain
-engine (int8 compares within the same quantized weights, since
-quantization itself legitimately changes logits). The fast tier runs
-the highest-interaction corners; the full 16-way sweep is
-``@pytest.mark.slow``.
+(PR 3), weight-only int8 (PR 6), chunked prefill, and multi-step
+double-buffered ticks — all share ONE correctness contract: greedy
+output is token-identical to the plain engine (int8 compares within the
+same quantized weights, since quantization itself legitimately changes
+logits). All 8 combinations of the first three run in tier 1.
 """
 
 import concurrent.futures as cf
@@ -67,7 +65,7 @@ def baselines(tiny_model):
     }
 
 
-def _combo_kw(spec, quant, chunked, paged):
+def _combo_kw(spec, quant, chunked):
     kw = {}
     if spec:
         kw.update(spec_draft_len=spec, spec_chunk=2)
@@ -75,31 +73,18 @@ def _combo_kw(spec, quant, chunked, paged):
         kw.update(quantize=quant)
     if chunked:
         kw.update(prefill_chunk=chunked)
-    if paged:
-        kw.update(paged_decode=True)
     return kw
 
 
-# Fast tier: the all-on composite per quantization level, plus each new
-# knob alone against the shared baseline.
-FAST_COMBOS = [
-    (2, None, 8, True),       # spec + chunked + paged, f32
-    (2, "int8", 8, True),     # everything on at once
-    (0, None, 8, False),      # chunked alone
-    (0, None, 0, True),       # paged alone
-]
-
-FULL_COMBOS = [(s, q, c, p)
-               for s in (0, 2) for q in (None, "int8")
-               for c in (0, 8) for p in (False, True)]
+FULL_COMBOS = [(s, q, c)
+               for s in (0, 2) for q in (None, "int8") for c in (0, 8)]
 
 
-@pytest.mark.parametrize("spec,quant,chunked,paged", FAST_COMBOS)
-def test_feature_combo_token_identity_fast(tiny_model, baselines, spec,
-                                           quant, chunked, paged):
-    outs, stats = _run(tiny_model,
-                       **_combo_kw(spec, quant, chunked, paged))
-    assert outs == baselines[quant], (spec, quant, chunked, paged)
+@pytest.mark.parametrize("spec,quant,chunked", FULL_COMBOS)
+def test_feature_combo_token_identity_full(tiny_model, baselines, spec,
+                                           quant, chunked):
+    outs, stats = _run(tiny_model, **_combo_kw(spec, quant, chunked))
+    assert outs == baselines[quant], (spec, quant, chunked)
     if spec:
         assert stats["spec_chunks"] > 0   # the verify path really ran
     if chunked:
@@ -107,36 +92,6 @@ def test_feature_combo_token_identity_fast(tiny_model, baselines, spec,
         # without a fetch — prefill syncs stay one per admission, so
         # prefill token counts are the only chunking trace here.
         assert stats["prefill_tokens"] > 0
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("spec,quant,chunked,paged", FULL_COMBOS)
-def test_feature_combo_token_identity_full(tiny_model, baselines, spec,
-                                           quant, chunked, paged):
-    outs, _ = _run(tiny_model, **_combo_kw(spec, quant, chunked, paged))
-    assert outs == baselines[quant], (spec, quant, chunked, paged)
-
-
-def test_cfg_level_paged_decode_pads_cache(tiny_model, baselines):
-    """LlamaConfig.paged_decode=True (no engine kwarg) must also pad
-    the cache allocation to a page multiple — its docstring promises
-    the engine pads, and an unpadded cache dies on the kernel's
-    page-multiple check at the first decode tick."""
-    import dataclasses
-
-    from ray_tpu.serve.llm import LLMEngine
-
-    cfg, params = tiny_model
-    pcfg = dataclasses.replace(cfg, paged_decode=True, decode_page=24)
-    eng = LLMEngine(pcfg, params, max_batch=2, max_len=64,
-                    prompt_buckets=[8, 16], prefix_block=8)
-    try:
-        assert eng.cache["k"].shape[3] % 24 == 0  # 64 -> 72 rows
-        outs = [eng.generate(p, max_new_tokens=N_NEW)["token_ids"]
-                for p in PROMPTS]
-    finally:
-        eng.close()
-    assert outs == baselines[None]
 
 
 # --------------------------------------------------------- multi-step
@@ -711,7 +666,6 @@ FAMILIES = {"olmo_hybrid": _olmo_hybrid, "minicpm_sala": _minicpm_sala,
 # family here MUST do both.
 NOT_RESET_WITH_THE_SLOT = {"dots3_note"}
 OPTIONS = {"quantize": dict(quantize="int8"),
-           "paged_decode": dict(paged_decode=True),
            "spec_draft_len": dict(spec_draft_len=2),
            "role": dict(role="prefill"),
            "kv_fleet": dict(kv_fleet_min_prefix_blocks=0)}
@@ -733,6 +687,22 @@ def test_a_family_refuses_what_its_cache_cannot_serve(family, option):
                        match=f"cannot serve with {option} yet") as refused:
         InferenceEngine(cfg, **{**kwargs, **OPTIONS[option]})
     assert cfg.model.ENGINE_REFUSES[option] in str(refused.value)
+
+
+def test_a_refused_option_the_engine_does_not_know_is_named(monkeypatch):
+    """A keyword that leaves the engine must take its `ENGINE_REFUSES`
+    lines with it: a stale key is an error that names the module and
+    the key, not a bare KeyError (or, worse, silence)."""
+    from ray_tpu.models import olmo_hybrid
+    from ray_tpu.serve.engine.core import InferenceEngine
+
+    monkeypatch.setitem(olmo_hybrid.ENGINE_REFUSES, "an_option_that_left",
+                        "its reason")
+    with pytest.raises(ValueError, match=r"olmo_hybrid\.ENGINE_REFUSES "
+                                         r"names 'an_option_that_left'"):
+        InferenceEngine(_olmo_hybrid(), max_batch=2, max_len=64,
+                        prompt_buckets=[8, 16],
+                        kv_fleet_min_prefix_blocks=-1)
 
 
 @pytest.mark.parametrize("family", FAMILIES)

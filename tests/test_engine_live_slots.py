@@ -17,7 +17,11 @@ from ray_tpu.models import llama
 from ray_tpu.serve.engine.decode_loop import DecodeLoop
 
 SLOTS, MAX_LEN, CHUNK = 4, 32, 4
-ROUTES = {"reference": {}, "kernel": dict(use_decode_kernel="interpret")}
+# The dispatcher's jnp twin; the kernel interpreted over a layer sliced
+# and padded (head size 16); the kernel interpreted over the whole cache
+# where it lies (head size 128, rows the block divides).
+ROUTES = {"reference": {}, "kernel": dict(interpret_kernels=True),
+          "kernel_inplace": dict(interpret_kernels=True, d_model=512)}
 
 
 def _roster():

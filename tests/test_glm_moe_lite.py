@@ -65,7 +65,7 @@ def test_prefill_then_decode_through_the_latent_cache(tiny, interpret):
     of the ABSORBED decode over the latent rows: every step's logits
     against the reference's full (expanded) forward pass."""
     c, cfg, params = tiny
-    cfg = dataclasses.replace(cfg, interpret_decode_kernel=interpret)
+    cfg = dataclasses.replace(cfg, interpret_kernels=interpret)
     tokens = _tokens(2, (1, 40))
     want = np.asarray(reference.logits_at(
         params, tokens, [(0, t) for t in range(23, 40)], c))
@@ -263,9 +263,9 @@ def test_engine_serves_the_family_end_to_end(tiny):
 
 
 @pytest.mark.parametrize("option", [
-    dict(quantize="int8"), dict(paged_decode=True), dict(spec_draft_len=2),
+    dict(quantize="int8"), dict(spec_draft_len=2),
     dict(role="prefill"), dict(kv_fleet_min_prefix_blocks=0)],
-    ids=["quantize", "paged_decode", "spec_draft_len", "role", "kv_fleet"])
+    ids=["quantize", "spec_draft_len", "role", "kv_fleet"])
 def test_engine_refuses_what_the_latent_cache_cannot_do(tiny, option):
     from ray_tpu.serve.engine.core import InferenceEngine
 

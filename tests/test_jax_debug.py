@@ -189,18 +189,16 @@ def test_steady_state_decode_programs_and_sync_cadence(debug_jax,
         eng.close()
 
 
-def test_chunked_paged_engine_declared_schedule(debug_jax):
-    """The chunked-prefill + paged-decode + multi-step engine keeps the
-    SAME declared budgets: one decode program (paged dispatch is a
-    static config branch inside it), prefill programs within the
+def test_chunked_multi_step_engine_declared_schedule(debug_jax):
+    """The chunked-prefill + multi-step engine keeps the
+    SAME declared budgets: one decode program, prefill programs within the
     per-bucket budget even though a long prompt now dispatches MANY
     chunks (intermediate chunks reuse bucket shapes and fetch nothing),
     exactly one counted prefill sync per ADMISSION (the final chunk's
     first-token fetch), and decode witness syncs == the per-chunk
     metric (multi-step moves the fetch one chunk behind dispatch, it
     never adds or drops one)."""
-    eng = _engine(prefill_chunk=16, paged_decode=True, prefix_block=16,
-                  multi_step=True)
+    eng = _engine(prefill_chunk=16, prefix_block=16, multi_step=True)
     try:
         # 40-token prompt -> chunks (16, 16, 8); short prompt -> one.
         out = eng.generate([3] * 40, max_new_tokens=12)

@@ -10,14 +10,14 @@ chip's compiler produces."""
 
 from __future__ import annotations
 
-import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from ray_tpu import ops
-from ray_tpu.models import llama
+from ray_tpu.ops import dsa_prefill
 
 
 def _pallas_eqns(jaxpr):
@@ -40,46 +40,86 @@ _X = jax.ShapeDtypeStruct((2, 8, 128), F32)
 _Q = jax.ShapeDtypeStruct((2, 8, 4, 32), F32)
 _K = jax.ShapeDtypeStruct((2, 8, 2, 32), F32)
 _POS = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+_SCALE = jax.ShapeDtypeStruct((128,), F32)
+_CACHE = jax.ShapeDtypeStruct((2, 2, 128, 128), F32)     # [B,KH,S,D]
+# A dispatcher of ``ops/`` that takes ``interpret``: its kernel's name ->
+# (the call, its arguments' shapes).
 GLUE = {
-    "rtpu_fused_rms_norm": (
-        lambda x, s: ops.fused_rms_norm(x, s, interpret=True),
-        _X, jax.ShapeDtypeStruct((128,), F32)),
+    "rtpu_fused_rms_norm": (ops.fused_rms_norm, _X, _SCALE),
     "rtpu_fused_rms_norm_residual": (
-        lambda x, r, s: ops.fused_rms_norm_residual(x, r, s, interpret=True),
-        _X, _X, jax.ShapeDtypeStruct((128,), F32)),
-    "rtpu_fused_qk_rope": (
-        lambda q, k, p: ops.fused_qk_rope(q, k, p, interpret=True),
-        _Q, _K, _POS),
-    "rtpu_fused_swiglu": (
-        lambda g, u: ops.fused_swiglu(g, u, interpret=True), _X, _X),
+        ops.fused_rms_norm_residual, _X, _X, _SCALE),
+    "rtpu_fused_qk_rope": (ops.fused_qk_rope, _Q, _K, _POS),
+    "rtpu_fused_swiglu": (ops.fused_swiglu, _X, _X),
+    "rtpu_decode_attention": (
+        functools.partial(ops.decode_attention, layout="bksd"),
+        jax.ShapeDtypeStruct((2, 4, 128), F32), _CACHE, _CACHE,
+        jax.ShapeDtypeStruct((2,), jnp.int32)),
+    dsa_prefill.NAME: (
+        functools.partial(dsa_prefill.dsa_prefill_attention, scale=0.1),
+        jax.ShapeDtypeStruct((1, 8, 2, 192), F32),        # q [B,T,H,qk]
+        jax.ShapeDtypeStruct((1, 128, 256), F32),         # rows [B,S,W]
+        jax.ShapeDtypeStruct((1, 8, 128), jnp.bool_),     # keep [B,T,S]
+        jax.ShapeDtypeStruct((128, 2, 128), F32),         # w_uk [r,H,nope]
+        jax.ShapeDtypeStruct((128, 2, 128), F32),         # w_uv [r,H,v]
+        jax.ShapeDtypeStruct((), jnp.int32)),             # rows_seen
 }
 
 
 @pytest.mark.parametrize("name", GLUE)
 def test_glue_kernel_carries_its_name(name):
     fn, *args = GLUE[name]
-    assert _kernels(fn, *args) == [(name, {"kernel": name})]
+    assert _kernels(functools.partial(fn, interpret=True), *args) \
+        == [(name, {"kernel": name})]
 
 
-@pytest.mark.parametrize("knob, name", [
-    ("use_decode_kernel", "rtpu_decode_attention"),
-    ("paged_decode", "rtpu_paged_decode_attention"),
-])
-def test_decode_chunk_carries_its_attention_kernels_name(knob, name):
-    """The engine's own program: the one kernel of the scanned layer
-    body, called once a layer for all slots, is the named one."""
+@pytest.mark.parametrize("name", GLUE)
+def test_glue_lowers_to_its_jnp_twin_off_the_tpu(name):
+    """With ``interpret`` unset the dispatcher decides from the platform
+    alone: off the TPU no ``pallas_call`` is left, so what the CPU
+    tier-1 compares against is the twin and no flag has to force it."""
+    assert jax.default_backend() != "tpu"
+    fn, *args = GLUE[name]
+    assert _kernels(fn, *args) == []
+
+
+# A served family's configuration file -> the kernels of its decode
+# step, under the names the benchmark's readers look for in a trace
+# (``benchmark/metrics/*_ms_per_step.py``).
+SERVED = {
+    "mistral-7b-v0.3-l16": {"rtpu_decode_attention"},
+    "glm-4.7-flash-l7": {"rtpu_mla_decode_attention"},
+    "olmo-hybrid-7b-l16": {"rtpu_gdn_decode", "rtpu_decode_attention"},
+    "minicpm-sala-l16": {"rtpu_sparse_decode_attention",
+                         "rtpu_lightning_decode"},
+    "zaya1-8b-l16": {"rtpu_decode_attention"},
+    "dots3-note-prev-l5-ep8": {"rtpu_dsa_select",
+                               "rtpu_dsa_decode_attention",
+                               "rtpu_swa_decode_attention"},
+}
+
+
+@pytest.mark.parametrize("config", SERVED)
+def test_decode_chunk_carries_its_attention_kernels_name(config):
+    """The engine's own program, every served family's at its
+    configuration file's rehearsal sizes with `interpret_kernels` (the
+    one hook every family's configuration has): the kernels of the
+    scanned layer bodies are the named ones, and no other."""
+    from benchmark.harness import manifest
     from ray_tpu.serve.engine.decode_loop import DecodeLoop
 
-    cfg = dataclasses.replace(llama.tiny_config(max_seq_len=64),
-                              **{knob: "interpret"})
+    suite = manifest.load()
+    c = suite.config({"config": config})
+    cfg = suite.builder(c).config({**c, **c["rehearse"]},
+                                  interpret_kernels=True)
     loop = DecodeLoop(cfg, max_len=64, chunk=2)
     slots = 2
-    params = jax.eval_shape(lambda: llama.init_params(
+    params = jax.eval_shape(lambda: cfg.model.init_params(
         cfg, jax.random.PRNGKey(0)))
-    cache = jax.eval_shape(lambda: llama.init_kv_cache(cfg, slots, 64))
+    cache = jax.eval_shape(lambda: cfg.model.init_kv_cache(cfg, slots, 64))
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32)
     kernels = _kernels(
         loop.decode_chunk, params, cache,
         jax.ShapeDtypeStruct((slots, 1), jnp.int32), vec, vec, vec,
         jax.ShapeDtypeStruct((slots,), jnp.bool_))
-    assert kernels and all(k == (name, {"kernel": name}) for k in kernels)
+    assert {name for name, _ in kernels} == SERVED[config]
+    assert all(meta == {"kernel": name} for name, meta in kernels)

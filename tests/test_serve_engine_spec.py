@@ -451,10 +451,9 @@ def test_concurrent_spec_streams(eng_spec):
         assert got[i] == reference_greedy(tiny, p, 7), p
 
 
-# -------------------------------------------------------------- slow sweep
+# ------------------------------------------------------------------- sweep
 
 
-@pytest.mark.slow
 def test_spec_equivalence_sweep(tiny_model):
     """Exhaustive greedy-equivalence sweep across spec configs x prompts
     x budgets (the quick tests above cover one config; this covers the

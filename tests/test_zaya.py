@@ -69,7 +69,7 @@ def test_prefill_then_decode_through_the_cache(tiny, interpret):
     what the slot keeps at the end, against the reference's full
     forward pass."""
     c, cfg, params = tiny
-    cfg = dataclasses.replace(cfg, interpret_decode_kernel=interpret)
+    cfg = dataclasses.replace(cfg, interpret_kernels=interpret)
     tokens = _tokens(2, (1, 40))
     want = np.asarray(reference.logits_at(
         params, tokens, [(0, t) for t in range(23, 40)], c))
