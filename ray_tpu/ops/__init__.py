@@ -29,6 +29,12 @@ gated_delta.gdn_decode     Pallas state step of a gated     on TPU, or ``interpr
                            where it lies (aliased)          ``ray_tpu.ops.gated_delta``, not from
 gated_delta.chunk_scan     (jnp chunked WY form under       here: a family the process does not
                            ``lax.scan``; no kernel yet)     serve costs it no import
+mamba2.mamba2_decode       Pallas state step of a Mamba-2   on TPU, or ``interpret=True`` off-TPU;
+                           layer (S^T of 2 heads a tile,    jnp twin elsewhere. Imported by its one
+                           B and C shared by the heads),    caller (``models/granite_hybrid.py``) as
+                           aliased like gdn_decode          ``ray_tpu.ops.mamba2``; the convolution
+mamba2.chunk_scan          (jnp SSD in chunks of 256 under  beside it is ``gated_delta.causal_conv``
+                           ``lax.scan``; no kernel yet)
 lightning.lightning_decode Pallas state step of a           on TPU, or ``interpret=True`` off-TPU;
                            lightning (constant-decay)       jnp twin elsewhere. ``ops.lightning`` and
                            layer, aliased like gdn_decode   ``ops.sparse_attention`` are imported by

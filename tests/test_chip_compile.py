@@ -1109,3 +1109,105 @@ def test_dots3_tick_prefill_chunk_fits_beside_the_cache(chip):
     assert mem.temp_size_in_bytes < 2 * GIB
     assert held + mem.temp_size_in_bytes < 12.5 * GIB
     assert _copies_of(text, cache) == []
+
+
+# ---------------------------------------------- the Granite-4.0-H cell
+
+# granite-4.0-h-micro's widths (benchmark/configs/granite-4.0-h-micro.json)
+# at one run of each kind and a second Mamba run (a run is scanned, so
+# the HLO is the 40-layer one's with fewer runs), the cell's 64 slots of
+# 2048 rows.
+def _granite_4l():
+    from ray_tpu.models import granite_hybrid as granite
+
+    return granite, granite.GraniteHybridConfig(
+        layer_kinds=("mamba", "mamba", "attention", "mamba"),
+        max_seq_len=2048)
+
+
+def test_mamba2_decode_steps_the_state_where_it_lies(chip):
+    """The state step at the published sizes: S^T of 2 heads side by
+    side ([128, 128]: whole tiles, 2,097,152 B a slot a layer, nothing
+    padded), the whole [L, B, ..] array the operand, aliased to the
+    output: one kernel, under its name, no temporaries."""
+    from ray_tpu.ops import mamba2
+
+    _, cfg = _granite_4l()
+    assert cfg.state_group == 2 and cfg.kv_pack == 2
+    state = _sds(chip, (36, 64, 32, 128, 128), jnp.float32)
+    assert state.size * 4 == 36 * 64 * 2_097_152
+    f32 = functools.partial(_sds, chip, dtype=jnp.float32)
+    c = jax.jit(mamba2.mamba2_decode, donate_argnums=(0,)).lower(
+        state, _sds(chip, (), jnp.int32), f32((64, 64, 64)), f32((64, 64)),
+        f32((64,)), f32((64, 128)), f32((64, 128))).compile()
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_mamba2_decode")
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= state.size * 4
+    assert mem.temp_size_in_bytes < 2 ** 21
+
+
+def test_granite_hybrid_decode_chunk_updates_rows_and_state_in_place(chip):
+    """The family's step through the engine's own `decode_chunk`: both
+    kernels in the scanned runs under their names, the attention
+    layers' rows read where they lie at head size 64 (two KV heads side
+    by side in a 128-lane row: no layer sliced out and padded), all
+    four cache arrays aliased, no array of any of their shapes copied,
+    temporaries far under one cache."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    granite, cfg = _granite_4l()
+    slots, rows = 64, 2048
+    loop = DecodeLoop(cfg, max_len=rows, chunk=8)
+    params = _abstract(chip, functools.partial(granite.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: granite.init_kv_cache(cfg, slots, rows))
+    assert set(cache) == {"k", "v", "ssm", "conv"}
+    assert cache["k"].shape == (1, 64, 4, 2048, 128)
+    c = _lower_decode_chunk(chip, loop, params, cache, slots)
+    text = c.as_text()
+    assert "%rtpu_mamba2_decode." in text
+    assert "%rtpu_decode_attention." in text and "%closed_call" not in text
+    assert "bf16[64,4,2048,128]" not in text      # no layer sliced out
+    nbytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < 2 ** 28
+    assert _copies_of(text, cache) == []
+    vec = _sds(chip, (slots,), jnp.int32)
+    out = jax.eval_shape(
+        loop.decode_chunk, params, cache, _sds(chip, (slots, 1), jnp.int32),
+        vec, vec, vec, _sds(chip, (slots,), jnp.bool_))
+    assert len(out) == 8 and set(out[7]) == {
+        "mamba2_slot_steps", "decode_attn_rows", "decode_attn_rows_streamed"}
+
+
+def test_granite_hybrid_tick_prefill_resets_the_slot_in_the_program(chip):
+    """The tick's prefill at the largest bucket: the chunked scan and
+    the flash kernel (head size 64, 4 query heads a KV head) in it, one
+    token and the two counters out, the cache aliased and no array of
+    its shapes copied."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    granite, cfg = _granite_4l()
+    loop = DecodeLoop(cfg, max_len=2048, chunk=8)
+    params = _abstract(chip, functools.partial(granite.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: granite.init_kv_cache(cfg, 64, 2048))
+    scalar = _sds(chip, (), jnp.int32)
+    args = (params, cache, _sds(chip, (1, 512), jnp.int32), scalar, scalar,
+            scalar)
+    lowered = loop.prefill_inplace.lower(*args)
+    assert "jit_prefill" in lowered.as_text()[:200]
+    c = lowered.compile()
+    out = jax.eval_shape(loop.prefill_inplace, *args)
+    assert (out[0].shape, out[0].dtype) == ((1,), jnp.int32)
+    assert len(out) == 3 and set(out[2]) == {"mamba2_prefill_tokens",
+                                             "state_resets"}
+    text = c.as_text()
+    assert "%flash_attention" in text and " while(" in text
+    nbytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < 2 ** 30
+    assert _copies_of(text, cache) == []

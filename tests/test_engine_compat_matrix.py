@@ -271,7 +271,8 @@ LONG = list(range(2, 22))
 FIRST_WAVE = [ANCHOR, ([4] * 3, 5), ([5] * 3, 13), ([6] * 3, 7), (LONG, 9)]
 SECOND_WAVE = [(LONG, 11), ([7] * 3, 6), ([8] * 5, 2)]
 CHURN_CASES = [("llama", 2), ("llama", 3), ("llama", 4),
-               ("olmo_hybrid", 2), ("minicpm_sala", 3), ("zaya", 3)]
+               ("olmo_hybrid", 2), ("minicpm_sala", 3), ("zaya", 3),
+               ("granite_hybrid", 2)]
 
 
 @pytest.mark.parametrize("family,max_batch", CHURN_CASES)
@@ -657,9 +658,23 @@ def _dots3_note():
         max_seq_len=64, dtype=jnp.float32)
 
 
+def _granite_hybrid():
+    from ray_tpu.models import granite_hybrid
+
+    import jax.numpy as jnp
+
+    return granite_hybrid.GraniteHybridConfig(
+        vocab_size=64, d_model=24,
+        layer_kinds=("mamba", "attention", "mamba", "mamba"), n_heads=2,
+        n_kv_heads=1, head_dim=12, mamba_heads=4, mamba_head_dim=12,
+        mamba_state=8, mamba_chunk=8, d_ff=32, max_seq_len=64,
+        dtype=jnp.float32)
+
+
 # family -> its tiny configuration; a further family is a further row.
 FAMILIES = {"olmo_hybrid": _olmo_hybrid, "minicpm_sala": _minicpm_sala,
-            "zaya": _zaya, "dots3_note": _dots3_note}
+            "zaya": _zaya, "dots3_note": _dots3_note,
+            "granite_hybrid": _granite_hybrid}
 # A family whose per-slot entry is valid by the query's position alone (a
 # ring of last rows): nothing is reset when a slot changes owner, so it
 # neither counts `state_resets` nor stamps them on a span. Every other

@@ -95,6 +95,7 @@ SERVED = {
     "dots3-note-prev-l5-ep8": {"rtpu_dsa_select",
                                "rtpu_dsa_decode_attention",
                                "rtpu_swa_decode_attention"},
+    "granite-4.0-h-micro": {"rtpu_mamba2_decode", "rtpu_decode_attention"},
 }
 
 
