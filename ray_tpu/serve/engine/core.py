@@ -141,7 +141,11 @@ class InferenceEngine:
     chunk and one per admission either way (the witness budget is
     unchanged); at most one trailing chunk per burst is dispatched
     wastefully (every roster member already frozen on device) and
-    dropped unfetched. Disabled automatically while speculation drafts
+    dropped unfetched. A request whose budget or row cap must end it
+    inside the chunk in flight hands its slot to the next waiter
+    before that chunk is fetched (``_hand_over``): the waiter joins
+    chunk N+1 where the slot would have ridden it frozen. Disabled
+    automatically while speculation drafts
     (drafts are proposed from host-visible tokens, which an in-flight
     chunk would lag by one dispatch); ``multi_step=False`` and the
     speculative engine dispatch, fetch and deliver a chunk in one
@@ -666,16 +670,59 @@ class InferenceEngine:
         """Match waiting requests to free slots; each admission becomes
         a prefill job (one chunk per tick — a single chunk when
         ``prefill_chunk`` is off, so unchunked admissions still prefill
-        fully on their admission tick)."""
+        fully on their admission tick). Slots whose holders are sure to
+        end inside the chunk in flight are on offer too
+        (``_hand_over``)."""
         self.scheduler.drain_into(self._queue)
         if self._parked:
             self._resume_tick()
+        lent = self._hand_over()
+        first = len(self._prefilling)
         self._run_admissions()
+        for job in self._prefilling[first:]:
+            if job.adm.slot in lent:
+                self.metrics.record_ahead()
         if self.scheduler.queue_depth() and not self.kv.free_slots():
             # Slot-starved with waiters present: a strictly higher
             # priority class may preempt the lowest-priority active.
             if self._preempt_tick():
                 self._run_admissions()
+
+    def _hand_over(self) -> frozenset:
+        """The early hand-over. A decode chunk is in flight, somebody
+        waits and the free pool cannot seat them: an active request of
+        that chunk's roster that ``scheduler.ends_within`` it — by the
+        budget or the row cap the host knew when it dispatched the
+        chunk, whatever the chunk samples — lets go of its slot NOW,
+        one for each waiter still unseated, where it would have at the
+        chunk's retire, after the next chunk had gone out with the slot
+        frozen in it. The ordinary admission seats the waiter (FIFO and
+        priority classes as ever), this tick's prefill writes the
+        slot's rows (and resets a state family's slot) and its job
+        joins the next chunk: the device's order is still chunk N, the
+        prefills, chunk N+1, so the old request's last step has run by
+        then. What chunk N still owes the old request it gets at the
+        retire (``scheduler.ending``). An EOS ahead of the budget is
+        nobody's to foresee and is seen at the retire as ever; so is
+        every finish on a schedule with no chunk in flight at the top
+        of a tick (serial, speculative). Parked requests resume ahead
+        of the line, so while any is parked nothing is lent to it (and
+        the decode role's installs are not in it). Returns the slots
+        lent."""
+        rec = self._inflight
+        if rec is None or self._parked:
+            return frozenset()
+        need = self.scheduler.queue_depth() - self.kv.free_slots()
+        if need <= 0:
+            return frozenset()
+        k, held = self.loop.chunk, rec["held"]
+        lent = [r for r in self.scheduler.active
+                if held.get(r.slot) is r
+                and self.scheduler.ends_within(r, k)][:need]
+        slots = frozenset(r.slot for r in lent)
+        for req in lent:
+            self.scheduler.hand_over(req)
+        return slots
 
     def _run_admissions(self) -> None:
         for adm in self.scheduler.admissions():
@@ -1678,9 +1725,10 @@ class InferenceEngine:
         """True when some request can still be live AFTER the in-flight
         chunk ``prev`` lands: one that joined the roster since (it is
         not in that chunk at all), or one whose budget and row cap —
-        both known host-side — survive another ``chunk`` tokens. When
-        nobody can, the next chunk would be all-frozen by construction:
-        skip it instead of burning a whole wasted dispatch per burst
+        both known host-side — survive another ``chunk`` tokens
+        (``scheduler.ends_within``, which ``_hand_over`` asks of one
+        slot at a time). When nobody can, the next chunk would be
+        all-frozen by construction: skip it instead of burning a whole wasted dispatch per burst
         (short generations — budget <= chunk — would otherwise pay ~2x
         decode compute for zero tokens). EOS is the one early stop the
         host can't predict; an EOS-ended burst still wastes at most one
@@ -1691,7 +1739,7 @@ class InferenceEngine:
         k = self.loop.chunk
         held = prev["held"]
         return any(held.get(r.slot) is not r
-                   or (r.remaining() > k and r.length + k + 1 < self.max_len)
+                   or not self.scheduler.ends_within(r, k)
                    for r in active)
 
     def _dispatch_chunk(self, prev: Optional[Dict[str, Any]] = None,
@@ -1763,11 +1811,13 @@ class InferenceEngine:
 
     def _retire_chunk(self, rec: Dict[str, Any]) -> bool:
         """The tick's ONE host fetch: land the chunk's tokens, deliver
-        to whoever it was dispatched with and still holds the slot (a
-        request that finished since reports n_valid 0 — the device
-        carried its done mask; one that failed or was parked since has
-        let go of its slot), retire finishes. False on device
-        failure."""
+        to whoever it was dispatched with and still holds the slot or
+        is ``ending`` (handed its slot over ahead: the slot's rows and
+        occupancy are the newcomer's, the chunk's tokens still this
+        request's, and they finish it). A request that finished since
+        reports n_valid 0 — the device carried its done mask; one that
+        failed or was parked since has let go of its slot. Retire
+        finishes. False on device failure."""
         began = self._fetch_blocked_t
         try:
             with self._tick.phase("decode_fetch",
@@ -1793,8 +1843,9 @@ class InferenceEngine:
         # previous retire ended just before this record's dispatch).
         elapsed = now - max(rec["t0"], self._last_retire_t)
         self._last_retire_t = now
+        ending = self.scheduler.ending
         holders = [(slot, req) for slot, req in rec["held"].items()
-                   if req.slot == slot]
+                   if req.slot == slot or req in ending]
         delivered = 0
         self.metrics.record_model_counters(counters)
         touched = self._span_attrs(counters)
@@ -1809,7 +1860,8 @@ class InferenceEngine:
                 for j in range(n):
                     tok = int(chunk_ids[slot, j])
                     req.length += 1
-                    self.kv.grow(slot)  # block-granular occupancy
+                    if req.slot == slot:
+                        self.kv.grow(slot)  # block-granular occupancy
                     req.generated.append(tok)
                     if req.stream_queue is not None:
                         req.stream_queue.put(("token", tok))
@@ -1999,7 +2051,8 @@ class InferenceEngine:
             self.metrics.record_depths(self.scheduler.queue_depth(),
                                        len(self.scheduler.active),
                                        self.kv.hit_rate())
-            if not self.scheduler.active and not landing:
+            if (not self.scheduler.active and not landing
+                    and not self.scheduler.ending):
                 if self._prefilling or self._install_waiting:
                     continue  # keep chunked prefills / installs advancing
                 # A burst just drained: the multi-step trailing chunk

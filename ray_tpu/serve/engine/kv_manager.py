@@ -303,6 +303,9 @@ class KVCacheManager:
         generated tokens that went back through the model) — they seed
         future prefix-cache hits. None/() disables reuse for this slot.
         """
+        if slot < 0:
+            # -1 is "holds no slot" (EngineRequest.slot), not the last.
+            raise ValueError("release of a request that holds no slot")
         info = self._slots[slot]
         if not info.in_use:
             return

@@ -62,6 +62,10 @@ ADMISSIONS_HEARD_TOTAL = _m.Counter(
     "rtpu_llm_admissions_heard_total",
     "admissions whose first prefill chunk was dispatched from the tick's "
     "listening wait, behind the decode chunk in flight")
+ADMISSIONS_AHEAD_TOTAL = _m.Counter(
+    "rtpu_llm_admissions_ahead_total",
+    "admissions into a slot whose last holder, sure to end inside the "
+    "decode chunk in flight, let go of it ahead of that chunk's retire")
 LISTEN_DEADLINE_LATE_TOTAL = _m.Counter(
     "rtpu_llm_listen_deadline_late_total",
     "ticks whose listening wait outran what it waited for: the next "
@@ -126,6 +130,10 @@ class EngineMetrics:
         # dispatched to a device that had run dry, and idled.
         self.admissions_heard = 0
         self.listen_deadline_late = 0
+        # The early hand-over (core.py ``_hand_over``): admissions into
+        # a slot whose last holder was still in the chunk in flight
+        # (against ``requests``).
+        self.admissions_ahead = 0
         # Prefill programs dispatched and the REAL tokens they carried
         # (``prefill_tokens`` counts a whole prompt, at its last chunk).
         self.prefill_chunks_dispatched = 0
@@ -230,6 +238,11 @@ class EngineMetrics:
         self.admissions_heard += 1
         ADMISSIONS_HEARD_TOTAL.inc(labels=self._labels)
 
+    def record_ahead(self) -> None:
+        """One admission into a slot handed over ahead."""
+        self.admissions_ahead += 1
+        ADMISSIONS_AHEAD_TOTAL.inc(labels=self._labels)
+
     def record_listen_late(self) -> None:
         """One tick whose listening wait left the device to run dry."""
         self.listen_deadline_late += 1
@@ -302,6 +315,7 @@ class EngineMetrics:
                 "decode_chunks_dispatched": self.chunks_dispatched,
                 "decode_chunks_carried": self.chunks_carried,
                 "admissions_heard": self.admissions_heard,
+                "admissions_ahead": self.admissions_ahead,
                 "listen_deadline_late": self.listen_deadline_late,
                 "prefill_chunks_dispatched": self.prefill_chunks_dispatched,
                 "prefill_chunk_tokens": self.prefill_chunk_tokens,

@@ -129,6 +129,10 @@ class Scheduler:
             self.prefill_chunk = 0
         self._waiting: Deque[EngineRequest] = collections.deque()
         self.active: List[EngineRequest] = []
+        # Requests that let go of their slot ahead of the retire that
+        # finishes them (``hand_over``): on no roster, holding no slot,
+        # still owed the tokens of the decode chunk in flight.
+        self.ending: List[EngineRequest] = []
         self.peak_active = 0
 
     # ------------------------------------------------------------- intake
@@ -249,35 +253,63 @@ class Scheduler:
                 or (req.eos_id is not None and last_tok == req.eos_id)
                 or req.length + 1 >= self.max_len)
 
-    def finish(self, req: EngineRequest) -> None:
-        """Retire an active request; its slot returns to the pool with
-        its resident tokens recorded for prefix reuse. Rows [0, length)
-        hold KV for prompt + generated[:-1] (the final generated token
-        never went back through the model)."""
+    def ends_within(self, req: EngineRequest, k: int) -> bool:
+        """``is_finished`` foreseen: the request's next ``k`` tokens are
+        sure to end it, by its budget or its row cap — the two clauses
+        above that the host knows ahead (an EOS can only end it
+        sooner). A decode chunk of ``k`` steps in flight for ``req``
+        leaves its slot frozen, whatever it samples (the scan applies
+        the same rules, ``decode_loop.decode_chunk``)."""
+        return req.remaining() <= k or req.length + k + 1 >= self.max_len
+
+    def _vacate(self, req: EngineRequest) -> None:
+        """``req``'s slot returns to the pool with its CONFIRMED rows
+        resident: rows [0, length) hold KV for prompt + generated[:-1]
+        as the host knows them (the last generated token never went
+        back through the model, and rows a chunk still in flight is
+        writing past them are never seeded)."""
         if req in self.active:
             self.active.remove(req)
         resident = list(req.prompt_ids) + list(req.generated[:-1])
         self.kv.release(req.slot, resident_tokens=resident)
         req.slot = -1
+
+    def finish(self, req: EngineRequest) -> None:
+        """Retire a request; its slot returns to the pool with its
+        resident tokens recorded for prefix reuse. One that handed its
+        slot over ahead (``ending``) has nothing left to return."""
+        if req in self.ending:
+            self.ending.remove(req)
+        else:
+            self._vacate(req)
 
     def preempt(self, req: EngineRequest) -> None:
         """Park an active request (priority preemption): the slot
-        returns to the pool with the CONFIRMED rows resident — prompt +
-        generated[:-1], exactly what finish() would seed — so the
-        resume continuation's re-prefill is a prefix-cache hit (or,
+        returns to the pool with exactly what finish() would seed, so
+        the resume continuation's re-prefill is a prefix-cache hit (or,
         once those rows are evicted and spilled, a fleet-tier pull)."""
-        if req in self.active:
-            self.active.remove(req)
-        resident = list(req.prompt_ids) + list(req.generated[:-1])
-        self.kv.release(req.slot, resident_tokens=resident)
-        req.slot = -1
+        self._vacate(req)
+
+    def hand_over(self, req: EngineRequest) -> None:
+        """An active request that ``ends_within`` the decode chunk in
+        flight lets go of its slot NOW, a tick ahead of the retire that
+        finishes it: the slot is the next waiter's to prefill behind
+        that chunk, and the request waits on ``ending`` for the chunk's
+        tokens (``core._retire_chunk`` delivers them by the chunk's own
+        record of who it was dispatched with)."""
+        self._vacate(req)
+        self.ending.append(req)
 
     def fail_active(self) -> List[EngineRequest]:
         """Device failure: retire the whole roster (slots recycled, no
-        prefix reuse) and hand the requests back for error delivery."""
+        prefix reuse) and hand the requests back for error delivery —
+        those the chunk in flight still owed tokens (``ending``) among
+        them."""
         failed = list(self.active)
         for req in failed:
             self.active.remove(req)
             self.kv.release(req.slot, resident_tokens=())
             req.slot = -1
+        failed += self.ending
+        self.ending = []
         return failed
