@@ -55,7 +55,10 @@ tail taken at the real length), and the decode step steps only the
 slots of its ``live`` mask (`kda.kda_decode`, one Pallas call a KDA
 layer for all slots).
 
-What the engine's seam asks: `init_params`, `init_kv_cache`,
+What the engine's seam asks: `init_params`, `serving_params` (the
+published tree -> the one every program of this module reads: the
+maps of a KDA layer's normed stream, q, k and v first, as ONE matrix a
+layer; the engine calls it once, where it takes its weights), `init_kv_cache`,
 `forward_with_cache`, `forward_last_with_cache` (the tick's prefill:
 one row of logits), `decode_step_with_cache`; each returns ``(logits,
 cache, counters, seen)``: ``counters`` ride the fetch the tick makes
@@ -317,6 +320,41 @@ def init_params(cfg: KimiLinearConfig, key: jax.Array) -> Params:
     }
 
 
+# The maps that read a KDA layer's normed stream, in the order of
+# ``w_in``'s outputs: q ++ k ++ v (each head-major: `conv_w`'s
+# channels), then the decay's and the gate's low ranks and beta.
+KDA_IN = ("w_q", "w_k", "w_v", "w_fa", "w_ga", "w_b")
+
+
+@jax.jit
+def _merged_in(*stacks):
+    """[nk, d, ..] stacks -> one [nk, all their outputs, d]."""
+    return jnp.concatenate([w.reshape(w.shape[:2] + (-1,)) for w in stacks],
+                           axis=-1).transpose(0, 2, 1)
+
+
+def serving_params(params: Params, cfg: KimiLinearConfig) -> Params:
+    """`init_params`'s tree (the published form: a checkpoint's
+    ``q_proj``, ``k_proj``, ``v_proj`` split by head, and what a
+    reference reads) -> the tree EVERY program of this module reads:
+    ``params["kda"]``'s six maps of the normed stream (`KDA_IN`) as ONE
+    stack ``w_in`` [nk, 3*H*dk + 2*r + H, d], output-major. A layer's
+    ``w_q`` indexed out of a [nk, d, H, dk] stack and contracted with
+    its heads split was sliced out of the stack and turned into the
+    layout the product reads, every layer-step (the chip's trace, PR
+    50: 3 x 18.9 MB a layer, 1.5 ms of a 24.5 ms decode step beside
+    1.8 ms of products); merged, one product slices the stack inside
+    its own fusion (PR 51: 1.6 ms for all six). Output-major because
+    the 2*r + H further outputs make the width no multiple of 128
+    lanes: input-major the compiler laid the WHOLE stack out again a
+    chunk (1.16 GB of temporaries; q, k and v alone it read in place
+    either way). One jitted call; every other leaf is handed on as the
+    same buffer."""
+    stack = dict(params[KDA])
+    w_in = _merged_in(*(stack.pop(w) for w in KDA_IN))
+    return dict(params, **{KDA: dict(stack, w_in=w_in)})
+
+
 # Feed-forward -------------------------------------------------------------
 
 def _swiglu(n, w_gate, w_up, w_down):
@@ -360,17 +398,13 @@ def _kda_projections(h, layer, cfg: KimiLinearConfig):
     """h [B,T,d] (normed) -> (u [B,T,C]: q~ ++ k~ ++ v~ before the
     convolution, g [B,T,H,dk] (the log decay, a vector a head), beta
     [B,T,H], gate [B,T,H,dv] before its sigmoid)."""
-    b, t, _ = h.shape
-    u = jnp.concatenate(
-        [_mm("btd,dhk->bthk", h, layer[w]).reshape(b, t, -1)
-         for w in ("w_q", "w_k", "w_v")], axis=-1)
-    low = _mm("btd,dr->btr", h, layer["w_fa"])
+    c, r = cfg.conv_channels, cfg.kda_rank
+    u, low, gate_low, beta = jnp.split(
+        _mm("btd,cd->btc", h, layer["w_in"]), (c, c + r, c + 2 * r), axis=-1)
     g = -jnp.exp(layer["a_log"])[:, None] * jax.nn.softplus(
         _mm("btr,rhk->bthk", low, layer["w_fb"]) + layer["dt_bias"])
-    beta = jax.nn.sigmoid(_mm("btd,dh->bth", h, layer["w_b"]))
-    gate = _mm("btr,rhv->bthv", _mm("btd,dr->btr", h, layer["w_ga"]),
-               layer["w_gb"])
-    return u, g, beta, gate
+    gate = _mm("btr,rhv->bthv", gate_low, layer["w_gb"])
+    return u, g, jax.nn.sigmoid(beta), gate
 
 
 def _heads(y, cfg: KimiLinearConfig):

@@ -31,7 +31,7 @@ import numpy as np
 
 from ray_tpu.devtools import jax_debug
 from ray_tpu.devtools import res_debug as _resdbg
-from ray_tpu.serve.engine.decode_loop import DecodeLoop
+from ray_tpu.serve.engine.decode_loop import DecodeLoop, serving_params
 from ray_tpu.serve.engine.drafter import PromptLookupDrafter, SpecControl
 from ray_tpu.serve.engine.kv_manager import KVCacheManager, chain_hashes
 from ray_tpu.serve.engine.metrics import EngineMetrics, TickClock
@@ -210,6 +210,10 @@ class InferenceEngine:
             self.params = (params if params is not None
                            else self.model.init_params(
                                self.cfg, jax.random.PRNGKey(seed)))
+            # The layout the family's programs read, made ONCE from the
+            # published tree; a family without one serves the tree it
+            # was given.
+            self.params = serving_params(self.cfg, self.params)
             if quantize is not None:
                 # Weight-only int8 (models/quant.py): decode/verify
                 # stream half the weight bytes per step; every engine

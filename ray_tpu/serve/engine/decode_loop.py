@@ -45,7 +45,9 @@ engine's cache is ONE buffer: the chunk, verify, install and
 tick-prefill programs take it donated and return it aliased, so the
 caller must rebind its reference to the result (``self.cache = ...``)
 and must rebuild the cache if a donated call raises — the old array is
-deleted either way.
+deleted either way. The ``params`` every program takes are the tree
+`serving_params` hands back: the family's own layout of its published
+weights where its module has one, else the tree as it was drawn.
 
 Speculative verification (``spec_window`` > 1) adds a SECOND chunk
 program, ``verify_chunk``: each scan iteration forwards a ``[B, W]``
@@ -62,6 +64,18 @@ W-row window overwrite resident prefix KV).
 """
 
 from __future__ import annotations
+
+
+def serving_params(cfg, params):
+    """``params`` as ``cfg.model.init_params`` draws them (the published
+    form, which a checkpoint arrives in and a reference reads) -> the
+    tree a `DecodeLoop`'s programs read: what the module's optional
+    ``serving_params(params, cfg)`` makes of it, once (`kimi_linear`:
+    the stacks that read a KDA layer's normed stream as one, in the
+    layout the product reads where it lies); the SAME tree where the
+    module has no such function."""
+    relayout = getattr(cfg.model, "serving_params", None)
+    return params if relayout is None else relayout(params, cfg)
 
 
 class DecodeLoop:

@@ -733,6 +733,37 @@ def test_a_refused_option_the_engine_does_not_know_is_named(monkeypatch):
                         kv_fleet_min_prefix_blocks=-1)
 
 
+@pytest.mark.parametrize("family", ["llama", *FAMILIES])
+def test_the_engine_serves_the_tree_its_family_lays_out(family):
+    """The model seam's optional `serving_params(params, cfg)`: a
+    family WITHOUT it serves the very tree it was handed
+    (``engine.params is params``: no copy, no program); a family with
+    it is handed the published tree and serves what the function makes
+    of it, every leaf the function leaves alone the same buffer."""
+    import jax
+
+    from ray_tpu.serve.engine.core import InferenceEngine
+
+    cfg = _family_cfg(family)
+    params = cfg.model.init_params(cfg, jax.random.PRNGKey(7))
+    engine = InferenceEngine(cfg, params, max_batch=2, max_len=64,
+                             prompt_buckets=[8, 16],
+                             kv_fleet_min_prefix_blocks=-1)
+    try:
+        if not hasattr(cfg.model, "serving_params"):
+            assert engine.params is params
+            return
+        given, held = (dict(jax.tree_util.tree_leaves_with_path(t))
+                       for t in (params, engine.params))
+        assert set(given) != set(held)              # something was laid out
+        untouched = set(given) & set(held)
+        assert untouched and all(held[k] is given[k] for k in untouched)
+        assert len(engine.generate([3, 1, 4, 1, 5],
+                                   max_new_tokens=3)["token_ids"]) == 3
+    finally:
+        engine.close()
+
+
 @pytest.mark.parametrize("family", FAMILIES)
 def test_a_family_with_slot_state_reuses_no_prefix(family):
     from ray_tpu.serve.engine.core import InferenceEngine
@@ -752,11 +783,15 @@ def _prefill_counters(cfg) -> dict:
     """The scalars a family's tick prefill counts, by name."""
     import jax
 
+    from ray_tpu.serve.engine.decode_loop import serving_params
+
     cache = cfg.model.init_kv_cache(cfg, 1, 16)
     out = jax.eval_shape(
         lambda p, c: cfg.model.forward_last_with_cache(
             p, jax.numpy.zeros((1, 8), "int32"), c, 0, 7, cfg)[2],
-        cfg.model.init_params(cfg, jax.random.PRNGKey(0)), cache)
+        serving_params(cfg, cfg.model.init_params(cfg,
+                                                  jax.random.PRNGKey(0))),
+        cache)
     return dict.fromkeys(out, 0)
 
 

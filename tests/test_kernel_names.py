@@ -108,7 +108,7 @@ def test_decode_chunk_carries_its_attention_kernels_name(config):
     one hook every family's configuration has): the kernels of the
     scanned layer bodies are the named ones, and no other."""
     from benchmark.harness import manifest
-    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop, serving_params
 
     suite = manifest.load()
     c = suite.config({"config": config})
@@ -116,8 +116,8 @@ def test_decode_chunk_carries_its_attention_kernels_name(config):
                                   interpret_kernels=True)
     loop = DecodeLoop(cfg, max_len=64, chunk=2)
     slots = 2
-    params = jax.eval_shape(lambda: cfg.model.init_params(
-        cfg, jax.random.PRNGKey(0)))
+    params = jax.eval_shape(lambda: serving_params(
+        cfg, cfg.model.init_params(cfg, jax.random.PRNGKey(0))))
     cache = jax.eval_shape(lambda: cfg.model.init_kv_cache(cfg, slots, 64))
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32)
     kernels = _kernels(

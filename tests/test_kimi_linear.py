@@ -50,8 +50,19 @@ ENGINE = dict(max_batch=2, max_len=128, prompt_buckets=[32, 64],
 
 @pytest.fixture(scope="module")
 def tiny():
+    """(cfg, the PUBLISHED tree: what the builder draws, the reference
+    reads and an engine is handed)."""
     cfg = builder.config(SHARE)
     return cfg, builder.init_params(cfg, 3)
+
+
+@pytest.fixture(scope="module")
+def served(tiny):
+    """(cfg, the tree the module's programs read: `serving_params` of
+    the published one, as the engine makes it where it takes its
+    weights)."""
+    cfg, params = tiny
+    return cfg, kimi.serving_params(params, cfg)
 
 
 def _rel(a, b):
@@ -187,17 +198,58 @@ def test_the_published_order_is_scanned_a_period_at_a_time():
     assert builder.reference.held_experts(file) == (0, 16, 256)
 
 
-def test_forward_equals_the_plain_reference(tiny):
+def test_serving_params_is_a_pure_relayout(tiny, served):
+    """Each KDA layer's ``w_in``, cut where its six maps end and turned
+    back, IS ``w_q``, ``w_k``, ``w_v`` (q ++ k ++ v, each head-major:
+    `conv_w`'s channels), ``w_fa``, ``w_ga`` and ``w_b``, bit for bit;
+    every other leaf is the same array, not a copy; `init_params`' keys
+    and shapes are the published ones."""
     cfg, params = tiny
+    _, laid = served
+    nk, d, h, dk, r = (cfg.n_kda_layers, cfg.d_model, cfg.kda_heads,
+                       cfg.kda_head_dim, cfg.kda_rank)
+    assert set(params["kda"]) - set(laid["kda"]) == set(kimi.KDA_IN)
+    assert set(laid["kda"]) - set(params["kda"]) == {"w_in"}
+    w_in = laid["kda"]["w_in"]
+    c = cfg.conv_channels
+    assert kimi.KDA_IN[:3] == ("w_q", "w_k", "w_v") and c == 3 * h * dk
+    assert w_in.shape == (nk, c + 2 * r + h, d)
+    assert w_in.dtype == params["kda"]["w_q"].dtype
+    ends = np.cumsum([h * dk] * 3 + [r, r])
+    for name, part in zip(kimi.KDA_IN, jnp.split(w_in, ends, axis=1)):
+        published = params["kda"][name]
+        np.testing.assert_array_equal(
+            part.transpose(0, 2, 1).reshape(published.shape), published,
+            err_msg=name)
+    same = jax.tree.map(
+        lambda a, b: a is b,
+        {**params, "kda": {k: v for k, v in params["kda"].items()
+                           if k not in kimi.KDA_IN}},
+        {**laid, "kda": {k: v for k, v in laid["kda"].items()
+                         if k != "w_in"}})
+    assert all(jax.tree.leaves(same))
+    # The draw is the published form, whatever is served.
+    drawn = jax.eval_shape(lambda: kimi.init_params(cfg,
+                                                    jax.random.PRNGKey(0)))
+    assert "w_in" not in drawn["kda"]
+    assert all(drawn["kda"][w].shape == (nk, d, h, dk)
+               for w in kimi.KDA_IN[:3])
+    assert drawn["kda"]["w_b"].shape == (nk, d, h)
+
+
+def test_forward_equals_the_plain_reference(tiny, served):
+    _, params = tiny
+    cfg, laid = served
     tokens = np.random.default_rng(0).integers(1, 256, (2, 90))
     rows = [(0, i) for i in range(0, 90, 7)] + [(1, 89), (1, 40)]
     want = builder.reference.logits_at(params, tokens, rows, SHARE)
-    logits = kimi.forward(params, jnp.asarray(tokens), cfg)
+    logits = kimi.forward(laid, jnp.asarray(tokens), cfg)
     got = jnp.stack([logits[s, p] for s, p in rows])
     assert _rel(got, want) < 2e-4
 
 
-def test_prefill_then_decode_through_the_cache_equals_the_reference(tiny):
+def test_prefill_then_decode_through_the_cache_equals_the_reference(tiny,
+                                                                    served):
     """The tick's prefill of 50 and of 20 tokens in buckets of 64 and 32
     into slots that hold another request's leavings, then 30 steps
     through the cache (the kernels interpreted), a third slot parked on
@@ -206,6 +258,7 @@ def test_prefill_then_decode_through_the_cache_equals_the_reference(tiny):
     from ray_tpu.serve.engine.decode_loop import DecodeLoop
 
     cfg, params = tiny
+    _, laid = served
     cfg = dataclasses.replace(cfg, interpret_kernels=True)
     tokens = np.random.default_rng(1).integers(1, 256, (2, 80))
     starts = (50, 20)
@@ -216,7 +269,7 @@ def test_prefill_then_decode_through_the_cache_equals_the_reference(tiny):
         padded = np.zeros((1, 64 if n > 32 else 32), np.int32)
         padded[0, :n] = tokens[s, :n]
         logits, cache, counters, seen = loop.prefill_last(
-            params, cache, jnp.asarray(padded), jnp.int32(s), jnp.int32(0),
+            laid, cache, jnp.asarray(padded), jnp.int32(s), jnp.int32(0),
             jnp.int32(n - 1))
         got[s].append(logits[0])
         assert int(counters["kda_prefill_tokens"]) == n
@@ -230,7 +283,7 @@ def test_prefill_then_decode_through_the_cache_equals_the_reference(tiny):
         for s in range(2):
             step_tokens[s, 0] = tokens[s, starts[s] + j]
         logits, cache, counters, seen = loop.decode_step_whole(
-            params, cache, jnp.asarray(step_tokens), jnp.asarray(lengths))
+            laid, cache, jnp.asarray(step_tokens), jnp.asarray(lengths))
         assert int(counters["kda_slot_steps"]) == 3 * 5
         assert int(counters["mla_decode_rows"]) == int(lengths.sum()) + 3
         assert int(counters["moe_layer_steps"]) == 6
@@ -250,11 +303,11 @@ def test_prefill_then_decode_through_the_cache_equals_the_reference(tiny):
         assert _rel(state, want_s) < 1e-4
 
 
-def test_chunked_prefill_equals_whole_prefill(tiny):
+def test_chunked_prefill_equals_whole_prefill(served):
     """64 tokens in one piece, and as 32 + 32 (the second continued from
     the slot's state, conv tail and latent rows at ``cache_index`` 32):
     the same logits and the same cache."""
-    cfg, params = tiny
+    cfg, params = served
     tokens = jnp.asarray(np.random.default_rng(2).integers(1, 256, (1, 64)))
     fresh = kimi.init_kv_cache(cfg, 1, 128)
     whole, cache_whole, _, _ = kimi.forward_with_cache(params, tokens, fresh,
@@ -369,9 +422,9 @@ def _ask(handle, prompt, n=10):
                           "max_new_tokens": n}).result()["token_ids"]
 
 
-def _greedy(tiny, prompt, got):
+def _greedy(served, prompt, got):
     """Teacher-forced: each token the argmax after what precedes it."""
-    cfg, params = tiny
+    cfg, params = served
     logits = kimi.forward(params, jnp.asarray([prompt + got]), cfg)[0]
     return np.asarray(jnp.argmax(logits[len(prompt) - 1:-1], -1)).tolist()
 
@@ -381,7 +434,7 @@ def _prompts(seed, *lengths):
     return [[int(t) for t in rng.integers(1, 256, n)] for n in lengths]
 
 
-def test_engine_resets_a_slots_state_and_reuses_no_prefix(tiny):
+def test_engine_resets_a_slots_state_and_reuses_no_prefix(tiny, served):
     """Through `serve.run(build_llm_deployment(..))`, one slot: request
     B after A gets the tokens a fresh engine gives it (state and conv
     tail were reset in the tick's prefill), and A again finds its rows
@@ -391,6 +444,10 @@ def test_engine_resets_a_slots_state_and_reuses_no_prefix(tiny):
     handle, engine = _serve(tiny, max_batch=1)
     try:
         assert set(engine.cache) == {"kv", "state", "conv"}
+        # Handed the published tree, the engine serves the laid-out one.
+        assert "w_in" in engine.params["kda"]
+        assert not set(kimi.KDA_IN) & set(engine.params["kda"])
+        assert engine.params["moe"]["w_gate"] is tiny[1]["moe"]["w_gate"]
         got_a = _ask(handle, a)
         again = _ask(handle, a)
         got_b = _ask(handle, b)
@@ -402,8 +459,8 @@ def test_engine_resets_a_slots_state_and_reuses_no_prefix(tiny):
         assert _ask(fresh, b) == got_b
     finally:
         fresh_engine.close()
-    assert got_a == _greedy(tiny, a, got_a) and again == got_a
-    assert got_b == _greedy(tiny, b, got_b)
+    assert got_a == _greedy(served, a, got_a) and again == got_a
+    assert got_b == _greedy(served, b, got_b)
     assert stats["prefix_reuse_vetoed"] == 1
     assert stats["prefix_hits"] == 0 and stats["prefix_tokens_reused"] == 0
     assert stats["state_resets"] == 3
@@ -425,7 +482,7 @@ def test_engine_resets_a_slots_state_and_reuses_no_prefix(tiny):
         "state_reset": 1, "expert_pairs_held": 7}
 
 
-def test_slots_that_are_not_live_leave_the_others_alone(tiny):
+def test_slots_that_are_not_live_leave_the_others_alone(tiny, served):
     """Two slots: a request of 6 tokens freezes in the middle of the
     other's chunks and its slot then idles; the other's 30 tokens are
     the model's own greedy ones, and the idle slot's state is finite
@@ -444,6 +501,6 @@ def test_slots_that_are_not_live_leave_the_others_alone(tiny):
         state = np.asarray(engine.cache["state"])
     finally:
         engine.close()
-    assert got["long"] == _greedy(tiny, long, got["long"])
-    assert got["short"] == _greedy(tiny, short, got["short"])
+    assert got["long"] == _greedy(served, long, got["long"])
+    assert got["short"] == _greedy(served, short, got["short"])
     assert np.isfinite(state).all() and state.any(axis=(0, 2, 3, 4)).all()
