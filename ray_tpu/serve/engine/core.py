@@ -34,7 +34,8 @@ from ray_tpu.devtools import res_debug as _resdbg
 from ray_tpu.serve.engine.decode_loop import DecodeLoop, serving_params
 from ray_tpu.serve.engine.drafter import PromptLookupDrafter, SpecControl
 from ray_tpu.serve.engine.kv_manager import KVCacheManager, chain_hashes
-from ray_tpu.serve.engine.metrics import EngineMetrics, TickClock
+from ray_tpu.serve.engine.metrics import (DeviceQueue, EngineMetrics,
+                                          TickClock)
 from ray_tpu.serve.engine.scheduler import (EngineRequest, Scheduler,
                                             bucket_for)
 from ray_tpu.util import compile_cache as _compile_cache
@@ -56,7 +57,8 @@ class _PrefillJob:
     device, once the FINAL chunk is dispatched and until
     ``_land_prefill`` fetches it. Engine-thread-only."""
 
-    __slots__ = ("adm", "pos", "idx", "t_pf0", "t0", "counters", "token")
+    __slots__ = ("adm", "pos", "idx", "t_pf0", "t0", "counters", "token",
+                 "programs", "ahead")
 
     def __init__(self, adm, pos: int):
         self.adm = adm
@@ -66,6 +68,11 @@ class _PrefillJob:
         self.t0 = 0.0
         self.counters: list = []
         self.token = None
+        # The chunks dispatched so far as the device's queue holds them
+        # (`DeviceQueue.put`), and how many (chunks, prefills) the
+        # first of them queued behind.
+        self.programs: list = []
+        self.ahead = (0, 0)
 
 
 def _timed_init(init):
@@ -300,6 +307,7 @@ class InferenceEngine:
         # The engine thread's phase clock (engine.tick.* counters and
         # spans); created here, used by that thread alone.
         self._tick = TickClock(self.metrics, jax.profiler.TraceAnnotation)
+        self._devq = DeviceQueue(self.metrics, self._tick)
 
         # Fleet KV page tier: evicted prefix blocks spill into a shared
         # page store (shm when a cluster runtime is attached, an
@@ -353,19 +361,11 @@ class InferenceEngine:
         self._resumes = 0
         self._last_retire_t = 0.0  # TPOT cadence anchor (see _retire_chunk)
         # What the listening wait (_listen) derives its deadline from,
-        # all measured by the tick itself: the stamp at which the last
-        # fetch returned IF it had to wait for the device (the program
-        # queued behind the fetched one began then; None when the fetch
-        # found its result ready, so nothing is known), the device
-        # seconds of the last few chunks whose start and end were both
-        # seen that way, and the host seconds of the last few carried
-        # dispatches. ``_unfetched_ahead``: a prefill chunk that no
-        # fetch will stamp the end of was dispatched since the last
-        # decode chunk, so the next chunk's start will not be seen.
-        self._fetch_blocked_t: Optional[float] = None
-        self._chunk_s: Deque[float] = deque(maxlen=8)
+        # all measured by the tick itself: the device's queue
+        # (``_devq``: when the chunk in flight began, the device
+        # seconds of the last few chunks whose ends were both seen) and
+        # the host seconds of the last few carried dispatches.
         self._dispatch_s: Deque[float] = deque(maxlen=8)
-        self._unfetched_ahead = False
         self._queue: "queue.Queue[EngineRequest]" = queue.Queue()
         # Decode role: KV-page install jobs handed over from prefill
         # replicas. Device work happens on the engine thread (installs
@@ -652,8 +652,8 @@ class InferenceEngine:
         out = self._jax.device_get(tree)  # rtpu-lint: disable=host-sync-in-hot-path — this IS the counted sync
         # A fetch that had to wait returns when the device ends that
         # program and begins the next in its queue: the one stamp of
-        # the device's own clock the host gets (see _listen_deadline).
-        self._fetch_blocked_t = time.perf_counter() if waits else None
+        # the device's own clock the host gets (see DeviceQueue).
+        self._devq.fetched(time.perf_counter() if waits else None)
         return out
 
     def _put(self, value):
@@ -1195,6 +1195,8 @@ class InferenceEngine:
                 job.t0 = self._tick.now
                 if job.idx == 0:
                     job.t_pf0 = job.t0
+                    job.ahead = self._devq.ahead()
+                    self.metrics.record_first_dispatch(*job.ahead)
                 suffix = req.prompt_ids[job.pos:job.pos + n]
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :n] = suffix
@@ -1207,6 +1209,9 @@ class InferenceEngine:
                 # Every chunk's counters ride the final chunk's fetch (a
                 # state family resets its slot in the FIRST chunk).
                 job.counters.extend(counters)
+                job.programs.append(self._devq.put(
+                    "prefill", job.t0, token.is_ready, tokens=n,
+                    bucket=bucket))
                 self.metrics.record_prefill_chunk(n)
                 # Per-chunk prefix commit: block occupancy and the
                 # slot's resident chain track the materialized prefix
@@ -1220,7 +1225,6 @@ class InferenceEngine:
         if final:
             job.token = token
         else:
-            self._unfetched_ahead = True
             self._prefill_span(job, job.idx, self._tick.now, ())
         job.idx += 1
         job.pos += n
@@ -1249,11 +1253,16 @@ class InferenceEngine:
         if req.trace_ctx is None:
             return
         n, bucket = job.adm.chunks[idx]
-        self._span("engine.prefill", job.t0, t1, req,
-                   {"prefill_tokens": n, "cached_tokens": job.adm.cached_len,
-                    "bucket": bucket, "slot": job.adm.slot,
-                    "chunk": idx, "chunks": len(job.adm.chunks),
-                    **self._span_attrs(counters)})
+        attrs = {"prefill_tokens": n, "cached_tokens": job.adm.cached_len,
+                 "bucket": bucket, "slot": job.adm.slot,
+                 "chunk": idx, "chunks": len(job.adm.chunks),
+                 "ahead_chunks": job.ahead[0],
+                 "ahead_prefills": job.ahead[1],
+                 **self._span_attrs(counters)}
+        split = job.programs[idx].split()
+        if split is not None:
+            attrs["behind_s"], attrs["own_s"] = split
+        self._span("engine.prefill", job.t0, t1, req, attrs)
 
     def _land_prefill(self, job: "_PrefillJob") -> None:
         """The ONE counted prefill sync of an admission, and what its
@@ -1286,6 +1295,11 @@ class InferenceEngine:
             return
         self._prefilling.remove(job)
         t1 = self._tick.now  # the first token is on the host
+        self._devq.seen(job.programs[-1], t1)
+        splits = [p.split() for p in job.programs]
+        if all(splits):
+            self.metrics.record_prefill_split(
+                sum(s[0] for s in splits), sum(s[1] for s in splits))
         self._prefill_span(job, len(job.adm.chunks) - 1, t1, counters)
         with self._tick.phase("prefill_deliver", slot=slot):
             # First generated token: from the LAST REAL prompt pos (row
@@ -1621,9 +1635,8 @@ class InferenceEngine:
         if self._roster_outlives_chunk(prev, joining):
             # Bound before the retire: a device failure found there
             # drops it with the cache (_recover_cache).
-            self._inflight = self._dispatch_chunk(prev, joining)
-            if listened and self._ran_dry(prev, landing):
-                self.metrics.record_listen_late()
+            self._inflight = self._dispatch_chunk(prev, joining,
+                                                  listened)
         if prev is not None:
             self._retire_chunk(prev)
         for job in landing:
@@ -1643,16 +1656,16 @@ class InferenceEngine:
         the SHORTEST of the last chunks' device times, less a margin of
         their scatter (longest less shortest) and the LONGEST of the
         last carried dispatches' host times. All measured by the tick
-        (``_fetch``, ``_retire_chunk``, ``_dispatch_chunk``); None when
-        any of it is unknown — the last fetch found its result ready,
-        no chunk has been timed, a program no fetch stamps lay ahead of
-        ``rec`` — and the tick then keeps the order it always had."""
-        began = self._fetch_blocked_t
-        if (began is None or not rec["timed"] or not self._chunk_s
+        (the device's queue, ``_dispatch_chunk``); None when any of it
+        is unknown — the last fetch found its result ready, no chunk
+        has been timed, a program no fetch stamps lay ahead of ``rec``
+        — and the tick then keeps the order it always had."""
+        began, owns = rec["program"].start, self._devq.chunk_owns
+        if (began is None or not rec["carried"] or not owns
                 or not self._dispatch_s):
             return None
-        shortest = min(self._chunk_s)
-        margin = max(self._chunk_s) - shortest + max(self._dispatch_s)
+        shortest = min(owns)
+        margin = max(owns) - shortest + max(self._dispatch_s)
         return began + shortest - margin
 
     @staticmethod
@@ -1660,15 +1673,6 @@ class InferenceEngine:
         """Chunk ``rec`` has left the device. No sync, no transfer: the
         ``_fetch`` count and the transfer guard see nothing."""
         return rec["outs"][0].is_ready()
-
-    def _ran_dry(self, prev: Optional[Dict[str, Any]],
-                 landing: List[_PrefillJob]) -> bool:
-        """Asked when a chunk has just been dispatched: the program
-        ahead of it (the tick's last prefill, else chunk ``prev``) had
-        left the device already, which has stood idle since."""
-        if landing:
-            return landing[-1].token.is_ready()
-        return prev is not None and self._chunk_done(prev)
 
     def _listen(self, landing: List[_PrefillJob]) -> bool:
         """While the chunk in flight runs and an arrival could be
@@ -1690,14 +1694,17 @@ class InferenceEngine:
         if rec is None or not self._admits_at_once():
             return False
         deadline = self._listen_deadline(rec)
-        if (deadline is None or deadline <= time.perf_counter()
-                or self._chunk_done(rec)):
+        now = time.perf_counter()
+        if deadline is None or deadline <= now or self._chunk_done(rec):
             return False
         with self._tick.phase("decode_fetch", listening=True) as attrs:
             attrs["heard"] = 0
+            self._devq.busy_at(now)
             while (self._inflight is rec and not self._shutdown
                    and not self._chunk_done(rec)):
-                left = deadline - time.perf_counter()
+                now = time.perf_counter()
+                self._devq.busy_at(now)
+                left = deadline - now
                 if left <= 0:
                     break
                 try:
@@ -1747,7 +1754,7 @@ class InferenceEngine:
                    for r in active)
 
     def _dispatch_chunk(self, prev: Optional[Dict[str, Any]] = None,
-                        joining=()):
+                        joining=(), listened: bool = False):
         """Enqueue one decode chunk (no host sync). Without ``prev``
         the inputs are the host's (``_roster_arrays``). With the record
         of the chunk in flight they are MERGED on the device, slot by
@@ -1761,6 +1768,10 @@ class InferenceEngine:
           the prefill's own output provides, and ``done`` by the scan's
           rules on it;
         - nobody (freed, failed, parked): ``done`` and the parked row.
+
+        ``listened``: the tick waited on its mailbox first, so the
+        program ahead may have left the device meanwhile; the queue
+        asks it (``DeviceQueue.put``, ``poll``).
 
         Returns the in-flight record _retire_chunk consumes, or None on
         a dispatch failure (roster and joiners failed)."""
@@ -1795,23 +1806,25 @@ class InferenceEngine:
         self.metrics.record_dispatch(carried)
         if carried:
             self._dispatch_s.append(self._tick.now - t0)
+        # A family's counters ride the chunk's one fetch.
+        rec = {"outs": (toks_d, n_valid_d, *counters_d),
+               "carried": carried,
+               "carry": (ntok_d, nlen_d, nrem_d, state[3], ndone_d),
+               # Who the chunk was dispatched with, by slot. The next
+               # dispatch carries a slot only for the SAME request, and
+               # the retire delivers only to it; the strong refs keep a
+               # finished request's identity from being recycled for a
+               # newly admitted one in the same slot while this record
+               # lives.
+               "held": {r.slot: r for r in roster},
+               "t0": t0}
         # The chunk begins where the program ahead of it ends; a fetch
         # will show that moment if that program is the chunk in flight
         # or a prefill whose token the tick lands.
-        timed = carried and not self._unfetched_ahead
-        self._unfetched_ahead = False
-        # A family's counters ride the chunk's one fetch.
-        return {"outs": (toks_d, n_valid_d, *counters_d),
-                "timed": timed,
-                "carry": (ntok_d, nlen_d, nrem_d, state[3], ndone_d),
-                # Who the chunk was dispatched with, by slot. The next
-                # dispatch carries a slot only for the SAME request, and
-                # the retire delivers only to it; the strong refs keep a
-                # finished request's identity from being recycled for a
-                # newly admitted one in the same slot while this record
-                # lives.
-                "held": {r.slot: r for r in roster},
-                "t0": t0}
+        rec["program"] = self._devq.put(
+            "chunk", t0, lambda: self._chunk_done(rec), poll=listened,
+            slots=len(roster))
+        return rec
 
     def _retire_chunk(self, rec: Dict[str, Any]) -> bool:
         """The tick's ONE host fetch: land the chunk's tokens, deliver
@@ -1822,7 +1835,6 @@ class InferenceEngine:
         reports n_valid 0 — the device carried its done mask; one that
         failed or was parked since has let go of its slot. Retire
         finishes. False on device failure."""
-        began = self._fetch_blocked_t
         try:
             with self._tick.phase("decode_fetch",
                                   slots=len(rec["held"])) as attrs:
@@ -1832,12 +1844,10 @@ class InferenceEngine:
         except BaseException as e:  # noqa: BLE001 — fail all waiters
             self._fail_roster(e)
             return False
-        ended = self._fetch_blocked_t
-        if rec["timed"] and began is not None and ended is not None:
-            # Both ends seen by a fetch that waited: device seconds,
-            # whatever the host did (or compiled) in between.
-            self._chunk_s.append(ended - began)
         now = self._tick.now
+        # Where fetches that waited saw both its ends: device seconds,
+        # whatever the host did (or compiled) in between.
+        split = self._devq.seen(rec["program"], now)
         # TPOT window: a PIPELINED chunk was dispatched one tick ago, so
         # dispatch->fetch would fold the whole intervening host tick
         # (which overlapped device compute) into per-token latency — an
@@ -1852,7 +1862,9 @@ class InferenceEngine:
                    if req.slot == slot or req in ending]
         delivered = 0
         self.metrics.record_model_counters(counters)
-        touched = self._span_attrs(counters)
+        touched = {**self._span_attrs(counters), "period_s": elapsed}
+        if split:
+            touched["own_s"] = split[1]
         with self._tick.phase("decode_deliver",
                               slots=len(holders)) as attrs:
             for slot, req in holders:
@@ -1879,8 +1891,14 @@ class InferenceEngine:
             # waste. Counted from what the device reports, not from the
             # roster at dispatch: a slot carried into the chunk already
             # frozen was never live in it.
-            live_steps = self.loop.chunk * int(np.count_nonzero(n_valid))
+            k, live = self.loop.chunk, n_valid[n_valid > 0]
+            live_steps = k * len(live)
             self.metrics.record_chunk(delivered, live_steps, elapsed)
+            # ... and of those steps, the ones scanned for a request
+            # that had ended inside the chunk.
+            self.metrics.record_retire(
+                k, elapsed, live_steps - int(live.sum()),
+                split[1] if split else None)
             _flight.record("engine_tick", tok=delivered, act=len(holders))
         return True
 
@@ -1955,6 +1973,8 @@ class InferenceEngine:
                         self._put(draft_buf), self._put(ndraft),
                         self._put(lengths), self._put(remaining),
                         self._put(eos_ids), self._put(done))
+                program = self._devq.put("chunk", t0, counts_d.is_ready,
+                                         slots=len(active), spec=True)
             with self._tick.phase("decode_fetch",
                                   slots=len(active)) as attrs:
                 # device_get returns host ndarrays: [B,C,W] + [B,C].
@@ -1964,6 +1984,7 @@ class InferenceEngine:
             self._fail_roster(e)
             return
         now = self._tick.now
+        self._devq.seen(program, now)
         live_steps = len(active) * C * W  # token-positions scanned
         delivered = 0
         accepted_total = 0

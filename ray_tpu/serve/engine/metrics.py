@@ -16,6 +16,10 @@ compiles (first request pays XLA), TPOT sits in the ms range.
 phase in the snapshot, and — while tracing is on — the same stamps as
 ``engine.tick.<phase>`` spans and profiler annotations (one set of
 clock reads, two outputs; engine/README.md "Tick phases").
+`DeviceQueue` keeps, on those stamps, the programs the thread has put on
+the device and not yet seen end: what each waited behind, what it took
+itself, and when the device ran dry (engine/README.md "The device's
+queue").
 """
 
 from __future__ import annotations
@@ -24,7 +28,7 @@ import collections
 import itertools
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Deque, Dict, Optional
 
 from ray_tpu.util import metrics as _m
 from ray_tpu.util import tracing as _tracing
@@ -66,10 +70,10 @@ ADMISSIONS_AHEAD_TOTAL = _m.Counter(
     "rtpu_llm_admissions_ahead_total",
     "admissions into a slot whose last holder, sure to end inside the "
     "decode chunk in flight, let go of it ahead of that chunk's retire")
-LISTEN_DEADLINE_LATE_TOTAL = _m.Counter(
-    "rtpu_llm_listen_deadline_late_total",
-    "ticks whose listening wait outran what it waited for: the next "
-    "decode chunk was dispatched to a device that had run dry")
+DEVICE_DRY_DISPATCHES_TOTAL = _m.Counter(
+    "rtpu_llm_device_dry_dispatches_total",
+    "programs dispatched to a device the engine thread knew had run "
+    "dry: every program ahead had been seen to end")
 SPEC_DRAFTED_TOTAL = _m.Counter(
     "rtpu_llm_spec_drafted_total",
     "draft tokens proposed by prompt-lookup speculation")
@@ -125,11 +129,8 @@ class EngineMetrics:
         self.chunks_dispatched = 0
         self.chunks_carried = 0
         # The listening wait (core.py ``_listen``): admissions whose
-        # first prefill chunk it dispatched (against ``requests``), and
-        # ticks on which it ended too late: the next chunk was
-        # dispatched to a device that had run dry, and idled.
+        # first prefill chunk it dispatched (against ``requests``).
         self.admissions_heard = 0
-        self.listen_deadline_late = 0
         # The early hand-over (core.py ``_hand_over``): admissions into
         # a slot whose last holder was still in the chunk in flight
         # (against ``requests``).
@@ -165,6 +166,17 @@ class EngineMetrics:
         self.tick_s = dict.fromkeys(TICK_PHASES, 0.0)
         self.tick_loop_s = 0.0
         self.ticks = 0
+        # What `DeviceQueue` and the retire read off the device's queue
+        # (engine/README.md "The device's queue" has each key). One
+        # writer, the engine thread; no lock (like ``tick_s``).
+        self.device_queue: Dict[str, Any] = {
+            "prefill_behind_s": 0.0, "prefill_own_s": 0.0,
+            "prefill_split": 0,
+            "prefill_ahead_chunks": 0, "prefill_ahead_prefills": 0,
+            "chunk_period_s": 0.0, "chunk_steps_retired": 0,
+            "chunk_own_s": 0.0, "chunk_steps_timed": 0,
+            "device_dry_s": 0.0, "device_dry_dispatches": 0,
+            "decode_steps_frozen": 0}
 
     # ------------------------------------------------------------ records
 
@@ -243,10 +255,43 @@ class EngineMetrics:
         self.admissions_ahead += 1
         ADMISSIONS_AHEAD_TOTAL.inc(labels=self._labels)
 
-    def record_listen_late(self) -> None:
-        """One tick whose listening wait left the device to run dry."""
-        self.listen_deadline_late += 1
-        LISTEN_DEADLINE_LATE_TOTAL.inc(labels=self._labels)
+    def record_dry_dispatch(self, dry_s: float) -> None:
+        """One program dispatched to a device known to have run dry,
+        ``dry_s`` seconds before (a lower bound)."""
+        self.device_queue["device_dry_dispatches"] += 1
+        self.device_queue["device_dry_s"] += dry_s
+        DEVICE_DRY_DISPATCHES_TOTAL.inc(labels=self._labels)
+
+    def record_first_dispatch(self, ahead_chunks: int,
+                              ahead_prefills: int) -> None:
+        """One admission's first prefill program went out behind that
+        many programs the thread had not yet seen end."""
+        self.device_queue["prefill_ahead_chunks"] += ahead_chunks
+        self.device_queue["prefill_ahead_prefills"] += ahead_prefills
+
+    def record_prefill_split(self, behind_s: float, own_s: float) -> None:
+        """One admission ALL of whose prefill programs had their start
+        and end seen: the seconds they waited on the device behind
+        other programs, and the seconds they took themselves."""
+        q = self.device_queue
+        q["prefill_split"] += 1
+        q["prefill_behind_s"] += behind_s
+        q["prefill_own_s"] += own_s
+
+    def record_retire(self, steps: int, period_s: float, frozen: int,
+                      own_s: Optional[float]) -> None:
+        """One plain chunk of ``steps`` steps retired ``period_s`` after
+        the last one (the cadence a roster member feels); ``frozen`` of
+        its slot-steps were scanned for a request that had ended inside
+        it; ``own_s`` its device seconds, where the queue saw both its
+        ends."""
+        q = self.device_queue
+        q["chunk_steps_retired"] += steps
+        q["chunk_period_s"] += period_s
+        q["decode_steps_frozen"] += frozen
+        if own_s is not None:
+            q["chunk_steps_timed"] += steps
+            q["chunk_own_s"] += own_s
 
     def record_prefill_chunk(self, tokens: int) -> None:
         """One prefill program enqueued, carrying ``tokens`` real
@@ -296,7 +341,8 @@ class EngineMetrics:
 
     def snapshot(self) -> Dict[str, Any]:
         tick = {f"tick_{k}_s": v for k, v in self.tick_s.items()}
-        tick.update(tick_loop_s=self.tick_loop_s, ticks=self.ticks)
+        tick.update(self.device_queue, tick_loop_s=self.tick_loop_s,
+                    ticks=self.ticks)
         with self._lock:
             return {
                 **tick,
@@ -316,7 +362,6 @@ class EngineMetrics:
                 "decode_chunks_carried": self.chunks_carried,
                 "admissions_heard": self.admissions_heard,
                 "admissions_ahead": self.admissions_ahead,
-                "listen_deadline_late": self.listen_deadline_late,
                 "prefill_chunks_dispatched": self.prefill_chunks_dispatched,
                 "prefill_chunk_tokens": self.prefill_chunk_tokens,
                 # decode tokens delivered per device token-position
@@ -418,16 +463,21 @@ class TickClock:
             return
         wall = _tracing.wall(t)
         name = "engine.tick." + self._open_phases[-1].name
+        self._span = _tracing.start_span(name, parent=self.root(wall),
+                                         start=wall)
+        self._twin = self._annotation(name)
+        self._twin.__enter__()
+
+    def root(self, wall: float) -> Optional[Dict[str, str]]:
+        """This engine's ``serve.engine`` root span, made at ``wall``
+        by whoever first asks while tracing is on."""
         if self._root is None:
             # parent={}: a trace of its own, whatever span the thread
             # that first ticks traced happens to be under.
             self._root = _tracing.emit_span(
                 "serve.engine", wall, wall, parent={},
                 attrs={"engine": self._metrics.name})
-        self._span = _tracing.start_span(name, parent=self._root,
-                                         start=wall)
-        self._twin = self._annotation(name)
-        self._twin.__enter__()
+        return self._root
 
     def _stop(self, t: float) -> None:
         phase, t0, self.now = self._open_phases[-1], self.now, t
@@ -442,3 +492,140 @@ class TickClock:
             self._span["attrs"] = dict(phase.attrs)
             _tracing.end_span(self._span, end=_tracing.wall(t))
             self._span = None
+
+
+class _Program:
+    """One program on the device, as `DeviceQueue` knows it. ``start``
+    and ``end`` are None until seen (or for good, where they never
+    are); ``done`` asks the device whether the program has left it (no
+    sync)."""
+
+    __slots__ = ("kind", "t_dispatch", "start", "end", "done", "attrs",
+                 "open")
+
+    def __init__(self, kind: str, t_dispatch: float, done, attrs: dict):
+        self.kind, self.t_dispatch = kind, t_dispatch
+        self.start: Optional[float] = None
+        self.end: Optional[float] = None
+        self.done, self.attrs, self.open = done, attrs, True
+
+    def split(self) -> Optional[tuple]:
+        """(behind, own) seconds: on the device behind other programs,
+        then running. None unless both ends were seen."""
+        if self.start is None or self.end is None:
+            return None
+        return self.start - self.t_dispatch, self.end - self.start
+
+
+class DeviceQueue:
+    """The programs the engine thread has put on the device and not yet
+    seen end, in the order it dispatched them, which is the order the
+    device runs them in. Kept on stamps the tick takes anyway: a
+    program's dispatch (its phase's opening stamp) and, where the fetch
+    of its result had to wait, that fetch's return: the device's own
+    clock, the moment the program ended and the next one began.
+
+    For a program ``e`` behind ``p``: ``start(e) = max(t_dispatch(e),
+    end(p))``, ``own = end - start``, ``behind = start - t_dispatch``.
+    A stamp stays unknown in three cases: the fetch found its result
+    ready (the program ended by then, nobody saw when); nobody fetches
+    the program at all (a prefill chunk that is not an admission's
+    last, a trailing chunk dropped; the next fetch closes it, end
+    unseen); and so the START of whatever ran behind either. A program
+    dispatched to a queue known empty starts at its dispatch, and the
+    device has been dry since the host knew it empty: a lower bound.
+
+    Engine-thread-only, no lock; ``clock`` is the thread's `TickClock`
+    (the ``device.<kind>`` spans go under its root). Programs the tick
+    puts on the device outside it (page exports and installs) run
+    unseen: their time counts as dry.
+    """
+
+    def __init__(self, metrics: EngineMetrics, clock: TickClock):
+        self._metrics = metrics
+        self._clock = clock
+        self._open: Deque[_Program] = collections.deque()
+        # Since when the host has known the device dry: the exact end
+        # of the last program where a fetch saw it, else the stamp at
+        # which a fetch found it ended. None while a program is open,
+        # and before the first.
+        self._empty_since: Optional[float] = None
+        # The return stamp of the last fetch, if it had to wait.
+        self._fetched_t: Optional[float] = None
+        # The listening wait's last poll that found the device busy.
+        self._busy_t: Optional[float] = None
+        # Device seconds of the last chunks whose ends were both seen.
+        self.chunk_owns: Deque[float] = collections.deque(maxlen=8)
+
+    def ahead(self) -> tuple:
+        """(chunks, prefills) not yet seen to end: what a program
+        dispatched now queues behind."""
+        chunks = sum(p.kind == "chunk" for p in self._open)
+        return chunks, len(self._open) - chunks
+
+    def put(self, kind: str, t_dispatch: float, done, poll: bool = False,
+            **attrs) -> _Program:
+        """A program was dispatched at ``t_dispatch``. ``poll``: ask
+        the program ahead whether it has left the device already (the
+        listening wait may have outrun it); if so the device has been
+        dry since the wait last found it busy, at most."""
+        e = _Program(kind, t_dispatch, done, attrs)
+        since = self._empty_since
+        if poll and self._open and self._open[-1].done():
+            since = self._busy_t
+            while self._open:
+                self._close()
+        if not self._open:
+            e.start = t_dispatch
+            if since is not None:
+                self._metrics.record_dry_dispatch(
+                    max(0.0, t_dispatch - since))
+            self._empty_since = None
+        self._open.append(e)
+        return e
+
+    def _close(self) -> _Program:
+        """The head of the queue has left the device."""
+        p = self._open.popleft()
+        p.open, p.done = False, None    # (a chunk's probe holds its record)
+        return p
+
+    def busy_at(self, t: float) -> None:
+        """The listening wait found the program it waits for still on
+        the device at ``t``."""
+        self._busy_t = t
+
+    def fetched(self, t_blocked: Optional[float]) -> None:
+        """A fetch returned: at ``t_blocked`` if it had to wait for the
+        device, which ended the fetched program then; None if the
+        result was ready."""
+        self._fetched_t = t_blocked
+
+    def seen(self, e: _Program, now: float) -> Optional[tuple]:
+        """The tick has fetched ``e``'s result (`fetched` has the
+        stamp; ``now`` is the witness where it has none). Closes ``e``
+        and every program ahead of it, those with their end unseen.
+        Returns ``e.split()``."""
+        if not e.open:
+            return None     # closed since: a later program's end, a poll
+        while self._close() is not e:
+            pass
+        e.end = self._fetched_t
+        if self._open:
+            if e.end is not None:
+                nxt = self._open[0]
+                nxt.start = max(nxt.t_dispatch, e.end)
+        else:
+            self._empty_since = now if e.end is None else e.end
+        split = e.split()
+        if split is not None:
+            if e.kind == "chunk":
+                self.chunk_owns.append(split[1])
+            if _tracing.enabled():
+                start = _tracing.wall(e.start)
+                _tracing.emit_span(
+                    "device." + e.kind, start, _tracing.wall(e.end),
+                    parent=self._clock.root(start),
+                    attrs=dict(e.attrs, behind_s=split[0],
+                               own_s=split[1]))
+        return split

@@ -140,7 +140,7 @@ def test_the_tick_keeps_its_order_where_it_must_not_listen(engine, why):
     in flight done already, the wait never begins: an arrival is
     admitted at the top of a tick, as it always was."""
     streams = [_decoding(engine)]
-    engine._chunk_s.append(2.0)         # a trusted estimate would wait
+    engine._devq.chunk_owns.append(2.0)     # a trusted estimate would wait
     asked, heard = [], []
     if why == "no_free_slot":
         streams.append(_decoding(engine))
@@ -152,7 +152,7 @@ def test_the_tick_keeps_its_order_where_it_must_not_listen(engine, why):
 
         def ready_fetch(tree, tag="decode"):
             out = fetch(tree, tag)
-            engine._fetch_blocked_t = None
+            engine._devq.fetched(None)
             return out
 
         engine._fetch = ready_fetch
@@ -161,6 +161,7 @@ def test_the_tick_keeps_its_order_where_it_must_not_listen(engine, why):
     engine._listen_deadline = lambda rec: (asked.append(1), deadline(rec))[1]
     engine._hear = lambda *args: (heard.append(1), hear(*args))[1]
     log = _log_the_loop(engine)
+    dry = engine.stats()["device_dry_dispatches"]
     t0 = time.perf_counter()
     if why == "no_free_slot":
         engine._queue.put(engine._make_request([2, 7, 1, 8], 3, None))
@@ -178,16 +179,21 @@ def test_the_tick_keeps_its_order_where_it_must_not_listen(engine, why):
     assert not heard
     stats = engine.stats()
     assert stats["admissions_heard"] == 0
-    assert stats["listen_deadline_late"] == 0
+    # ... and no chunk went out to a device the thread knew dry: one is
+    # in flight whenever the next is dispatched.
+    assert stats["device_dry_dispatches"] == dry
 
 
 @pytest.mark.parametrize("chunk_ends", ["before_its_successor_is_out",
                                         "after"])
 def test_a_wait_that_left_the_device_dry_is_counted(engine, chunk_ends):
-    """``listen_deadline_late``: the chunk dispatched after a wait found
-    the program ahead of it gone from the device already."""
+    """``device_dry_dispatches``: the chunk dispatched after a wait
+    found the program ahead of it gone from the device already (the
+    queue asks it, ``DeviceQueue.put``), dry since the wait's last look
+    at most."""
     stream = _decoding(engine)
     dry = chunk_ends == "before_its_successor_is_out"
+    before = engine.stats()
     waits = []
     # The chunk "ends" at the wait's own deadline, or not at all.
     engine._chunk_done = lambda rec: bool(
@@ -198,8 +204,10 @@ def test_a_wait_that_left_the_device_dry_is_counted(engine, chunk_ends):
     engine._listen_deadline = lambda rec: None
     assert isinstance(next(stream), int)
     stats = engine.stats()
-    late = stats["listen_deadline_late"]
+    late = stats["device_dry_dispatches"] - before["device_dry_dispatches"]
     assert late >= 4 if dry else late == 0
+    dry_s = stats["device_dry_s"] - before["device_dry_s"]
+    assert 0.0 <= dry_s < late * 0.05 + 1e-9
     assert stats["admissions_heard"] == 0
 
 
@@ -299,18 +307,29 @@ def test_the_deadline_is_derived_from_what_the_tick_measured():
     the last carried dispatches; unknown where any of it is."""
     eng = _engine()
     eng.close()                 # the thread is gone: the state is ours
-    rec = {"timed": True}
+    q = eng._devq
+    ahead = q.put("prefill", 98.0, None)
+    rec = {"carried": True, "program": q.put("chunk", 99.0, None)}
     assert eng._listen_deadline(rec) is None        # nothing timed yet
-    eng._chunk_s.extend([0.090, 0.087, 0.088])
+    q.chunk_owns.extend([0.090, 0.087, 0.088])
     eng._dispatch_s.extend([0.001, 0.003, 0.002])
-    assert eng._listen_deadline(rec) is None        # the fetch found it ready
-    eng._fetch_blocked_t = 100.0
+    assert eng._listen_deadline(rec) is None        # no fetch has returned
+    q.fetched(100.0)
+    q.seen(ahead, 100.1)                            # ... and waited
     assert eng._listen_deadline(rec) == pytest.approx(
         100.0 + 0.087 - (0.090 - 0.087) - 0.003)
-    assert eng._listen_deadline({"timed": False}) is None
+    assert eng._listen_deadline(dict(rec, carried=False)) is None
     # Only the last few of each count: one slow chunk ages out.
-    eng._chunk_s.extend([0.087] * eng._chunk_s.maxlen)
+    q.chunk_owns.extend([0.087] * q.chunk_owns.maxlen)
     assert eng._listen_deadline(rec) == pytest.approx(100.0 + 0.087 - 0.003)
+    # The fetch found its result ready, or a program no fetch stamps
+    # lay ahead: the chunk's start is not known.
+    for stamp, fetched in ((None, 1), (103.0, 2)):
+        ahead = [q.put("prefill", 101.0, None) for _ in range(2)]
+        late = {"carried": True, "program": q.put("chunk", 102.0, None)}
+        q.fetched(stamp)
+        q.seen(ahead[-1] if fetched == 1 else late["program"], 103.5)
+        assert eng._listen_deadline(late) is None
     # An arrival would be admitted at once only ahead of everyone.
     assert eng._admits_at_once()
     eng.scheduler._waiting.append(object())
@@ -320,11 +339,13 @@ def test_the_deadline_is_derived_from_what_the_tick_measured():
 def test_both_counters_are_flat_keys_a_counter_delta_can_subtract():
     """(e)"""
     snap = EngineMetrics("flat").snapshot()
-    for key in ("admissions_heard", "listen_deadline_late"):
+    for key in ("admissions_heard", "device_dry_dispatches"):
         assert snap[key] == 0 and isinstance(snap[key], int)
+    assert snap["device_dry_s"] == 0.0
     m = EngineMetrics("counted")
     m.record_heard()
     m.record_heard()
-    m.record_listen_late()
+    m.record_dry_dispatch(0.25)
     snap = m.snapshot()
-    assert (snap["admissions_heard"], snap["listen_deadline_late"]) == (2, 1)
+    assert (snap["admissions_heard"], snap["device_dry_dispatches"],
+            snap["device_dry_s"]) == (2, 1, 0.25)

@@ -196,8 +196,12 @@ def test_traced_ticks_emit_only_phase_names_disjoint_under_one_root(
                 s["name"] for s in ticks}
     # Nothing else of the engine's: no request carried a trace context
     # (a process with a compile account adds ITS ``compile.<program>``).
+    # The programs' own spans (`DeviceQueue`) are named outside
+    # ``engine.``: the benchmark hands every ``engine.*`` span to the
+    # idle-gap attribution, and one that covers busy time owns no gap.
     assert {s["name"] for s in spans if not s["name"].startswith("compile.")
-            } <= TICK_NAMES | {"serve.engine"}
+            } <= TICK_NAMES | {"serve.engine", "device.chunk",
+                               "device.prefill"}
     roots = [s for s in spans if s["name"] == "serve.engine"]
     assert len(roots) == 1 and roots[0]["parent_id"] == ""
     assert roots[0]["attrs"]["engine"] == engine.metrics.name
@@ -244,7 +248,11 @@ def test_tracing_off_ticks_are_span_free_and_sync_budget_unchanged(engine):
     assert len(_buffer) == before
     assert engine._tick._root is None          # no root until traced
     # 1 prefill sync + ceil(5/2) decode-chunk syncs, as before this PR.
-    assert engine.stats()["decode_host_syncs"] == 3
+    stats = engine.stats()
+    assert stats["decode_host_syncs"] == 3
+    # The device's queue counted all the same: three chunks of two.
+    assert stats["chunk_steps_retired"] == 6 and stats["chunk_period_s"] > 0
+    assert not engine._devq._open
 
 
 # ------------------------------- the pipelined schedule under the same clock
@@ -331,6 +339,196 @@ def test_a_device_failure_between_dispatch_and_retire_fails_the_roster_once(
     assert engine.generate([2, 7, 1, 8], max_new_tokens=7)["token_ids"] \
         == want
     assert engine.stats()["cache_rebuilds"] == stats["cache_rebuilds"]
+
+
+# ---------------------------------------------------- the device's queue
+
+QUEUE_KEYS = {"prefill_behind_s": float, "prefill_own_s": float,
+              "prefill_split": int, "prefill_ahead_chunks": int,
+              "prefill_ahead_prefills": int, "chunk_period_s": float,
+              "chunk_steps_retired": int, "chunk_own_s": float,
+              "chunk_steps_timed": int, "device_dry_s": float,
+              "device_dry_dispatches": int, "decode_steps_frozen": int}
+
+
+def _every_fetch_waits(eng):
+    """On the CPU's tiny engine a result is often ready before its
+    fetch: stamp every fetch's return as one that had to wait, so that
+    the queue sees every end (the arithmetic is what is held here)."""
+    fetch = eng._fetch
+
+    def waited(tree, tag="decode"):
+        out = fetch(tree, tag)
+        eng._devq.fetched(time.perf_counter())
+        return out
+
+    eng._fetch = waited
+
+
+def test_every_queue_counter_is_a_flat_key_of_the_snapshot(engine):
+    snap = EngineMetrics("flat").snapshot()
+    for key, kind in QUEUE_KEYS.items():
+        assert snap[key] == 0 and type(snap[key]) is kind, key
+    stats = engine.stats()
+    assert set(QUEUE_KEYS) <= set(stats)
+    assert "listen_deadline_late" not in stats
+
+
+def test_a_split_prefill_is_its_wait_less_the_fetch_s_last_lines(engine):
+    """``behind + own`` of an admission whose prefill's ends were both
+    seen is its ``prefill_s`` less the host's time between the fetch's
+    return and the phase's closing stamp."""
+    _every_fetch_waits(engine)
+    waits, splits = [], []
+    admit, split = engine.metrics.record_admit, \
+        engine.metrics.record_prefill_split
+    engine.metrics.record_admit = lambda q, p, *rest: (
+        waits.append(p), admit(q, p, *rest))[1]
+    engine.metrics.record_prefill_split = lambda b, o: (
+        splits.append((b, o)), split(b, o))[1]
+    streams = _stream_pair(engine)          # two behind a chunk or two
+    for n in (2, 5, 9):
+        engine.generate(list(range(1, n + 1)), max_new_tokens=3)
+    for stream in streams:
+        stream.close()
+    s = engine.stats()
+    assert s["requests"] == len(waits) == 5 == len(splits)
+    assert s["prefill_split"] == 5
+    assert s["prefill_behind_s"] == pytest.approx(sum(b for b, _ in splits))
+    assert s["prefill_own_s"] == pytest.approx(sum(o for _, o in splits))
+    for wait, (behind, own) in zip(waits, splits):
+        assert behind >= 0.0 and own > 0.0
+        assert 0.0 <= wait - (behind + own) < 0.02
+    residue = sorted(w - b - o for w, (b, o) in zip(waits, splits))
+    assert residue[len(residue) // 2] < 1e-3
+    # An arrival beside a running stream queued behind its chunk.
+    assert s["prefill_ahead_chunks"] >= 1 and s["prefill_ahead_prefills"] >= 0
+
+
+def test_an_unchunked_prefill_whose_fetch_found_it_ready_is_not_split(engine):
+    fetch = engine._fetch
+    engine._fetch = lambda tree, tag="decode": (
+        fetch(tree, tag), engine._devq.fetched(None))[0]
+    engine.generate([1, 2, 3], max_new_tokens=4)
+    s = engine.stats()
+    assert s["requests"] == 1 and s["prefill_split"] == 0
+    assert s["prefill_own_s"] == 0.0 == s["chunk_own_s"]
+    assert s["chunk_steps_timed"] == 0 < s["chunk_steps_retired"]
+
+
+def test_a_chunked_prefill_is_not_split_and_closes_behind_itself():
+    """Only the last chunk of an admission is fetched: the others' ends
+    are seen by nobody, the admission is not split, and the queue holds
+    none of them afterwards."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    eng = LLMEngine(prefill_chunk=4, **ENGINE_KW)
+    try:
+        _every_fetch_waits(eng)
+        eng.generate(list(range(1, 20)), max_new_tokens=4)  # three chunks
+        eng.generate([1, 2, 3], max_new_tokens=2)           # one
+        s = eng.stats()
+    finally:
+        eng.close()
+    assert s["requests"] == 2 and s["prefill_split"] == 1
+    assert s["prefill_chunks_dispatched"] == 4
+    assert not eng._devq._open
+
+
+def test_the_retire_cadence_adds_up_to_the_loop_while_a_roster_decodes(
+        engine):
+    """``chunk_period_s`` is the time from one retire to the next (the
+    first's from its dispatch): the periods are disjoint stretches of
+    the loop's working seconds, and while a roster decodes nearly all
+    of them."""
+    _every_fetch_waits(engine)
+    for stream in _stream_pair(engine):     # every program compiles here
+        list(stream)
+    before = engine.stats()
+    for stream in _stream_pair(engine):
+        assert len(list(stream)) == 45
+    time.sleep(0.15)                    # the loop is idle: counts stand
+    after = engine.stats()
+    d = {k: after[k] - before[k] for k in
+         ("chunk_period_s", "chunk_steps_retired", "chunk_own_s",
+          "chunk_steps_timed", "tick_loop_s", "tick_idle_s",
+          "decode_host_syncs")}
+    assert d["chunk_steps_retired"] == 2 * d["decode_host_syncs"] >= 50
+    worked = d["tick_loop_s"] - d["tick_idle_s"]
+    assert 0.6 * worked <= d["chunk_period_s"] <= worked * 1.02
+    # A chunk's own device seconds fit inside the cadence.
+    assert 0 < d["chunk_steps_timed"] <= d["chunk_steps_retired"]
+    assert 0.0 < d["chunk_own_s"] <= d["chunk_period_s"] * 1.02
+
+
+@pytest.mark.parametrize("multi_step", [True, False],
+                         ids=["pipelined", "serial"])
+def test_steps_scanned_past_a_request_s_end_are_counted_frozen(multi_step):
+    """A budget of 4: one token from the prefill, three from a chunk of
+    eight, whose other five steps run for nobody."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    eng = LLMEngine(**{**ENGINE_KW, "decode_chunk": 8,
+                       "multi_step": multi_step})
+    try:
+        assert len(eng.generate([3, 1, 4], max_new_tokens=4)["token_ids"]) == 4
+        s = eng.stats()
+        assert (s["decode_steps"], s["decode_steps_frozen"]) == (8, 5)
+        assert s["chunk_steps_retired"] == 8
+        eng.generate([3, 1, 4], max_new_tokens=9)       # a whole chunk
+        s = eng.stats()
+        assert (s["decode_steps"], s["decode_steps_frozen"]) == (16, 5)
+    finally:
+        eng.close()
+
+
+def test_device_spans_are_disjoint_and_in_dispatch_order(engine, sink):
+    _every_fetch_waits(engine)
+    put, programs = engine._devq.put, []
+    engine._devq.put = lambda *a, **kw: (programs.append(put(*a, **kw)),
+                                         programs[-1])[1]
+    streams = _stream_pair(engine, warm=8)
+    engine.generate([7, 7, 7, 7], max_new_tokens=5)
+    for stream in streams:
+        stream.close()
+    time.sleep(0.1)
+    spans = _collect(sink)
+    device = [s for s in spans if s["name"].startswith("device.")]
+    root = next(s for s in spans if s["name"] == "serve.engine")
+    assert all(s["parent_id"] == root["span_id"] for s in device)
+    assert {s["name"] for s in device} == {"device.chunk", "device.prefill"}
+    # As emitted they are in dispatch order, one for each program whose
+    # ends were both seen, and no two overlap: one device, one queue.
+    split = [p for p in programs if p.split() is not None]
+    assert [(s["name"], s["start"], s["end"]) for s in device] == [
+        ("device." + p.kind, tracing.wall(p.start), tracing.wall(p.end))
+        for p in split]
+    assert len(split) >= len(programs) - 2
+    for a, b in zip(device, device[1:]):
+        assert a["end"] <= b["start"]
+    for s in device:
+        attrs = s["attrs"]
+        assert attrs["own_s"] == pytest.approx(s["end"] - s["start"], abs=1e-6)
+        assert attrs["behind_s"] >= 0.0
+        assert ("slots" in attrs) == (s["name"] == "device.chunk")
+        assert ("bucket" in attrs and attrs["tokens"] > 0) == (
+            s["name"] == "device.prefill")
+
+
+def test_request_spans_carry_the_queue_s_split(engine, sink):
+    _every_fetch_waits(engine)
+    with tracing.trace("client") as root:
+        engine.generate([5, 6, 7, 8], max_new_tokens=5)
+    spans = [s for s in _collect(sink) if s["trace_id"] == root.trace_id]
+    prefill = next(s for s in spans if s["name"] == "engine.prefill")
+    attrs = prefill["attrs"]
+    assert (attrs["ahead_chunks"], attrs["ahead_prefills"]) == (0, 0)
+    assert attrs["behind_s"] == 0.0 < attrs["own_s"]
+    assert attrs["own_s"] <= prefill["end"] - prefill["start"]
+    chunks = [s for s in spans if s["name"] == "engine.decode_chunk"]
+    assert chunks and all(c["attrs"]["period_s"] > 0 for c in chunks)
+    assert all(0 < c["attrs"]["own_s"] for c in chunks
+               if "own_s" in c["attrs"])
 
 
 # ------------------------------------------------------ the clock alone
