@@ -291,6 +291,18 @@ def _tp_ring(seq_len: int, blocks, cfg: LlamaConfig, cache_kv=None) -> bool:
         blocks["w_gate"].shape[-1]) > 1
 
 
+def _write_each(cache, rows, starts):
+    """rows [n, T, KH, D] -> cache[b, :, starts[b]:starts[b]+T] of an
+    [n, KH, S, D] layer of n slots' rows, one write a row (n is a
+    pair's 2). ``starts`` is bounded by `_block`'s contract, row by
+    row."""
+    rows = rows.swapaxes(1, 2).astype(cache.dtype)
+    for b in range(rows.shape[0]):
+        cache = lax.dynamic_update_slice(  # rtpu-lint: disable=unclamped-dynamic-update-slice
+            cache, rows[b:b + 1], (b, 0, starts[b], 0))
+    return cache
+
+
 def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
            cache_kv=None, cache_index=None, standard_positions: bool = False):
     """One transformer block. Returns (x, new_kv | None).
@@ -343,10 +355,17 @@ def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
         # strip, so index+T never exceeds the cache extent. XLA would
         # clamp an overrun backwards over resident rows — callers
         # adding a new write path must re-establish the bound.
-        ck = lax.dynamic_update_slice(  # rtpu-lint: disable=unclamped-dynamic-update-slice
-            ck, k.swapaxes(1, 2).astype(ck.dtype), (0, 0, cache_index, 0))
-        cv = lax.dynamic_update_slice(  # rtpu-lint: disable=unclamped-dynamic-update-slice
-            cv, v.swapaxes(1, 2).astype(cv.dtype), (0, 0, cache_index, 0))
+        if jnp.ndim(cache_index):
+            # One index a ROW (`forward_last_rows_with_cache`): each
+            # row's bucket written at its own, under the same contract.
+            ck, cv = (_write_each(c, r, cache_index)
+                      for c, r in ((ck, k), (cv, v)))
+            cache_index = cache_index[:, None]
+        else:
+            ck = lax.dynamic_update_slice(  # rtpu-lint: disable=unclamped-dynamic-update-slice
+                ck, k.swapaxes(1, 2).astype(ck.dtype), (0, 0, cache_index, 0))
+            cv = lax.dynamic_update_slice(  # rtpu-lint: disable=unclamped-dynamic-update-slice
+                cv, v.swapaxes(1, 2).astype(cv.dtype), (0, 0, cache_index, 0))
         new_kv = (ck, cv)
         kv_len = ck.shape[2]
         kv_pos = jnp.broadcast_to(jnp.arange(kv_len), (x.shape[0], kv_len))
@@ -525,7 +544,8 @@ def _hidden_with_cache(params: Params, tokens: jnp.ndarray,
     ``forward_with_cache`` and ``forward_last_with_cache``, which differ
     in the rows they give the head."""
     b, t = tokens.shape
-    positions = cache_index + jnp.broadcast_to(jnp.arange(t), (b, t))
+    start = cache_index[:, None] if jnp.ndim(cache_index) else cache_index
+    positions = start + jnp.broadcast_to(jnp.arange(t), (b, t))
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
 
     def body(x, layer_and_kv):
@@ -561,6 +581,24 @@ def forward_last_with_cache(params: Params, tokens: jnp.ndarray,
     lie past the slot's length."""
     x, cache = _hidden_with_cache(params, tokens, cache, cache_index, cfg)
     row = lax.dynamic_index_in_dim(x, last, axis=1)             # [B, 1, d]
+    return _head_matmul(row, params, cfg)[:, 0], cache
+
+
+def forward_last_rows_with_cache(params: Params, tokens: jnp.ndarray,
+                                 cache: Dict[str, jnp.ndarray], cache_index,
+                                 last, cfg: LlamaConfig
+                                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """`forward_last_with_cache` for rows that are prompts APART: tokens
+    [n, T], ``cache`` the n slots' rows, ``cache_index`` [n] and
+    ``last`` [n] a row's own (a prefix hit starts past 0; a shorter
+    prompt in a longer partner's bucket ends earlier) -> (logits [n, V],
+    cache). Row b's K and V are written at ``cache_index[b]`` and its
+    queries see keys under ``cache_index[b] + T``, its own alone: the
+    weights are read once for all of them. What a module offers this
+    function for, the engine's tick may prefill two waiting prompts in
+    one program (`serve/engine/core.py` ``_partner``)."""
+    x, cache = _hidden_with_cache(params, tokens, cache, cache_index, cfg)
+    row = jnp.take_along_axis(x, last[:, None, None], axis=1)   # [n, 1, d]
     return _head_matmul(row, params, cfg)[:, 0], cache
 
 

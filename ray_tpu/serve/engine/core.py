@@ -48,6 +48,22 @@ from ray_tpu.util import tracing as _tracing
 _LISTEN_SLICE_S = 0.002
 
 
+# Two waiting prompts are prefilled in ONE program (`_partner`) while
+# that program holds at most this many rows: twice the larger of their
+# two buckets. Up to here a tick prefill costs little more than the
+# stream of the weights, whatever its rows; past it the rows' arithmetic
+# shows. The bare program on a v5e over 7.0 GiB of bf16 weights (my chip
+# run, PR 53; PERF.md section 5), [1, Pb] | [2, Pb] | two of [1, Pb]:
+# 128: 16.7 | 16.2 | 33.3 ms; 256: 18.0 | 26.2 | 35.9; 512: 25.6 | 50.3 |
+# 51.1; 1,024: 49.5. A matrix takes as long to multiply as to stream at
+# 197e12 / 819e9 = 240 rows, and a program this short hides neither
+# under the other: 512 rows in all is where the table bends. A pair of
+# 512s would save 1 ms of 51 and keep the first of the two a whole
+# program longer from its token. The chip's and the weights' dtype's,
+# not a model's.
+_PAIR_ROWS = 512
+
+
 class _PrefillJob:
     """One admission's prefill progress: ``idx`` chunks of ``adm.chunks``
     dispatched, next chunk writing at row ``pos``; ``counters`` holds
@@ -352,6 +368,7 @@ class InferenceEngine:
         # fetched). Engine-thread-only state; bounded by max_batch and
         # one chunk respectively.
         self._prefilling: List[_PrefillJob] = []
+        self._buckets_met: set = set()   # `_compile_bucket`
         self._inflight: Optional[Dict[str, Any]] = None
         # Priority preemption (per-tenant QoS): parked lower-priority
         # requests awaiting resume, plus lifetime counters. Engine-
@@ -1169,66 +1186,157 @@ class InferenceEngine:
         schedule lands each where it is dispatched and returns
         nothing."""
         landing: List[_PrefillJob] = []
-        for job in list(self._prefilling):
-            if job not in self._prefilling:
-                continue  # failed with the cache an earlier job lost
-            if not self._dispatch_prefill(job):
+        jobs = list(self._prefilling)
+        for i, job in enumerate(jobs):
+            if job not in self._prefilling or job.idx == len(job.adm.chunks):
+                continue  # failed with the cache an earlier job lost,
+                #           or gone out as an earlier job's partner
+            partner = self._partner(job, jobs[i + 1:])
+            if not self._dispatch_prefill(job, partner):
                 continue
-            if self._pipelined:
-                landing.append(job)
-            else:
-                self._land_prefill(job)
+            for done in (job, partner) if partner else (job,):
+                if self._pipelined:
+                    landing.append(done)
+                else:
+                    self._land_prefill(done)
         return landing
 
-    def _dispatch_prefill(self, job: "_PrefillJob") -> bool:
+    def _partner(self, job: "_PrefillJob",
+                 behind: List[_PrefillJob]) -> Optional[_PrefillJob]:
+        """The job that shares ``job``'s prefill program, or None: the
+        first of those waiting ``behind`` it (same bucket before the
+        neighbouring one) such that both are at the ONLY chunk of their
+        plans, the program for both holds at most `_PAIR_ROWS` rows,
+        and each prompt, padded to the pair's bucket, still ends within
+        its slot's rows — the scheduler's plan promised that for the
+        job's own bucket, and an overrun is clamped backwards over rows
+        that are resident (`llama._block`). Only where the
+        configuration's model module offers the forward for two prompts
+        in one call (``loop.prefill_pair``); what the engine can see,
+        not an option."""
+        if self.loop.prefill_pair is None or len(job.adm.chunks) != 1:
+            return None
+        bucket = job.adm.chunks[0][1]
+
+        def pairs(other: _PrefillJob) -> bool:
+            if (other not in self._prefilling or other.idx
+                    or len(other.adm.chunks) != 1):
+                return False
+            both = max(bucket, other.adm.chunks[0][1])
+            return (2 * both <= _PAIR_ROWS
+                    and max(job.pos, other.pos) + both <= self.max_len)
+
+        admitted = [other for other in behind if pairs(other)]
+        same = [o for o in admitted if o.adm.chunks[0][1] == bucket]
+        return (same or admitted or [None])[0]
+
+    def _dispatch_prefill(self, job: "_PrefillJob",
+                          partner: Optional[_PrefillJob] = None) -> bool:
         """Dispatch one prefill chunk, no host sync. True when it was
         the job's FINAL chunk: ``job.token`` is then the first
         generated token, on the device, and the job stays in
         ``_prefilling`` until ``_land_prefill`` has fetched it. A chunk
-        that raises aborts its admission alone."""
-        req, slot = job.adm.request, job.adm.slot
-        n, bucket = job.adm.chunks[job.idx]
+        that raises aborts its admission alone.
+
+        With a ``partner`` (`_partner`: each at its plan's only chunk)
+        the two prompts go out as ONE program over ``[2, bucket]``, the
+        shorter padded to the larger bucket: one entry of the device's
+        queue that both jobs hold, one dispatch counted, a token each;
+        neither queued behind the other. One that raises aborts both
+        admissions and nobody else."""
+        jobs = (job,) if partner is None else (job, partner)
+        plans = [j.adm.chunks[j.idx] for j in jobs]
+        bucket = max(b for _, b in plans)
+        real = sum(n for n, _ in plans)
         final = job.idx == len(job.adm.chunks) - 1
         try:
-            with self._tick.phase("prefill_dispatch", slot=slot,
-                                  bucket=bucket, tokens=n):
-                job.t0 = self._tick.now
-                if job.idx == 0:
-                    job.t_pf0 = job.t0
-                    job.ahead = self._devq.ahead()
-                    self.metrics.record_first_dispatch(*job.ahead)
-                suffix = req.prompt_ids[job.pos:job.pos + n]
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :n] = suffix
-                # The head reads ONE row, the last real token's, and
-                # its argmax is what comes back.
-                token, self.cache, *counters = self.loop.prefill_inplace(
-                    self.params, self.cache, self._put(padded),
-                    self._put(np.int32(slot)), self._put(np.int32(job.pos)),
-                    self._put(np.int32(n - 1)))
+            with self._tick.phase("prefill_dispatch", slot=job.adm.slot,
+                                  bucket=bucket, tokens=real,
+                                  rows=len(jobs)):
+                t0, ahead = self._tick.now, self._devq.ahead()
+                for j in jobs:
+                    j.t0 = t0
+                    if j.idx == 0:
+                        j.t_pf0, j.ahead = t0, ahead
+                        self.metrics.record_first_dispatch(*ahead)
+                padded = np.zeros((len(jobs), bucket), np.int32)
+                for i, (j, (n, b)) in enumerate(zip(jobs, plans)):
+                    padded[i, :n] = j.adm.request.prompt_ids[j.pos:j.pos + n]
+                    self._compile_bucket(b)
+                # The head reads ONE row a prompt, the last real
+                # token's, and its argmax is what comes back.
+                if partner is None:
+                    token, self.cache, *counters = self.loop.prefill_inplace(
+                        self.params, self.cache, self._put(padded),
+                        self._put(np.int32(job.adm.slot)),
+                        self._put(np.int32(job.pos)),
+                        self._put(np.int32(plans[0][0] - 1)))
+                    tokens = (token,)
+                else:
+                    tokens, self.cache, *counters = self.loop.prefill_pair(
+                        self.params, self.cache, self._put(padded),
+                        *(self._put(np.array(v, np.int32)) for v in (
+                            [j.adm.slot for j in jobs],
+                            [j.pos for j in jobs],
+                            [n - 1 for n, _ in plans])))
                 # Every chunk's counters ride the final chunk's fetch (a
-                # state family resets its slot in the FIRST chunk).
+                # state family resets its slot in the FIRST chunk); a
+                # pair's are the program's, counted once.
                 job.counters.extend(counters)
-                job.programs.append(self._devq.put(
-                    "prefill", job.t0, token.is_ready, tokens=n,
-                    bucket=bucket))
-                self.metrics.record_prefill_chunk(n)
-                # Per-chunk prefix commit: block occupancy and the
-                # slot's resident chain track the materialized prefix
-                # as chunks land, not the whole prompt up-front.
-                self.kv.commit_prefill(slot, req.prompt_ids[:job.pos + n])
+                program = self._devq.put(
+                    "prefill", t0, tokens[0].is_ready, tokens=real,
+                    bucket=bucket, rows=len(jobs))
+                self.metrics.record_prefill_chunk(real)
+                if partner is not None:
+                    self.metrics.record_prefill_pair()
+                for j, (n, _) in zip(jobs, plans):
+                    j.programs.append(program)
+                    # Per-chunk prefix commit: block occupancy and the
+                    # slot's resident chain track the materialized
+                    # prefix as chunks land, not the whole prompt
+                    # up-front.
+                    self.kv.commit_prefill(
+                        j.adm.slot, j.adm.request.prompt_ids[:j.pos + n])
         except BaseException as e:  # noqa: BLE001 — one bad request
             # must not kill the engine thread (every later request
             # would hang on a dead engine).
-            self._abort_prefill(job, e)
+            for j in jobs:
+                if j in self._prefilling:  # (not failed with the cache)
+                    self._abort_prefill(j, e)
             return False
-        if final:
-            job.token = token
-        else:
-            self._prefill_span(job, job.idx, self._tick.now, ())
-        job.idx += 1
-        job.pos += n
+        for j, (n, _), token in zip(jobs, plans, tokens):
+            if final:
+                j.token = token
+            else:
+                self._prefill_span(j, j.idx, self._tick.now, ())
+            j.idx += 1
+            j.pos += n
         return final
+
+    def _compile_bucket(self, bucket: int) -> None:
+        """A bucket's tick prefills are compiled where the bucket is
+        first met, BOTH of them where two of its prompts may pair: from
+        the arguments' shapes, nothing run and nothing donated, and the
+        dispatches that follow find the programs compiled. A pair may
+        be the first to need either program in the middle of a timed
+        window, and the first job of a small bucket may go out in its
+        partner's larger one; so set-up's one request a bucket pays for
+        all of them (`util/compile_cache.py` counts them as it counts
+        every compile). A family without the paired program compiles
+        where it always did, at the first dispatch."""
+        if bucket in self._buckets_met:
+            return
+        self._buckets_met.add(bucket)  # rtpu-lint: disable=unbounded-registry-growth — one entry a configured prompt bucket, at most
+        if self.loop.prefill_pair is None or 2 * bucket > _PAIR_ROWS:
+            return
+        for program, rows, index in (
+                (self.loop.prefill_inplace, 1, np.int32(0)),
+                (self.loop.prefill_pair, 2, np.zeros(2, np.int32))):
+            index = self._put(index)
+            program.lower(
+                self.params, self.cache,
+                self._put(np.zeros((rows, bucket), np.int32)),
+                index, index, index).compile()
 
     def _abort_prefill(self, job: "_PrefillJob", e: BaseException) -> None:
         """A prefill chunk (or the fetch of its token) failed: this
@@ -1252,14 +1360,16 @@ class InferenceEngine:
         req = job.adm.request
         if req.trace_ctx is None:
             return
-        n, bucket = job.adm.chunks[idx]
+        n, program = job.adm.chunks[idx][0], job.programs[idx]
+        # The bucket and the rows (2: a pair's) of the program that ran.
         attrs = {"prefill_tokens": n, "cached_tokens": job.adm.cached_len,
-                 "bucket": bucket, "slot": job.adm.slot,
+                 "bucket": program.attrs["bucket"], "slot": job.adm.slot,
+                 "rows": program.attrs["rows"],
                  "chunk": idx, "chunks": len(job.adm.chunks),
                  "ahead_chunks": job.ahead[0],
                  "ahead_prefills": job.ahead[1],
                  **self._span_attrs(counters)}
-        split = job.programs[idx].split()
+        split = program.split()
         if split is not None:
             attrs["behind_s"], attrs["own_s"] = split
         self._span("engine.prefill", job.t0, t1, req, attrs)

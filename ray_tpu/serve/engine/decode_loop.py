@@ -31,7 +31,13 @@ live=None)`` (the step: all slots in one batch, each slot's new row a
 layer written at its own length, the family's decode-attention kernel
 on TPU and its jnp reference off it, so CPU tests cover the identical
 loop; ``live`` is the chunk's mask of the slots whose tokens anyone
-reads). Each may
+reads). A module may offer a fourth,
+``forward_last_rows_with_cache(params, tokens, rows, cache_index, last,
+cfg)``: ``forward_last_with_cache`` for rows that are prompts APART,
+``cache_index`` and ``last`` one a row; where it does, the tick may
+prefill two waiting prompts in one program (``prefill_pair``; the rule
+is the engine's, ``core._partner``), and where it does not, nothing of
+this file or the tick differs. Each may
 return, after its two results, a dict of scalar counters that the
 tick's programs hand on (summed over a chunk's steps: a routed family's
 expert counters ride the fetches the tick already makes) and then a
@@ -121,7 +127,9 @@ class DecodeLoop:
         """Under RTPU_DEBUG_JAX=1, wrap every jit entry point in the
         recompile witness with its DECLARED steady-state program
         budget: one chunk program (+ one verify program when built),
-        one prefill program per prompt bucket. Off, wrap_jit returns
+        one prefill program per prompt bucket (and, where the family
+        has the paired one, at most one of those a bucket). Off,
+        wrap_jit returns
         the functions untouched — zero overhead."""
         from ray_tpu.devtools import jax_debug
 
@@ -133,6 +141,11 @@ class DecodeLoop:
         self.prefill_inplace = jax_debug.wrap_jit(
             self.prefill_inplace, "decode_loop.prefill_inplace",
             budget=self.prefill_budget or None)
+        if self.prefill_pair is not None:
+            # At most one paired program a bucket, as of single ones.
+            self.prefill_pair = jax_debug.wrap_jit(
+                self.prefill_pair, "decode_loop.prefill_pair",
+                budget=self.prefill_budget or None)
         self.decode_chunk = jax_debug.wrap_jit(
             self.decode_chunk, "decode_loop.decode_chunk", budget=1)
         self.decode_step = jax_debug.wrap_jit(
@@ -156,9 +169,10 @@ class DecodeLoop:
         from ray_tpu.devtools.jax_debug import JitWitness
 
         out = {}
-        for name in ("prefill", "prefill_inplace", "decode_chunk",
-                     "decode_step", "roster_merge", "roster_join",
-                     "verify_chunk", "export_page", "install_page"):
+        for name in ("prefill", "prefill_inplace", "prefill_pair",
+                     "decode_chunk", "decode_step", "roster_merge",
+                     "roster_join", "verify_chunk", "export_page",
+                     "install_page"):
             fn = getattr(self, name, None)
             if isinstance(fn, JitWitness):
                 out[name] = fn.program_count
@@ -232,6 +246,42 @@ class DecodeLoop:
         # prefill by (`jit_prefill`).
         tick_prefill.__name__ = prefill.__name__
         self.prefill_inplace = jax.jit(tick_prefill, donate_argnums=(1,))
+
+        rows_forward = getattr(model, "forward_last_rows_with_cache", None)
+
+        def tick_prefill_pair(params, cache, tokens, slots, cache_index,
+                              last):
+            """The tick's prefill for TWO waiting prompts: tokens
+            [2, Pb] (the shorter prompt padded to its partner's
+            bucket), ``slots`` [2] (distinct by contract: two
+            admissions never hold one slot), ``cache_index`` [2] and
+            ``last`` [2] each row's own -> ((token a, token b), each
+            int32 [1] as ``tick_prefill`` hands one back, the cache,
+            the family's counters). The weights are streamed once for
+            both."""
+            row = {k: jnp.concatenate(
+                [jax.lax.dynamic_slice_in_dim(v, slots[i], 1, axis=1)
+                 for i in range(2)], axis=1) for k, v in cache.items()}
+            logits, new_row, *counters = rows_forward(
+                params, tokens, row, cache_index, last, cfg)[:3]
+            for i in range(2):
+                # Bounded by contract, as ``in_slot``'s start is.
+                cache = {k: jax.lax.dynamic_update_slice_in_dim(  # rtpu-lint: disable=unclamped-dynamic-update-slice
+                    cache[k], new_row[k][:, i:i + 1], slots[i], axis=1)
+                    for k in cache}
+            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return ((token[:1], token[1:]), cache, *counters)
+
+        # Only where the configuration's model module offers the
+        # forward for rows that are prompts apart (``models/llama.py``);
+        # None for every other family, and the tick then prefills one
+        # prompt a program as it always did. Under the name the tick's
+        # prefill has: to a trace's reader it IS one.
+        self.prefill_pair = None
+        if rows_forward is not None:
+            tick_prefill_pair.__name__ = prefill.__name__
+            self.prefill_pair = jax.jit(tick_prefill_pair,
+                                        donate_argnums=(1,))
 
         def step(params, cache, tokens, lengths, live=None):
             """One decode step for every slot: tokens [B,1], lengths [B],

@@ -139,6 +139,9 @@ class EngineMetrics:
         # (``prefill_tokens`` counts a whole prompt, at its last chunk).
         self.prefill_chunks_dispatched = 0
         self.prefill_chunk_tokens = 0
+        # ... and those of them that carried TWO admissions (core.py
+        # ``_partner``; against the admissions, two a pair).
+        self.prefill_pairs = 0
         self.spec_drafted = 0      # draft tokens proposed
         self.spec_accepted = 0     # draft tokens verified + accepted
         self.spec_chunks = 0       # chunks through the verify program
@@ -295,9 +298,14 @@ class EngineMetrics:
 
     def record_prefill_chunk(self, tokens: int) -> None:
         """One prefill program enqueued, carrying ``tokens`` real
-        prompt tokens (bucket padding left out)."""
+        prompt tokens (bucket padding left out; a pair's program
+        carries both prompts')."""
         self.prefill_chunks_dispatched += 1
         self.prefill_chunk_tokens += tokens
+
+    def record_prefill_pair(self) -> None:
+        """That program carried two admissions."""
+        self.prefill_pairs += 1
 
     def record_model_counters(self, counters) -> None:
         """What a prefill or a chunk's steps counted on the device: a
@@ -364,6 +372,7 @@ class EngineMetrics:
                 "admissions_ahead": self.admissions_ahead,
                 "prefill_chunks_dispatched": self.prefill_chunks_dispatched,
                 "prefill_chunk_tokens": self.prefill_chunk_tokens,
+                "prefill_pairs": self.prefill_pairs,
                 # decode tokens delivered per device token-position
                 # scanned (first tokens come from prefill, so they're
                 # excluded): < 1.0 when slots freeze mid-chunk or

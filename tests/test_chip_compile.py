@@ -298,6 +298,29 @@ def test_engine_tick_prefill_updates_the_cache_in_place(chip, engine):
             >= 0.99 * logits.size * logits.dtype.itemsize)
 
 
+@pytest.mark.parametrize("bucket", [128, 256])
+def test_engine_paired_tick_prefill_updates_the_cache_in_place(chip, bucket):
+    """The tick's prefill for TWO waiting prompts (`prefill_pair`:
+    tokens [2, Pb], two slots, an index and a last row each) at
+    Mistral's widths: under the tick prefill's program name, both
+    slots' rows rewritten where they lie, two 4-byte tokens out and no
+    bucket of logits."""
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg, slots, rows = ENGINES["mistral7b_2l"]
+    loop = DecodeLoop(cfg, max_len=rows, chunk=8)
+    params, cache = _engine_args(chip, cfg, slots, rows)
+    two = _sds(chip, (2,), jnp.int32)
+    args = (params, cache, _sds(chip, (2, bucket), jnp.int32), two, two, two)
+    lowered = loop.prefill_pair.lower(*args)
+    assert "jit_prefill" in lowered.as_text()[:200]
+    pair = lowered.compile()
+    _assert_cache_in_place(pair, cache)
+    tokens, _ = jax.eval_shape(loop.prefill_pair, *args)
+    assert [(t.shape, t.dtype) for t in tokens] == [((1,), jnp.int32)] * 2
+    assert f"{bucket},{cfg.vocab_size}]" not in pair.as_text()
+
+
 # ------------------------------------- the latent cache and the experts
 
 # GLM-4.7-Flash's widths (benchmark/configs/glm-4.7-flash-l7.json) with

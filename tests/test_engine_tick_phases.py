@@ -377,30 +377,39 @@ def test_every_queue_counter_is_a_flat_key_of_the_snapshot(engine):
 def test_a_split_prefill_is_its_wait_less_the_fetch_s_last_lines(engine):
     """``behind + own`` of an admission whose prefill's ends were both
     seen is its ``prefill_s`` less the host's time between the fetch's
-    return and the phase's closing stamp."""
+    return and the phase's closing stamp. That residue lies inside the
+    landing that holds it (for the second of a pair, from the FIRST's
+    landing on: one program, one end), which the test times around the
+    call: a bound a loaded machine keeps, where a number of
+    milliseconds is the scheduler's to break."""
     _every_fetch_waits(engine)
-    waits, splits = [], []
-    admit, split = engine.metrics.record_admit, \
-        engine.metrics.record_prefill_split
+    waits, splits, landings, entered = [], [], [], {}
+    admit, split, land = engine.metrics.record_admit, \
+        engine.metrics.record_prefill_split, engine._land_prefill
     engine.metrics.record_admit = lambda q, p, *rest: (
         waits.append(p), admit(q, p, *rest))[1]
     engine.metrics.record_prefill_split = lambda b, o: (
         splits.append((b, o)), split(b, o))[1]
+
+    def timed_landing(job):
+        t = entered.setdefault(id(job.programs[-1]), time.perf_counter())
+        land(job)
+        landings.append(time.perf_counter() - t)
+
+    engine._land_prefill = timed_landing
     streams = _stream_pair(engine)          # two behind a chunk or two
     for n in (2, 5, 9):
         engine.generate(list(range(1, n + 1)), max_new_tokens=3)
     for stream in streams:
         stream.close()
     s = engine.stats()
-    assert s["requests"] == len(waits) == 5 == len(splits)
+    assert s["requests"] == len(waits) == 5 == len(splits) == len(landings)
     assert s["prefill_split"] == 5
     assert s["prefill_behind_s"] == pytest.approx(sum(b for b, _ in splits))
     assert s["prefill_own_s"] == pytest.approx(sum(o for _, o in splits))
-    for wait, (behind, own) in zip(waits, splits):
+    for wait, (behind, own), landing in zip(waits, splits, landings):
         assert behind >= 0.0 and own > 0.0
-        assert 0.0 <= wait - (behind + own) < 0.02
-    residue = sorted(w - b - o for w, (b, o) in zip(waits, splits))
-    assert residue[len(residue) // 2] < 1e-3
+        assert 0.0 <= wait - (behind + own) <= landing
     # An arrival beside a running stream queued behind its chunk.
     assert s["prefill_ahead_chunks"] >= 1 and s["prefill_ahead_prefills"] >= 0
 
