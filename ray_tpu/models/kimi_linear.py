@@ -501,12 +501,12 @@ def _expand(rows, layer, cfg: KimiLinearConfig):
                     cfg.attn_head_dim), _padded(v, cfg.attn_head_dim))
 
 
-def _mla_prefill_block(x, layer, kv_l, cache_index, positions,
-                       cfg: KimiLinearConfig):
-    """x [B,T,d], kv_l [B,S,W] (this layer's rows of the slot) -> (x,
-    kv_l). Expanded MLA."""
-    h = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
-    q, rows = _queries_and_row(h, layer, cfg)
+def mla_prefill_attend(q, rows, layer, kv_l, cache_index, positions, cfg):
+    """q [B,T,H,qk], rows [B,T,W] (the tokens' cache rows), kv_l
+    [B,S,W] (this layer's rows of the slot) -> (the mixer's output
+    [B,T,d] float32, kv_l with the rows written). Expanded MLA, for
+    every family whose latent row is ``c~ ++ one shared key`` (this
+    one's shared key is plain, `models/xing_mhc.py`'s rotated)."""
     q = _padded(q, cfg.attn_head_dim)
     # cache_index + T is bounded by the engine's contract, as in
     # llama._block: the scheduler admits only what fits a slot's rows.
@@ -520,14 +520,42 @@ def _mla_prefill_block(x, layer, kv_l, cache_index, positions,
     def through_the_cache(_):
         k, v = _expand(kv_l, layer, cfg)
         s = kv_l.shape[1]
-        kv_pos = jnp.broadcast_to(jnp.arange(s), (x.shape[0], s))
+        kv_pos = jnp.broadcast_to(jnp.arange(s), (q.shape[0], s))
         attend = blockwise_attention if s >= 1024 else causal_attention
         return attend(q, k, v, q_positions=positions, kv_positions=kv_pos,
                       scale=cfg.attn_scale).astype(q.dtype)
 
     attn = lax.cond(cache_index == 0, fresh, through_the_cache, None)
-    return x + _mm("bthv,hvd->btd", attn[..., :cfg.v_head_dim],
-                   layer["w_o"]), kv_l
+    return _mm("bthv,hvd->btd", attn[..., :cfg.v_head_dim],
+               layer["w_o"]), kv_l
+
+
+def mla_decode_attend(q, row, layer, layer_idx, kv, lengths, cfg):
+    """q [B,H,qk], row [B,W] (each slot's new cache row), the whole
+    [L,B,S,W] array carried -> (the mixer's output [B,d] float32, kv).
+    Absorbed MLA: the step attends over the latent rows themselves."""
+    kv = _write_rows(kv, layer_idx, lengths, row)
+    nope = cfg.qk_nope_head_dim
+    q_lat = _mm("bhk,rhk->bhr", q[..., :nope], layer["w_uk"])
+    q = _padded(jnp.concatenate([q_lat.astype(q.dtype), q[..., nope:]],
+                                axis=-1), cfg.cache_row_dim)     # [B,H,W]
+    o_lat = mla_decode_attention(
+        q, kv, (lengths + 1).astype(jnp.int32), layer=layer_idx,
+        v_dim=cfg.kv_lora_rank, scale=cfg.attn_scale,
+        interpret=cfg.interpret_kernels)
+    o = _mm("bhr,rhv->bhv", o_lat, layer["w_uv"])
+    return _mm("bhv,hvd->bd", o, layer["w_o"]), kv
+
+
+def _mla_prefill_block(x, layer, kv_l, cache_index, positions,
+                       cfg: KimiLinearConfig):
+    """x [B,T,d], kv_l [B,S,W] (this layer's rows of the slot) -> (x,
+    kv_l). Expanded MLA."""
+    h = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
+    q, rows = _queries_and_row(h, layer, cfg)
+    o, kv_l = mla_prefill_attend(q, rows, layer, kv_l, cache_index,
+                                 positions, cfg)
+    return x + o, kv_l
 
 
 def _mla_decode_block(x, layer, layer_idx, kv, lengths,
@@ -536,17 +564,9 @@ def _mla_decode_block(x, layer, layer_idx, kv, lengths,
     Absorbed MLA: the step attends over the latent rows themselves."""
     h = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
     q, rows = _queries_and_row(h, layer, cfg)
-    kv = _write_rows(kv, layer_idx, lengths, rows[:, 0])
-    nope = cfg.qk_nope_head_dim
-    q_lat = _mm("bhk,rhk->bhr", q[:, 0, :, :nope], layer["w_uk"])
-    q = _padded(jnp.concatenate([q_lat.astype(q.dtype), q[:, 0, :, nope:]],
-                                axis=-1), cfg.cache_row_dim)     # [B,H,W]
-    o_lat = mla_decode_attention(
-        q, kv, (lengths + 1).astype(jnp.int32), layer=layer_idx,
-        v_dim=cfg.kv_lora_rank, scale=cfg.attn_scale,
-        interpret=cfg.interpret_kernels)
-    o = _mm("bhr,rhv->bhv", o_lat, layer["w_uv"])
-    return x + _mm("bhv,hvd->bd", o, layer["w_o"])[:, None], kv
+    o, kv = mla_decode_attend(q[:, 0], rows[:, 0], layer, layer_idx, kv,
+                              lengths, cfg)
+    return x + o[:, None], kv
 
 
 # The engine's seam --------------------------------------------------------

@@ -71,6 +71,15 @@ dsa_prefill                Pallas kernel of a CHUNK of      on TPU where the wid
                            heads, a block of scores kept    is `row_select`'s selection, so no row the
                            in fast memory, tiles past the   queries chose is dropped and none added
                            rows written not read
+mhc.mhc_pre, .mhc_post     Pallas kernels of a four-stream    on TPU where the width is whole lanes, or
+                           (mHC) residual over a block of     ``interpret=True`` off-TPU; jnp twins
+                           ROWS: ``rtpu_mhc_pre`` (the norm,  elsewhere. Imported by its one caller
+                           the product with Phi at float32    (``models/xing_mhc.py``) as
+                           precision, sigmoids, 20 Sinkhorn   ``ray_tpu.ops.mhc``; a decode step's
+                           passes by lane rolls, the streams' slots and a prefill bucket's tokens are
+                           weighted sum) and ``rtpu_mhc_post``  the same two kernels
+                           (H_res X + H_post^T y written over
+                           X, aliased)
 grouped_experts            Pallas grouped kernels between a   a PREFILL's rows on the TPU (or
  .grouped_swiglu           stable sort by expert and its      ``interpret=True``): ``T * k`` at least
                            inverse (dropless, any k):         ``KERNEL_ROWS_A_GROUP`` (8) a held group,
@@ -80,14 +89,16 @@ grouped_experts            Pallas grouped kernels between a   a PREFILL's rows o
                            touched expert's matrices copied   a masked dense product. Imported by its
                            once a call, row tiles past the    callers (``models/glm_moe_lite.py``,
                            groups' total never visited        ``models/zaya.py``,
-                                                            ``models/dots3_note.py`` and
-                                                            ``models/kimi_linear.py``, which hold
+                                                            ``models/dots3_note.py``,
+                                                            ``models/kimi_linear.py`` and through
+                                                            it ``models/xing_mhc.py``, which hold
                                                             a SHARE of the experts: ``held``)
 ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (with attention.py
                                                             the only importers of shard_map —
                                                             rtpu-lint banned-API rule)
 rms_norm                   (fp32 jnp reference)             always; the fused ops' exactness anchor
-apply_rope                 (fp32 jnp reference)             always
+apply_rope                 (fp32 jnp reference)             always; ``freqs`` where the frequencies
+                                                            are scaled (``rotary.YarnScaling``)
 fused_rms_norm             Pallas one-pass norm kernel      ``LlamaConfig.fused_ops``: kernel on TPU
 fused_rms_norm_residual    + residual-add fold              or under ``interpret``; reference impl
 fused_qk_rope              one kernel for q AND k           elsewhere (same custom VJP both ways,

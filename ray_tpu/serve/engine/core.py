@@ -319,7 +319,8 @@ class InferenceEngine:
         # The pipelined chunk schedule (_pipelined_tick) is the
         # drafter-free engine's; drafts need host-visible tokens.
         self._pipelined = self.multi_step and self.drafter is None
-        self.metrics = EngineMetrics(name)
+        self.metrics = EngineMetrics(
+            name, getattr(self.model, "COUNTER_MAXES", ()))
         # The engine thread's phase clock (engine.tick.* counters and
         # spans); created here, used by that thread alone.
         self._tick = TickClock(self.metrics, jax.profiler.TraceAnnotation)

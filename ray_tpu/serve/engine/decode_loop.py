@@ -39,10 +39,11 @@ prefill two waiting prompts in one program (``prefill_pair``; the rule
 is the engine's, ``core._partner``), and where it does not, nothing of
 this file or the tick differs. Each may
 return, after its two results, a dict of scalar counters that the
-tick's programs hand on (summed over a chunk's steps: a routed family's
-expert counters ride the fetches the tick already makes) and then a
-dict of what a check against a reference reads (each token's chosen
-experts), which only the functional programs return. All this file
+tick's programs hand on (summed over a chunk's steps, or, for the names
+in the family's optional ``COUNTER_MAXES``, their largest: a routed
+family's expert counters ride the fetches the tick already makes) and
+then a dict of what a check against a reference reads (each token's
+chosen experts), which only the check's programs return. All this file
 assumes of a cache is a dict of arrays with the slot axis second; a
 family that keeps per-slot STATE among them (``SLOT_STATE_KEYS``: a
 linear-attention layer's, which every step overwrites) steps only the
@@ -242,12 +243,19 @@ class DecodeLoop:
         # lie, and 4 bytes and the counters are all there is to fetch.
         self.prefill = jax.jit(prefill)
         self.prefill_last = jax.jit(prefill_last)
+        # ``prefill_last`` with the cache DONATED, for a check on an
+        # idle engine's own cache where a second one does not fit: all
+        # that the functional program returns, the caller's cache
+        # rewritten where it lies (rebind it, as after the tick's).
+        self.prefill_last_inplace = jax.jit(prefill_last,
+                                            donate_argnums=(1,))
         # The name that device traces and their readers know the tick's
         # prefill by (`jit_prefill`).
         tick_prefill.__name__ = prefill.__name__
         self.prefill_inplace = jax.jit(tick_prefill, donate_argnums=(1,))
 
         rows_forward = getattr(model, "forward_last_rows_with_cache", None)
+        maxes = getattr(model, "COUNTER_MAXES", ())
 
         def tick_prefill_pair(params, cache, tokens, slots, cache_index,
                               last):
@@ -341,7 +349,11 @@ class DecodeLoop:
                     length=self.chunk)
             n_valid = self.chunk - jnp.sum(was_done.astype(jnp.int32),
                                            axis=0)
-            counters = jax.tree.map(lambda c: jnp.sum(c, axis=0), counters)
+            # Over the chunk's steps: summed, but for the names of which
+            # the family wants the largest (``COUNTER_MAXES``).
+            counters = [{name: (jnp.max if name in maxes else jnp.sum)(
+                per_step, axis=0) for name, per_step in c.items()}
+                for c in counters]
             return (toks.T, n_valid, tok, lengths, remaining, done, cache,
                     *counters)
 
@@ -353,10 +365,15 @@ class DecodeLoop:
         # family's step whole, its logits and all it returns besides,
         # for a check against a reference (compiled only if called).
         self.decode_step = jax.jit(step)
-        self.decode_step_whole = jax.jit(
-            lambda params, cache, tokens, lengths, live=None:
-            model.decode_step_with_cache(params, tokens, cache, lengths,
-                                         cfg, live))
+
+        def step_whole(params, cache, tokens, lengths, live=None):
+            return model.decode_step_with_cache(params, tokens, cache,
+                                                lengths, cfg, live)
+
+        self.decode_step_whole = jax.jit(step_whole)
+        # The same with the cache donated (``prefill_last_inplace``).
+        self.decode_step_whole_inplace = jax.jit(step_whole,
+                                                 donate_argnums=(1,))
 
         def roster_merge(keep, carried, fresh):
             """A chunk's five inputs (tokens, lengths, remaining,

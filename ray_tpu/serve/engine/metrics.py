@@ -28,7 +28,7 @@ import collections
 import itertools
 import threading
 import time
-from typing import Any, Deque, Dict, Optional
+from typing import Any, Deque, Dict, Optional, Tuple
 
 from ray_tpu.util import metrics as _m
 from ray_tpu.util import tracing as _tracing
@@ -114,8 +114,12 @@ class EngineMetrics:
     """One engine's counters; thread-safe enough for engine-thread writes
     + caller-thread snapshot reads (all updates hold ``_lock``)."""
 
-    def __init__(self, name: Optional[str] = None):
+    def __init__(self, name: Optional[str] = None,
+                 counter_maxes: Tuple[str, ...] = ()):
         self.name = name or f"engine-{next(_ENGINE_SEQ)}"
+        # The model's counters of which the largest is kept, not the
+        # sum (the family's ``COUNTER_MAXES``).
+        self._counter_maxes = frozenset(counter_maxes)
         self._labels = {"engine": self.name}
         self._lock = threading.Lock()
         self.requests = 0
@@ -311,12 +315,15 @@ class EngineMetrics:
         """What a prefill or a chunk's steps counted on the device: a
         list holding one dict of named scalars, or nothing (a family
         without counters). They came with a fetch the tick makes
-        anyway."""
+        anyway. Summed over the engine's life, but for the names the
+        family lists as ``COUNTER_MAXES``: of those the largest."""
         with self._lock:
             for fetched in counters:
                 for name, value in fetched.items():
+                    kept, new = self.model_counters.get(name, 0), value.item()
                     self.model_counters[name] = (
-                        self.model_counters.get(name, 0) + value.item())
+                        max(kept, new) if name in self._counter_maxes
+                        else kept + new)
 
     def record_spec(self, drafted: int, accepted: int) -> None:
         """One speculative verify chunk: ``drafted`` tokens proposed

@@ -1446,3 +1446,147 @@ def test_kimi_linear_tick_prefill_resets_the_slot_in_the_program(chip):
     assert mem.temp_size_in_bytes < 250_536_960 - 2304 * 4096 * 2
     assert _copies_of(text, cache) == []
     assert _written_out(text, _KDA_PROJECTION_SLICES) == []
+
+
+# --------------------------------------------- the four-stream residual
+
+@pytest.mark.parametrize("rows", (32, 512, 2048))
+def test_mhc_kernels_compile_at_the_step_and_the_buckets(chip, rows):
+    """The two mixes of a sub-layer at the published sizes (4 streams
+    of 3584, ``Phi`` as [24, 14336]): a decode step's 32 rows and a
+    prefill bucket's tokens are ONE kernel each over another grid,
+    under its own name; the write-back aliases the streams it read."""
+    from ray_tpu.ops import mhc
+
+    spec, c = mhc.MhcSpec(), 3584
+    f32 = functools.partial(_sds, chip, dtype=jnp.float32)
+    streams = f32((rows, spec.n * c))
+    pre = _compile(functools.partial(mhc.mhc_pre, spec=spec), streams,
+                   f32((spec.n_maps, spec.n * c)), f32((3,)),
+                   f32((spec.n_maps,)))
+    assert _kernel_calls(pre) == 1 and _names_kernel(pre, mhc.PRE)
+    post = jax.jit(functools.partial(mhc.mhc_post, spec=spec),
+                   donate_argnums=(0,)).lower(
+        streams, f32((rows, c)), f32((rows, mhc.LANES))).compile()
+    assert _kernel_calls(post) == 1 and _names_kernel(post, mhc.POST)
+    assert post.memory_analysis().alias_size_in_bytes >= rows * spec.n * c * 4
+
+
+def _xing_5l():
+    from ray_tpu.models import xing_mhc
+
+    return xing_mhc, xing_mhc.XingMhcConfig(
+        vocab_size=16384, n_layers=5, held_experts=(0, 8), max_seq_len=2048)
+
+
+def test_xing_mhc_decode_chunk_mixes_the_streams_in_named_kernels(chip):
+    """The family's step through the engine's own `decode_chunk` (the
+    layer body is scanned, so 5 layers' HLO is the 40's): both mHC
+    kernels and the latent kernel under their names in the scanned
+    layers, the held experts' grouped products as the rule of
+    ``ops/grouped_experts.py`` gives them (128 rows over 8 held groups:
+    the repo's kernels), the cache aliased and no array of its shape
+    copied, no expert stack copied."""
+    from ray_tpu.ops import grouped_experts as ge, mhc
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    xing, cfg = _xing_5l()
+    slots, rows = 32, 2048
+    loop = DecodeLoop(cfg, max_len=rows, chunk=8)
+    params = _abstract(chip, functools.partial(xing.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: xing.init_kv_cache(cfg, slots, rows))
+    assert cache["kv"].shape == (5, 32, 2048, 640)
+    c = _lower_decode_chunk(chip, loop, params, cache, slots)
+    text = c.as_text()
+    assert f"%{mhc.PRE}." in text and f"%{mhc.POST}." in text
+    assert "%rtpu_mla_decode_attention." in text
+    assert ge._takes_kernels(slots * cfg.n_experts_per_tok, 8, None)
+    assert f"%{ge.SWIGLU}." in text and f"%{ge.MATMUL}." in text
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache["kv"].size * 2
+    assert mem.temp_size_in_bytes < 2 ** 28
+    assert _copies_of(text, cache) == []
+    assert _copies_of(text, {k: params["moe"][k]
+                             for k in ge.EXPERT_STACKS}) == []
+    vec = _sds(chip, (slots,), jnp.int32)
+    out = jax.eval_shape(
+        loop.decode_chunk, params, cache, _sds(chip, (slots, 1), jnp.int32),
+        vec, vec, vec, _sds(chip, (slots,), jnp.bool_))
+    assert len(out) == 8 and set(out[7]) == {
+        "mhc_step_rows", "mhc_sinkhorn_err_max",
+        "moe_layer_steps", "moe_expert_hits", "moe_pairs_routed",
+        "moe_pairs_held", "mla_decode_rows"}
+
+
+def test_xing_mhc_tick_prefill_returns_one_token(chip):
+    """The tick's prefill at the largest bucket: the mHC kernels over
+    2,048 rows, the flash kernel on keys and values padded to 256 and
+    the grouped kernels in it, one token and the counters out, the
+    cache aliased and no array of its shape copied."""
+    from ray_tpu.ops import mhc
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    xing, cfg = _xing_5l()
+    loop = DecodeLoop(cfg, max_len=2048, chunk=8)
+    params = _abstract(chip, functools.partial(xing.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: xing.init_kv_cache(cfg, 32, 2048))
+    scalar = _sds(chip, (), jnp.int32)
+    args = (params, cache, _sds(chip, (1, 2048), jnp.int32), scalar, scalar,
+            scalar)
+    c = loop.prefill_inplace.lower(*args).compile()
+    out = jax.eval_shape(loop.prefill_inplace, *args)
+    assert (out[0].shape, out[0].dtype) == ((1,), jnp.int32)
+    assert len(out) == 3 and {"mhc_prefill_rows", "mhc_sinkhorn_err_max",
+                              "moe_pairs_held"} <= set(out[2])
+    text = c.as_text()
+    assert f"%{mhc.PRE}." in text and f"%{mhc.POST}." in text
+    assert "%flash_attention" in text and " while(" in text
+    assert "%rtpu_grouped_swiglu." in text and "ragged-dot" not in text
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache["kv"].size * 2
+    assert mem.temp_size_in_bytes < 2 ** 30
+    assert _copies_of(text, cache) == []
+
+
+def test_xing_mhc_check_programs_rewrite_the_engines_own_cache(chip):
+    """What `benchmark/drivers/serve_routed_mhc.py` replays at set-up,
+    at the shapes the window times (a second 3.36 GB cache does not fit
+    beside the weights): the donating twins of `prefill_last` and
+    `decode_step_whole` alias the cache and copy no array of its shape,
+    run the same kernels as the tick's programs (32 slots x 4 experts a
+    token are whole row tiles: the grouped kernels, no ``ragged_dot``),
+    and return logits and everything the check reads beside them."""
+    from ray_tpu.ops import mhc
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    xing, cfg = _xing_5l()
+    slots, rows = 32, 2048
+    loop = DecodeLoop(cfg, max_len=rows, chunk=8)
+    params = _abstract(chip, functools.partial(xing.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: xing.init_kv_cache(cfg, slots, rows))
+    scalar = _sds(chip, (), jnp.int32)
+    step = (params, cache, _sds(chip, (slots, 1), jnp.int32),
+            _sds(chip, (slots,), jnp.int32))
+    prefill = (params, cache, _sds(chip, (1, 512), jnp.int32), scalar,
+               scalar, scalar)
+    for program, args, width in (
+            (loop.decode_step_whole_inplace, step, slots),
+            (loop.prefill_last_inplace, prefill, 1)):
+        c = program.lower(*args).compile()
+        text = c.as_text()
+        assert f"%{mhc.PRE}." in text and f"%{mhc.POST}." in text
+        assert "%rtpu_grouped_swiglu." in text and "ragged-dot" not in text
+        mem = c.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache["kv"].size * 2
+        assert _copies_of(text, cache) == []
+        logits, _, counters, seen = jax.eval_shape(program, *args)
+        assert logits.shape == (width, cfg.vocab_size)
+        mixes = seen["mhc_mixes"]
+        assert mixes["first"].shape == (width, 4 * 3584)
+        assert mixes["after"].shape == (5, 2, width, 4 * 3584)
+        assert mixes["y"].shape == (5, 2, width, 3584)
+        assert seen["mhc_end"]["streams"].shape == (width, 4, 3584)
+

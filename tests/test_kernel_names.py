@@ -98,6 +98,8 @@ SERVED = {
     "granite-4.0-h-micro": {"rtpu_mamba2_decode", "rtpu_decode_attention"},
     "kimi-linear-48b-a3b-ep16": {"rtpu_kda_decode",
                                  "rtpu_mla_decode_attention"},
+    "xing4.0-29b-a4b-ep8": {"rtpu_mhc_pre", "rtpu_mhc_post",
+                            "rtpu_mla_decode_attention"},
 }
 
 
