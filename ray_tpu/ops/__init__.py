@@ -75,9 +75,10 @@ mhc.mhc_pre, .mhc_post     Pallas kernels of a four-stream    on TPU where the w
                            (mHC) residual over a block of     ``interpret=True`` off-TPU; jnp twins
                            ROWS: ``rtpu_mhc_pre`` (the norm,  elsewhere. Imported by its one caller
                            the product with Phi at float32    (``models/xing_mhc.py``) as
-                           precision, sigmoids, 20 Sinkhorn   ``ray_tpu.ops.mhc``; a decode step's
-                           passes by lane rolls, the streams' slots and a prefill bucket's tokens are
-                           weighted sum) and ``rtpu_mhc_post``  the same two kernels
+                           precision in three bf16 passes,    ``ray_tpu.ops.mhc``; a decode step's
+                           sigmoids, 20 Sinkhorn passes with  slots and a prefill bucket's tokens are
+                           rows on the lanes, the streams'    the same two kernels
+                           weighted sum) and ``rtpu_mhc_post``
                            (H_res X + H_post^T y written over
                            X, aliased)
 grouped_experts            Pallas grouped kernels between a   a PREFILL's rows on the TPU (or

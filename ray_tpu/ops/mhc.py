@@ -18,9 +18,10 @@ matrix whose rows and columns sum to one (Sinkhorn-Knopp). Then
 
 Both are passes over ``X``, 4 x the bytes of an ordinary residual, and
 little else: ``rtpu_mhc_pre`` reads a block of rows once (the norm's
-sum, the product with ``Phi`` on the MXU at float32 precision, the
-sigmoids and the Sinkhorn passes in fast memory, the weighted sum of
-the streams) and ``rtpu_mhc_post`` rewrites ``X`` where it lies. A
+sum; the product with ``Phi`` on the MXU at float32 precision, its six
+bf16 terms in three passes; the sigmoids and the Sinkhorn passes in
+fast memory with the block's rows on the lanes; the weighted sum of the
+streams) and ``rtpu_mhc_post`` rewrites ``X`` where it lies. A
 decode step's 32 rows and a prefill bucket's tokens are the same two
 kernels over other grids. The kernels run on the TPU (or under
 ``interpret``), their ``jnp`` twins elsewhere.
@@ -51,7 +52,7 @@ from jax import lax
 import jax.numpy as jnp
 import numpy as np
 
-F32 = jnp.float32
+F32, BF16 = jnp.float32, jnp.bfloat16
 LANES = 128
 ROW_TILE = 128                  # rows a grid step (fewer where there are fewer)
 COLUMN_CHUNK = 512              # lanes of a stream an inner step works on
@@ -169,59 +170,108 @@ def _chunks(c: int):
     return [(c0, step) for c0 in range(0, c, step)]
 
 
-def _group_sums(m, roll, *, stride: int, last):
-    """Each lane's sum over its group of ``n`` lanes ``stride`` apart
-    (``n`` = 4: two doubling steps down, the total kept where ``last``
-    marks a group's last member, two doubling steps back up)."""
-    a = m + roll(m, stride)
-    total = jnp.where(last, a + roll(a, 2 * stride), 0.0)
-    total = total + roll(total, LANES - stride)
-    return total + roll(total, LANES - 2 * stride)
+def _bf16_terms(a):
+    """float32 -> (hi, mid, lo) bfloat16 with ``a = hi + mid + lo`` to
+    2^-24 of it: the terms the MXU's float32 product is made of."""
+    terms, rest = [], a
+    for _ in range(3):
+        terms.append(rest.astype(BF16))
+        rest = rest - terms[-1].astype(F32)
+    return terms
 
 
-def _pre_kernel(x_ref, phi_ref, ab_ref, o_ref, maps_ref, phi_pad, *,
+def _maps_rows_on_lanes(pre_t, spec: MhcSpec, roll):
+    """pre_t [128, R]: map logit ``s`` of R rows on sublane ``s``, the
+    rows on the lanes -> the maps in that layout (the sigmoids, the
+    Sinkhorn passes). Row ``i`` of every H_res is ONE [8, R] array, its
+    four entries twice along the sublanes: any four sublanes in a run
+    hold the row once, so two rolls and two adds leave its sum on all
+    eight, and a column's sum is an add across the four arrays."""
+    n = spec.n
+    low = lax.broadcasted_iota(jnp.int32, (2 * n, pre_t.shape[1]), 0) < n
+    sig = jax.nn.sigmoid(pre_t[0:2 * n])
+    m = jnp.exp(jnp.clip(pre_t[2 * n:spec.n_maps], spec.clamp_min,
+                         spec.clamp_max))
+    rows_of = []
+    for pair in (m[0:2 * n], m[2 * n:4 * n]):    # rows (0, 1) and (2, 3)
+        other = roll(pair, n, 0)
+        rows_of += [jnp.where(low, pair, other), jnp.where(low, other, pair)]
+
+    def once(_, rows_of):
+        out = []
+        for r in rows_of:
+            two = r + roll(r, 1, 0)
+            out.append(r * (1.0 / (two + roll(two, 2, 0) + spec.hc_eps)))
+        column = 1.0 / ((out[0] + out[1]) + (out[2] + out[3]) + spec.hc_eps)
+        return tuple(r * column for r in out)
+
+    r0, r1, r2, r3 = lax.fori_loop(0, spec.sinkhorn_iters, once,
+                                   tuple(rows_of))
+    return jnp.concatenate(
+        [jnp.where(low, sig, 2.0 * sig), jnp.where(low, r0, r1),
+         jnp.where(low, r2, r3),
+         jnp.zeros((LANES - spec.n_maps, pre_t.shape[1]), F32)], axis=0)
+
+
+# The float32 product u Phi is six bf16 products of the operands' terms
+# (hi.hi, hi.mid, mid.hi, hi.lo, lo.hi, mid.mid) summed in float32:
+# what ``Precision.HIGHEST`` computes in six MXU passes, four fifths of
+# whose columns multiply the zeros that pad Phi's 24 outputs to a tile.
+# Phi's three terms side by side in ONE tile give the six in three.
+
+def _packed_terms(phi_t):
+    """phi_t [24, K] float32 -> [128, K] bfloat16: rows 0..23 its hi
+    term, 24..47 mid, 48..71 lo, the rest zero."""
+    terms = [t.astype(F32) for t in _bf16_terms(phi_t)]
+    idle = jnp.zeros((LANES - 3 * phi_t.shape[0], phi_t.shape[1]), F32)
+    return jnp.concatenate(terms + [idle], axis=0).astype(BF16)
+
+
+def _against_packed(sums, part, packed):
+    """One chunk of columns more: part [R, k] float32, packed [128, k]
+    (`_packed_terms`) -> the three [R, 128] sums, one a term of
+    ``part``: lanes 0..23 that term against Phi's hi, 24.. mid, 48.. lo."""
+    return [acc + lax.dot_general(term, packed, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=F32)
+            for acc, term in zip(sums, _bf16_terms(part))]
+
+
+def _six_terms(sums, roll, m_cols: int):
+    """`_against_packed`'s sums -> [R, 128] whose lanes 0..23 are the
+    float32 product, the smallest terms summed first (the other lanes
+    hold sums nobody reads)."""
+    hi, mid, lo = sums
+    onto = lambda a, term: roll(a, LANES - term * m_cols, 1)
+    return ((lo + onto(hi, 2) + onto(mid, 1)) + (mid + onto(hi, 1))) + hi
+
+
+def _pre_kernel(x_ref, phi_ref, ab_ref, o_ref, maps_ref, packed, *,
                 spec: MhcSpec, c: int):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n, m_cols = spec.n, spec.n_maps
+    n = spec.n
 
     @pl.when(pl.program_id(0) == 0)
     def _lay_out_phi():
-        # The MXU multiplies whole 128-column tiles: Phi's 24 outputs
-        # stand in the first rows of a zeroed tile of fast memory.
-        phi_pad[...] = jnp.zeros(phi_pad.shape, F32)
-        phi_pad[0:m_cols, :] = phi_ref[...]
+        packed[...] = _packed_terms(phi_ref[...])
 
     rows = x_ref.shape[0]
-    raw = jnp.zeros((rows, LANES), F32)
-    squares = jnp.zeros((rows, 1), F32)
+    squares = jnp.zeros((rows, LANES), F32)
+    sums = [jnp.zeros((rows, LANES), F32)] * 3
     for k0, step in _chunks(n * c):
         part = x_ref[:, k0:k0 + step]
-        squares = squares + jnp.sum(part * part, axis=1, keepdims=True)
-        raw = raw + lax.dot_general(
-            part, phi_pad[:, k0:k0 + step], (((1,), (1,)), ((), ())),
-            precision=lax.Precision.HIGHEST, preferred_element_type=F32)
-    rs = lax.rsqrt(squares / (n * c) + spec.norm_eps)
+        square = part * part
+        for l0 in range(0, step, LANES):
+            squares = squares + square[:, l0:l0 + LANES]
+        sums = _against_packed(sums, part, packed[:, k0:k0 + step])
+    raw = _six_terms(sums, pltpu.roll, spec.n_maps)
+    rs = lax.rsqrt(jnp.sum(squares, axis=1, keepdims=True) / (n * c)
+                   + spec.norm_eps)
+    # Past the 24 map columns the scale and the bias are zero.
     pre = raw * rs * ab_ref[0:1, :] + ab_ref[1:2, :]
-
-    lane = lax.broadcasted_iota(jnp.int32, pre.shape, 1)
-    in_res = (lane >= 2 * n) & (lane < m_cols)
-    sig = jax.nn.sigmoid(pre)
-    m = jnp.where(in_res, jnp.exp(jnp.clip(pre, spec.clamp_min,
-                                           spec.clamp_max)), 0.0)
-    roll = lambda a, by: pltpu.roll(a, by, 1)
-    row_last = in_res & (lane % n == n - 1)
-    column_last = (lane >= m_cols - n) & (lane < m_cols)
-
-    def once(_, m):
-        m = m / (_group_sums(m, roll, stride=1, last=row_last)
-                 + spec.hc_eps)
-        return m / (_group_sums(m, roll, stride=n, last=column_last)
-                    + spec.hc_eps)
-
-    m = lax.fori_loop(0, spec.sinkhorn_iters, once, m)
-    maps = jnp.where(lane < n, sig, jnp.where(lane < 2 * n, 2.0 * sig, m))
+    maps = _maps_rows_on_lanes(_padded_rows(pre, LANES).T, spec,
+                               pltpu.roll).T[0:rows]
     maps_ref[...] = maps
     for c0, step in _chunks(c):
         acc = maps[:, 0:1] * x_ref[:, c0:c0 + step]
@@ -290,7 +340,7 @@ def mhc_pre(streams, phi_t, alpha, bias, *, spec: MhcSpec,
                    pl.BlockSpec((tile, LANES), lambda r: (r, 0))],
         out_shape=[jax.ShapeDtypeStruct((padded, c), F32),
                    jax.ShapeDtypeStruct((padded, LANES), F32)],
-        scratch_shapes=[pltpu.VMEM((LANES, n * c), F32)],
+        scratch_shapes=[pltpu.VMEM((LANES, n * c), BF16)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),

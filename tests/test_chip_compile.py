@@ -1465,6 +1465,13 @@ def test_mhc_kernels_compile_at_the_step_and_the_buckets(chip, rows):
                    f32((spec.n_maps, spec.n * c)), f32((3,)),
                    f32((spec.n_maps,)))
     assert _kernel_calls(pre) == 1 and _names_kernel(pre, mhc.PRE)
+    # What the benchmark's readers parse: the call's FIRST result has
+    # the call's own rows (a step's 32 are not padded to a tile outside
+    # the kernel), the maps follow.
+    bare = re.sub(r"\{[^}]*\}", "", pre.as_text())    # without layouts
+    assert re.search(rf"%{mhc.PRE}[.\d]* = \(([^)]*)\) custom-call",
+                     bare).group(1) == (
+        f"f32[{rows},{c}], f32[{rows},{mhc.LANES}]")
     post = jax.jit(functools.partial(mhc.mhc_post, spec=spec),
                    donate_argnums=(0,)).lower(
         streams, f32((rows, c)), f32((rows, mhc.LANES))).compile()

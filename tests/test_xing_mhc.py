@@ -71,15 +71,14 @@ def _mix_inputs(rows, c, seed=0):
     return spec, streams, phi_t, alpha, bias, y
 
 
-@pytest.mark.parametrize("rows, c", [(1, 128), (32, 256), (256, 512)])
-def test_mhc_kernels_equal_their_twins_and_the_reference(rows, c):
-    """One row, a decode step's 32 and a bucket's 256: the interpreted
-    kernels, the ``jnp`` twins and the plain reference's `_maps` give
-    the same maps, the same collapsed input and the same write-back.
-    Float32 sums in three orders: 1e-5 of a map of order one."""
+def _mixes_agree(spec, streams, phi_t, alpha, bias, y):
+    """The interpreted kernels, the ``jnp`` twins and the plain
+    reference's `_maps` give the same maps, the same collapsed input and
+    the same write-back. Float32 sums in three orders: 1e-5 of a map of
+    order one."""
     from benchmark.reference import mhc_mla_moe_decoder as ref
 
-    spec, streams, phi_t, alpha, bias, y = _mix_inputs(rows, c)
+    rows, c = y.shape
     x, maps = mhc.mhc_pre(streams, phi_t, alpha, bias, spec=spec)
     x_k, maps_k = mhc.mhc_pre(streams, phi_t, alpha, bias, spec=spec,
                               interpret=True)
@@ -88,8 +87,8 @@ def test_mhc_kernels_equal_their_twins_and_the_reference(rows, c):
     np.testing.assert_allclose(x_k, x, atol=1e-5)
     apart = streams.reshape(rows, 4, c)
     h_pre, h_post, h_res = ref._maps(
-        apart, phi_t, alpha, bias, n=4, iters=20, hc_eps=1e-6, lo=-30.0,
-        hi=30.0, eps=1e-6)
+        apart, phi_t, alpha, bias, n=4, iters=spec.sinkhorn_iters,
+        hc_eps=1e-6, lo=-30.0, hi=30.0, eps=1e-6)
     got = mhc.split_maps(maps_k, 4)
     for a, b in zip(got, (h_pre, h_post, h_res)):
         np.testing.assert_allclose(a, b, atol=1e-5)
@@ -101,6 +100,71 @@ def test_mhc_kernels_equal_their_twins_and_the_reference(rows, c):
         out_k.reshape(rows, 4, c),
         ref._write_back(apart, y, h_post, h_res), atol=1e-5)
     assert not np.any(np.asarray(maps_k[:, 24:]))
+    return maps_k
+
+
+@pytest.mark.parametrize("rows, c", [(1, 128), (32, 256), (256, 512),
+                                     (128, 256), (384, 512), (200, 256)])
+def test_mhc_kernels_equal_their_twins_and_the_reference(rows, c):
+    """One row, a decode step's 32 and a bucket's 256; ONE full tile of
+    128 rows, three of them, and a count of rows that ends inside a
+    tile."""
+    _mixes_agree(*_mix_inputs(rows, c))
+
+
+def test_mhc_kernels_equal_their_twins_after_one_sinkhorn_pass():
+    """``sinkhorn_iters`` 1 (what the control `sinkhorn_one_pass` hands
+    in): the kernel stops where the twins stop, rows still off by a
+    tenth and more."""
+    import dataclasses
+
+    spec, *rest = _mix_inputs(40, 128, seed=2)
+    maps = _mixes_agree(dataclasses.replace(spec, sinkhorn_iters=1), *rest)
+    assert float(mhc.sinkhorn_error(maps, 4)) > 0.1
+
+
+def test_mhc_kernels_equal_their_twins_with_logits_at_both_clamps():
+    """``a_res`` large (12 where the drawn ones lie in [0.5, 1.5]; the
+    products' last bits grow with it): H_res starts from entries down
+    to ``exp(-30)`` and up to ``exp(30)``, 26 decimal orders apart, and
+    the kernel's reciprocals and the twins' divisions end on the same
+    maps."""
+    spec, streams, phi_t, alpha, bias, y = _mix_inputs(136, 128, seed=3)
+    alpha = alpha.at[2].set(12.0)
+    raw = mhc.map_logits(streams, phi_t, alpha, bias, spec)[:, 8:]
+    assert float(raw.min()) < -30 and float(raw.max()) > 30
+    maps = _mixes_agree(spec, streams, phi_t, alpha, bias, y)
+    assert np.all(np.isfinite(maps))
+
+
+def test_the_packed_product_is_the_float32_product():
+    """`rtpu_mhc_pre` multiplies by ``Phi`` in three bf16 passes that
+    carry the float32 product's six terms: at K = 2,048 it equals
+    ``einsum`` at ``HIGHEST`` on the float32 operands to 2e-6 of a logit
+    of order one (and lies nearer the float64 product than that sum
+    does), where the three largest terms alone (``bf16_3x``) are ten
+    times further off: an edit that drops a term fails here."""
+    k = jax.random.split(jax.random.PRNGKey(5), 2)
+    u = jax.random.normal(k[0], (128, 2048), jnp.float32)
+    phi_t = jax.random.normal(k[1], (24, 2048), jnp.float32) / (3 * 2048 ** .5)
+    product = lambda a, b: jnp.einsum("rk,mk->rm", a, b,
+                                      precision=jax.lax.Precision.HIGHEST)
+    want = product(u, phi_t)
+    exact = np.asarray(u, np.float64) @ np.asarray(phi_t, np.float64).T
+    sums, packed = [jnp.zeros((128, 128), jnp.float32)] * 3, (
+        mhc._packed_terms(phi_t))
+    for k0 in range(0, 2048, 512):
+        sums = mhc._against_packed(sums, u[:, k0:k0 + 512],
+                                   packed[:, k0:k0 + 512])
+    got = mhc._six_terms(sums, jnp.roll, 24)[:, :24]
+    assert 1.0 < float(jnp.abs(want).max()) < 2.0
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    assert np.abs(got - exact).max() <= np.abs(want - exact).max()
+    (u_hi, u_mid, _), (p_hi, p_mid, _) = (
+        [t.astype(jnp.float32) for t in mhc._bf16_terms(a)]
+        for a in (u, phi_t))
+    three = product(u_hi, p_mid) + product(u_mid, p_hi) + product(u_hi, p_hi)
+    assert float(jnp.abs(three - want).max()) > 2 * 2e-6
 
 
 def test_h_res_is_doubly_stochastic():
