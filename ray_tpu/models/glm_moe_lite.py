@@ -68,6 +68,7 @@ from ray_tpu.ops import (
     causal_attention,
     full_causal_attention,
     mla_decode_attention,
+    mla_step_rows,
     rms_norm,
 )
 from ray_tpu.ops.grouped_experts import (
@@ -474,7 +475,9 @@ def decode_step_with_cache(params: Params, tokens: jnp.ndarray,
     at least one of the B tokens chose (a frozen slot's token is
     computed like any other: static shapes); ``mla_decode_rows`` the
     cache rows a layer's attention is asked to read, Σ (lengths + 1)
-    over the slots (an idle slot is parked at length 0: one row).
+    over the slots (an idle slot is parked at length 0: one row), and
+    ``mla_decode_rows_streamed`` the rows of the blocks the kernel
+    fetches for them (`ops.mla_step_rows`).
     ``live`` (the seam hands it to every family) is not read: the
     benchmark's ``mla_decode_attn_roofline`` takes ``max_len`` rows off
     ``mla_decode_rows`` for every slot-step that was not live, so this
@@ -506,7 +509,7 @@ def decode_step_with_cache(params: Params, tokens: jnp.ndarray,
     logits = jnp.einsum("bd,dv->bv", x[:, 0], params["lm_head"])
     counters = {"moe_layer_steps": jnp.int32(cfg.n_moe_layers),
                 "moe_expert_hits": jnp.sum(hits),
-                "mla_decode_rows": jnp.sum(lengths.astype(jnp.int32) + 1)}
+                **mla_step_rows(lengths, kv, cfg.n_heads)}
     return logits, {"kv": kv}, counters, {"experts": experts}
 
 

@@ -76,7 +76,7 @@ from ray_tpu.models.glm_moe_lite import ENGINE_REFUSES  # noqa: F401 — the
 from ray_tpu.models.kimi_linear import (_swiglu, mla_decode_attend,
                                         mla_prefill_attend, moe_ffn)
 from ray_tpu.models.olmo_hybrid import _layer_of, _mm, _real
-from ray_tpu.ops import apply_rope, mhc, rms_norm
+from ray_tpu.ops import apply_rope, mhc, mla_step_rows, rms_norm
 from ray_tpu.ops.grouped_experts import split_expert_stacks
 from ray_tpu.ops.rotary import YarnScaling
 
@@ -501,7 +501,8 @@ def decode_step_with_cache(params: Params, tokens: jnp.ndarray,
     or column sum - 1| of any H_res in this call; `COUNTER_MAXES`: the
     engine keeps the largest of them)
     and the routed counters as `kimi_linear` gives them, over every
-    slot's token; ``mla_decode_rows`` as `glm_moe_lite` counts it."""
+    slot's token; ``mla_decode_rows`` and ``mla_decode_rows_streamed``
+    as `glm_moe_lite` counts them."""
     b = tokens.shape[0]
     live = (jnp.ones(lengths.shape, bool) if live is None
             else live.astype(bool))
@@ -546,7 +547,7 @@ def decode_step_with_cache(params: Params, tokens: jnp.ndarray,
         "moe_pairs_routed": jnp.int32(cfg.n_moe_layers * b
                                       * cfg.n_experts_per_tok),
         "moe_pairs_held": jnp.sum(moe["load"]).astype(jnp.int32),
-        "mla_decode_rows": jnp.sum(lengths.astype(jnp.int32) + 1)}
+        **mla_step_rows(lengths, kv, cfg.n_heads)}
     return (logits, {"kv": kv}, counters,
             {"experts": moe["experts"],                    # [Lm,B,1,k]
              "mhc_maps": dense["maps"][0].reshape(b, 1, -1),

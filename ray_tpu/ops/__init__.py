@@ -20,9 +20,11 @@ decode_attention           Pallas single-query kernel:      on TPU, or ``interpr
                            streams the blocks of rows that  jnp reference elsewhere
                            begin under each slot's length
 mla_decode_attention       Pallas single-query kernel over  on TPU, or ``interpret=True`` off-TPU;
-                           a LATENT cache: one shared key   jnp reference elsewhere
-                           whose first columns are the
-                           value, each row read once
+                           a LATENT cache: one shared key   jnp reference elsewhere.
+                           whose first columns are the      ``mla_step_rows`` gives a step's counters
+                           value; streams the blocks that   ``mla_decode_rows`` and
+                           begin under each slot's length,  ``mla_decode_rows_streamed`` (the rows of
+                           each row read once               the blocks the kernel fetches)
 gated_delta.gdn_decode     Pallas state step of a gated     on TPU, or ``interpret=True`` off-TPU;
                            delta-rule layer: every slot's   jnp twin elsewhere. Imported by its one
                            state of one layer stepped       caller (``models/olmo_hybrid.py``) as
@@ -137,6 +139,7 @@ from ray_tpu.ops.fused import (
 from ray_tpu.ops.mla_decode import (
     mla_decode_attention,
     mla_decode_attention_reference,
+    mla_step_rows,
 )
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
@@ -157,6 +160,7 @@ __all__ = [
     "fused_swiglu",
     "mla_decode_attention",
     "mla_decode_attention_reference",
+    "mla_step_rows",
     "online_softmax_update",
     "repeat_kv",
     "ring_attention",

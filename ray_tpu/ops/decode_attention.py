@@ -78,11 +78,12 @@ _Q_GROUP_BYTES = 1 << 20
 LANES = 128
 
 
-def decode_block_rows(s: int, kh: int, d: int, itemsize: int) -> int:
+def decode_block_rows(s: int, kh: int, d: int, itemsize: int,
+                      target: int = DMA_TARGET_BYTES) -> int:
     """Rows of a slot that one block holds: the largest power of two
     whose block stays under the DMA's byte target (a row fills whole
     lane tiles), at least 128, at most the slot."""
-    rows = max(128, DMA_TARGET_BYTES // (kh * (d + -d % LANES) * itemsize))
+    rows = max(128, target // (kh * (d + -d % LANES) * itemsize))
     return min(s, 1 << (rows.bit_length() - 1))
 
 
@@ -111,6 +112,86 @@ def decode_step_rows(lengths, live, cache):
             jnp.sum(blocks_streamed(seen, block_s)) * block_s}
 
 
+def slot_group(b: int, slot_bytes: int) -> int:
+    """The slots of one grid step: the largest divisor of ``b`` whose
+    queries (``slot_bytes`` a slot) fit ``_Q_GROUP_BYTES``."""
+    group = max(1, min(b, _Q_GROUP_BYTES // slot_bytes))
+    while b % group:
+        group -= 1
+    return group
+
+
+def list_blocks(len_ref, work_ref, first, slots: int, block_s: int):
+    """Write into ``work_ref`` [2, n] (SMEM) the (slot of the group,
+    block) pairs of the ``slots`` slots from ``first`` on whose block
+    begins under the slot's length, in reading order -> how many."""
+    def list_slot(j, t):
+        def list_block(i, t):
+            work_ref[0, t] = j
+            work_ref[1, t] = i
+            return t + 1
+        return jax.lax.fori_loop(
+            0, blocks_streamed(len_ref[first + j], block_s), list_block, t)
+
+    return jax.lax.fori_loop(0, slots, list_slot, 0)
+
+
+def walk_blocks(total, nbuf: int, copies, block, arrive=None):
+    """ONE loop over a list of ``total`` blocks: ``copies(t)`` are block
+    t's copies into buffer ``t mod nbuf``, started ``nbuf - 1`` blocks
+    ahead of ``block(t)``, which finds them landed.
+
+    ``arrive(t)`` (optional) is the part of block t's work that needs
+    its copies and nothing of the blocks before it. It runs ONE
+    iteration early, in the same basic block as ``block(t - 1, ...)``,
+    so the two chains run under each other, and ``block(t, arrived)``
+    is handed its result; block t's buffer stays whole until
+    ``block(t)`` is through, so the copies are ``nbuf - 2`` blocks ahead
+    of what is read first."""
+    import jax.experimental.pallas as pl
+
+    def start(t):
+        @pl.when(t < total)
+        def _():
+            for copy in copies(t):
+                copy.start()
+
+    def wait(t):
+        for copy in copies(t):
+            copy.wait()
+
+    for t in range(nbuf - 1):
+        start(t)
+
+    if arrive is None:
+        def step(t, _):
+            start(t + nbuf - 1)     # into the buffer block t - 1 has left
+            wait(t)
+            block(t)
+
+        jax.lax.fori_loop(0, total, step, None)
+        return
+
+    @pl.when(total > 0)
+    def _walk():
+        wait(0)
+
+        def step(t, arrived):
+            start(t + nbuf - 1)
+
+            @pl.when(t + 1 < total)
+            def _():
+                wait(t + 1)
+
+            # The list's last block arrives twice; nobody reads the
+            # second.
+            ahead = arrive(jnp.minimum(t + 1, total - 1))
+            block(t, arrived)
+            return ahead
+
+        jax.lax.fori_loop(0, total, step, arrive(0))
+
+
 def _decode_kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
                    k_buf, v_buf, sems, work_ref, m_ref, l_ref, acc_ref, *,
                    block_s: int, scale: float):
@@ -120,16 +201,7 @@ def _decode_kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
     slots, nbuf = q_ref.shape[0], k_buf.shape[0]
     first = pl.program_id(0) * slots
     layer = layer_ref[0]
-
-    def list_slot(j, t):
-        def list_block(i, t):
-            work_ref[0, t] = j
-            work_ref[1, t] = i
-            return t + 1
-        return jax.lax.fori_loop(
-            0, blocks_streamed(len_ref[first + j], block_s), list_block, t)
-
-    total = jax.lax.fori_loop(0, slots, list_slot, 0)
+    total = list_blocks(len_ref, work_ref, first, slots, block_s)
     o_ref[...] = jnp.zeros_like(o_ref)
 
     def copies(t):
@@ -141,19 +213,7 @@ def _decode_kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
             for n, (hbm, vmem) in enumerate(((k_hbm, k_buf),
                                              (v_hbm, v_buf)))]
 
-    def start(t):
-        @pl.when(t < total)
-        def _():
-            for copy in copies(t):
-                copy.start()
-
-    for t in range(nbuf - 1):
-        start(t)
-
-    def block(t, _):
-        start(t + nbuf - 1)     # into the buffer block t - 1 has left
-        for copy in copies(t):
-            copy.wait()
+    def block(t):
         j, i, buf = work_ref[0, t], work_ref[1, t], jax.lax.rem(t, nbuf)
         length = len_ref[first + j]
 
@@ -187,7 +247,7 @@ def _decode_kernel(len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         def _finish():
             o_ref[j] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
-    jax.lax.fori_loop(0, total, block, None)
+    walk_blocks(total, nbuf, copies, block)
 
 
 @functools.partial(jax.jit,
@@ -246,11 +306,7 @@ def decode_attention(q, k, v, lengths, *, layer=None,
             q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_d)))
         k, v, layer = k[None], v[None], 0    # [1,B,KH,S,D]: layer 0 of one
     rep, scale, d = h // kh, d ** -0.5, d + pad_d
-    # The slots of one grid step: the largest divisor of B whose q fits.
-    group = max(1, min(b, _Q_GROUP_BYTES
-                       // (kh * max(rep, 16) * d * q.dtype.itemsize)))
-    while b % group:
-        group -= 1
+    group = slot_group(b, kh * max(rep, 16) * d * q.dtype.itemsize)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     q_spec = pl.BlockSpec((group, kh, rep, d), lambda g, *_: (g, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(

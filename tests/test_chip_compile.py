@@ -333,24 +333,44 @@ def _glm_3l():
                                                        max_seq_len=4096)
 
 
-def test_mla_decode_attention_compiles(chip):
+# (slots, rows a slot, heads, with ``keep``) of the cells that call the
+# latent kernel over rows of 640 columns, 512 of them the values.
+MLA_CELLS = {"glm47flash.code.flood": (32, 4096, 20, False),
+             "xing4.rag.flood": (32, 2048, 32, False),
+             "kimilinear.reason.flood": (64, 2048, 32, False),
+             "dots3.longdoc.flood": (16, 32768, 128, True)}
+
+
+@pytest.mark.parametrize("cell", sorted(MLA_CELLS))
+def test_mla_decode_attention_compiles(chip, cell):
     """The latent row at the width the cache holds it (576 values
-    padded to 640: five whole lane tiles), 20 query heads, blocks of
-    512 rows: one kernel, under its name, the cache read where it lies
-    (no copy, no temporaries)."""
-    from ray_tpu.ops.mla_decode import mla_decode_attention
+    padded to 640: five whole lane tiles) at the four cells' slots,
+    rows and heads: one kernel, under its name, the layer picked out of
+    the whole cache, which stays in HBM (no copy, no temporaries), the
+    block found from the shapes, and the kernel's buffers inside the
+    compiler's own VMEM limit (none is asked for)."""
+    from ray_tpu.ops.mla_decode import mla_block_rows, mla_decode_attention
 
     glm, cfg = _glm_3l()
     assert (cfg.cache_row_values, cfg.cache_row_dim) == (576, 640)
+    slots, rows, heads, kept = MLA_CELLS[cell]
+    name = "rtpu_dsa_decode_attention" if kept else \
+        "rtpu_mla_decode_attention"
+    args = [_sds(chip, (slots, heads, 640)), _sds(chip, (3, slots, rows, 640)),
+            _sds(chip, (slots,), jnp.int32), _sds(chip, (), jnp.int32)]
+    if kept:
+        args.append(_sds(chip, (slots, rows), jnp.bool_))
     c = _compile(
-        lambda q, cache, lens, layer: mla_decode_attention(
+        lambda q, cache, lens, layer, keep=None: mla_decode_attention(
             q, cache, lens, layer=layer, v_dim=cfg.kv_lora_rank,
-            scale=cfg.attn_scale),
-        _sds(chip, (32, cfg.n_heads, 640)), _sds(chip, (3, 32, 4096, 640)),
-        _sds(chip, (32,), jnp.int32), _sds(chip, (), jnp.int32))
+            scale=cfg.attn_scale, keep=keep, name=name), *args)
     assert _kernel_calls(c) == 1
-    assert _names_kernel(c, "rtpu_mla_decode_attention")
-    assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+    assert _names_kernel(c, name)
+    assert "vmem_limit_bytes" not in c.as_text()
+    # Under ``keep`` the mask as float32, a block's tile a row.
+    assert c.memory_analysis().temp_size_in_bytes < (
+        2 ** 20 + kept * slots * rows * 4)
+    assert mla_block_rows(rows, 640, 2, heads) == (512 if kept else 256)
 
 
 def test_glm_decode_chunk_updates_the_latent_cache_in_place(chip):
@@ -1073,6 +1093,8 @@ def test_mla_decode_attention_compiles_at_the_window_layers_width(chip):
         _sds(chip, (DOTS3_SLOTS, 640), jnp.bool_))
     assert _kernel_calls(c) == 1
     assert _names_kernel(c, "rtpu_swa_decode_attention")
+    assert "vmem_limit_bytes" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def test_dots3_decode_chunk_fits_and_updates_its_three_entries_in_place(chip):
@@ -1414,7 +1436,8 @@ def test_kimi_linear_decode_chunk_updates_its_three_entries_in_place(chip):
         vec, vec, vec, _sds(chip, (slots,), jnp.bool_))
     assert len(out) == 8 and set(out[7]) == {
         "kda_slot_steps", "moe_layer_steps", "moe_expert_hits",
-        "moe_pairs_routed", "moe_pairs_held", "mla_decode_rows"}
+        "moe_pairs_routed", "moe_pairs_held", "mla_decode_rows",
+        "mla_decode_rows_streamed"}
 
 
 def test_kimi_linear_tick_prefill_resets_the_slot_in_the_program(chip):
@@ -1523,7 +1546,7 @@ def test_xing_mhc_decode_chunk_mixes_the_streams_in_named_kernels(chip):
     assert len(out) == 8 and set(out[7]) == {
         "mhc_step_rows", "mhc_sinkhorn_err_max",
         "moe_layer_steps", "moe_expert_hits", "moe_pairs_routed",
-        "moe_pairs_held", "mla_decode_rows"}
+        "moe_pairs_held", "mla_decode_rows", "mla_decode_rows_streamed"}
 
 
 def test_xing_mhc_tick_prefill_returns_one_token(chip):
