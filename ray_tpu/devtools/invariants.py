@@ -250,6 +250,12 @@ ARRAY_ATTR_RE = re.compile(
 JAX_HOT_PATH_ROOTS: dict[str, set[str]] = {
     "ray_tpu.serve.engine.core": {"_decode_tick", "_admit",
                                   "_engine_loop"},
+    # The tick's four mechanisms (engine/README.md), by entry method.
+    "ray_tpu.serve.engine.drafter": {"drafts", "tick"},
+    "ray_tpu.serve.engine.kv_fleet": {"extend", "spill_evicted",
+                                      "note_prefill_cost"},
+    "ray_tpu.serve.engine.handoff": {"finish", "tick"},
+    "ray_tpu.serve.engine.preempt": {"park", "resume"},
     "ray_tpu.serve.engine.decode_loop": {"__init__"},
     "ray_tpu.parallel.spmd": {"make_train_step", "make_eval_step"},
 }
@@ -420,6 +426,10 @@ RES_REGISTRY_MODULES = {
     "ray_tpu.serve._private.qos",
     "ray_tpu.serve._private.replica",
     "ray_tpu.serve.engine.core",
+    "ray_tpu.serve.engine.drafter",
+    "ray_tpu.serve.engine.kv_fleet",
+    "ray_tpu.serve.engine.handoff",
+    "ray_tpu.serve.engine.preempt",
     "ray_tpu.devtools.rpc_debug",
     "ray_tpu.devtools.res_debug",
     "ray_tpu.util.tracing",

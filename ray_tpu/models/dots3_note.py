@@ -24,7 +24,7 @@ The low-rank latents are rescaled by constants (``rho_q = sqrt(d /
 q_lora_rank)``, ``rho_kv = sqrt(d / kv_lora_rank)``,
 ``apply_mla_qkv_lora_rescale``). Layer 0 carries a dense SwiGLU, the
 rest a routed expert layer with one shared expert: the router
-(`glm_moe_lite.route`, shared) ranks all ``n_experts``, the gates are
+(`common.route`, shared) ranks all ``n_experts``, the gates are
 normalised over all the chosen, and this chip multiplies the pairs that
 fall on the experts it HOLDS (``held_experts``; ``ops/grouped_experts``
 is told which): what the absent experts would add is left out, and that
@@ -83,7 +83,7 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
-from ray_tpu.models.glm_moe_lite import route
+from ray_tpu.models.common import route
 from ray_tpu.ops import apply_rope, mla_decode_attention, rms_norm
 from ray_tpu.ops import row_select
 from ray_tpu.ops.dsa_prefill import dsa_prefill_attention
@@ -94,17 +94,6 @@ F32 = jnp.float32
 NEG_INF = -1e30
 FULL, SLIDING = "full_attention", "sliding_attention"
 
-# Engine options this family's cache cannot serve yet, each with its
-# reason; `InferenceEngine` refuses them at construction.
-ENGINE_REFUSES = {
-    "quantize": "models/quant.py quantizes llama's weight tree only",
-    "spec_draft_len": "verify_chunk vmaps forward_with_cache over llama's "
-                      "{k, v} cache, and a rejected draft has already "
-                      "overwritten a ring row",
-    "role": "export_page/install_page carry k_page and v_page",
-    "kv_fleet": "kv_fleet.pack_page carries k_page and v_page; pages "
-                "without the ring at their end resume nothing",
-}
 # Cache entries that hold per-slot contents of fixed size and no rows a
 # token: the sliding layers' ring, overwritten as the slot advances.
 SLOT_STATE_KEYS = ("win",)
@@ -333,7 +322,7 @@ def _swiglu(x, w_gate, w_up, w_down):
 def moe_ffn(x, layer, stacks, layer_idx, cfg: Dots3NoteConfig, valid=None):
     """x [T, d] -> (y [T, d], experts [T, k], load [held], pairs held,
     gates [T, k] float32, the router's input [T, d] float32): the router ranks ALL ``n_experts`` in
-    float32 (`glm_moe_lite.route`, its product at the chip's highest
+    float32 (`common.route`, its product at the chip's highest
     precision: the default would round the float32 router to bf16), the
     gates are normalised over all the chosen, and the pairs on this
     chip's experts are multiplied (dropless); a pair on an absent
@@ -561,7 +550,7 @@ def _sliding_prefill_block(x, layer, win_l, cache_index, positions, last,
 def _write_rows(cache, layer_idx, at, rows):
     """rows [B,W] -> cache[layer_idx, b, at[b]] of an [L,B,S,W] entry:
     a scatter into the free view [L*B, S, W], the form the chip's
-    compiler updates in place (llama._write_rows, PR 26). ``at`` is
+    compiler updates in place (common._write_rows, PR 26). ``at`` is
     bounded by the engine's contract (or by the ring's size)."""
     n_layers, b, s, w = cache.shape
     slots = layer_idx * b + jnp.arange(b, dtype=jnp.int32)

@@ -48,8 +48,8 @@ Each of the three returns ``(logits, cache, counters, seen)``:
 ``counters`` are scalars that ride the fetch the tick makes anyway,
 ``seen`` (each token's chosen experts) is what a check against a
 reference reads, returned by the functional programs only.
-`ENGINE_REFUSES` names what the latent cache cannot do yet, and
-`SPAN_ATTRS` the counters that the engine's request spans carry.
+`SPAN_ATTRS` names the counters that the engine's request spans carry
+(no `ENGINE_OFFERS`: the engine's optional mechanisms want llama's cache).
 """
 
 from __future__ import annotations
@@ -62,6 +62,7 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import _write_latent_rows, route
 from ray_tpu.ops import (
     apply_rope,
     blockwise_attention,
@@ -80,15 +81,6 @@ from ray_tpu.ops.grouped_experts import (
 Params = Dict[str, Any]
 F32 = jnp.float32
 
-# Engine options this family's cache cannot serve yet, each with its
-# reason; `InferenceEngine` refuses them at construction.
-ENGINE_REFUSES = {
-    "quantize": "models/quant.py quantizes llama's weight tree only",
-    "spec_draft_len": "verify_chunk vmaps forward_with_cache over llama's "
-                      "{k, v} cache",
-    "role": "export_page/install_page carry k_page and v_page",
-    "kv_fleet": "kv_fleet.pack_page carries k_page and v_page",
-}
 # Fetched counter -> the attribute under which the request's span
 # (``engine.prefill``, ``engine.decode_chunk``) carries it.
 SPAN_ATTRS = {"moe_prefill_load_max": "experts_max_load",
@@ -223,22 +215,6 @@ def init_params(cfg: GlmMoeLiteConfig, key: jax.Array) -> Params:
 
 # Experts ------------------------------------------------------------------
 
-def route(x, router, bias, cfg: GlmMoeLiteConfig, precision=None):
-    """x [T, d] -> (experts [T, k] int32, gates [T, k] float32): chosen
-    on ``s + b``, weighted by ``s``. ``precision`` is the router
-    product's (None: the backend's default, which on the TPU rounds a
-    float32 router to bf16; a family that states a float32 router asks
-    for `lax.Precision.HIGHEST`)."""
-    s = jax.nn.sigmoid(jnp.einsum("td,de->te", x, router,
-                                  precision=precision,
-                                  preferred_element_type=F32))
-    _, experts = lax.top_k(s + bias.astype(F32), cfg.n_experts_per_tok)
-    gates = jnp.take_along_axis(s, experts, axis=-1)
-    if cfg.norm_topk_prob:
-        gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-20)
-    return experts.astype(jnp.int32), gates * cfg.routed_scaling_factor
-
-
 def _swiglu(x, w_gate, w_up, w_down):
     gate = jnp.einsum("td,df->tf", x, w_gate)
     up = jnp.einsum("td,df->tf", x, w_up)
@@ -342,20 +318,6 @@ def _prefill_block(x, layer, moe, cache_l, cache_index, positions, valid,
     return x + y.astype(x.dtype), cache_l, experts, load
 
 
-def _write_rows(cache, layer_idx, lengths, rows):
-    """rows [B,W] -> cache[layer_idx, b, lengths[b]] of the [L,B,S,W]
-    cache: a scatter of W-wide rows into the free view [L*B, S, W], the
-    form the chip's compiler updates in place (llama._write_rows, PR
-    26). ``lengths`` is bounded by the engine's contract."""
-    n_layers, b, s, w = cache.shape
-    slots = layer_idx * b + jnp.arange(b, dtype=jnp.int32)
-    flat = cache.reshape(n_layers * b, s, w)
-    flat = flat.at[slots, lengths.astype(jnp.int32)].set(
-        rows.astype(cache.dtype), unique_indices=True,
-        indices_are_sorted=True)
-    return flat.reshape(cache.shape)
-
-
 def _decode_block(x, layer, moe, layer_idx, cache, lengths,
                   cfg: GlmMoeLiteConfig):
     """x [B,1,d], the whole [L,B,S,W] cache carried -> (x, cache,
@@ -363,7 +325,7 @@ def _decode_block(x, layer, moe, layer_idx, cache, lengths,
     the latent rows."""
     h = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
     q_nope, q_rope, rows = _queries_and_row(h, layer, lengths[:, None], cfg)
-    cache = _write_rows(cache, layer_idx, lengths, rows[:, 0])
+    cache = _write_latent_rows(cache, layer_idx, lengths, rows[:, 0])
     q_lat = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], layer["w_uk"])
     pad = jnp.zeros(q_lat.shape[:2] + (cfg.cache_row_dim
                                        - cfg.cache_row_values,), q_lat.dtype)

@@ -27,7 +27,7 @@ NO rotation: ``q = h W_q`` (H x [q_a | q_b]), ``[c | s] = h W_dkv``,
 ``(|q_a| + |q_b|)^-1/2``.
 
 Layer 0 carries a dense SwiGLU, the rest a routed expert layer with one
-shared expert: the router (`glm_moe_lite.route`, shared; float32, at
+shared expert: the router (`common.route`, shared; float32, at
 the chip's highest precision) ranks all ``n_experts``, the gates are
 normalised over all the chosen, and this chip multiplies the pairs that
 fall on the experts it HOLDS (``held_experts``;
@@ -76,19 +76,10 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
-from ray_tpu.models.glm_moe_lite import _write_rows, route
-from ray_tpu.models.olmo_hybrid import (_layer_of, _mm, _real,
-                                        _starts_fresh)
-from ray_tpu.ops import (
-    blockwise_attention,
-    causal_attention,
-    full_causal_attention,
-    gated_delta,
-    kda,
-    mla_decode_attention,
-    mla_step_rows,
-    rms_norm,
-)
+from ray_tpu.models.common import (_layer_of, _mm, _real, _starts_fresh,
+                                   _swiglu, mla_decode_attend,
+                                   mla_prefill_attend, route)
+from ray_tpu.ops import gated_delta, kda, mla_step_rows, rms_norm
 from ray_tpu.ops.grouped_experts import grouped_swiglu, split_expert_stacks
 
 Params = Dict[str, Any]
@@ -98,18 +89,6 @@ KDA, MLA = "kda", "mla"
 # Cache entries that hold one state a slot and no rows (this module's
 # header says what the engine does about them).
 SLOT_STATE_KEYS = ("state", "conv")
-# Engine options this family's cache cannot serve yet, each with its
-# reason; `InferenceEngine` refuses them at construction.
-ENGINE_REFUSES = {
-    "quantize": "models/quant.py quantizes llama's weight tree only",
-    "spec_draft_len": "a rejected draft would have stepped the state: "
-                      "verify needs a snapshot to roll back to",
-    "role": "export_page/install_page carry k_page and v_page, not the "
-            "latent rows, nor the state a decode replica would need "
-            "beside them",
-    "kv_fleet": "kv_fleet.pack_page carries k_page and v_page; rows "
-                "without the state at their end cannot be resumed",
-}
 # Fetched counter -> the attribute under which the request's span
 # (``engine.prefill``, ``engine.decode_chunk``) carries it.
 SPAN_ATTRS = {"state_resets": "state_reset",
@@ -358,15 +337,10 @@ def serving_params(params: Params, cfg: KimiLinearConfig) -> Params:
 
 # Feed-forward -------------------------------------------------------------
 
-def _swiglu(n, w_gate, w_up, w_down):
-    ff = jax.nn.silu(_mm("td,df->tf", n, w_gate)) * _mm("td,df->tf", n, w_up)
-    return _mm("tf,fd->td", ff, w_down)
-
-
 def moe_ffn(n, layer, stacks, layer_idx, cfg: KimiLinearConfig, valid=None):
     """n [T, d] float32 (the normed stream) -> (y [T, d] float32,
     experts [T, k], load [held]): the router ranks ALL ``n_experts`` in
-    float32 (`glm_moe_lite.route`, its product at the chip's highest
+    float32 (`common.route`, its product at the chip's highest
     precision), the gates are normalised over all the chosen, and the
     pairs on this chip's experts are multiplied (dropless); a pair on
     an absent expert adds nothing here."""
@@ -482,70 +456,6 @@ def _queries_and_row(h, layer, cfg: KimiLinearConfig):
                                       - cfg.cache_row_values,), F32)
     row = jnp.concatenate([c_kv, ckr[..., cfg.kv_lora_rank:], pad], axis=-1)
     return q.astype(cfg.dtype), row.astype(cfg.dtype)
-
-
-def _padded(a, width: int):
-    return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, width - a.shape[-1]),))
-
-
-def _expand(rows, layer, cfg: KimiLinearConfig):
-    """Cache rows [B,S,W] -> per-head keys and values [B,S,H,
-    ``attn_head_dim``]: the up-projections applied, the one shared key
-    beside each head's, zeros up to the kernel's head size."""
-    c_kv = rows[..., :cfg.kv_lora_rank]
-    shared = rows[..., cfg.kv_lora_rank:cfg.cache_row_values]
-    k_a = _mm("bsr,rhk->bshk", c_kv, layer["w_uk"]).astype(rows.dtype)
-    v = _mm("bsr,rhv->bshv", c_kv, layer["w_uv"]).astype(rows.dtype)
-    shared = jnp.broadcast_to(shared[:, :, None, :],
-                              k_a.shape[:3] + (cfg.qk_rope_head_dim,))
-    return (_padded(jnp.concatenate([k_a, shared], axis=-1),
-                    cfg.attn_head_dim), _padded(v, cfg.attn_head_dim))
-
-
-def mla_prefill_attend(q, rows, layer, kv_l, cache_index, positions, cfg):
-    """q [B,T,H,qk], rows [B,T,W] (the tokens' cache rows), kv_l
-    [B,S,W] (this layer's rows of the slot) -> (the mixer's output
-    [B,T,d] float32, kv_l with the rows written). Expanded MLA, for
-    every family whose latent row is ``c~ ++ one shared key`` (this
-    one's shared key is plain, `models/xing_mhc.py`'s rotated)."""
-    q = _padded(q, cfg.attn_head_dim)
-    # cache_index + T is bounded by the engine's contract, as in
-    # llama._block: the scheduler admits only what fits a slot's rows.
-    kv_l = lax.dynamic_update_slice(  # rtpu-lint: disable=unclamped-dynamic-update-slice
-        kv_l, rows.astype(kv_l.dtype), (0, cache_index, 0))
-
-    def fresh(_):
-        k, v = _expand(rows, layer, cfg)
-        return full_causal_attention(q, k, v, scale=cfg.attn_scale)
-
-    def through_the_cache(_):
-        k, v = _expand(kv_l, layer, cfg)
-        s = kv_l.shape[1]
-        kv_pos = jnp.broadcast_to(jnp.arange(s), (q.shape[0], s))
-        attend = blockwise_attention if s >= 1024 else causal_attention
-        return attend(q, k, v, q_positions=positions, kv_positions=kv_pos,
-                      scale=cfg.attn_scale).astype(q.dtype)
-
-    attn = lax.cond(cache_index == 0, fresh, through_the_cache, None)
-    return _mm("bthv,hvd->btd", attn[..., :cfg.v_head_dim],
-               layer["w_o"]), kv_l
-
-
-def mla_decode_attend(q, row, layer, layer_idx, kv, lengths, cfg):
-    """q [B,H,qk], row [B,W] (each slot's new cache row), the whole
-    [L,B,S,W] array carried -> (the mixer's output [B,d] float32, kv).
-    Absorbed MLA: the step attends over the latent rows themselves."""
-    kv = _write_rows(kv, layer_idx, lengths, row)
-    nope = cfg.qk_nope_head_dim
-    q_lat = _mm("bhk,rhk->bhr", q[..., :nope], layer["w_uk"])
-    q = _padded(jnp.concatenate([q_lat.astype(q.dtype), q[..., nope:]],
-                                axis=-1), cfg.cache_row_dim)     # [B,H,W]
-    o_lat = mla_decode_attention(
-        q, kv, (lengths + 1).astype(jnp.int32), layer=layer_idx,
-        v_dim=cfg.kv_lora_rank, scale=cfg.attn_scale,
-        interpret=cfg.interpret_kernels)
-    o = _mm("bhr,rhv->bhv", o_lat, layer["w_uv"])
-    return _mm("bhv,hvd->bd", o, layer["w_o"]), kv
 
 
 def _mla_prefill_block(x, layer, kv_l, cache_index, positions,

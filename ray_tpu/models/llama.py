@@ -44,11 +44,16 @@ from ray_tpu.ops import (
     ring_attention,
     rms_norm,
 )
+from ray_tpu.models.common import _write_rows
 from ray_tpu.models.quant import QuantTensor
 from ray_tpu.parallel import collective_matmul
 from ray_tpu.parallel.mesh import constrain
 
 Params = Dict[str, Any]
+# The optional mechanisms of the serving engine this family offers (the
+# default is none): each was written for this module's {k, v} cache and
+# weight tree (`serve/engine/decode_loop.py` ``ENGINE_OPTIONS``).
+ENGINE_OFFERS = ("quantize", "spec_draft_len", "role", "kv_fleet")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -600,29 +605,6 @@ def forward_last_rows_with_cache(params: Params, tokens: jnp.ndarray,
     x, cache = _hidden_with_cache(params, tokens, cache, cache_index, cfg)
     row = jnp.take_along_axis(x, last[:, None, None], axis=1)   # [n, 1, d]
     return _head_matmul(row, params, cfg)[:, 0], cache
-
-
-def _write_rows(cache, layer_idx, lengths, rows):
-    """rows [B,KH,D] -> cache[layer_idx, b, :, lengths[b], :] of the
-    [L,B,KH,S,D] cache, in place under a loop that carries it.
-
-    A scatter of D-wide rows into the cache seen as [L*B*KH, S, D] (a
-    free view): that is the form the chip's compiler updates in place
-    in the cache's own layout. Scattered as [KH,D] windows of the 5-D
-    array it re-lays the WHOLE cache out, KH inside S, and back around
-    every step; one dynamic_update_slice a slot stays in place but
-    costs 64 small operations a layer (measured, PERF.md PR 26).
-    ``lengths`` is bounded BY CONTRACT like ``_block``'s cache_index:
-    the engine parks a done or empty slot's write on a row of its own
-    that nothing reads (decode_loop's header), under the cache's
-    extent."""
-    n_layers, b, kh, s, d = cache.shape
-    heads = layer_idx * (b * kh) + jnp.arange(b * kh, dtype=jnp.int32)
-    flat = cache.reshape(n_layers * b * kh, s, d)
-    flat = flat.at[heads, jnp.repeat(lengths.astype(jnp.int32), kh)].set(
-        rows.reshape(b * kh, d).astype(cache.dtype),
-        unique_indices=True, indices_are_sorted=True)
-    return flat.reshape(cache.shape)
 
 
 def _decode_block(x, layer, layer_idx, cache_k, cache_v, lengths, seen,

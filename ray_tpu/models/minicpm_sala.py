@@ -72,7 +72,7 @@ steps every LIVE slot's state where it lies (`lightning_decode`) and
 reads each live slot's selected blocks where they lie
 (`sparse_decode_attention`).
 
-`SLOT_STATE_KEYS`, `ENGINE_REFUSES`, `SPAN_ATTRS`: the engine's
+`SLOT_STATE_KEYS`, `SPAN_ATTRS`: the engine's
 contract for a family with per-slot state (``models/olmo_hybrid.py``).
 """
 
@@ -86,13 +86,12 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _write_rows
+from ray_tpu.models.common import (_layer_of, _mm, _real, _starts_fresh,
+                                   _write_rows)
 # The other state family's: a product with bf16 operands and a float32
 # sum, a layer of a stack sliced where its products read it, whether a
 # prefill starts a request (its slot's state is then not read) and which
 # tokens of a bucket are real.
-from ray_tpu.models.olmo_hybrid import (_layer_of, _mm, _real,
-                                        _starts_fresh)
 from ray_tpu.ops import apply_rope, lightning, rms_norm, sparse_attention
 from ray_tpu.ops.sparse_attention import Selection
 
@@ -101,15 +100,6 @@ F32 = jnp.float32
 SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
 
 SLOT_STATE_KEYS = ("state",)
-ENGINE_REFUSES = {
-    "quantize": "models/quant.py quantizes llama's weight tree only",
-    "spec_draft_len": "a rejected draft would have stepped the state and "
-                      "completed windows: verify needs a snapshot",
-    "role": "export_page/install_page carry k_page and v_page, not the "
-            "compressed keys and the state beside them",
-    "kv_fleet": "kv_fleet.pack_page carries k_page and v_page; rows "
-                "without the state at their end cannot be resumed",
-}
 # Fetched counter -> the attribute the request's span carries it under.
 SPAN_ATTRS = {"state_resets": "state_reset",
               "prefill_chunks": "prefill_chunks"}

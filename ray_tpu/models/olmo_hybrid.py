@@ -56,7 +56,7 @@ slots); the full layers call ``ops.decode_attention`` as llama does.
 state: the step then steps only the slots of its ``live`` mask (a slot
 that is idle or between two chunks of its prefill must not be) and the
 engine reuses no prefix (a freed slot holds the state at its LAST token, not at a shared
-prefix's end). `ENGINE_REFUSES` names what such a cache cannot do yet.
+prefix's end). No `ENGINE_OFFERS`: no optional mechanism serves such a cache.
 """
 
 from __future__ import annotations
@@ -69,7 +69,8 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _write_rows
+from ray_tpu.models.common import (_layer_of, _mm, _real, _starts_fresh,
+                                   _write_rows)
 from ray_tpu.ops import (
     blockwise_attention,
     causal_attention,
@@ -86,15 +87,6 @@ F32 = jnp.float32
 # Cache entries that hold one state a slot and no rows (this module's
 # header says what the engine does about them).
 SLOT_STATE_KEYS = ("state", "conv")
-ENGINE_REFUSES = {
-    "quantize": "models/quant.py quantizes llama's weight tree only",
-    "spec_draft_len": "a rejected draft would have stepped the state: "
-                      "verify needs a snapshot to roll back to",
-    "role": "export_page/install_page carry k_page and v_page, not the "
-            "state a decode replica would need beside them",
-    "kv_fleet": "kv_fleet.pack_page carries k_page and v_page; rows "
-                "without the state at their end cannot be resumed",
-}
 # Fetched counter -> the attribute the request's span carries it under.
 SPAN_ATTRS = {"state_resets": "state_reset"}
 
@@ -211,13 +203,6 @@ def init_params(cfg: OlmoHybridConfig, key: jax.Array) -> Params:
 
 # The two halves of a block ------------------------------------------------
 
-def _mm(eq: str, x, w):
-    """A product with a weight: the activation rounded to the weight's
-    type on the way in (the MXU's operands), accumulated and handed on
-    in float32."""
-    return jnp.einsum(eq, x.astype(w.dtype), w, preferred_element_type=F32)
-
-
 def _after(x, mixed, layer, cfg: OlmoHybridConfig):
     """``x + RMSNorm(mixed)``, then the SwiGLU half, post-normed too.
     The residual stream is float32 (this module's header)."""
@@ -260,20 +245,6 @@ def _linear_out(o, gate, layer, cfg: OlmoHybridConfig):
     """o [..,H,dv] float32 -> the mixer's output [..,d]."""
     y = rms_norm(o, layer["ln_o"], cfg.norm_eps) * jax.nn.silu(gate)
     return _mm("...hv,hvd->...d", y, layer["w_o"])
-
-
-def _starts_fresh(cache_index):
-    """Whether a prefill at ``cache_index`` starts a request: its slot's
-    state is then whatever the last request left, and is not read."""
-    return cache_index == 0
-
-
-def _real(t: int, last):
-    """(valid [T], tokens that are real) of a bucket of ``t`` whose
-    last real token is ``last`` (None: all are)."""
-    if last is None:
-        return None, t
-    return jnp.arange(t) <= last, jnp.asarray(last, jnp.int32) + 1
 
 
 def _linear_prefill_block(x, layer, state_l, conv_l, cache_index, last,
@@ -394,17 +365,6 @@ def init_kv_cache(cfg: OlmoHybridConfig, batch: int, max_len: int,
         # sizes): decode then convolves what prefill convolved.
         "conv": jnp.zeros((cfg.n_linear_layers, batch,
                            (cfg.conv_width - 1) * cfg.conv_channels), F32)}
-
-
-def _layer_of(stack: Params, idx):
-    """Layer ``idx`` of a stack of layers, sliced where it is used: a
-    loop nested in the scan over periods that took its layers as a
-    [linear_per_period, ..] slice of the stack would have that slice
-    COPIED out for it every period (the chip's trace, PR 33: 6 GB of
-    weights a decode step); indexed from the whole stack inside the
-    inner loop, each matrix is read by its product where it lies."""
-    return jax.tree.map(
-        lambda a: lax.dynamic_index_in_dim(a, idx, 0, keepdims=False), stack)
 
 
 def _prefill(params, tokens, cache, cache_index, last,

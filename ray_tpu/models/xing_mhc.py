@@ -34,7 +34,7 @@ expanded prefill and the absorbed decode are `models/kimi_linear.py`'s
 shared key``, here rotated; qk 192 beside v 128), the row written as
 `glm_moe_lite` writes it.
 
-**Experts**: `glm_moe_lite.route` (sigmoid + correction bias, gates
+**Experts**: `common.route` (sigmoid + correction bias, gates
 normalised over all the chosen and scaled, float32 at the chip's
 highest precision) ranks all ``n_experts``; this chip multiplies the
 pairs that fall on the experts it HOLDS (``held_experts``,
@@ -71,11 +71,9 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
-from ray_tpu.models.glm_moe_lite import ENGINE_REFUSES  # noqa: F401 — the
-# same latent cache, the same four options refused for the same reasons
-from ray_tpu.models.kimi_linear import (_swiglu, mla_decode_attend,
-                                        mla_prefill_attend, moe_ffn)
-from ray_tpu.models.olmo_hybrid import _layer_of, _mm, _real
+from ray_tpu.models.common import (_layer_of, _mm, _real, _swiglu,
+                                   mla_decode_attend, mla_prefill_attend)
+from ray_tpu.models.kimi_linear import moe_ffn
 from ray_tpu.ops import apply_rope, mhc, mla_step_rows, rms_norm
 from ray_tpu.ops.grouped_experts import split_expert_stacks
 from ray_tpu.ops.rotary import YarnScaling

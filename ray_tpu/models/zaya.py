@@ -73,7 +73,7 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _write_rows
+from ray_tpu.models.common import _write_rows
 from ray_tpu.ops import (
     apply_rope,
     blockwise_attention,
@@ -95,15 +95,6 @@ _HIGHEST = lax.Precision.HIGHEST
 # The cache entry that holds one tail a slot and no rows (this module's
 # header says what the engine does about it).
 SLOT_STATE_KEYS = ("tail",)
-ENGINE_REFUSES = {
-    "quantize": "models/quant.py quantizes llama's weight tree only",
-    "spec_draft_len": "a rejected draft would have stepped the tail: "
-                      "verify needs a snapshot to roll back to",
-    "role": "export_page/install_page carry k_page and v_page, not the "
-            "tail a decode replica would need beside them",
-    "kv_fleet": "kv_fleet.pack_page carries k_page and v_page; rows "
-                "without the tail at their end cannot be resumed",
-}
 # Fetched counter -> the attribute the request's span carries it under.
 SPAN_ATTRS = {"moe_prefill_load_max": "experts_max_load",
               "moe_expert_hits": "experts_touched",

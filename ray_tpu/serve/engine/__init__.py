@@ -1,24 +1,16 @@
 """ray_tpu.serve.engine: device-resident LLM inference engine.
 
-The serving engine as a subsystem (vs the round-5 single-file
-serve/llm.py), four cooperating modules under one orchestrator:
-
-- ``decode_loop``  — jitted K-step decode scan that keeps EOS/budget
-  termination ON DEVICE; one host sync per K tokens. With speculation
-  enabled it also compiles the multi-token verify program (one forward
-  per [B, draft+1] candidate window, on-device accept masks).
-- ``drafter``      — model-free prompt-lookup draft proposer (longest
-  suffix n-gram over prompt + generated) and the per-request adaptive
-  draft-length controller.
-- ``kv_manager``   — slot allocation, block-granular occupancy, and
-  hash-based prefix caching over freed slots' resident KV; speculative
-  grow/rollback keeps rejected draft rows out of the prefix index.
-- ``scheduler``    — model-free continuous-batching admission (FIFO,
-  bucketed prefill, slot recycling, per-request token accounting).
-- ``metrics``      — TTFT/TPOT/queue-depth/prefix-hit-rate plus
-  drafted/accepted speculation counters through the util/metrics
-  registry + the engine ``stats()`` snapshot.
-- ``core``         — ``InferenceEngine``, the engine-thread glue.
+- ``core``         — ``InferenceEngine``: the engine-thread tick, alone.
+- ``decode_loop``  — the jitted programs (prefill, K-step decode scan
+  with EOS/budget termination ON DEVICE, verify, KV pages) and what an
+  optional mechanism needs of a family (``check_offers``).
+- ``kv_manager``   — slots, block occupancy, hash-based prefix cache.
+- ``scheduler``    — model-free continuous-batching admission.
+- ``metrics``      — counters, the tick's clock, the device's queue.
+- ``drafter`` (``Speculation``), ``handoff`` (``KVHandoff``: the
+  prefill/decode roles), ``preempt`` (``Preemption``), ``kv_fleet``
+  (``FleetTier``; imported only when the tier is on) — the four
+  mechanisms the tick calls out to, one object each.
 
 See README.md in this package for the architecture notes;
 ``serve/llm.py`` remains the compatibility facade (``LLMEngine``).

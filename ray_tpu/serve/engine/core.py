@@ -14,30 +14,38 @@ the frozen overshoot, recycles the slot into the prefix cache
 ``serve/llm.py`` keeps the public surface (``LLMEngine.generate`` /
 ``generate_stream`` / ``build_llm_deployment``) as a facade over this
 class.
+
+This module is the tick and nothing else: the fleet KV tier
+(``self.fleet``, kv_fleet.py), the prefill/decode roles
+(``self.handoff``, handoff.py), priority preemption
+(``self.preemption``, preempt.py) and speculation (``self.speculation``,
+drafter.py) are one object each, called at the places and through the
+device surface that engine/README.md lists.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 import queue
 import threading
 import time
 import zlib
-from collections import OrderedDict, deque
+from collections import deque
 from typing import Any, Deque, Dict, List, Optional
 
 import numpy as np
 
 from ray_tpu.devtools import jax_debug
 from ray_tpu.devtools import res_debug as _resdbg
-from ray_tpu.serve.engine.decode_loop import DecodeLoop, serving_params
-from ray_tpu.serve.engine.drafter import PromptLookupDrafter, SpecControl
-from ray_tpu.serve.engine.kv_manager import KVCacheManager, chain_hashes
+from ray_tpu.serve.engine.decode_loop import (DecodeLoop, check_offers,
+                                              serving_params)
+from ray_tpu.serve.engine.drafter import Speculation
+from ray_tpu.serve.engine.handoff import KVHandoff
+from ray_tpu.serve.engine.kv_manager import KVCacheManager
 from ray_tpu.serve.engine.metrics import (DeviceQueue, EngineMetrics,
                                           TickClock)
-from ray_tpu.serve.engine.scheduler import (EngineRequest, Scheduler,
-                                            bucket_for)
+from ray_tpu.serve.engine.preempt import Preemption
+from ray_tpu.serve.engine.scheduler import EngineRequest, Scheduler
 from ray_tpu.util import compile_cache as _compile_cache
 from ray_tpu.util import flight_recorder as _flight
 from ray_tpu.util import tracing as _tracing
@@ -109,70 +117,35 @@ class InferenceEngine:
     """Slot-based continuous-batching engine with a device-resident
     decode loop and prefix caching.
 
-    Constructor signature is a superset of the round-5 ``LLMEngine``:
-    ``decode_chunk`` now defaults to 8 (K decode steps per host sync —
-    a per-token fetch puts the host round-trip on every token) and
-    ``prefix_block`` sets the prefix-cache block granularity.
-
-    Speculative decoding (``spec_draft_len`` > 0): each decode tick the
-    host proposes up to ``spec_chunk * spec_draft_len`` continuation
-    tokens per request by prompt lookup (drafter.py), the device
-    verifies them in multi-token windows (decode_loop.verify_chunk) and
-    the host commits exactly the accepted prefix — greedy output is
-    token-identical to spec-off, only the number of forward passes per
-    token changes. Program choice is per TICK and roster-wide: a tick
-    with no drafts anywhere dispatches the unchanged plain chunk, while
-    one drafting request routes the whole roster through the verify
-    program (draft-free neighbors then advance ``spec_chunk`` tokens
-    per dispatch instead of ``decode_chunk`` — co-batching interference
-    comparable to sharing the roster with any long request).
-    ``spec_draft_len=0`` (the default) builds none of this: no verify
-    program, no cache padding, byte-identical engine behavior to the
-    pre-speculation subsystem.
-
-    ``quantize="int8"`` quantizes the matmul weights to weight-only
-    int8 at engine construction (per-output-channel fp32 scales,
-    ``models/quant.py``): decode and verify read HALF the weight bytes
-    per step — the same memory-bandwidth bound speculative decoding
-    attacks, so the two knobs compound. Greedy outputs may differ from
-    the f32 engine (quantization error), but spec-on vs spec-off WITHIN
-    a quantized engine keeps the token-identical invariant (both run
-    the same quantized weights).
+    ``decode_chunk`` is K decode steps per host sync (a per-token fetch
+    puts the host round-trip on every token); ``prefix_block`` the
+    prefix-cache block granularity. ``spec_draft_len`` > 0 builds the
+    speculative tick (``drafter.Speculation``), ``quantize="int8"``
+    serves weight-only int8 weights (``models/quant.py``), ``role`` and
+    ``kv_fleet_*`` the page mechanisms: engine/README.md has a section
+    each, and each is served for a family that offers it.
 
     ``prefill_chunk`` > 0 splits long prompt suffixes into chunks of
     that many real tokens and dispatches ONE chunk per engine tick,
-    interleaved with the roster's decode chunks (Sarathi-style chunked
-    prefill, Agrawal et al. 2024): a long prompt no longer stalls
-    every co-batched request's TPOT for its whole prefill. Only the
-    final chunk's token is fetched (still one counted prefill sync
-    per admission), the KV manager commits the materialized prefix
-    chain per chunk, and greedy output is token-identical to the
-    unchunked path (same positions, same rows, same math).
+    interleaved with the roster's decode chunks (``_prefill_tick``;
+    Sarathi-style, Agrawal et al. 2024). Only the final chunk's token is
+    fetched, and greedy output is token-identical to the unchunked path.
 
     ``multi_step`` (default on, plain-decode path only) double-buffers
     decode dispatch: each tick enqueues chunk N+1 BEFORE fetching chunk
-    N's tokens, WHATEVER the roster did in between. The roster's decode
-    state (tokens, lengths, budgets, EOS ids, done mask) lives on the
-    device across a roster change: a slot still held by the request it
-    was dispatched with takes chunk N's carry (a finish inside chunk N
-    stays frozen), a request activated since takes the host's values,
-    a request whose prefill was dispatched this tick takes its first
-    token from the prefill's own device output, and a slot nobody
-    holds is parked and done (``_dispatch_chunk``). Chunk N's fetch
-    and delivery and the admissions' first-token fetches then run
-    under chunk N+1's device time. Exactly one host sync per FETCHED
-    chunk and one per admission either way (the witness budget is
-    unchanged); at most one trailing chunk per burst is dispatched
-    wastefully (every roster member already frozen on device) and
-    dropped unfetched. A request whose budget or row cap must end it
-    inside the chunk in flight hands its slot to the next waiter
-    before that chunk is fetched (``_hand_over``): the waiter joins
-    chunk N+1 where the slot would have ridden it frozen. Disabled
-    automatically while speculation drafts
-    (drafts are proposed from host-visible tokens, which an in-flight
-    chunk would lag by one dispatch); ``multi_step=False`` and the
-    speculative engine dispatch, fetch and deliver a chunk in one
-    tick, and fetch a first token where its prefill is dispatched.
+    N's tokens, WHATEVER the roster did in between (``_pipelined_tick``;
+    the roster's decode state is merged on the device, slot by slot:
+    ``_dispatch_chunk``), so chunk N's fetch and delivery and the
+    admissions' first-token fetches run under chunk N+1's device time.
+    Exactly one host sync per FETCHED chunk and one per admission either
+    way (the witness budget is unchanged). A request that must end
+    inside the chunk in flight hands its slot to the next waiter before
+    that chunk is fetched (``_hand_over``). Disabled automatically while
+    speculation drafts (drafts are proposed from host-visible tokens,
+    which an in-flight chunk would lag by one dispatch);
+    ``multi_step=False`` and the speculative engine dispatch, fetch and
+    deliver a chunk in one tick, and fetch a first token where its
+    prefill is dispatched.
     """
 
     @_timed_init
@@ -207,27 +180,20 @@ class InferenceEngine:
         self.model = self.cfg.model
         # Fetched counter -> the attribute its request's span carries.
         self._span_attr_names = getattr(self.model, "SPAN_ATTRS", {})
+        # Fleet KV tier gate (kv_fleet.py): None defers to the config
+        # knob; -1 = off.
         gate = kv_fleet_min_prefix_blocks
         if gate is None:
             from ray_tpu.core.config import GLOBAL_CONFIG as _cfg
 
             gate = _cfg.serve_kv_fleet_min_prefix_blocks
         fleet_on = not (isinstance(gate, int) and gate < 0)
-        # What this family's cache cannot do yet is refused here, by
-        # name, never run wrong.
-        asked = {"quantize": quantize is not None,
-                 "spec_draft_len": int(spec_draft_len) > 0,
-                 "role": role != "colocated", "kv_fleet": fleet_on}
-        for option, why in getattr(self.model, "ENGINE_REFUSES", {}).items():
-            if option not in asked:
-                raise ValueError(
-                    f"{self.model.__name__}.ENGINE_REFUSES names "
-                    f"{option!r}, which is no option of this engine "
-                    f"(it knows {sorted(asked)})")
-            if asked[option]:
-                raise ValueError(
-                    f"{self.model.__name__} cannot serve with {option} "
-                    f"yet: {why}")
+        self.spec_draft_len = max(0, int(spec_draft_len))
+        # An optional mechanism serves a family that offers it (the seam's
+        # ENGINE_OFFERS) or is refused here, by name, before a weight is made.
+        check_offers(self.model, quantize=quantize is not None,
+                     spec_draft_len=self.spec_draft_len > 0,
+                     role=role != "colocated", kv_fleet=fleet_on)
         self.quantize = quantize
         with _compile_cache.phase("engine.weights"):
             self.params = (params if params is not None
@@ -252,39 +218,27 @@ class InferenceEngine:
         self.max_len = min(max_len, self.cfg.max_seq_len)
         self.decode_chunk = max(1, int(decode_chunk))
         self.buckets = prompt_buckets or [32, 64, 128]
-        self.spec_draft_len = max(0, int(spec_draft_len))
-        self.spec_adaptive = bool(spec_adaptive)
-        self.drafter = (PromptLookupDrafter(ngram_max=spec_ngram_max)
-                        if self.spec_draft_len else None)
-
-        # Fleet KV tier gate (kv_fleet.py). None defers to the config
-        # knob; -1 = off (the engine below is byte-identical to the
-        # pre-fleet one: no transfer programs for colocated roles, no
-        # spill hook, no extra snapshot keys); 0 = always pull; n>0 =
-        # pull only contiguous runs of >= n blocks; "auto" = gate on
-        # the measured pull-vs-recompute crossover.
-        self._fleet_min_blocks = gate
-
+        # KV-page export/install (the roles, the fleet tier) moves
+        # whole pages of ``prefix_block`` rows.
+        pages = role != "colocated" or fleet_on
         with _compile_cache.phase("engine.decode_loop"):
             self.loop = DecodeLoop(
                 self.cfg, max_len=self.max_len, chunk=self.decode_chunk,
                 spec_window=self.spec_draft_len + 1, spec_chunk=spec_chunk,
                 prefill_budget=len(self.buckets),
-                kv_page=(prefix_block
-                         if (role != "colocated" or fleet_on) else 0))
+                kv_page=prefix_block if pages else 0)
         # Verify windows span spec_draft_len+1 rows; the scratch strip
         # past max_len absorbs parked/overrun writes so they can never
         # clamp back onto resident rows (decode_loop docstring). Row
         # accounting everywhere else still uses the logical max_len.
         cache_rows = self.max_len + self.loop.scratch_rows
-        if role != "colocated" or fleet_on:
-            # KV-page export/install moves whole pages: pad the
-            # allocation so the tail page of a max-length prompt never
-            # needs the transfer programs' defensive clamp (a clamped
-            # start on ONE side of a prefill→decode pair whose scratch
-            # strips differ would land rows at the wrong offset). The
-            # fleet spill/pull tier moves the same pages, so a
-            # fleet-enabled colocated engine pads identically.
+        if pages:
+            # Pad the allocation so the tail page of a max-length
+            # prompt never needs the transfer programs' defensive clamp
+            # (a clamped start on ONE side of a prefill→decode pair
+            # whose scratch strips differ would land rows at the wrong
+            # offset). The fleet spill/pull tier moves the same pages,
+            # so a fleet-enabled colocated engine pads identically.
             cache_rows = -(-cache_rows // prefix_block) * prefix_block
         # ONE cache buffer for the engine's life: every tick program
         # takes it donated and hands it back aliased (decode_loop's
@@ -316,9 +270,6 @@ class InferenceEngine:
                                    prefill_chunk=prefill_chunk)
         self.prefill_chunk = self.scheduler.prefill_chunk
         self.multi_step = bool(multi_step)
-        # The pipelined chunk schedule (_pipelined_tick) is the
-        # drafter-free engine's; drafts need host-visible tokens.
-        self._pipelined = self.multi_step and self.drafter is None
         self.metrics = EngineMetrics(
             name, getattr(self.model, "COUNTER_MAXES", ()))
         # The engine thread's phase clock (engine.tick.* counters and
@@ -326,42 +277,20 @@ class InferenceEngine:
         self._tick = TickClock(self.metrics, jax.profiler.TraceAnnotation)
         self._devq = DeviceQueue(self.metrics, self._tick)
 
-        # Fleet KV page tier: evicted prefix blocks spill into a shared
-        # page store (shm when a cluster runtime is attached, an
-        # in-process LRU otherwise) and cache misses pull them back
-        # through the install_page + chain-verify seam. self._fleet is
-        # the off switch every fleet code path gates on.
-        self._fleet = None
+        # The four mechanisms, each one object built where its keyword
+        # asks for it (preemption has none and is always built).
+        self.speculation = (Speculation(self, spec_ngram_max, spec_adaptive)
+                            if self.spec_draft_len else None)
+        # The pipelined chunk schedule (_pipelined_tick) is the
+        # drafter-free engine's; drafts need host-visible tokens.
+        self._pipelined = self.multi_step and self.speculation is None
+        self.preemption = Preemption(self)
+        self.handoff = KVHandoff(self) if role != "colocated" else None
+        self.fleet = None
         if fleet_on:
-            from ray_tpu.serve.engine import kv_fleet as _kvf
+            from ray_tpu.serve.engine.kv_fleet import FleetTier
 
-            self._fleet = _kvf.resolve_store(kv_fleet_store)
-            self._fleet_ns = _kvf.fleet_namespace(
-                self.cfg, self.kv.block_size, quantize, seed)
-            self._fleet_lock = threading.Lock()
-            self._fleet_recent: "OrderedDict[int, None]" = OrderedDict()
-            self._fleet_block_count = 0
-            self._fleet_stats = {"kv_fleet_hits": 0,
-                                 "kv_fleet_pulled_blocks": 0,
-                                 "kv_fleet_spilled_blocks": 0,
-                                 "kv_fleet_tokens_reused": 0,
-                                 "kv_fleet_rejects": 0}
-            # Pull-vs-recompute crossover inputs: store-side costs are
-            # measured now (synthetic page roundtrip); the recompute
-            # side arrives from real prefill timings (_note_prefill_cost).
-            self._fleet_pf_ms_blk: Optional[float] = None
-            self._fleet_pf_samples = 0
-            self._fleet_pull_ms_page, self._fleet_lookup_ms = \
-                self._measure_fleet_costs()
-            self.kv.spill_hook = self._spill_evicted
-            # Serialization + store puts happen off the engine thread:
-            # the engine only exports (device work must stay on its
-            # thread) and hands host pages over.
-            self._spill_q: "queue.Queue" = queue.Queue()
-            self._spill_thread = _resdbg.track_thread(
-                threading.Thread(target=self._spill_loop, daemon=True,
-                                 name="llm-kv-spill"), owner=self)
-            self._spill_thread.start()
+            self.fleet = FleetTier(self, gate, kv_fleet_store, seed)
 
         # Chunked-prefill jobs in flight (admitted requests whose
         # suffix is still materializing, one chunk per tick) and the
@@ -371,12 +300,6 @@ class InferenceEngine:
         self._prefilling: List[_PrefillJob] = []
         self._buckets_met: set = set()   # `_compile_bucket`
         self._inflight: Optional[Dict[str, Any]] = None
-        # Priority preemption (per-tenant QoS): parked lower-priority
-        # requests awaiting resume, plus lifetime counters. Engine-
-        # thread-only state like the roster itself.
-        self._parked: List[EngineRequest] = []
-        self._preempts = 0
-        self._resumes = 0
         self._last_retire_t = 0.0  # TPOT cadence anchor (see _retire_chunk)
         # What the listening wait (_listen) derives its deadline from,
         # all measured by the tick itself: the device's queue
@@ -385,12 +308,6 @@ class InferenceEngine:
         # the host seconds of the last few carried dispatches.
         self._dispatch_s: Deque[float] = deque(maxlen=8)
         self._queue: "queue.Queue[EngineRequest]" = queue.Queue()
-        # Decode role: KV-page install jobs handed over from prefill
-        # replicas. Device work happens on the engine thread (installs
-        # run under the tick transfer guard like every other dispatch);
-        # jobs that race slot exhaustion wait in FIFO order.
-        self._install_queue: "queue.Queue" = queue.Queue()
-        self._install_waiting: List[tuple] = []
         self._shutdown = False
         self._thread = _resdbg.track_thread(
             threading.Thread(target=self._engine_loop, daemon=True,
@@ -482,7 +399,7 @@ class InferenceEngine:
         # accounting but never push it onto the stream queue (disagg
         # stream frames start at absolute index 1).
         req.generated.append(int(payload["first_token"]))
-        self._install_queue.put((req, payload))
+        self.handoff.arrivals.put((req, payload))
         return req
 
     def install_remote(self, payload: Dict[str, Any],
@@ -513,15 +430,8 @@ class InferenceEngine:
             raise ValueError("prompt_ids must be ints in [0, vocab_size)")
         if len(req.prompt_ids) + max_new_tokens > self.max_len:
             raise ValueError("prompt + max_new_tokens exceeds max_len")
-        if self.spec_draft_len:
-            # Draft-buffer capacity at full acceptance: every window
-            # advances draft_len+1 positions (_draft_for_roster packs
-            # rows at that stride), the last window needs no bonus.
-            cap = (self.loop.spec_chunk * (self.spec_draft_len + 1)) - 1
-            req.spec = SpecControl(
-                allowance=self.spec_draft_len,
-                max_allowance=cap if self.spec_adaptive
-                else self.spec_draft_len)
+        if self.speculation is not None:
+            req.spec = self.speculation.control()
         return req
 
     def stats(self) -> Dict[str, Any]:
@@ -530,12 +440,11 @@ class InferenceEngine:
                "quantize": self.quantize,
                "role": self.role,
                "prefilling": len(self._prefilling),
-               "installs_waiting": len(self._install_waiting),
+               "installs_waiting": (len(self.handoff.waiting)
+                                    if self.handoff is not None else 0),
                "waiting": (self._queue.qsize()
                            + self.scheduler.queue_depth()),
-               "parked": len(self._parked),
-               "preempts": self._preempts,
-               "resumes": self._resumes,
+               **self.preemption.stats(),
                "cache_rebuilds": self._cache_rebuilds,
                "kv_bytes_per_token": self._kv_bytes_per_token}
         if self._state_bytes_per_slot:
@@ -561,18 +470,8 @@ class InferenceEngine:
                        compile_lower_s=t["lower_s"],
                        compile_backend_s=t["compile_s"],
                        compile_cache_load_s=t["cache_load_s"])
-        if self._fleet is not None:
-            with self._fleet_lock:
-                out.update(self._fleet_stats)
-            out["kv_pull_vs_recompute_crossover_blocks"] = \
-                self._crossover_blocks()
-            out["kv_fleet_pull_ms_per_page"] = self._fleet_pull_ms_page
-            out["kv_fleet_lookup_ms"] = self._fleet_lookup_ms
-            out["kv_fleet_prefill_ms_per_block"] = self._fleet_pf_ms_blk
-            try:
-                out["kv_fleet_store"] = self._fleet.stats()
-            except Exception:  # rtpu-lint: disable=swallowed-exception — stats enrichment; a store without a stats endpoint is fine
-                pass
+        if self.fleet is not None:
+            out.update(self.fleet.stats())
         return out
 
     def load_snapshot(self) -> Dict[str, Any]:
@@ -586,8 +485,9 @@ class InferenceEngine:
         snap = {
             "role": self.role,
             "waiting": (self._queue.qsize() + self.scheduler.queue_depth()
-                        + len(self._install_waiting)
-                        + self._install_queue.qsize()),
+                        + (len(self.handoff.waiting)
+                           + self.handoff.arrivals.qsize()
+                           if self.handoff is not None else 0)),
             "active": len(self.scheduler.active),
             # Admitted but still materializing their prompt (chunked
             # prefill): they hold slots and will decode — surfaced
@@ -596,7 +496,7 @@ class InferenceEngine:
             "prefilling": len(self._prefilling),
             # Parked (preempted) requests will re-admit: queue pressure
             # the router should see even though they hold no slot.
-            "parked": len(self._parked),
+            "parked": len(self.preemption.parked),
             "slots": self.max_batch,
             "free_slots": self.kv.free_slots(),
             "kv_free_blocks": self.kv.free_blocks(),
@@ -607,15 +507,8 @@ class InferenceEngine:
             "prefix_hashes": self.kv.resident_hashes(
                 cfg.serve_snapshot_prefix_hashes),
         }
-        if self._fleet is not None:
-            # Fleet-residency summary for the router's fleet term:
-            # distinct blocks this replica can re-install without
-            # recompute, plus the capped newest chain hashes. Keys
-            # exist ONLY when the tier is on, so fleet-off snapshots
-            # stay byte-identical.
-            with self._fleet_lock:
-                snap["fleet_kv_blocks"] = self._fleet_block_count
-                snap["fleet_kv_hashes"] = list(self._fleet_recent)
+        if self.fleet is not None:
+            snap.update(self.fleet.snapshot())
         return snap
 
     def close(self) -> None:
@@ -634,26 +527,9 @@ class InferenceEngine:
         # off = one env read.
         _resdbg.check_balanced("engine.close", kinds=("kv_spec",),
                                owner=self.kv)
-        # Sessions still parked at close never resume: settle their
-        # pins deliberately (teardown mid-workload is a drain, not a
-        # leak), then assert nothing else is left outstanding.
-        for req in self._parked:
-            _resdbg.note_release("parked_kv", (id(self), id(req)))
-        self._parked.clear()
-        _resdbg.check_balanced("engine.close", kinds=("parked_kv",),
-                               owner=self)
-        if self._fleet is not None:
-            # Drain the spill worker AFTER the engine thread is gone
-            # (it was the only producer): every exported page either
-            # lands in the store or is released — an in-flight tier
-            # transition abandoned here is what kv_page_obj catches.
-            self._spill_q.put(None)
-            if (self._spill_thread.is_alive()
-                    and self._spill_thread
-                    is not threading.current_thread()):
-                self._spill_thread.join(timeout=30.0)
-            _resdbg.check_balanced("engine.close", kinds=("kv_page_obj",),
-                                   owner=self)
+        self.preemption.close()
+        if self.fleet is not None:
+            self.fleet.close()
         if self._thread is not threading.current_thread():
             _resdbg.check_balanced("engine.close", kinds=("thread",),
                                    owner=self)
@@ -696,8 +572,8 @@ class InferenceEngine:
         end inside the chunk in flight are on offer too
         (``_hand_over``)."""
         self.scheduler.drain_into(self._queue)
-        if self._parked:
-            self._resume_tick()
+        if self.preemption.parked:
+            self.preemption.resume()
         lent = self._hand_over()
         first = len(self._prefilling)
         self._run_admissions()
@@ -707,7 +583,7 @@ class InferenceEngine:
         if self.scheduler.queue_depth() and not self.kv.free_slots():
             # Slot-starved with waiters present: a strictly higher
             # priority class may preempt the lowest-priority active.
-            if self._preempt_tick():
+            if self.preemption.park():
                 self._run_admissions()
 
     def _hand_over(self) -> frozenset:
@@ -732,7 +608,7 @@ class InferenceEngine:
         the decode role's installs are not in it). Returns the slots
         lent."""
         rec = self._inflight
-        if rec is None or self._parked:
+        if rec is None or self.preemption.parked:
             return frozenset()
         need = self.scheduler.queue_depth() - self.kv.free_slots()
         if need <= 0:
@@ -748,10 +624,10 @@ class InferenceEngine:
 
     def _run_admissions(self) -> None:
         for adm in self.scheduler.admissions():
-            if (self._fleet is not None
+            if (self.fleet is not None
                     and adm.cached_len < len(adm.request.prompt_ids) - 1):
                 try:
-                    self._fleet_extend(adm)
+                    self.fleet.extend(adm)
                 except Exception as e:  # noqa: BLE001 — a failed pull is a skipped optimization; recompute covers it
                     # A failed pull/install is a skipped optimization:
                     # rows it may have touched sit past cached_len and
@@ -764,138 +640,12 @@ class InferenceEngine:
                         continue
             self._prefilling.append(_PrefillJob(adm, pos=adm.cached_len))
 
-    # -------------------------------------------- priority preemption
-
-    def _preempt_tick(self) -> bool:
-        """Park the lowest-priority active request when a strictly
-        higher-priority arrival is starved for a slot. The victim's
-        slot recycles with its confirmed rows prefix-resident
-        (scheduler.preempt), so the resume continuation re-prefills
-        from cache — or pulls the pages back through the fleet spill
-        tier once they're evicted (the export/install seam). Returns
-        True when a slot was freed."""
-        hp = self.scheduler.max_waiting_priority()
-        if hp is None or not self.scheduler.active:
-            return False
-        # Victim: lowest class, newest arrival within it (LIFO — the
-        # request with the least sunk decode work loses its slot).
-        victim = min(self.scheduler.active,
-                     key=lambda r: (r.priority, -r.arrival_t))
-        if victim.priority >= hp:
-            return False
-        if self._inflight is not None:
-            # Land the in-flight decode chunk BEFORE recycling a slot:
-            # it was dispatched with the victim in its roster, and
-            # _retire_chunk delivers a slot's tokens only to the
-            # request the chunk was dispatched with while it still
-            # holds the slot — parked first, the victim would lose
-            # them (and prefill them again on resume). With nothing in
-            # flight the next chunk is built from the host's values,
-            # the preemptor's among them.
-            prev, self._inflight = self._inflight, None
-            if not self._retire_chunk(prev):
-                return False
-            if self.kv.free_slots():
-                return True  # retirement finished someone: slot free
-            if victim not in self.scheduler.active:
-                victim = min(self.scheduler.active,
-                             key=lambda r: (r.priority, -r.arrival_t))
-                if victim.priority >= hp:
-                    return False
-        t0 = time.perf_counter()
-        self.scheduler.preempt(victim)
-        self._parked.append(victim)
-        # RTPU_DEBUG_RES: a parked session pins scheduler + KV residency
-        # until it resumes (or the engine closes) — an entry left behind
-        # by a resume/close path is exactly the leak the witness flags.
-        _resdbg.note_acquire("parked_kv", key=(id(self), id(victim)),
-                             owner=self, note="preempt_park")
-        self._preempts += 1
-        if victim.trace_ctx is not None:
-            self._span("engine.preempt_park", t0, time.perf_counter(),
-                       victim, {"priority": victim.priority,
-                                "generated": len(victim.generated),
-                                "remaining": victim.remaining()})
-        return True
-
-    def _resume_tick(self) -> None:
-        """Re-admit parked requests (highest priority first) while
-        slots are free and no strictly higher-priority request is
-        still waiting — a resume that would immediately be preempted
-        again is thrash, not progress."""
-        if not self.kv.free_slots():
-            return
-        self._parked.sort(key=lambda r: (-r.priority, r.arrival_t))
-        waiting_hp = self.scheduler.max_waiting_priority()
-        resumed: List[EngineRequest] = []
-        for req in self._parked:
-            if not self.kv.free_slots():
-                break
-            if waiting_hp is not None and waiting_hp > req.priority:
-                break
-            self._resume_one(req)
-            resumed.append(req)
-        for req in resumed:
-            self._parked.remove(req)
-            _resdbg.note_release("parked_kv", (id(self), id(req)))
-
-    def _resume_one(self, orig: EngineRequest) -> None:
-        """Resume a parked request as a CONTINUATION: a fresh request
-        whose prompt is ``prompt + generated`` (greedy determinism
-        makes the regenerated suffix token-identical) and whose budget
-        is the remainder. The continuation shares the stream queue —
-        tokens keep flowing on the original stream — and its result
-        merges into the original future. Admission runs the normal
-        path, so the parked rows come back as a prefix-cache hit or a
-        fleet pull (the park/resume KV round-trip)."""
-        t0 = time.perf_counter()
-        cont = EngineRequest(
-            list(orig.prompt_ids) + list(orig.generated),
-            max_new_tokens=orig.remaining(),
-            eos_id=orig.eos_id,
-            stream_queue=orig.stream_queue,
-            arrival_t=orig.arrival_t,
-            trace_ctx=orig.trace_ctx,
-            tenant=orig.tenant, priority=orig.priority)
-        if self.spec_draft_len:
-            cap = (self.loop.spec_chunk * (self.spec_draft_len + 1)) - 1
-            cont.spec = SpecControl(
-                allowance=self.spec_draft_len,
-                max_allowance=cap if self.spec_adaptive
-                else self.spec_draft_len)
-
-        def _merge(fut, _orig=orig):
-            try:
-                r = fut.result()
-            except BaseException as e:  # noqa: BLE001 — delivered upstream
-                if not _orig.future.done():
-                    _orig.future.set_exception(e)
-                return
-            out = dict(r)
-            out["token_ids"] = list(_orig.generated) + list(r["token_ids"])
-            out["num_generated"] = len(out["token_ids"])
-            out["cached_prefix_len"] = _orig.cached_len
-            out["preempted"] = out.get("preempted", 0) + 1
-            if not _orig.future.done():
-                _orig.future.set_result(out)
-
-        cont.future.add_done_callback(_merge)
-        self.scheduler.submit(cont)
-        self._resumes += 1
-        if orig.trace_ctx is not None:
-            self._span("engine.preempt_resume", t0, time.perf_counter(),
-                       orig, {"priority": orig.priority,
-                              "resume_prompt": len(cont.prompt_ids),
-                              "remaining": cont.max_new_tokens})
-
-    # -------------------------------------------------- fleet KV tier
-
     def export_pages(self, slot: int, block_starts: List[int],
                      tag: str = "kv_export"):
-        """THE KV page export path — the disagg handoff
-        (_finish_handoff) and the spill tier (_spill_evicted) both go
-        through here, so they cannot drift: one jitted program per
-        page, ONE counted host sync for the whole batch, and the
+        """THE KV page export path — the disagg handoff (handoff.py
+        ``finish``) and the spill tier (kv_fleet.py ``spill_evicted``)
+        both go through here, so they cannot drift: one jitted program
+        per page, ONE counted host sync for the whole batch, and the
         padded-tail invariant stated once — the cache allocation is
         padded to a page multiple whenever the transfer programs are
         built, so export_page's defensive clamp (start <= S - P) never
@@ -913,264 +663,6 @@ class InferenceEngine:
         crcs = [zlib.crc32(k.tobytes()) ^ zlib.crc32(v.tobytes())
                 for k, v in zip(pages_k, pages_v)]
         return pages_k, pages_v, crcs
-
-    def _spill_evicted(self, slot: int, resident, chain,
-                       keep_blocks: int) -> None:
-        """kv_manager spill hook: an acquire is about to overwrite this
-        slot's resident rows — export every COMPLETE block the page
-        store doesn't already hold (HBM -> shm tier transition). The
-        kept prefix (blocks < ``keep_blocks``) is exported too, not
-        just the dying suffix: under affinity routing a hot prefix may
-        NEVER be fully evicted on its home replica, and spilling it on
-        first reuse is what makes it pullable by the rest of the fleet
-        (and survivable past this replica's death) — the contains
-        dedupe makes the steady-state cost zero. Runs on the engine
-        thread before any row is written (the new admission's first
-        prefill chunk dispatches strictly later), so the dynamic_slice
-        snapshots are taken from live rows; the fetch-to-host is the
-        batch's one counted sync (tag kv_spill) and serialization/puts
-        happen on the spill worker."""
-        from ray_tpu.serve.engine import kv_fleet as _kvf
-
-        P = self.kv.block_size
-        todo = []
-        for i in range(min(len(chain), len(resident) // P)):
-            oid = _kvf.page_object_id(self._fleet_ns, chain[i])
-            if not self._fleet.contains(oid):
-                todo.append((i, oid))
-        if not todo:
-            return
-        req = getattr(self.kv, "current_request", None)
-        t0 = time.perf_counter()
-        pages_k, pages_v, crcs = self.export_pages(
-            slot, [i * P for i, _ in todo], tag="kv_spill")
-        jobs = []
-        for (i, oid), k, v, crc in zip(todo, pages_k, pages_v, crcs):
-            key = _resdbg.note_acquire("kv_page_obj", owner=self,
-                                       note=f"spill block {i}")
-            jobs.append((oid, tuple(resident[i * P:(i + 1) * P]),
-                         tuple(chain[:i + 1]), k, v, crc, key))
-        self._spill_q.put(jobs)
-        if req is not None and req.trace_ctx is not None:
-            self._span("engine.kv_spill", t0, time.perf_counter(), req,
-                       {"blocks": len(todo), "slot": slot})
-
-    def _spill_loop(self) -> None:
-        """Spill worker: pack + store-put the exported pages. Pure host
-        work on host arrays — no device access, so it needs no tick
-        guard and never contends with the engine thread's dispatch."""
-        from ray_tpu.serve.engine import kv_fleet as _kvf
-
-        while True:
-            jobs = self._spill_q.get()
-            if jobs is None:
-                return
-            for oid, toks, ch, k, v, crc, key in jobs:
-                try:
-                    payload = _kvf.pack_page(toks, ch, k, v, crc)
-                    if self._fleet.put(oid, payload):
-                        with self._fleet_lock:
-                            self._fleet_stats[
-                                "kv_fleet_spilled_blocks"] += 1
-                        self._note_fleet_hash(ch[-1])
-                except Exception:  # rtpu-lint: disable=swallowed-exception — a failed put is a skipped optimization, never a veto
-                    pass
-                finally:
-                    _resdbg.note_release("kv_page_obj", key)
-
-    def _fleet_extend(self, adm) -> None:
-        """Fleet lookup on a (partial) prefix-cache miss: walk the
-        prompt's block chain depth by depth past the local hit, pull
-        each resident page from the tier store, and install through the
-        same install_page + chain/CRC-verify seam as the disagg handoff
-        — then shrink the admission's prefill plan to the suffix.
-        Longest-contiguous-resident-prefix wins; the walk stops at the
-        first miss or rejected payload and never partially applies: a
-        failure before commit leaves cached_len untouched and the
-        suffix prefill overwrites any rows already written."""
-        from ray_tpu.serve.engine import kv_fleet as _kvf
-
-        req = adm.request
-        plen = len(req.prompt_ids)
-        P = self.kv.block_size
-        want = chain_hashes(req.prompt_ids, P)
-        max_d = min(len(want), (plen - 1) // P)
-        d0 = adm.cached_len // P
-        if max_d <= d0:
-            return
-        t0 = time.perf_counter()
-        payloads = []
-        for d in range(d0 + 1, max_d + 1):
-            oid = _kvf.page_object_id(self._fleet_ns, want[d - 1])
-            try:
-                raw = self._fleet.get(oid)
-            except Exception:  # rtpu-lint: disable=swallowed-exception — a store/pull error is a tier miss; the walk stops here
-                raw = None
-            if raw is None:
-                break
-            page = _kvf.unpack_page(raw)
-            if (page is None
-                    or page["chain"] != [int(h) for h in want[:d]]
-                    or page["tokens"] != [
-                        int(t) for t in
-                        req.prompt_ids[(d - 1) * P:d * P]]):
-                # Corrupt bytes (CRC/framing) or a chain-hash collision:
-                # reject — recompute covers this depth and everything
-                # past it, and the slot keeps its local state.
-                with self._fleet_lock:
-                    self._fleet_stats["kv_fleet_rejects"] += 1
-                break
-            payloads.append(page)
-        run = len(payloads)
-        # Same depth veto as scheduler.admissions: the bucket-padded
-        # suffix prefill must still fit under max_len.
-        while run > 0 and (adm.cached_len + run * P
-                           + self.scheduler._prefill_rows(
-                               plen - adm.cached_len - run * P)
-                           > self.max_len):
-            run -= 1
-        if run <= 0 or run < self._fleet_gate():
-            return
-        keys = [_resdbg.note_acquire("kv_page_obj", owner=self,
-                                     note="fleet pull")
-                for _ in range(run)]
-        try:
-            # Pages are verified depth-by-depth but INSTALLED as one
-            # contiguous run: install_page's update-slice is
-            # polymorphic over the page-row dimension, so stacking the
-            # run along the token axis writes all blocks in a single
-            # dispatch (one program per run length) instead of one
-            # dispatch per block — on small models the per-call
-            # overhead of a per-block loop costs more than the prefill
-            # it saves.
-            k_run = np.concatenate(
-                [p["k_page"] for p in payloads[:run]], axis=2)
-            v_run = np.concatenate(
-                [p["v_page"] for p in payloads[:run]], axis=2)
-            self.cache = self.loop.install_page(
-                self.cache, self._put(k_run), self._put(v_run),
-                self._put(np.int32(adm.slot)),
-                self._put(np.int32(d0 * P)))
-            new_cached = adm.cached_len + run * P
-            self.kv.commit_prefill(adm.slot, req.prompt_ids[:new_cached])
-            got_chain = list(self.kv.slot_chain(adm.slot))
-            if got_chain != [int(h) for h in want[:d0 + run]]:
-                raise RuntimeError(
-                    "KV chain mismatch after fleet install: the slot's "
-                    "block hashes disagree with the pulled prefix's")
-        finally:
-            for key in keys:
-                _resdbg.note_release("kv_page_obj", key)
-        adm.cached_len = new_cached
-        req.cached_len = new_cached
-        suffix = plen - new_cached
-        adm.chunks = self.scheduler.prefill_plan(suffix)
-        adm.bucket = bucket_for(suffix, self.buckets)
-        with self._fleet_lock:
-            self._fleet_stats["kv_fleet_hits"] += 1
-            self._fleet_stats["kv_fleet_pulled_blocks"] += run
-            self._fleet_stats["kv_fleet_tokens_reused"] += run * P
-        for j in range(run):
-            self._note_fleet_hash(want[d0 + j])
-        if req.trace_ctx is not None:
-            self._span("engine.kv_fleet_pull", t0, time.perf_counter(),
-                       req, {"blocks": run, "tokens": run * P,
-                             "slot": adm.slot})
-
-    def _note_fleet_hash(self, h: int) -> None:
-        """Record a chain hash this replica can serve from the fleet
-        tier (spilled or pulled) — the capped newest-first summary the
-        load snapshot ships for the router's fleet term."""
-        from ray_tpu.core.config import GLOBAL_CONFIG as cfg
-
-        cap = max(1, cfg.serve_snapshot_fleet_hashes)
-        with self._fleet_lock:
-            if h not in self._fleet_recent:
-                self._fleet_block_count += 1
-            self._fleet_recent[h] = None
-            self._fleet_recent.move_to_end(h)
-            while len(self._fleet_recent) > cap:
-                self._fleet_recent.popitem(last=False)
-
-    def _note_prefill_cost(self, seconds: float,
-                           suffix_tokens: int) -> None:
-        """Recompute-side crossover input: EWMA of measured prefill
-        milliseconds per block. The engine's first admission is
-        excluded — it pays the bucket compiles, which are not a
-        recompute cost."""
-        self._fleet_pf_samples += 1
-        if self._fleet_pf_samples == 1 or suffix_tokens <= 0:
-            return
-        ms_blk = seconds * 1e3 * self.kv.block_size / suffix_tokens
-        prev = self._fleet_pf_ms_blk
-        self._fleet_pf_ms_blk = (ms_blk if prev is None
-                                 else 0.8 * prev + 0.2 * ms_blk)
-
-    def _measure_fleet_costs(self):
-        """Pull-side crossover inputs, measured at engine start: the
-        per-page cost of a store roundtrip (put+get+decode of a
-        real-shaped synthetic page) and the per-walk lookup cost
-        (contains probe). Host-only — no device work, no compiles."""
-        from ray_tpu.serve.engine import kv_fleet as _kvf
-
-        P = self.kv.block_size
-        page = np.zeros((self.cfg.n_layers, self.cfg.n_kv_heads, P,
-                         self.cfg.head_dim), np.float32)
-        crc = zlib.crc32(page.tobytes()) ^ zlib.crc32(page.tobytes())
-        probe_hash = hash(("rtpu-kv-fleet-probe", id(self)))
-        oid = _kvf.page_object_id(self._fleet_ns, probe_hash)
-        payload = _kvf.pack_page([0] * P, [probe_hash], page, page, crc)
-        pull_ms, lookup_ms = [], []
-        try:
-            for _ in range(5):
-                self._fleet.delete(oid)
-                t0 = time.perf_counter()
-                self._fleet.put(oid, payload)
-                raw = self._fleet.get(oid)
-                if raw is not None:
-                    _kvf.unpack_page(raw)
-                pull_ms.append((time.perf_counter() - t0) * 1e3)
-                t0 = time.perf_counter()
-                self._fleet.contains(oid)
-                lookup_ms.append((time.perf_counter() - t0) * 1e3)
-        except Exception:  # rtpu-lint: disable=swallowed-exception — an unprobeable store just disables the measured crossover
-            return None, None
-        finally:
-            try:
-                self._fleet.delete(oid)
-            except Exception:  # rtpu-lint: disable=swallowed-exception — best-effort probe-object cleanup
-                pass
-        if not pull_ms:
-            return None, None
-        return min(pull_ms), min(lookup_ms)
-
-    def _crossover_blocks(self) -> Optional[int]:
-        """Measured pull-vs-recompute crossover: the contiguous run
-        length (blocks) past which pulling beats recomputing. Pulling d
-        blocks costs ~lookup + d*pull_page; recomputing them rides the
-        suffix prefill at ~d*prefill_block. None until the recompute
-        side has a sample; -1 when pulling never pays off."""
-        pf, pull = self._fleet_pf_ms_blk, self._fleet_pull_ms_page
-        if pf is None or pull is None:
-            return None
-        margin = pf - pull
-        if margin <= 0:
-            return -1
-        return max(1, math.ceil((self._fleet_lookup_ms or 0.0) / margin))
-
-    def _fleet_gate(self) -> int:
-        """Effective minimum pullable run: the knob when explicit, the
-        measured crossover when 'auto' (optimistic single-block pulls
-        until the recompute side has a sample)."""
-        g = self._fleet_min_blocks
-        if isinstance(g, int):
-            return max(0, g)
-        co = self._crossover_blocks()
-        if co is None:
-            return 1
-        if co < 0:
-            return 1 << 30
-        return co
 
     def _prefill_tick(self) -> List[_PrefillJob]:
         """Advance EVERY in-progress prefill by one chunk. Intermediate
@@ -1420,9 +912,9 @@ class InferenceEngine:
             req.first_token_t = t1
             queue_s = max(0.0, job.t_pf0 - req.arrival_t)
             prefill_s = max(0.0, t1 - job.t_pf0)
-            if self._fleet is not None:
-                self._note_prefill_cost(prefill_s,
-                                        len(req.prompt_ids) - cached)
+            if self.fleet is not None:
+                self.fleet.note_prefill_cost(prefill_s,
+                                             len(req.prompt_ids) - cached)
             if req.trace_ctx is not None:
                 # The request's wait, on its real stamps: arrival to
                 # the first chunk's dispatch.
@@ -1435,145 +927,10 @@ class InferenceEngine:
                 req.first_put_t = time.perf_counter()
                 req.stream_queue.put(("token", first))
             if req.handoff:
-                self._finish_handoff(req)
+                self.handoff.finish(req)
                 return
             self.scheduler.activate(req)
             self._maybe_finish(req, first)
-
-    def _finish_handoff(self, req: EngineRequest) -> None:
-        """Prefill role: resolve the request with a KV handoff payload
-        (or a completed result when the first token already ends it)
-        and recycle the slot — seeding the prefill-side prefix cache
-        with the full prompt, so repeat-prefix traffic keeps its reuse
-        win on the prefill pool."""
-        slot = req.slot
-        plen = len(req.prompt_ids)
-        first = req.generated[-1]
-        done = (len(req.generated) >= req.max_new_tokens
-                or (req.eos_id is not None and first == req.eos_id)
-                or plen + 1 >= self.max_len)
-        result: Dict[str, Any]
-        if done:
-            result = {"token_ids": list(req.generated),
-                      "num_generated": len(req.generated),
-                      "cached_prefix_len": req.cached_len}
-        else:
-            P = self.kv.block_size
-            # Shared export path (export_pages): one program per page,
-            # ONE host sync for the batch, tagged kv_export so the
-            # RTPU_DEBUG_JAX witness attributes it separately from the
-            # counted prefill sync.
-            pages_k, pages_v, crcs = self.export_pages(
-                slot, [p * P for p in range(-(-plen // P))],
-                tag="kv_export")
-            result = {
-                "kv_handoff": True,
-                "prompt_ids": list(req.prompt_ids),
-                "first_token": int(first),
-                "max_new_tokens": req.max_new_tokens,
-                "eos_id": req.eos_id,
-                "page": P,
-                "rows": plen,
-                "pages_k": pages_k,
-                "pages_v": pages_v,
-                # Content integrity: the chain hashes cover TOKEN
-                # identity (both sides derive them from prompt_ids);
-                # these cover the page BYTES, so a transport/export bug
-                # that mangles KV data fails the install instead of
-                # decoding garbage.
-                "page_crc": crcs,
-                "chain": list(self.kv.slot_chain(slot)),
-                "cached_prefix_len": req.cached_len,
-            }
-            if req.tenant or req.priority:
-                # QoS attribution survives the handoff: the decode-role
-                # engine schedules the installed request in the same
-                # class the prefill side admitted it in.
-                result["tenant"] = req.tenant
-                result["priority"] = req.priority
-        self.kv.release(slot, resident_tokens=req.prompt_ids)
-        req.slot = -1
-        if not req.future.done():
-            req.future.set_result(result)
-        if req.stream_queue is not None and done:
-            req.stream_queue.put(("done", None))
-        if req.trace_ctx is not None:
-            _tracing.flush()
-
-    def _install_tick(self) -> None:
-        """Decode role: install queued KV handoffs into free slots,
-        FIFO. A job that races slot exhaustion waits (installs never
-        jump the line — later handoffs can't acquire either)."""
-        while True:
-            try:
-                self._install_waiting.append(
-                    self._install_queue.get_nowait())
-            except queue.Empty:
-                break
-        pending = self._install_waiting
-        self._install_waiting = []
-        for i, (req, payload) in enumerate(pending):
-            if not self.kv.free_slots():
-                self._install_waiting.extend(pending[i:])
-                return
-            try:
-                self._install_one(req, payload)
-            except BaseException as e:  # noqa: BLE001 — one bad handoff
-                # must not kill the engine thread
-                self._recover_cache(e)
-                self._deliver_error([req], e)
-
-    def _install_one(self, req: EngineRequest,
-                     payload: Dict[str, Any]) -> None:
-        # fit vetoes every reuse depth: the handoff's pages OVERWRITE
-        # the slot's rows wholesale, so counting a resident-prefix
-        # "hit" here would pollute the prefix-cache stats with reuse
-        # that never happens.
-        self.kv.current_request = req
-        try:
-            got = self.kv.acquire(req.prompt_ids, fit=lambda c: False)
-        finally:
-            self.kv.current_request = None
-        if got is None:
-            raise RuntimeError("no free slot for KV install")
-        slot, _cached = got
-        P = int(payload["page"])
-        try:
-            crcs = payload.get("page_crc")
-            for i, (kp, vp) in enumerate(zip(payload["pages_k"],
-                                             payload["pages_v"])):
-                if crcs is not None:
-                    import zlib
-
-                    got_crc = (zlib.crc32(np.ascontiguousarray(kp)
-                                          .tobytes())
-                               ^ zlib.crc32(np.ascontiguousarray(vp)
-                                            .tobytes()))
-                    if got_crc != crcs[i]:
-                        raise RuntimeError(
-                            f"KV page {i} checksum mismatch: the page "
-                            "bytes were corrupted in transit")
-                self.cache = self.loop.install_page(
-                    self.cache, self._put(kp), self._put(vp),
-                    self._put(np.int32(slot)),
-                    self._put(np.int32(i * P)))
-            self.kv.commit_prefill(slot, req.prompt_ids)
-            # Chain equality covers TOKEN/protocol identity (same
-            # prompt, same block algorithm/size); the per-page CRCs
-            # above cover the KV BYTES themselves.
-            chain = list(self.kv.slot_chain(slot))
-            want = payload.get("chain")
-            if want is not None and chain != list(want):
-                raise RuntimeError(
-                    "KV chain mismatch after install: the decode side's "
-                    "block hashes disagree with the prefill side's")
-        except BaseException:
-            self.kv.release(slot, resident_tokens=())
-            raise
-        req.slot = slot
-        req.first_token_t = time.perf_counter()
-        self.scheduler.activate(req)
-        self._maybe_finish(req, req.generated[-1])
 
     def _maybe_finish(self, req: EngineRequest, last_tok: int) -> bool:
         done = self.scheduler.is_finished(req, last_tok)
@@ -1702,11 +1059,11 @@ class InferenceEngine:
         tokens, which an in-flight chunk would lag by one dispatch
         (``landing`` is empty off that schedule: ``_prefill_tick``).
         """
-        if self.drafter is not None:
+        if self.speculation is not None:
             with self._tick.phase("decode_dispatch", drafting=True):
-                drafts = self._draft_for_roster()
+                drafts = self.speculation.drafts()
             if drafts:
-                self._spec_tick(drafts)
+                self.speculation.tick(drafts)
                 return
         if self._pipelined:
             self._pipelined_tick(landing)
@@ -1720,6 +1077,14 @@ class InferenceEngine:
         rec = self._dispatch_chunk()
         if rec is not None:
             self._retire_chunk(rec)
+
+    def _land_inflight(self) -> bool:
+        """Land the decode chunk in flight, if one is, NOW: a mechanism
+        that recycles a slot outside the retire (preemption) calls this
+        first, so the chunk's tokens reach whoever it was dispatched with
+        while they hold their slots. False on device failure."""
+        prev, self._inflight = self._inflight, None
+        return prev is None or self._retire_chunk(prev)
 
     def _pipelined_tick(self, landing: List[_PrefillJob]) -> None:
         """Multi-step schedule: enqueue chunk N+1 BEFORE fetching chunk
@@ -1757,7 +1122,7 @@ class InferenceEngine:
         """An arrival would be admitted the moment it is heard: nobody
         waits ahead of it (FIFO holds) and a slot is free. The decode
         role's arrivals come by another queue."""
-        return (self.kv.free_slots() > 0 and not self._parked
+        return (self.kv.free_slots() > 0 and not self.preemption.parked
                 and not self.scheduler.queue_depth()
                 and self.role != "decode")
 
@@ -2013,162 +1378,10 @@ class InferenceEngine:
             _flight.record("engine_tick", tok=delivered, act=len(holders))
         return True
 
-    # -------------------------------------------------------- speculation
-
-    def _draft_for_roster(self) -> Dict[int, List[int]]:
-        """Prompt-lookup proposals for this tick, keyed by slot.
-        Empty dict = nothing to verify (dispatch the plain program)."""
-        # A fully accepted window advances W = K+1 positions (K drafts
-        # + the model's bonus token), so a continuation long enough to
-        # keep all spec_chunk windows fed spans C*W - 1 positions (the
-        # final window needs no bonus prediction).
-        cap = self.loop.spec_chunk * (self.spec_draft_len + 1) - 1
-        out: Dict[int, List[int]] = {}
-        for req in self.scheduler.active:
-            # Drafting past the request's own stopping point is pure
-            # waste: at most remaining-1 drafts can be emitted (the last
-            # budgeted token is always the model's own), and the row cap
-            # freezes the slot at max_len-1 rows.
-            need = min(req.spec.budget(), cap, req.remaining() - 1,
-                       self.max_len - req.length - 2)
-            if need <= 0:
-                continue
-            cont = self.drafter.draft(req.prompt_ids + req.generated,
-                                      need)
-            if cont:
-                out[req.slot] = cont
-            else:
-                req.spec.miss()
-        return out
-
-    def _spec_tick(self, drafts: Dict[int, List[int]]) -> None:
-        """One speculative verify chunk: K-token draft windows verified
-        on device, accepted prefixes committed, rejected rows rolled
-        back — still ONE host fetch."""
-        active = self.scheduler.active
-        C, K = self.loop.spec_chunk, self.spec_draft_len
-        W = K + 1
-        try:
-            with self._tick.phase("decode_dispatch", slots=len(active),
-                                  spec=True):
-                t0 = self._tick.now
-                tokens, lengths, remaining, eos_ids, done = \
-                    self._roster_arrays(active)
-                draft_buf = np.zeros((self.max_batch, C, K), np.int32)
-                ndraft = np.zeros((self.max_batch,), np.int32)
-                for slot, cont in drafts.items():
-                    # Window rows are packed at stride W = K+1, not K:
-                    # the only path to row i is i FULLY accepted
-                    # windows, and each full window advances K+1
-                    # positions (K drafts + the model's bonus token).
-                    # The continuation's prediction for a bonus position
-                    # is skipped — the bonus comes from the model's own
-                    # argmax, so drafting it would desynchronize every
-                    # later row by one position per window (systematic
-                    # row-1+ rejection on any repetition with period
-                    # > 1).
-                    packed = 0
-                    for i in range(C):
-                        row = cont[i * (K + 1):i * (K + 1) + K]
-                        if not row:
-                            break
-                        draft_buf[slot, i, :len(row)] = row
-                        packed += len(row)
-                    ndraft[slot] = packed
-                for req in active:
-                    self.kv.begin_speculation(
-                        req.slot, min(C * W, self.max_len - req.length))
-                emits_d, counts_d, _len_d, _done_d, self.cache = \
-                    self.loop.verify_chunk(
-                        self.params, self.cache, self._put(tokens),
-                        self._put(draft_buf), self._put(ndraft),
-                        self._put(lengths), self._put(remaining),
-                        self._put(eos_ids), self._put(done))
-                program = self._devq.put("chunk", t0, counts_d.is_ready,
-                                         slots=len(active), spec=True)
-            with self._tick.phase("decode_fetch",
-                                  slots=len(active)) as attrs:
-                # device_get returns host ndarrays: [B,C,W] + [B,C].
-                emits, counts = self._fetch((emits_d, counts_d))
-                attrs["bytes"] = emits.nbytes + counts.nbytes
-        except BaseException as e:  # noqa: BLE001 — fail all waiters
-            self._fail_roster(e)
-            return
-        now = self._tick.now
-        self._devq.seen(program, now)
-        live_steps = len(active) * C * W  # token-positions scanned
-        delivered = 0
-        accepted_total = 0
-        with self._tick.phase("decode_deliver", slots=len(active),
-                              spec=True) as attrs:
-            for req in list(active):
-                s = req.slot
-                n = int(counts[s].sum())
-                # Commit the verified rows, roll back the reservation
-                # for the rejected remainder BEFORE delivery:
-                # _maybe_finish may release the slot, and a released
-                # slot must carry no in-flight reservation into the
-                # free pool.
-                self.kv.commit_speculation(s, n)
-                delivered += n
-                req_accepted = int(np.maximum(counts[s] - 1, 0).sum())
-                accepted_total += req_accepted
-                if req.trace_ctx is not None and n:
-                    self._span("engine.decode_chunk", t0, now, req,
-                               {"tokens": n, "slot": s, "spec": True,
-                                "spec_accepted": req_accepted,
-                                "drafted": int(ndraft[s])})
-                finished = False
-                for i in range(C):
-                    for j in range(int(counts[s, i])):
-                        tok = int(emits[s, i, j])
-                        req.length += 1
-                        req.generated.append(tok)
-                        if req.stream_queue is not None:
-                            req.stream_queue.put(("token", tok))
-                        if self._maybe_finish(req, tok):
-                            finished = True
-                            break
-                    if finished:
-                        break
-                if (self.spec_adaptive and not finished
-                        and s in drafts):
-                    consumed, acc = self._spec_outcome(
-                        counts[s], int(ndraft[s]), K, W)
-                    if consumed:
-                        req.spec.observe(consumed, acc)
-            attrs["tokens"] = delivered
-            self.metrics.record_chunk(delivered, live_steps, now - t0)
-            self.metrics.record_spec(int(ndraft.sum()), accepted_total)
-            _flight.record("engine_tick", tok=delivered, act=len(active),
-                           spec=True)
-
-    @staticmethod
-    def _spec_outcome(counts_row, drafted: int, K: int, W: int):
-        """(verified, accepted) draft tokens for one non-finished slot's
-        chunk — the adaptive controller's signal. Only drafts the device
-        actually checked count as verified: a request that finished
-        mid-chunk never reaches here (its unchecked tail is neither
-        accepted nor rejected), and windows after a divergence run
-        draft-free, consuming nothing."""
-        consumed = accepted = 0
-        nd_rem = drafted
-        for m in (int(x) for x in counts_row):
-            if m == 0:
-                break
-            k_i = min(nd_rem, K)
-            if m == W:  # full window: all K drafts accepted
-                consumed += k_i
-                accepted += k_i
-                nd_rem -= k_i
-            else:
-                consumed += k_i
-                accepted += m - 1
-                nd_rem = 0
-        return consumed, accepted
-
     def _engine_loop(self) -> None:
         tick = self._tick
+        # The decode role's arrivals come by the handoff's own queue.
+        installs = self.handoff if self.role == "decode" else None
         while not self._shutdown:
             tick.lap()
             # tick_guard is a null context unless RTPU_DEBUG_JAX=1 and
@@ -2180,16 +1393,17 @@ class InferenceEngine:
                 with tick.phase("admit") as attrs:
                     self._admit()
                     attrs["prefilling"] = len(self._prefilling)
-                if self.role == "decode":
+                if installs is not None:
                     with tick.phase("install"):
-                        self._install_tick()
+                        installs.tick()
                 landing = self._prefill_tick()
             self.metrics.record_depths(self.scheduler.queue_depth(),
                                        len(self.scheduler.active),
                                        self.kv.hit_rate())
             if (not self.scheduler.active and not landing
                     and not self.scheduler.ending):
-                if self._prefilling or self._install_waiting:
+                if self._prefilling or (installs is not None
+                                        and installs.waiting):
                     continue  # keep chunked prefills / installs advancing
                 # A burst just drained: the multi-step trailing chunk
                 # (dispatched while every member was already frozen on
@@ -2197,8 +1411,8 @@ class InferenceEngine:
                 # unfetched. Its cache output already landed at
                 # dispatch time.
                 self._inflight = None
-                if (self.role == "decode"
-                        and not self._install_queue.empty()):
+                if (installs is not None
+                        and not installs.arrivals.empty()):
                     continue  # a handoff just arrived: install it now
                 try:
                     # Straight into the waiting line (re-putting to the

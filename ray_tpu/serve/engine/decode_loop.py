@@ -85,6 +85,37 @@ def serving_params(cfg, params):
     return params if relayout is None else relayout(params, cfg)
 
 
+# What each optional mechanism needs of a family, said ONCE: a family's
+# module lists the options it offers in ``ENGINE_OFFERS``
+# (`models/llama.py` alone does) and writes no reason of its own.
+_ROWS_ALONE = (
+    "verify_chunk, export_page / install_page and kv_fleet.pack_page are "
+    "written for a {k, v} cache of rows with no per-slot state beside "
+    "them (llama's)")
+ENGINE_OPTIONS = {
+    "quantize": "models/quant.py quantizes llama's weight tree only",
+    "spec_draft_len": _ROWS_ALONE, "role": _ROWS_ALONE,
+    "kv_fleet": _ROWS_ALONE}
+
+
+def check_offers(model, **asked: bool) -> None:
+    """``asked``: engine option -> whether this engine was asked for it.
+    An option its family does not offer is a ``ValueError`` that names
+    both and says what the mechanism needs; so is a name in the
+    family's ``ENGINE_OFFERS`` that is no option of the engine."""
+    offers = getattr(model, "ENGINE_OFFERS", ())
+    stale = [option for option in offers if option not in ENGINE_OPTIONS]
+    if stale:
+        raise ValueError(
+            f"{model.__name__}.ENGINE_OFFERS names {stale[0]!r}, which is no "
+            f"option of this engine (it knows {sorted(ENGINE_OPTIONS)})")
+    for option, on in asked.items():
+        if on and option not in offers:
+            raise ValueError(
+                f"{model.__name__} cannot serve with {option} yet: "
+                f"{ENGINE_OPTIONS[option]}")
+
+
 class DecodeLoop:
     """Compiled prefill + chunked-decode programs for one model/cache.
 

@@ -14,9 +14,10 @@ loops greedy decode itself falls into. The scan is bounded
 the same controller as rejections, so non-repetitive contexts stop
 paying even the lookup after a few ticks.
 
-Everything here is host-side and jax-free (unit-testable without a
-model): the device-side verification of these drafts lives in
-``decode_loop.DecodeLoop.verify_chunk``.
+The drafter and the controller are host-side and jax-free
+(unit-testable without a model); the device-side verification of these
+drafts lives in ``decode_loop.DecodeLoop.verify_chunk``, and
+``Speculation`` below is the engine's tick around it.
 
 Adaptive draft length: drafting is speculative WORK — every drafted
 token widens the verify window the device must compute. ``SpecControl``
@@ -33,7 +34,11 @@ become repetitive since.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Sequence
+from typing import Dict, List, Sequence
+
+import numpy as np
+
+from ray_tpu.util import flight_recorder as _flight
 
 
 class PromptLookupDrafter:
@@ -168,3 +173,184 @@ class SpecControl:
                 self._cooldown = self.probe_interval
         else:
             self._bad_streak = 0
+
+
+class Speculation:
+    """The speculative tick of an engine built with ``spec_draft_len``
+    > 0 (``engine.speculation``; None otherwise: no verify program, no
+    cache padding): ``drafts`` proposes, ``tick`` verifies them on the
+    device and commits exactly the accepted prefix (engine/README.md
+    "Speculative decoding"). A verify chunk is a tick phase and an
+    entry of the device's queue like any other chunk: beside the
+    engine's device surface this uses its ``_tick`` and ``_devq``."""
+
+    def __init__(self, engine, ngram_max: int, adaptive: bool):
+        self.engine = engine
+        self.draft_len = engine.spec_draft_len
+        self.adaptive = bool(adaptive)
+        self.drafter = PromptLookupDrafter(ngram_max=ngram_max)
+
+    def _capacity(self) -> int:
+        # A fully accepted window advances W = K+1 positions (K drafts
+        # + the model's bonus token), so a continuation long enough to
+        # keep all spec_chunk windows fed spans C*W - 1 positions (the
+        # final window needs no bonus prediction).
+        return self.engine.loop.spec_chunk * (self.draft_len + 1) - 1
+
+    def control(self) -> SpecControl:
+        """A new request's controller: the draft buffer's capacity at
+        full acceptance is its ceiling (``drafts`` packs rows at that
+        stride)."""
+        return SpecControl(
+            allowance=self.draft_len,
+            max_allowance=(self._capacity() if self.adaptive
+                           else self.draft_len))
+
+    def drafts(self) -> Dict[int, List[int]]:
+        """Prompt-lookup proposals for this tick, keyed by slot.
+        Empty dict = nothing to verify (dispatch the plain program)."""
+        eng, cap = self.engine, self._capacity()
+        out: Dict[int, List[int]] = {}
+        for req in eng.scheduler.active:
+            # Drafting past the request's own stopping point is pure
+            # waste: at most remaining-1 drafts can be emitted (the last
+            # budgeted token is always the model's own), and the row cap
+            # freezes the slot at max_len-1 rows.
+            need = min(req.spec.budget(), cap, req.remaining() - 1,
+                       eng.max_len - req.length - 2)
+            if need <= 0:
+                continue
+            cont = self.drafter.draft(req.prompt_ids + req.generated,
+                                      need)
+            if cont:
+                out[req.slot] = cont
+            else:
+                req.spec.miss()
+        return out
+
+    def tick(self, drafts: Dict[int, List[int]]) -> None:
+        """One speculative verify chunk: K-token draft windows verified
+        on device, accepted prefixes committed, rejected rows rolled
+        back — still ONE host fetch."""
+        eng = self.engine
+        active = eng.scheduler.active
+        C, K = eng.loop.spec_chunk, self.draft_len
+        W = K + 1
+        try:
+            with eng._tick.phase("decode_dispatch", slots=len(active),
+                                 spec=True):
+                t0 = eng._tick.now
+                tokens, lengths, remaining, eos_ids, done = \
+                    eng._roster_arrays(active)
+                draft_buf = np.zeros((eng.max_batch, C, K), np.int32)
+                ndraft = np.zeros((eng.max_batch,), np.int32)
+                for slot, cont in drafts.items():
+                    # Window rows are packed at stride W = K+1, not K:
+                    # the only path to row i is i FULLY accepted
+                    # windows, and each full window advances K+1
+                    # positions (K drafts + the model's bonus token).
+                    # The continuation's prediction for a bonus position
+                    # is skipped — the bonus comes from the model's own
+                    # argmax, so drafting it would desynchronize every
+                    # later row by one position per window (systematic
+                    # row-1+ rejection on any repetition with period
+                    # > 1).
+                    packed = 0
+                    for i in range(C):
+                        row = cont[i * (K + 1):i * (K + 1) + K]
+                        if not row:
+                            break
+                        draft_buf[slot, i, :len(row)] = row
+                        packed += len(row)
+                    ndraft[slot] = packed
+                for req in active:
+                    eng.kv.begin_speculation(
+                        req.slot, min(C * W, eng.max_len - req.length))
+                emits_d, counts_d, _len_d, _done_d, eng.cache = \
+                    eng.loop.verify_chunk(
+                        eng.params, eng.cache, eng._put(tokens),
+                        eng._put(draft_buf), eng._put(ndraft),
+                        eng._put(lengths), eng._put(remaining),
+                        eng._put(eos_ids), eng._put(done))
+                program = eng._devq.put("chunk", t0, counts_d.is_ready,
+                                        slots=len(active), spec=True)
+            with eng._tick.phase("decode_fetch",
+                                 slots=len(active)) as attrs:
+                # device_get returns host ndarrays: [B,C,W] + [B,C].
+                emits, counts = eng._fetch((emits_d, counts_d))
+                attrs["bytes"] = emits.nbytes + counts.nbytes
+        except BaseException as e:  # noqa: BLE001 — fail all waiters
+            eng._fail_roster(e)
+            return
+        now = eng._tick.now
+        eng._devq.seen(program, now)
+        live_steps = len(active) * C * W  # token-positions scanned
+        delivered = 0
+        accepted_total = 0
+        with eng._tick.phase("decode_deliver", slots=len(active),
+                             spec=True) as attrs:
+            for req in list(active):
+                s = req.slot
+                n = int(counts[s].sum())
+                # Commit the verified rows, roll back the reservation
+                # for the rejected remainder BEFORE delivery:
+                # _maybe_finish may release the slot, and a released
+                # slot must carry no in-flight reservation into the
+                # free pool.
+                eng.kv.commit_speculation(s, n)
+                delivered += n
+                req_accepted = int(np.maximum(counts[s] - 1, 0).sum())
+                accepted_total += req_accepted
+                if req.trace_ctx is not None and n:
+                    eng._span("engine.decode_chunk", t0, now, req,
+                              {"tokens": n, "slot": s, "spec": True,
+                               "spec_accepted": req_accepted,
+                               "drafted": int(ndraft[s])})
+                finished = False
+                for i in range(C):
+                    for j in range(int(counts[s, i])):
+                        tok = int(emits[s, i, j])
+                        req.length += 1
+                        req.generated.append(tok)
+                        if req.stream_queue is not None:
+                            req.stream_queue.put(("token", tok))
+                        if eng._maybe_finish(req, tok):
+                            finished = True
+                            break
+                    if finished:
+                        break
+                if (self.adaptive and not finished
+                        and s in drafts):
+                    consumed, acc = self._outcome(
+                        counts[s], int(ndraft[s]), K, W)
+                    if consumed:
+                        req.spec.observe(consumed, acc)
+            attrs["tokens"] = delivered
+            eng.metrics.record_chunk(delivered, live_steps, now - t0)
+            eng.metrics.record_spec(int(ndraft.sum()), accepted_total)
+            _flight.record("engine_tick", tok=delivered, act=len(active),
+                           spec=True)
+
+    @staticmethod
+    def _outcome(counts_row, drafted: int, K: int, W: int):
+        """(verified, accepted) draft tokens for one non-finished slot's
+        chunk — the adaptive controller's signal. Only drafts the device
+        actually checked count as verified: a request that finished
+        mid-chunk never reaches here (its unchecked tail is neither
+        accepted nor rejected), and windows after a divergence run
+        draft-free, consuming nothing."""
+        consumed = accepted = 0
+        nd_rem = drafted
+        for m in (int(x) for x in counts_row):
+            if m == 0:
+                break
+            k_i = min(nd_rem, K)
+            if m == W:  # full window: all K drafts accepted
+                consumed += k_i
+                accepted += k_i
+                nd_rem -= k_i
+            else:
+                consumed += k_i
+                accepted += m - 1
+                nd_rem = 0
+        return consumed, accepted

@@ -69,7 +69,7 @@ class EngineRequest:
     # Per-tenant QoS: tenant attribution (stats only at engine tier)
     # and the strict priority class — admission serves higher classes
     # first, and a starved higher-priority arrival may PREEMPT a
-    # lower-priority active request (core._preempt_tick parks it; its
+    # lower-priority active request (preempt.Preemption.park parks it; its
     # KV rows stay prefix-resident and it resumes as a continuation).
     tenant: str = ""
     priority: int = 0

@@ -5,11 +5,16 @@ donation-then-read), and the per-family baseline mechanics.
 
 from __future__ import annotations
 
+import ast
+import importlib.util
 import json
 import os
 import subprocess
 import sys
 
+import pytest
+
+from ray_tpu.devtools import invariants as inv
 from ray_tpu.devtools import lint
 from ray_tpu.devtools.jaxlint import lint_source
 
@@ -208,6 +213,46 @@ def test_intended_sync_allow_comment_honored():
         "        jax.device_get(x)  "
         "# rtpu-lint: disable=host-sync-in-hot-path\n")
     assert lint_source(src, CORE, "core.py") == []
+
+
+# The engine's four mechanisms live in modules of their own: module ->
+# the methods the tick calls them by (engine/README.md's call sites).
+MECHANISMS = {
+    "ray_tpu.serve.engine.drafter": {"drafts", "tick"},
+    "ray_tpu.serve.engine.kv_fleet": {"extend", "spill_evicted",
+                                      "note_prefill_cost"},
+    "ray_tpu.serve.engine.handoff": {"finish", "tick"},
+    "ray_tpu.serve.engine.preempt": {"park", "resume"},
+}
+
+
+@pytest.mark.parametrize("module", MECHANISMS)
+def test_a_mechanisms_tick_entries_are_hot_path_roots(module):
+    """Code that left `core.py` did not leave the host-sync rule: each
+    entry the tick calls is a declared root that the real module
+    defines, and a planted ``np.asarray`` on a device value there, or
+    in a helper it calls, is a finding."""
+    assert inv.JAX_HOT_PATH_ROOTS[module] == MECHANISMS[module]
+    assert module in inv.RES_REGISTRY_MODULES
+    real = ast.parse(open(importlib.util.find_spec(module).origin).read())
+    defined = {n.name for n in ast.walk(real)
+               if isinstance(n, ast.FunctionDef)}
+    assert MECHANISMS[module] <= defined
+    for entry in sorted(MECHANISMS[module]):
+        src = (
+            "import numpy as np\n"
+            "class Mechanism:\n"
+            f"    def {entry}(self):\n"
+            "        return self._helper()\n"
+            "    def _helper(self):\n"
+            "        eng = self.engine\n"
+            "        toks, eng.cache = eng.loop.decode_chunk(eng.params)\n"
+            "        return np.asarray(toks)\n"
+            "    def offline(self):\n"
+            "        return np.asarray(self.engine.loop.decode_chunk(1))\n")
+        fs = lint_source(src, module, "mechanism.py")
+        assert [(f.rule, f.scope) for f in fs] == [
+            ("host-sync-in-hot-path", "_helper")], (entry, fs)
 
 
 # ---------------------------------------- unclamped-dynamic-update-slice

@@ -702,34 +702,37 @@ OPTIONS = {"quantize": dict(quantize="int8"),
 @pytest.mark.parametrize("option", OPTIONS)
 @pytest.mark.parametrize("family", FAMILIES)
 def test_a_family_refuses_what_its_cache_cannot_serve(family, option):
-    """Every option in the family's `ENGINE_REFUSES` raises at
-    construction, by name and with the family's reason, before any
-    weight is made; without it the engine comes up."""
+    """A family that does not offer an option (none but llama offers
+    any: the seam's default) is refused it at construction, by name and
+    with the MECHANISM's one sentence on what it needs of a family,
+    before any weight is made; without it the engine comes up."""
     from ray_tpu.serve.engine.core import InferenceEngine
+    from ray_tpu.serve.engine.decode_loop import ENGINE_OPTIONS
 
     cfg = FAMILIES[family]()
-    assert option in cfg.model.ENGINE_REFUSES
+    assert option not in getattr(cfg.model, "ENGINE_OFFERS", ())
     kwargs = dict(max_batch=2, max_len=64, prompt_buckets=[8, 16],
                   kv_fleet_min_prefix_blocks=-1)
     with pytest.raises(ValueError,
                        match=f"cannot serve with {option} yet") as refused:
         InferenceEngine(cfg, **{**kwargs, **OPTIONS[option]})
-    assert cfg.model.ENGINE_REFUSES[option] in str(refused.value)
+    assert cfg.model.__name__ in str(refused.value)
+    assert ENGINE_OPTIONS[option] in str(refused.value)
 
 
-def test_a_refused_option_the_engine_does_not_know_is_named(monkeypatch):
-    """A keyword that leaves the engine must take its `ENGINE_REFUSES`
-    lines with it: a stale key is an error that names the module and
-    the key, not a bare KeyError (or, worse, silence)."""
-    from ray_tpu.models import olmo_hybrid
+def test_an_offered_option_the_engine_does_not_know_is_named(monkeypatch):
+    """A keyword that leaves the engine must take its name out of every
+    family's `ENGINE_OFFERS` with it: a stale name is an error that
+    names the module and the name, not silence."""
+    from ray_tpu.models import llama
     from ray_tpu.serve.engine.core import InferenceEngine
 
-    monkeypatch.setitem(olmo_hybrid.ENGINE_REFUSES, "an_option_that_left",
-                        "its reason")
-    with pytest.raises(ValueError, match=r"olmo_hybrid\.ENGINE_REFUSES "
+    monkeypatch.setattr(llama, "ENGINE_OFFERS",
+                        (*llama.ENGINE_OFFERS, "an_option_that_left"))
+    with pytest.raises(ValueError, match=r"llama\.ENGINE_OFFERS "
                                          r"names 'an_option_that_left'"):
-        InferenceEngine(_olmo_hybrid(), max_batch=2, max_len=64,
-                        prompt_buckets=[8, 16],
+        InferenceEngine(llama.tiny_config(max_seq_len=64), max_batch=2,
+                        max_len=64, prompt_buckets=[8, 16],
                         kv_fleet_min_prefix_blocks=-1)
 
 

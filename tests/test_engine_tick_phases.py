@@ -381,7 +381,15 @@ def test_a_split_prefill_is_its_wait_less_the_fetch_s_last_lines(engine):
     landing that holds it (for the second of a pair, from the FIRST's
     landing on: one program, one end), which the test times around the
     call: a bound a loaded machine keeps, where a number of
-    milliseconds is the scheduler's to break."""
+    milliseconds is the scheduler's to break.
+
+    Not every admission is split. The second stream arrives while the
+    first decodes beside a free slot: where the chunk in flight outlasts
+    the host's work (six loaded workers) the tick HEARS it in its
+    listening wait, and the dispatch that follows may find that prefill
+    gone from the device already and close it with its end unseen
+    (`DeviceQueue.put`, ``poll``). Such an admission is counted as
+    heard and not as split; every other one is split."""
     _every_fetch_waits(engine)
     waits, splits, landings, entered = [], [], [], {}
     admit, split, land = engine.metrics.record_admit, \
@@ -393,8 +401,10 @@ def test_a_split_prefill_is_its_wait_less_the_fetch_s_last_lines(engine):
 
     def timed_landing(job):
         t = entered.setdefault(id(job.programs[-1]), time.perf_counter())
+        had = len(splits)
         land(job)
-        landings.append(time.perf_counter() - t)
+        # (the landing's seconds, its split or None)
+        landings.append((time.perf_counter() - t, (splits[had:] or [None])[0]))
 
     engine._land_prefill = timed_landing
     streams = _stream_pair(engine)          # two behind a chunk or two
@@ -403,13 +413,17 @@ def test_a_split_prefill_is_its_wait_less_the_fetch_s_last_lines(engine):
     for stream in streams:
         stream.close()
     s = engine.stats()
-    assert s["requests"] == len(waits) == 5 == len(splits) == len(landings)
-    assert s["prefill_split"] == 5
+    assert s["requests"] == len(waits) == 5 == len(landings)
+    assert s["prefill_split"] == len(splits) <= 5
+    assert 5 - len(splits) <= s["admissions_heard"]
     assert s["prefill_behind_s"] == pytest.approx(sum(b for b, _ in splits))
     assert s["prefill_own_s"] == pytest.approx(sum(o for _, o in splits))
-    for wait, (behind, own), landing in zip(waits, splits, landings):
-        assert behind >= 0.0 and own > 0.0
-        assert 0.0 <= wait - (behind + own) <= landing
+    assert [found for _, found in landings if found] == splits
+    for wait, (landing, found) in zip(waits, landings):
+        if found is not None:
+            behind, own = found
+            assert behind >= 0.0 and own > 0.0
+            assert 0.0 <= wait - (behind + own) <= landing
     # An arrival beside a running stream queued behind its chunk.
     assert s["prefill_ahead_chunks"] >= 1 and s["prefill_ahead_prefills"] >= 0
 
@@ -512,7 +526,10 @@ def test_device_spans_are_disjoint_and_in_dispatch_order(engine, sink):
     assert [(s["name"], s["start"], s["end"]) for s in device] == [
         ("device." + p.kind, tracing.wall(p.start), tracing.wall(p.end))
         for p in split]
-    assert len(split) >= len(programs) - 2
+    # (an arrival HEARD in the listening wait may cost two: its prefill
+    # and the chunk ahead, closed unseen by the next dispatch's poll)
+    heard = engine.stats()["admissions_heard"]
+    assert len(split) >= len(programs) - 2 - 2 * heard
     for a, b in zip(device, device[1:]):
         assert a["end"] <= b["start"]
     for s in device:

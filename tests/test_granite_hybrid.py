@@ -438,7 +438,7 @@ def test_the_engine_refuses_what_the_state_cannot_serve(tiny):
     cfg, params = tiny
     from ray_tpu.serve.engine.core import InferenceEngine
 
-    with pytest.raises(ValueError, match="spec_draft_len"):
+    with pytest.raises(ValueError, match="granite_hybrid cannot serve "
+                                         "with spec_draft_len yet"):
         InferenceEngine(cfg=cfg, params=params, spec_draft_len=2, **ENGINE)
-    assert set(granite.ENGINE_REFUSES) == {"quantize", "spec_draft_len",
-                                           "role", "kv_fleet"}
+    assert not hasattr(granite, "ENGINE_OFFERS")

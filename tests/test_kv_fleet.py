@@ -245,7 +245,7 @@ def test_fleet_off_is_byte_identical_surface():
     colocated engine, no spill hook, no fleet snapshot/stats keys."""
     eng = _engine()
     try:
-        assert eng._fleet is None
+        assert eng.fleet is None
         assert eng.kv.spill_hook is None
         assert eng.loop.kv_page == 0
         assert "kv_fleet_hits" not in eng.stats()
@@ -315,7 +315,7 @@ def test_eviction_under_preemption_cross_replica_resume():
     eng_a = _engine(kv_fleet_min_prefix_blocks=0, kv_fleet_store=store)
     # The victim stays parked on A (the replica it must leave): resume
     # is disabled, so only the cross-replica continuation can finish it.
-    eng_a._resume_tick = lambda: None
+    eng_a.preemption.resume = lambda: None
     try:
         lo = eng_a._make_request(P1, 24, None, stream=True, priority=0)
         eng_a._queue.put(lo)
@@ -327,7 +327,7 @@ def test_eviction_under_preemption_cross_replica_resume():
                                  priority=5)
         eng_a._queue.put(hi)
         deadline = time.time() + 120
-        while not eng_a._parked:
+        while not eng_a.preemption.parked:
             assert time.time() < deadline, "lo never parked"
             time.sleep(0.001)
         hi.future.result(timeout=120)
@@ -336,8 +336,8 @@ def test_eviction_under_preemption_cross_replica_resume():
         # their complete blocks spill into the shared store.
         eng_a.generate(P2, max_new_tokens=8)
         _wait_objects(store, 4)  # the victim's 4 complete prompt blocks
-        assert eng_a._preempts >= 1
-        assert eng_a._parked and eng_a._parked[0] is lo
+        assert eng_a.preemption.preempts >= 1
+        assert eng_a.preemption.parked[0] is lo
         prefix = list(lo.prompt_ids) + list(lo.generated)
         remaining = lo.remaining()
         assert lo.generated and remaining > 0

@@ -178,7 +178,7 @@ def test_spec_off_path_identical(tiny_model, eng_plain):
     tokens, same host-sync cadence."""
     eng = make_engine(tiny_model, decode_chunk=4, spec_draft_len=0)
     try:
-        assert eng.drafter is None
+        assert eng.speculation is None
         assert eng.loop.scratch_rows == 0
         assert not hasattr(eng.loop, "verify_chunk")
         assert eng.cache["k"].shape == eng_plain.cache["k"].shape
@@ -289,7 +289,7 @@ def test_adaptive_shrinks_to_zero_under_adversarial_drafts(tiny_model):
             def draft(self, context, need):
                 return [bogus] * need
 
-        eng.drafter = BogusDrafter()
+        eng.speculation.drafter = BogusDrafter()
         base_spec = eng.metrics.spec_chunks
         base_syncs = eng.metrics.host_syncs
         out = eng.generate(prompt, max_new_tokens=30)
@@ -326,7 +326,7 @@ def test_oracle_drafts_sustain_full_windows(tiny_model):
                 g = len(context) - len(prompt)
                 return free[g:g + need]
 
-        eng.drafter = OracleDrafter()
+        eng.speculation.drafter = OracleDrafter()
         base_syncs = eng.metrics.host_syncs
         base_drafted = eng.metrics.spec_drafted
         base_accepted = eng.metrics.spec_accepted
@@ -366,14 +366,14 @@ def test_lookup_miss_backoff_stops_scanning(tiny_model):
     eng = make_engine(tiny_model, decode_chunk=4, spec_draft_len=4)
     try:
         calls = [0]
-        real = eng.drafter
+        real = eng.speculation.drafter
 
         class CountingMissDrafter:
             def draft(self, context, need):
                 calls[0] += 1
                 return []
 
-        eng.drafter = CountingMissDrafter()
+        eng.speculation.drafter = CountingMissDrafter()
         base = eng.metrics.host_syncs
         eng.generate([1, 2, 3], max_new_tokens=30)
         ticks = eng.metrics.host_syncs - base
@@ -381,7 +381,7 @@ def test_lookup_miss_backoff_stops_scanning(tiny_model):
         # Lookup ran only until the streak zeroed the allowance, plus
         # sparse probes — not every tick.
         assert calls[0] < ticks
-        eng.drafter = real
+        eng.speculation.drafter = real
     finally:
         eng.close()
 

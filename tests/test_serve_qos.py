@@ -232,7 +232,7 @@ def test_priority_preemption_park_resume_token_identity(tiny_model):
         out_lo = lo.future.result(timeout=120)
     finally:
         eng.close()
-    assert eng._preempts >= 1 and eng._resumes >= 1
+    assert eng.preemption.preempts >= 1 and eng.preemption.resumes >= 1
     assert out_hi["token_ids"] == ref_hi
     assert out_lo["token_ids"] == ref_lo
     assert out_lo.get("preempted", 0) >= 1
@@ -263,7 +263,7 @@ def test_preemption_parked_kv_witness_balanced(tiny_model, monkeypatch):
             lo.future.result(timeout=120)
         finally:
             eng.close()
-        assert eng._preempts >= 1 and eng._resumes >= 1
+        assert eng.preemption.preempts >= 1 and eng.preemption.resumes >= 1
         assert res_debug.outstanding("parked_kv") == {}
         bad = [v for v in res_debug.violations()
                if "parked_kv" in v.get("outstanding", {})]
@@ -300,5 +300,5 @@ def test_preemption_streams_survive_park_resume(tiny_model):
         hi.future.result(timeout=120)
     finally:
         eng.close()
-    assert eng._preempts >= 1
+    assert eng.preemption.preempts >= 1
     assert got == ref_lo
