@@ -62,7 +62,14 @@ would change. A prefill chunk's full layers attend under their mask in
 a third kernel (``rtpu_dsa_prefill_attention``, ``ops/dsa_prefill.py``:
 the slot's latent rows expanded a tile at a time, a block of scores in
 fast memory; PR 43); the selection that makes the mask is ``jnp``
-(`row_select.index_scores`, `select_rows`).
+(`row_select.index_scores`, `select_rows`). Its sliding layers read the
+window in a fourth (``rtpu_swa_prefill_attention``,
+``ops/swa_prefill.py``; PR 60): the chunk's rows and the ``window - 1``
+ring rows before them expanded once, a block of queries over its own
+rows and the reach before it with the mask made from positions inside
+the kernel, which also counts what each query read (``window_rows``,
+``window_first``). At a window or widths that are not whole tiles (the
+toy geometries) that module's ``jnp`` twin runs.
 
 What the engine's seam asks: `init_params`, `init_kv_cache`,
 `forward_with_cache`, `forward_last_with_cache` (the tick's prefill:
@@ -88,10 +95,10 @@ from ray_tpu.ops import apply_rope, mla_decode_attention, rms_norm
 from ray_tpu.ops import row_select
 from ray_tpu.ops.dsa_prefill import dsa_prefill_attention
 from ray_tpu.ops.grouped_experts import grouped_swiglu, split_expert_stacks
+from ray_tpu.ops.swa_prefill import NO_ROW, swa_prefill_attention
 
 Params = Dict[str, Any]
 F32 = jnp.float32
-NEG_INF = -1e30
 FULL, SLIDING = "full_attention", "sliding_attention"
 
 # Cache entries that hold per-slot contents of fixed size and no rows a
@@ -387,13 +394,15 @@ def _queries_and_row(h, layer, positions, g: LatentGeometry, cfg):
 
 
 def _expand(rows, layer, g: LatentGeometry):
-    """Cache rows [S,W] -> per-head keys [S,H,qk] and values [S,H,v]."""
+    """Cache rows [B,S,W] -> per-head keys [B,H,S,qk] and values
+    [B,H,S,v], head-major: a head's rows are whole (rows, lanes) tiles,
+    which is how `swa_prefill_attention` reads them."""
     c_kv = rows[..., :g.kv_lora_rank]
     k_rope = rows[..., g.kv_lora_rank:g.row_values]
-    k_nope = jnp.einsum("sr,rhk->shk", c_kv, layer["w_uk"])
-    v = jnp.einsum("sr,rhv->shv", c_kv, layer["w_uv"])
-    k_rope = jnp.broadcast_to(k_rope[:, None, :],
-                              k_nope.shape[:2] + (g.qk_rope_head_dim,))
+    k_nope = jnp.einsum("bsr,rhk->bhsk", c_kv, layer["w_uk"])
+    v = jnp.einsum("bsr,rhv->bhsv", c_kv, layer["w_uv"])
+    k_rope = jnp.broadcast_to(k_rope[:, None],
+                              k_nope.shape[:3] + (g.qk_rope_head_dim,))
     return jnp.concatenate([k_nope, k_rope], axis=-1), v
 
 
@@ -475,16 +484,13 @@ def _is_a_row(positions):
     return positions >= 0
 
 
-_SWA_QUERIES = 512      # queries a block of a sliding layer's prefill
-
-
 def _window_read(mask, positions):
     """The mask a sliding layer's attention ran under [.., rows] and the
     position each of those rows holds -> {"rows": how many rows the
     query attended to, "first": the lowest position among them}: what a
     check holds to the published window."""
     return {"rows": jnp.sum(mask, -1, dtype=jnp.int32),
-            "first": jnp.min(jnp.where(mask, positions, 2 ** 30), -1)}
+            "first": jnp.min(jnp.where(mask, positions, NO_ROW), -1)}
 
 
 def _ring_positions(t, ring: int):
@@ -498,53 +504,37 @@ def _sliding_prefill_block(x, layer, win_l, cache_index, positions, last,
                            cfg: Dots3NoteConfig):
     """x [B,T,d], win_l [B,R,W]: this layer's ring of the slot(s), row
     ``last`` the chunk's last real one -> (x after attention, win_l,
-    what each query read: `_window_read`). A query reads the rows of its chunk and, from the ring, the
-    ``window - 1`` rows before the chunk; the ring then takes the
-    chunk's last real rows."""
+    what each query read: `_window_read`'s two). A query reads the rows
+    of its chunk and, from the ring, the ``window - 1`` rows before the
+    chunk (`swa_prefill_attention`: one kernel at the published sizes,
+    which also counts what each query read from the mask it ran under;
+    a ring row that `_is_a_row` disowns is handed over as no row); the
+    ring then takes the chunk's last real rows."""
     g, ring, reach = cfg.sliding, cfg.ring_rows, cfg.window - 1
     b, t = x.shape[:2]
     h = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
     _, q_nope, q_rope, rows = _queries_and_row(h, layer, positions, g, cfg)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     before = cache_index - reach + jnp.arange(reach, dtype=jnp.int32)
-    # A block of `_SWA_QUERIES` queries reads its own rows and the
-    # ``reach`` before its first: the scores are [blocks, H, qb, reach
-    # + qb], not the chunk's square.
-    qb = _SWA_QUERIES if t % _SWA_QUERIES == 0 else t
-
-    def one(q, rows, win_s, pos):
-        old = jnp.take(win_s, jnp.mod(before, ring), axis=0)     # [reach,W]
-        k, v = _expand(jnp.concatenate([old.astype(rows.dtype), rows]),
-                       layer, g)                                 # [reach+T,..]
-        k_pos = jnp.concatenate([before, pos])
-        n = t // qb
-        span = reach + qb
-        starts = jnp.arange(n) * qb
-        take = starts[:, None] + jnp.arange(span)[None, :]       # [n,span]
-        k_b, v_b, kp_b = k[take], v[take], k_pos[take]
-        q_b, qp_b = q.reshape(n, qb, *q.shape[1:]), pos.reshape(n, qb)
-        logits = jnp.einsum("nthk,nshk->nhts", q_b, k_b,
-                            preferred_element_type=F32) * g.scale
-        mask = ((kp_b[:, None, :] <= qp_b[:, :, None])
-                & (kp_b[:, None, :] >= qp_b[:, :, None] - reach)
-                & _is_a_row(kp_b)[:, None, :])[:, None]          # [n,1,t,s]
-        logits = jnp.where(mask, logits, NEG_INF)
-        p = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("nhts,nshv->nthv", p.astype(v_b.dtype), v_b,
-                         preferred_element_type=F32)
-        read = _window_read(mask[:, 0], kp_b[:, None, :])        # [n,qb] x 2
-        # The ring after the chunk: row j holds the newest real
-        # position congruent to j, the chunk's where it has one.
-        end = cache_index + (t - 1 if last is None else last)
-        held = _ring_positions(jnp.asarray(end, jnp.int32), ring)
-        mine = held >= cache_index
-        new = jnp.take(rows, jnp.clip(held - cache_index, 0, t - 1), axis=0)
-        win_s = jnp.where(mine[:, None], new.astype(win_s.dtype), win_s)
-        return (out.reshape(t, *out.shape[2:]), win_s,
-                jax.tree.map(lambda a: a.reshape(t), read))
-
-    attn, win_l, read = jax.vmap(one)(q, rows, win_l, positions)
-    return _gate_and_out(x, h, attn, layer), win_l, read
+    old = jnp.take(win_l, jnp.mod(before, ring), axis=1)         # [B,reach,W]
+    # Expanded ONCE for the chunk: a block of queries reads two blocks
+    # of rows, so a kernel that expanded would expand every row twice.
+    k, v = _expand(jnp.concatenate([old.astype(rows.dtype), rows], 1),
+                   layer, g)                                 # [B,H,reach+T,..]
+    k_pos = jnp.concatenate([jnp.broadcast_to(before, (b, reach)),
+                             positions], 1)
+    attn, n_rows, first = swa_prefill_attention(
+        q, k, v, positions, jnp.where(_is_a_row(k_pos), k_pos, NO_ROW),
+        reach=reach, scale=g.scale, interpret=cfg.interpret_kernels)
+    # The ring after the chunk: row j holds the newest real position
+    # congruent to j, the chunk's where it has one.
+    end = cache_index + (t - 1 if last is None else last)
+    held = _ring_positions(jnp.asarray(end, jnp.int32), ring)
+    new = jnp.take(rows, jnp.clip(held - cache_index, 0, t - 1), axis=1)
+    win_l = jnp.where((held >= cache_index)[:, None], new.astype(win_l.dtype),
+                      win_l)
+    return (_gate_and_out(x, h, attn, layer), win_l,
+            {"rows": n_rows, "first": first})
 
 
 def _write_rows(cache, layer_idx, at, rows):

@@ -73,6 +73,16 @@ dsa_prefill                Pallas kernel of a CHUNK of      on TPU where the wid
                            heads, a block of scores kept    is `row_select`'s selection, so no row the
                            in fast memory, tiles past the   queries chose is dropped and none added
                            rows written not read
+swa_prefill                Pallas kernel of a CHUNK of      on TPU where the widths and the window's
+ .swa_prefill_attention    queries over a WINDOW: a block   reach are whole 128-tiles and the reach
+                           of queries over its own rows     divides the chunk, or ``interpret=True``
+                           and the reach before them (keys  off-TPU; jnp twin elsewhere (a block's
+                           and values expanded once,        scores and its gathered span through HBM).
+                           outside, head-major, given       Imported by its one caller
+                           twice a block apart), the mask   (``models/dots3_note.py``: the sliding
+                           made from positions and the      layers' prefill); also writes how many
+                           scores kept in fast memory       rows each query read and the lowest
+                                                            position among them, from that mask
 mhc.mhc_pre, .mhc_post     Pallas kernels of a four-stream    on TPU where the width is whole lanes, or
                            (mHC) residual over a block of     ``interpret=True`` off-TPU; jnp twins
                            ROWS: ``rtpu_mhc_pre`` (the norm,  elsewhere. Imported by its one caller

@@ -1076,6 +1076,37 @@ def test_dsa_prefill_attention_compiles_at_the_shorter_buckets(chip, queries):
         128 * 256 * 2 + DOTS3_ROWS + 128 * 128 * 4) * 1.1
 
 
+@pytest.mark.parametrize("queries", [512, 1024, 2048])
+def test_swa_prefill_attention_compiles_at_the_cells_buckets(chip, queries):
+    """The window layers' prefill attention at the published sizes and
+    the cell's three buckets: 64 heads of 192 + 64 and 128 columns over
+    the chunk's rows and the 512 before them, expanded head-major
+    outside: ONE kernel under its name (and not the full layers'), a
+    block's scores in fast memory and no [blocks, 64, 512, 1024] float32
+    array beside it, inside the fast memory it asks for."""
+    from ray_tpu.ops.swa_prefill import swa_prefill_attention
+
+    rows = 512 + queries
+    c = swa_prefill_attention.lower(
+        _sds(chip, (1, queries, 64, 256)), _sds(chip, (1, 64, rows, 256)),
+        _sds(chip, (1, 64, rows, 128)), _sds(chip, (1, queries), jnp.int32),
+        _sds(chip, (1, rows), jnp.int32), reach=512,
+        scale=256 ** -0.5).compile()
+    assert _kernel_calls(c) == 1
+    assert _names_kernel(c, "rtpu_swa_prefill_attention")
+    assert "rtpu_dsa_prefill_attention" not in c.as_text()
+    out = jax.eval_shape(
+        functools.partial(swa_prefill_attention, reach=512, scale=0.0625),
+        _sds(chip, (1, queries, 64, 256)), _sds(chip, (1, 64, rows, 256)),
+        _sds(chip, (1, 64, rows, 128)), _sds(chip, (1, queries), jnp.int32),
+        _sds(chip, (1, rows), jnp.int32))
+    assert [(o.shape, o.dtype) for o in out] == [
+        ((1, queries, 64, 128), jnp.float32), ((1, queries), jnp.int32),
+        ((1, queries), jnp.int32)]
+    # Nothing but the outputs' own reshapes: no scores, no gathered span.
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 def test_mla_decode_attention_compiles_at_the_window_layers_width(chip):
     """The latent kernel at its second geometry: 64 heads over rows of
     1,088 values padded to 1,152 (nine whole lane tiles), the ring of
@@ -1136,7 +1167,7 @@ def test_dots3_tick_prefill_chunk_fits_beside_the_cache(chip):
     ``cache_index``, each with its own 2,048 rows): the scoring's loop
     over the slot's rows, the masked attention as ONE kernel under its
     name (no [128, 2048, 512] float32 tile of scores among the
-    temporaries), one token and the counters out, the three entries
+    temporaries), the window layers' as another, one token and the counters out, the three entries
     aliased and none copied; its temporaries (the score array, the
     mask) leave the chip room."""
     from ray_tpu.serve.engine.decode_loop import DecodeLoop
@@ -1161,6 +1192,10 @@ def test_dots3_tick_prefill_chunk_fits_beside_the_cache(chip):
     assert _names_kernel(c, "rtpu_grouped_matmul")
     assert "%rtpu_dsa_prefill_attention." in text
     assert "f32[1,128,2048,128]" not in text and "f32[128,2048," not in text
+    # The window layers' attention is a kernel too (PR 60): no block's
+    # scores and no gathered span of keys among the arrays.
+    assert "%rtpu_swa_prefill_attention." in text
+    assert "f32[4,64,512,1024]" not in text and "[4,1024,64," not in text
     mem = c.memory_analysis()
     assert mem.alias_size_in_bytes >= held - 8_186_107_904
     assert mem.temp_size_in_bytes < 2 * GIB

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu import ops
-from ray_tpu.ops import dsa_prefill
+from ray_tpu.ops import dsa_prefill, swa_prefill
 
 
 def _pallas_eqns(jaxpr):
@@ -62,6 +62,14 @@ GLUE = {
         jax.ShapeDtypeStruct((128, 2, 128), F32),         # w_uk [r,H,nope]
         jax.ShapeDtypeStruct((128, 2, 128), F32),         # w_uv [r,H,v]
         jax.ShapeDtypeStruct((), jnp.int32)),             # rows_seen
+    swa_prefill.NAME: (
+        functools.partial(swa_prefill.swa_prefill_attention, reach=128,
+                          scale=0.1),
+        jax.ShapeDtypeStruct((1, 256, 2, 128), F32),      # q [B,T,H,qk]
+        jax.ShapeDtypeStruct((1, 2, 384, 128), F32),      # k [B,H,reach+T,qk]
+        jax.ShapeDtypeStruct((1, 2, 384, 128), F32),      # v [B,H,reach+T,v]
+        jax.ShapeDtypeStruct((1, 256), jnp.int32),        # q_pos
+        jax.ShapeDtypeStruct((1, 384), jnp.int32)),       # k_pos
 }
 
 
