@@ -285,6 +285,8 @@ def phase_kernels(sz: Sizes, seed: int, rec: Recorder) -> None:
     # 32 held: seven rows of eight in no group). Both round each
     # product once to bf16; they differ in the order of the float32
     # accumulations, a unit of bf16's last place at the output's top.
+    # What is compared is the layer's sum under gates of one (`gated_sum`
+    # selects the rows of no group out: no kernel wrote them).
     from ray_tpu.ops import grouped_experts as ge
 
     t, d_e, f_e = (256, d, f) if it else (2048, 2048, 1536)
@@ -306,9 +308,12 @@ def phase_kernels(sz: Sizes, seed: int, rec: Recorder) -> None:
         if not takes_kernels:           # the reference: steered HERE
             ge._takes_kernels = lambda *_: False
         try:
-            return jax.jit(lambda x, e, w, i: ge.grouped_swiglu(
-                x, e, w, i, n, interpret=it or None, **kw))(
-                    xe, experts, stacks, jnp.int32(1))
+            def layer(x, e, w, i):
+                pairs, load = ge.grouped_swiglu(x, e, w, i, n,
+                                                interpret=it or None, **kw)
+                return ge.gated_sum(pairs, jnp.ones(e.shape, jnp.float32)), load
+
+            return jax.jit(layer)(xe, experts, stacks, jnp.int32(1))
         finally:
             ge._takes_kernels = rule
 

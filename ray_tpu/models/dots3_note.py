@@ -94,7 +94,11 @@ from ray_tpu.models.common import route
 from ray_tpu.ops import apply_rope, mla_decode_attention, rms_norm
 from ray_tpu.ops import row_select
 from ray_tpu.ops.dsa_prefill import dsa_prefill_attention
-from ray_tpu.ops.grouped_experts import grouped_swiglu, split_expert_stacks
+from ray_tpu.ops.grouped_experts import (
+    gated_sum,
+    grouped_swiglu,
+    split_expert_stacks,
+)
 from ray_tpu.ops.swa_prefill import NO_ROW, swa_prefill_attention
 
 Params = Dict[str, Any]
@@ -345,7 +349,7 @@ def moe_ffn(x, layer, stacks, layer_idx, cfg: Dots3NoteConfig, valid=None):
                            cfg, precision=lax.Precision.HIGHEST)
     y, load = grouped_swiglu(x, experts, stacks, layer_idx, cfg.n_experts,
                              valid, held=cfg.held_experts)
-    y = jnp.einsum("tkd,tk->td", y.astype(F32), gates).astype(x.dtype)
+    y = gated_sum(y, gates).astype(x.dtype)
     shared = _swiglu(x, layer["ws_gate"], layer["ws_up"], layer["ws_down"])
     return y + shared, experts, load, jnp.sum(load), gates, router_in
 

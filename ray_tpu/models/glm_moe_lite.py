@@ -74,6 +74,7 @@ from ray_tpu.ops import (
 )
 from ray_tpu.ops.grouped_experts import (
     EXPERT_STACKS,  # noqa: F401 — the names of a layer's expert matrices
+    gated_sum,
     grouped_swiglu,
     split_expert_stacks,
 )
@@ -228,14 +229,15 @@ def moe_ffn(x, layer, stacks, layer_idx, cfg: GlmMoeLiteConfig, valid=None):
 
     Dropless: the T*k chosen pairs go through the grouped product that
     every routed family shares (``ops/grouped_experts.py``: sorted by
-    expert, each group multiplied by its expert's matrices; ``load[e]``
-    is the group's size; ``valid`` [T], a prefill bucket's real tokens,
-    keeps padding out of every group). This family's own: `route`, the
-    gates' weighted sum and the shared expert."""
+    expert, each group multiplied by its expert's matrices, a token's
+    rows summed under its gates; ``load[e]`` is the group's size;
+    ``valid`` [T], a prefill bucket's real tokens, keeps padding out of
+    every group). This family's own: `route`, the gates, the dtype the
+    sum is handed on in and the shared expert."""
     experts, gates = route(x, layer["router"], layer["router_bias"], cfg)
     y, load = grouped_swiglu(x, experts, stacks, layer_idx, cfg.n_experts,
                              valid)
-    y = jnp.einsum("tkd,tk->td", y.astype(F32), gates).astype(x.dtype)
+    y = gated_sum(y, gates).astype(x.dtype)
     shared = _swiglu(x, layer["ws_gate"], layer["ws_up"], layer["ws_down"])
     return y + shared, experts, load
 

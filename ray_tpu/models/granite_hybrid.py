@@ -120,7 +120,11 @@ from ray_tpu.ops import (
     rms_norm,
 )
 from ray_tpu.ops.decode_attention import LANES
-from ray_tpu.ops.grouped_experts import grouped_swiglu, split_expert_stacks
+from ray_tpu.ops.grouped_experts import (
+    gated_sum,
+    grouped_swiglu,
+    split_expert_stacks,
+)
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -332,7 +336,7 @@ def moe_ffn(n, router, stacks, layer_idx, cfg: GraniteHybridConfig,
     y, load = grouped_swiglu(
         n.astype(cfg.dtype), experts, stacks, layer_idx, cfg.n_experts,
         valid, held=cfg.held, interpret=cfg.interpret_kernels or None)
-    return (jnp.einsum("tkd,tk->td", y.astype(F32), gates),
+    return (gated_sum(y, gates),
             {"experts": experts, "load": load, "logits": top})
 
 

@@ -80,7 +80,11 @@ from ray_tpu.models.common import (_layer_of, _mm, _real, _starts_fresh,
                                    _swiglu, mla_decode_attend,
                                    mla_prefill_attend, route)
 from ray_tpu.ops import gated_delta, kda, mla_step_rows, rms_norm
-from ray_tpu.ops.grouped_experts import grouped_swiglu, split_expert_stacks
+from ray_tpu.ops.grouped_experts import (
+    gated_sum,
+    grouped_swiglu,
+    split_expert_stacks,
+)
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -348,7 +352,7 @@ def moe_ffn(n, layer, stacks, layer_idx, cfg: KimiLinearConfig, valid=None):
                            precision=lax.Precision.HIGHEST)
     y, load = grouped_swiglu(n.astype(cfg.dtype), experts, stacks, layer_idx,
                              cfg.n_experts, valid, held=cfg.held_experts)
-    y = jnp.einsum("tkd,tk->td", y.astype(F32), gates)
+    y = gated_sum(y, gates)
     shared = _swiglu(n, layer["ws_gate"], layer["ws_up"], layer["ws_down"])
     return y + shared, experts, load
 

@@ -84,6 +84,7 @@ from ray_tpu.ops import (
     rms_norm,
 )
 from ray_tpu.ops.grouped_experts import (
+    gated_sum,
     grouped_swiglu,
     split_expert_stacks,
 )
@@ -246,7 +247,7 @@ def moe_ffn(x, layer, stacks, layer_idx, cfg: ZayaConfig, valid=None):
     expert, gate, p = route(g, layer, cfg)
     y, load = grouped_swiglu(g.astype(cfg.dtype), expert[:, None], stacks,
                              layer_idx, cfg.n_experts, valid)
-    y = y[:, 0].astype(F32) * gate[:, None]
+    y = gated_sum(y, gate[:, None])
     return y, expert, load, {"router_in": g, "router_p": p}
 
 

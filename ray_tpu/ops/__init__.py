@@ -95,16 +95,18 @@ mhc.mhc_pre, .mhc_post     Pallas kernels of a four-stream    on TPU where the w
                            X, aliased)
 grouped_experts            Pallas grouped kernels between a   a PREFILL's rows on the TPU (or
  .grouped_swiglu           stable sort by expert and its      ``interpret=True``): ``T * k`` at least
-                           inverse (dropless, any k):         ``KERNEL_ROWS_A_GROUP`` (8) a held group,
-                           ``rtpu_grouped_swiglu`` (gate and  in whole tiles of 128 rows. Else (a decode
-                           up in one pass) and                step; off the chip) ``lax.ragged_dot`` x 3:
-                           ``rtpu_grouped_matmul``: each      the chip compiler's grouped matmul, elsewhere
-                           touched expert's matrices copied   a masked dense product. Imported by its
+ .gated_sum                inverse (dropless, any k; pairs    ``KERNEL_ROWS_A_GROUP`` (8) a held group,
+                           k-major, both gathers in range):   in whole tiles of 128 rows. Else (a decode
+                           ``rtpu_grouped_swiglu`` (gate and  step; off the chip) ``lax.ragged_dot`` x 3:
+                           up in one pass) and                the chip compiler's grouped matmul, elsewhere
+                           ``rtpu_grouped_matmul``: each      a masked dense product. ``gated_sum`` is the
+                           touched expert's matrices copied   same code everywhere. Imported by its
                            once a call, row tiles past the    callers (``models/glm_moe_lite.py``,
-                           groups' total never visited        ``models/zaya.py``,
-                                                            ``models/dots3_note.py``,
-                                                            ``models/kimi_linear.py`` and through
-                                                            it ``models/xing_mhc.py``, which hold
+                           groups' total never visited;       ``models/zaya.py``,
+                           then the gates' float32 sum over   ``models/dots3_note.py``,
+                           the ``[k, T, d]`` rows, those of   ``models/kimi_linear.py`` and through
+                           no group selected out in the one   it ``models/xing_mhc.py``,
+                           fusion that widens them            ``models/granite_hybrid.py``, which hold
                                                             a SHARE of the experts: ``held``)
 ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (with attention.py
                                                             the only importers of shard_map —

@@ -5,8 +5,15 @@ layer, ``models/dots3_note.py`` (8 of 256, 32 held),
 ``models/kimi_linear.py`` (8 of 256, 16 held), ``models/xing_mhc.py``
 (4 of 64, 8 held) and ``models/granite_hybrid.py`` (10 of 72, 36 held).
 Each token's chosen experts are given, the (token, expert) pairs are
-sorted by expert and each group multiplied by its own expert's SwiGLU
-matrices. No capacity: no pair is dropped however skewed the routing.
+sorted by expert, each group multiplied by its own expert's SwiGLU
+matrices (`grouped_swiglu`) and a token's rows weighed by its gates and
+summed (`gated_sum`). No capacity: no pair is dropped however skewed the
+routing. A pair's row is written to HBM by a gather or a kernel and by
+nothing else: pairs numbered k-major, so that the rows gathered back
+are ``[k, T, d]`` as they lie; both gathers in range by construction;
+the rows of pairs in no group (an absent expert, a bucket's padding),
+which no kernel visits, left as they are and selected out inside the
+sum's one fusion, where the rows are widened too.
 Two implementations of the products, chosen from static shapes
 (`_takes_kernels`: the pairs a held group): from 8 pairs a group on,
 the chip takes the Pallas kernels of this module
@@ -19,15 +26,16 @@ everything off the chip, takes ``jax.lax.ragged_dot`` (the chip's
 compiler has a grouped-matmul kernel for it; elsewhere it is a masked
 dense product, fine at test sizes): the decode steps of GLM (2 pairs a
 group), ZAYA (4) and dots3 (4). What a family keeps for itself is its
-router (which experts, with what weights) and whatever it adds to the
-sum (a shared expert, a scaling factor). A chip that holds a share says
-which (``held``): the router keeps its width, pairs on absent experts
-go to no group.
+router (which experts, with what gates), whatever it adds to the sum (a
+shared expert, a scaling factor) and the dtype it hands on. A chip that
+holds a share says which (``held``): the router keeps its width, pairs
+on absent experts go to no group.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 from jax import lax
@@ -245,24 +253,43 @@ def _takes_kernels(rows: int, groups: int, interpret) -> bool:
                 and rows >= KERNEL_ROWS_A_GROUP * groups)
 
 
+class Pairs(NamedTuple):
+    """What `grouped_swiglu` hands `gated_sum`: the pairs' rows laid
+    k-major (``rows[j, t]``: token t's j-th chosen expert applied to
+    it, in the stacks' dtype) and which of them are in a group
+    (``there`` [k, T], or None: all are). A row that is not ``there``
+    was written by no one and may hold anything, NaN included."""
+    rows: jax.Array
+    there: jax.Array | None
+
+
 def grouped_swiglu(x, experts, stacks, layer_idx, n_experts: int,
                    valid=None, held=None, interpret=None):
     """x [T, d], experts [T, k] int32 (each token's chosen experts of
     expert layer ``layer_idx``, among the router's ``n_experts``),
-    ``stacks`` every expert layer's experts (`expert_stacks`) -> (y
-    [T, k, d]: each pair's expert applied to its token, load [E] int32:
-    each group's size).
+    ``stacks`` every expert layer's experts (`expert_stacks`) ->
+    (`Pairs`: each pair's expert applied to its token, for `gated_sum`;
+    load [E] int32: each group's size).
+
+    A pair's row crosses HBM where a gather or a kernel needs it and
+    nowhere else. The pairs are numbered k-major (pair ``j * T + t``),
+    so the rows gathered back into that order ARE ``[k, T, d]`` and a
+    token's sum reads the major axis whatever k is (``[T, k, d]`` is
+    re-laid and padded where k is no whole tile of sublanes: 10, 4).
+    Both gathers index with a permutation's remainder or inverse and
+    say so (``mode="clip"``: the default writes every row again to put
+    NaN where an index might be out of range).
 
     ``valid`` [T] (a prefill bucket's real tokens) keeps padding out of
-    every group: such pairs sort last, past the groups' total, and
-    their rows are zeroed.
+    every group: such pairs sort last, past the groups' total, no
+    kernel visits their tiles, and `gated_sum` leaves them out.
 
     ``held`` = (first, count): this chip's share of an expert-parallel
     layer. The stacks hold experts ``first .. first + count - 1`` of
     every layer and no others; the router still ranks all ``n_experts``
     and a pair on an absent expert goes to no group, as padding does
-    (its row comes back zero: what the absent chips would add is left
-    out, no code stands in for them). ``load`` is then over the
+    (it adds nothing to the sum: what the absent chips would add is
+    left out, no code stands in for them). ``load`` is then over the
     ``count`` held experts. Absent, every expert is held.
 
     Two implementations of the three products, chosen from the shapes
@@ -276,13 +303,15 @@ def grouped_swiglu(x, experts, stacks, layer_idx, n_experts: int,
     and take SwiGLU of the rounded gate and up in that dtype."""
     t, d = x.shape
     k, e = experts.shape[1], n_experts
-    flat = experts.reshape(t * k)
+    flat = experts.T.reshape(k * t)                  # pair j * T + t
     if held is not None:
         first, e = held
         flat = flat - first
         flat = jnp.where((flat >= 0) & (flat < e), flat, e)
     if valid is not None:
-        flat = jnp.where(jnp.repeat(valid, k), flat, e)
+        flat = jnp.where(jnp.tile(valid, k), flat, e)
+    there = (None if valid is None and held is None
+             else (flat < e).reshape(k, t))
     kernels = _takes_kernels(t * k, e, interpret)
     pad = -(t * k) % ROW_TILE if kernels else 0
     if pad:                         # rows of no group, sorted last
@@ -290,9 +319,7 @@ def grouped_swiglu(x, experts, stacks, layer_idx, n_experts: int,
     order = jnp.argsort(flat, stable=True)
     load = jnp.sum(flat[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :],
                    axis=0, dtype=jnp.int32)
-    # (A padding row reads the last token: `jnp.take` clips.)
-    xs = jnp.take(x, order // k, axis=0,                     # [T*k, d]
-                  **({"mode": "clip"} if pad else {}))
+    xs = jnp.take(x, order % t, axis=0, mode="clip")         # [T*k, d]
     if kernels:
         ys = _kernel_products(xs, *(stacks[m] for m in EXPERT_STACKS), load,
                               layer_idx * e, interpret=bool(interpret))
@@ -304,9 +331,21 @@ def grouped_swiglu(x, experts, stacks, layer_idx, n_experts: int,
         hidden = (jax.nn.silu(lax.ragged_dot(xs, stacks["w_gate"], sizes))
                   * lax.ragged_dot(xs, stacks["w_up"], sizes))
         ys = lax.ragged_dot(hidden, stacks["w_down"], sizes)     # [T*k, d]
-    if valid is not None or held is not None:
-        ys = jnp.where((jnp.take(flat, order) < e)[:, None], ys, 0)
-    back = jnp.argsort(order)                # pair i sits at row back[i]
-    if pad:
-        back = back[:t * k]
-    return jnp.take(ys, back, axis=0).reshape(t, k, d), load
+    back = jnp.argsort(order)[:t * k]        # pair i sits at row back[i]
+    return Pairs(jnp.take(ys, back, axis=0, mode="clip").reshape(k, t, d),
+                 there), load
+
+
+def gated_sum(pairs: Pairs, gates):
+    """`grouped_swiglu`'s pairs, gates [T, k] float32 -> [T, d]
+    float32: each pair's rounded row times its gate, summed over a
+    token's experts in float32. A pair in no group is SELECTED out
+    (never multiplied by zero: its row may be NaN), in the rows' own
+    dtype and BEFORE they are widened: in that order the chip's compiler
+    takes the select and the widening into the sum's one fusion, so no
+    array of the pairs' size is written here (widened first, it writes
+    the float32 copy)."""
+    rows, there = pairs
+    if there is not None:
+        rows = jnp.where(there[:, :, None], rows, 0)
+    return jnp.einsum("ktd,tk->td", rows.astype(F32), gates)

@@ -1403,6 +1403,14 @@ def test_granite_small_tick_prefill_routes_a_bucket_through_the_kernels(chip):
     text = c.as_text()
     assert "%flash_attention" in text and " while(" in text
     assert "%rtpu_grouped_swiglu." in text and "ragged-dot" not in text
+    # In the engine's own program too (PR 62): the pairs' rows are bf16
+    # wherever they are written, none selected, none laid token-major
+    # (where the family's widening was hoisted above the zeroing pass
+    # and the gather back: `f32[20480,4096]`, `f32[2048,10,4096]`).
+    written = "\n".join(_written(text))
+    assert re.findall(r"f32\[(?:10240|10,1024|1024,10),4096\]", written) == []
+    assert re.findall(r"bf16\[10240,4096\]\S* select\(", written) == []
+    assert "[1024,10,4096]" not in text and "bf16[10,1024,4096]" in written
     nbytes = sum(a.size * a.dtype.itemsize for a in cache.values())
     mem = c.memory_analysis()
     assert mem.alias_size_in_bytes >= nbytes
@@ -1449,6 +1457,100 @@ def test_grouped_expert_kernels_compile_at_the_prefill_shapes(chip, cell):
     assert _copies_of(c.as_text(), stacks) == []
     # ``hidden`` and the walk's tables: no second copy of rows or output.
     assert c.memory_analysis().temp_size_in_bytes < 1.1 * rows * d_ff * 2
+
+
+# (T, k, d, d_ff, the router's experts, held, expert layers in the stack):
+# one expert layer at a 2,048-token bucket of Granite, xing4 and dots3
+# and at GLM's largest.
+EXPERT_LAYERS = {"granite4hsmall": (2048, 10, 4096, 768, 72, (0, 36), 10),
+                 "xing4": (2048, 4, 3584, 1024, 64, (0, 8), 38),
+                 "dots3": (2048, 8, 5120, 1536, 256, (64, 32), 4),
+                 "glm47flash": (4096, 4, 2048, 1536, 64, None, 6)}
+_RESULT = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
+
+
+def _by_name(text: str) -> dict:
+    """The compiled module's computations by their names."""
+    return {comp[0].removeprefix("ENTRY ").split(" ")[0][1:]: comp
+            for comp in _computations(text)}
+
+
+def _written(text: str) -> list:
+    """The lines of a compiled program whose results are written to
+    memory: every computation's but a fusion's own (what a fusion
+    computes on the way to its root stays in registers)."""
+    fused = set(re.findall(r"fusion\(.*calls=%([\w.\-]+)", text))
+    return [line for name, comp in _by_name(text).items()
+            if name not in fused for line in comp]
+
+
+def _results_of(lines, elements: int) -> list:
+    """(name, dtype, op) of the instructions whose result has at least
+    ``elements`` elements."""
+    found = []
+    for m in filter(None, map(_RESULT.match, lines)):
+        size = functools.reduce(
+            lambda a, b: a * int(b), filter(None, m.group(3).split(",")), 1)
+        if size >= elements:
+            found.append((m.group(1), m.group(2), m.group(4)))
+    return found
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_LAYERS))
+def test_expert_layer_writes_its_pairs_by_two_gathers_and_two_kernels(
+        chip, cell):
+    """One expert layer (sort, kernels, unsort, the gates' sum:
+    `grouped_swiglu` then `gated_sum`, under a bucket's ``valid``) as
+    the chip's compiler schedules it: of the arrays of ``T * k x d``
+    elements that cross HBM, one is the gather into expert order, one
+    `rtpu_grouped_matmul`'s result, one the gather back, and there is
+    NO other: no fill select of a ``take``, no zeroing pass, no relayout
+    to ``[T, k, d]`` (a copy where k is no whole tile of sublanes: 10,
+    4), no widened copy. The rows gathered back are bitcast to
+    ``[k, T, d]`` and read by the one fusion that writes ``f32[T, d]``."""
+    from ray_tpu.ops import grouped_experts as ge
+
+    t, k, d, d_ff, n_experts, held, layers = EXPERT_LAYERS[cell]
+    count = n_experts if held is None else held[1]
+
+    def layer(x, experts, gates, stacks, valid, layer_idx):
+        pairs, load = ge.grouped_swiglu(x, experts, stacks, layer_idx,
+                                        n_experts, valid, held=held)
+        return ge.gated_sum(pairs, gates), load
+
+    assert ge._takes_kernels(t * k, count, None)
+    stacks = {"w_gate": _sds(chip, (layers * count, d, d_ff)),
+              "w_up": _sds(chip, (layers * count, d, d_ff)),
+              "w_down": _sds(chip, (layers * count, d_ff, d))}
+    c = _compile(layer, _sds(chip, (t, d)), _sds(chip, (t, k), jnp.int32),
+                 _sds(chip, (t, k), jnp.float32), stacks,
+                 _sds(chip, (t,), jnp.bool_), _sds(chip, (), jnp.int32))
+    text = c.as_text()
+    assert _names_kernel(c, ge.SWIGLU) and _names_kernel(c, ge.MATMUL)
+    assert _kernel_calls(c) == 2 and "ragged-dot" not in text
+    assert _copies_of(text, stacks) == []
+    body = _by_name(text)
+    entry = next(comp for comp in body.values()
+                 if comp[0].startswith("ENTRY"))
+    pairs = [r for r in _results_of(entry, t * k * d)
+             if r[2] not in ("bitcast", "parameter")]
+    calls = dict(re.findall(r"%(\S+) = .* fusion\(.*calls=%([\w.\-]+)",
+                            "\n".join(entry)))
+    gathers = [r for r in pairs if any(
+        " gather(" in line for line in body.get(calls.get(r[0]), []))]
+    assert len(gathers) == 2
+    assert sorted(r[0].split(".")[0] for r in pairs if r not in gathers) == [
+        ge.MATMUL], pairs
+    assert {r[1] for r in pairs} == {"bf16"}
+    assert [r for r in _results_of(_written(text), t * k * d)
+            if r[1] == "f32" or r[2] in ("select", "copy", "reshape")] == []
+    # The sum: the gather back, bitcast to [k, T, d], into one fusion.
+    assert re.search(rf"bf16\[{k},{t},{d}\]\S* bitcast\(%{gathers[1][0]}\)",
+                     text) or k == 1
+    assert f"[{t},{k},{d}]" not in text
+    out = [r for r in _results_of(entry, t * d)
+           if r[1] == "f32" and r[2] == "fusion"]
+    assert len(out) == 1, out
 
 
 # ------------------------------------------------ the Kimi-Linear cell

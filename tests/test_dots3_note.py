@@ -355,8 +355,13 @@ def test_a_layer_that_holds_every_expert_is_the_layer_before_held(valid):
     mask = None if valid is None else jnp.arange(t) < valid
     a = grouped_swiglu(x, experts, stacks, 1, e, mask)
     b = grouped_swiglu(x, experts, stacks, 1, e, mask, held=(0, e))
-    for got, want in zip(a, b):
-        assert (np.asarray(got) == np.asarray(want)).all()
+    (pairs, load), (pairs_held, load_held) = a, b
+    assert (np.asarray(pairs.rows) == np.asarray(pairs_held.rows)).all()
+    assert (np.asarray(load) == np.asarray(load_held)).all()
+    if valid is None:           # every pair in a group: no mask to hand on
+        assert pairs.there is None and bool(pairs_held.there.all())
+    else:
+        assert (np.asarray(pairs.there) == np.asarray(pairs_held.there)).all()
     assert int(a[1].sum()) == (t if valid is None else valid) * k
 
 

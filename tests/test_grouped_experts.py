@@ -58,6 +58,58 @@ def _dense(x, experts, stacks, layer_idx, n_experts, valid, held):
     return jnp.where(there[..., None], y, 0)
 
 
+def _rows(pairs):
+    """`ge.Pairs` -> [T, k, d] float32, the rows of no group zero (they
+    hold whatever was there: only `gated_sum`'s select keeps them out)."""
+    rows, there = pairs
+    rows = rows.astype(F32)
+    if there is not None:
+        rows = jnp.where(there[:, :, None], rows, 0)
+    return np.asarray(rows.transpose(1, 0, 2))
+
+
+def _parents_layer(x, experts, gates, stacks, layer_idx, n_experts, valid,
+                   held, interpret):
+    """The expert layer as it stood before PR 62, kept here: pairs
+    numbered token-major, the sorted rows zeroed where they are in no
+    group, gathered back, re-laid to [T, k, d], widened, ``einsum``."""
+    t, d = x.shape
+    k, e = experts.shape[1], n_experts
+    flat = experts.reshape(t * k)
+    if held is not None:
+        first, e = held
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < e), flat, e)
+    if valid is not None:
+        flat = jnp.where(jnp.repeat(valid, k), flat, e)
+    kernels = ge._takes_kernels(t * k, e, interpret)
+    pad = -(t * k) % ge.ROW_TILE if kernels else 0
+    if pad:
+        flat = jnp.concatenate([flat, jnp.full((pad,), e, flat.dtype)])
+    order = jnp.argsort(flat, stable=True)
+    load = jnp.sum(flat[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :],
+                   axis=0, dtype=jnp.int32)
+    xs = jnp.take(x, order // k, axis=0, **({"mode": "clip"} if pad else {}))
+    if kernels:
+        ys = ge._kernel_products(
+            xs, *(stacks[m] for m in ge.EXPERT_STACKS), load, layer_idx * e,
+            interpret=bool(interpret))
+    else:
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((stacks["w_gate"].shape[0],), jnp.int32), load,
+            (layer_idx * e,))
+        hidden = (jax.nn.silu(jax.lax.ragged_dot(xs, stacks["w_gate"], sizes))
+                  * jax.lax.ragged_dot(xs, stacks["w_up"], sizes))
+        ys = jax.lax.ragged_dot(hidden, stacks["w_down"], sizes)
+    if valid is not None or held is not None:
+        ys = jnp.where((jnp.take(flat, order) < e)[:, None], ys, 0)
+    back = jnp.argsort(order)
+    if pad:
+        back = back[:t * k]
+    y = jnp.take(ys, back, axis=0).reshape(t, k, d)
+    return jnp.einsum("tkd,tk->td", y.astype(F32), gates), load
+
+
 def _case(name):
     """-> (experts [T, k], n_experts, layers, layer_idx, valid, held)."""
     key = jax.random.PRNGKey(sum(map(ord, name)))
@@ -114,7 +166,9 @@ def test_kernels_give_what_ragged_dot_gives(name, dtype):
     (y, load), (y_ragged, load_ragged) = run(True), run(None)
     np.testing.assert_array_equal(load, load_ragged)
     assert int(load.sum()) <= experts.size
-    y, y_ragged = np.asarray(y.astype(F32)), np.asarray(y_ragged.astype(F32))
+    assert y.rows.dtype == dtype and y.rows.shape == experts.shape[::-1] + (D,)
+    assert (y.there is None) == (valid is None and held is None)
+    y, y_ragged = _rows(y), _rows(y_ragged)
     # The same products rounded at the same places: what differs is the
     # order of a float32 accumulation, a unit or two of the last place
     # of the dtype after the rounding (of the output's own size, and of
@@ -127,7 +181,67 @@ def test_kernels_give_what_ragged_dot_gives(name, dtype):
     scale = np.abs(dense).max()
     rounding = 0.03 if dtype == jnp.bfloat16 else 1e-5
     assert np.abs(y - dense).max() <= rounding * scale
-    assert ((dense == 0) <= (y == 0)).all()      # no-group rows come back 0
+    assert ((dense == 0) <= (y == 0)).all()      # no-group rows: not there
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("name", CASES)
+def test_the_layer_is_the_parents_sort_zero_relay_and_einsum(name, dtype):
+    """Pairs laid k-major, both gathers in range, the rows of no group
+    selected out where they are summed: what the token-major sort, the
+    zeroing pass, ``reshape(t, k, d)`` and the families' ``einsum`` gave
+    (`_parents_layer`), through the interpreted kernels (which pad 320
+    and 640 rows to whole tiles) and through ``ragged_dot``."""
+    experts, n_experts, layers, layer_idx, valid, held = _case(name)
+    held_count = n_experts if held is None else held[1]
+    key = jax.random.PRNGKey(11)
+    stacks = _stacks(key, layers * held_count, dtype)
+    x = jax.random.normal(jax.random.fold_in(key, 1),
+                          (experts.shape[0], D), F32).astype(dtype)
+    gates = jax.nn.softmax(jax.random.normal(
+        jax.random.fold_in(key, 2), experts.shape, F32), axis=-1)
+
+    def layer(x, experts, gates, stacks, i, interpret):
+        pairs, load = ge.grouped_swiglu(x, experts, stacks, i, n_experts,
+                                        valid, held, interpret)
+        return ge.gated_sum(pairs, gates), load
+
+    for interpret in (True, None):
+        args = (x, experts, gates, stacks, jnp.int32(layer_idx))
+        y, load = jax.jit(functools.partial(layer, interpret=interpret))(*args)
+        want, load_want = jax.jit(functools.partial(
+            _parents_layer, n_experts=n_experts, valid=valid, held=held,
+            interpret=interpret))(*args)
+        np.testing.assert_array_equal(load, load_want)
+        assert y.dtype == F32 and y.shape == x.shape
+        # Each row's products are its own whatever its neighbours; what
+        # may differ is the order of a float32 sum of k gated rows.
+        y, want = np.asarray(y), np.asarray(want)
+        np.testing.assert_allclose(y, want, rtol=2e-6,
+                                   atol=2e-6 * np.abs(want).max())
+        if valid is not None:
+            assert not y[~np.asarray(valid)].any()
+
+
+@pytest.mark.parametrize("k", [1, 4, 10])
+def test_rows_of_no_group_may_hold_nan_and_the_sum_has_none(k):
+    """The kernels visit no tile of absent or padding pairs and the
+    zeroing pass is gone: `gated_sum` is handed rows the test fills
+    with NaN and infinity wherever ``there`` is false."""
+    t, key = 37, jax.random.PRNGKey(k)
+    rows = jax.random.normal(key, (k, t, D), F32).astype(jnp.bfloat16)
+    there = jax.random.bernoulli(jax.random.fold_in(key, 1), 0.5, (k, t))
+    there = there.at[:, 0].set(False).at[0, 1].set(True)
+    gates = jax.nn.softmax(jax.random.normal(
+        jax.random.fold_in(key, 2), (t, k), F32), axis=-1)
+    junk = jnp.where(jnp.arange(D) % 2 == 0, jnp.nan, jnp.inf).astype(
+        rows.dtype)
+    dirty = jnp.where(there[:, :, None], rows, junk)
+    got = np.asarray(jax.jit(ge.gated_sum)(ge.Pairs(dirty, there), gates))
+    assert np.isfinite(got).all() and not got[0].any() and got[1].any()
+    clean = jnp.where(there[:, :, None], rows, 0)
+    np.testing.assert_array_equal(
+        got, jax.jit(ge.gated_sum)(ge.Pairs(clean, None), gates))
 
 
 def _jaxpr_of(tokens, k, n_experts, d, d_ff, layers, held=None,
@@ -229,5 +343,6 @@ def test_columns_are_tiled_where_two_buffers_a_matrix_do_not_fit(monkeypatch):
             x, experts, stacks, jnp.int32(1))
     (y, load), (y_ragged, load_ragged) = run(True), run(None)
     np.testing.assert_array_equal(load, load_ragged)
+    y, y_ragged = _rows(y), _rows(y_ragged)
     np.testing.assert_allclose(y, y_ragged, rtol=1e-5,
                                atol=1e-5 * np.abs(y_ragged).max())
