@@ -113,7 +113,7 @@ SOCKET_NAME_RE = re.compile(r"sock", re.IGNORECASE)
 
 #: One seam per jax mesh API (written for the installed jax, 0.9):
 #: ambient meshes are entered through ``mesh_context`` and manual
-#: partitioning (``shard_map``) lives in the two ops modules that own
+#: partitioning (``shard_map``) lives in the ops modules that own
 #: a per-shard kernel — model and parallel code calls those ops.
 #: dotted-call-suffix -> replacement hint.
 BANNED_CALLS = {
@@ -127,17 +127,19 @@ BANNED_CALLS = {
 
 #: Module paths whose import is banned outside the exempt modules.
 #: import-path -> (replacement hint, exempt modules).
-_SHARD_MAP_OWNERS = {"ray_tpu.ops.ring_attention", "ray_tpu.ops.attention"}
+_SHARD_MAP_OWNERS = {"ray_tpu.ops.ring_attention", "ray_tpu.ops.attention",
+                     "ray_tpu.ops.fused"}
 BANNED_IMPORTS = {
     "jax.experimental.shard_map": (
-        "shard_map belongs to ray_tpu.ops (ring_attention, attention); "
+        "shard_map belongs to ray_tpu.ops (ring_attention, attention, "
+        "fused); "
         "the jax.experimental path is deprecated — they import "
         "jax.shard_map",
         _SHARD_MAP_OWNERS,
     ),
     "jax.shard_map": (
-        "shard_map belongs to ray_tpu.ops (ring_attention, attention): "
-        "call those ops instead of partitioning by hand",
+        "shard_map belongs to ray_tpu.ops (ring_attention, attention, "
+        "fused): call those ops instead of partitioning by hand",
         _SHARD_MAP_OWNERS,
     ),
 }

@@ -41,6 +41,7 @@ from ray_tpu.ops import (
     fused_rms_norm,
     fused_rms_norm_residual,
     fused_swiglu,
+    qk_rope_on_mesh_fits,
     ring_attention,
     rms_norm,
 )
@@ -75,12 +76,15 @@ class LlamaConfig:
     # the Pallas interpreter off the TPU.
     interpret_kernels: bool = False
     # Fused Pallas kernels for the per-layer glue (ops/fused.py):
-    # RMSNorm(+residual), rotary folded over the QK projection outputs,
-    # and SwiGLU each become one VMEM pass instead of several XLA HBM
-    # round trips. True = fused kernels on TPU, jnp references elsewhere
-    # (same custom-VJP wrapper either way, so the train path fuses too);
-    # "interpret" = run the kernels under the Pallas interpreter off-TPU
-    # (equivalence-test escape hatch); False = the plain unfused ops.
+    # RMSNorm(+residual), SwiGLU and, on the cache paths, rotary folded
+    # over the QK projection outputs each become one VMEM pass instead
+    # of several XLA HBM round trips. True = fused kernels on TPU, jnp
+    # references elsewhere (same custom-VJP wrapper either way, so the
+    # train path fuses too); "interpret" = run the kernels under the
+    # Pallas interpreter off-TPU (equivalence-test escape hatch); False =
+    # the plain unfused ops. The whole-sequence block WITHOUT a cache
+    # does not ask here for its rope: `_rope_kernel` reads the backend,
+    # the mesh and the shapes.
     fused_ops: Any = False
     # jax.checkpoint policy name: what a layer keeps for its backward
     # beside its input, in bytes of a batch B of S tokens over the whole
@@ -280,11 +284,28 @@ def _named(x, name: str):
     return checkpoint_name(x.reshape(*x.shape[:2], -1), name).reshape(x.shape)
 
 
+def _rope_kernel(x, layer, cfg: LlamaConfig, mesh: Optional[Mesh]) -> bool:
+    """Whether a whole-sequence, no-cache `_block` rotates q and k by
+    `ops.fused_qk_rope`'s Pallas kernel (forward and backward, behind the
+    tp ring too): on the TPU (or where ``cfg.interpret_kernels`` asks for
+    the interpreter), with dense weights, wherever a device's share of q
+    and k is whole lane tiles of heads: as `use_fused_kernel` chooses the
+    flash kernels, read off the backend, the mesh and the shapes, never
+    asked for. Elsewhere `apply_rope`, which a head of 64 lanes costs ten
+    times its bytes' time (PERF.md section 6, PR 63)."""
+    if not (cfg.interpret_kernels or jax.default_backend() == "tpu") or any(
+            isinstance(layer[w], QuantTensor) for w in ("wq", "wk", "wv")):
+        return False
+    return qk_rope_on_mesh_fits(*x.shape[:2], layer["wq"].shape[-2],
+                                layer["wk"].shape[-2], cfg.head_dim, mesh)
+
+
 def _tp_ring(seq_len: int, blocks, cfg: LlamaConfig, cache_kv=None) -> bool:
     """Whether `_block`'s four matmul groups run as collective matmuls
     (`parallel/collective_matmul.py`): no cache, dense weights, plain XLA
     around them (``cfg.fused_ops``' Pallas calls cannot be traced inside
-    the ring's `shard_map`, so that option keeps the program it had), and
+    the ring's `shard_map`, so that option keeps the program it had;
+    rope's kernel runs behind the ring, in a `shard_map` of its own), and
     a mesh and shapes that `collective_matmul.ring_size` accepts.
     ``blocks`` is one layer's weights or the stacked ones (sizes are
     read from the right)."""
@@ -317,17 +338,22 @@ def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
     asked for), arrives and leaves sequence-sharded over tp
     (``res_seq``): the norms and the residual additions run on a shard,
     the four matmul groups move the other shards themselves under their
-    products, and rotary embedding and SwiGLU are applied to each
-    shard's products as they are made. Every other call multiplies with
-    `_wdot` and leaves its collectives to the partitioner."""
+    products, SwiGLU is applied to each shard's products as they are
+    made, and so is rotary embedding where it is plain XLA. Every other
+    call multiplies with `_wdot` and leaves its collectives to the
+    partitioner. With no cache, and where `_rope_kernel` finds a
+    device's share of q and k dense, q, k and v are multiplied with a
+    row's heads side by side and ONE Pallas call rotates q and k behind
+    the products (behind the ring's assembly, where it runs)."""
     ring = _tp_ring(x.shape[1], layer, cfg, cache_kv)
     stream = ("batch", "res_seq" if ring else "seq", None)
     fused = bool(cfg.fused_ops)
     interp = cfg.fused_ops == "interpret"
+    dense = cache_kv is None and _rope_kernel(x, layer, cfg, mesh)
 
     def rope(qkv, pos):
         q, k, v = qkv
-        if fused:
+        if cache_kv is not None and fused:
             return (*fused_qk_rope(q, k, pos, cfg.rope_theta,
                                    interpret=interp), v)
         return (apply_rope(q, pos, cfg.rope_theta),
@@ -340,16 +366,34 @@ def _block(x, layer, positions, cfg: LlamaConfig, mesh: Optional[Mesh],
 
     h = _norm(x, layer["ln_attn"], cfg)
     wqkv = (layer["wq"], layer["wk"], layer["wv"])
+    eqn, heads = "bsd,dhk->bshk", (None,)
+    if dense:
+        # The products are written with a row's heads side by side, as
+        # the kernel and the policy's kept arrays want them: [D, H, hd]
+        # -> [D, H·hd] is a view of a weight, the same of a product is a
+        # pass over it where hd is half a lane tile. v's with them: a
+        # product written [.., H, 64] takes twice the time of the flat
+        # one (17.5 ms against 8.7 a step's 24 on a v5e, PR 63).
+        wqkv = tuple(w.reshape(w.shape[0], -1) for w in wqkv)
+        eqn, heads = "bsd,dn->bsn", ()
     if ring:
         q, k, v = collective_matmul.gather_matmul(
-            "bsd,dhk->bshk", h, wqkv, rowwise=rope, row_args=(positions,))
+            eqn, h, wqkv, rowwise=None if dense else rope,
+            row_args=() if dense else (positions,))
     else:
-        q, k, v = (_wdot("bsd,dhk->bshk", h, w) for w in wqkv)
-    q = constrain(q, ("batch", "seq", "heads", None))
-    k = constrain(k, ("batch", "seq", "kv_heads", None))
-    if not ring:
-        q, k, v = rope((q, k, v), positions)
-    q, k = _named(q, _Q_ROPE), _named(k, _K_ROPE)
+        q, k, v = (_wdot(eqn, h, w) for w in wqkv)
+    q = constrain(q, ("batch", "seq", "heads", *heads))
+    k = constrain(k, ("batch", "seq", "kv_heads", *heads))
+    if dense:
+        q, k = fused_qk_rope(q, k, positions, cfg.rope_theta,
+                             head_dim=cfg.head_dim,
+                             interpret=cfg.interpret_kernels, mesh=mesh)
+        q, k, v = (a.reshape(*a.shape[:2], -1, cfg.head_dim) for a in (
+            checkpoint_name(q, _Q_ROPE), checkpoint_name(k, _K_ROPE), v))
+    else:
+        if not ring:
+            q, k, v = rope((q, k, v), positions)
+        q, k = _named(q, _Q_ROPE), _named(k, _K_ROPE)
 
     new_kv = None
     if cache_kv is not None:

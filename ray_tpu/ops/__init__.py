@@ -109,15 +109,25 @@ grouped_experts            Pallas grouped kernels between a   a PREFILL's rows o
                            fusion that widens them            ``models/granite_hybrid.py``, which hold
                                                             a SHARE of the experts: ``held``)
 ring_attention             shard_map ppermute ring          mesh ``sp`` axis > 1 (with attention.py
-                                                            the only importers of shard_map —
-                                                            rtpu-lint banned-API rule)
+                                                            and fused.py the only importers of
+                                                            shard_map — rtpu-lint banned-API rule)
 rms_norm                   (fp32 jnp reference)             always; the fused ops' exactness anchor
 apply_rope                 (fp32 jnp reference)             always; ``freqs`` where the frequencies
                                                             are scaled (``rotary.YarnScaling``)
 fused_rms_norm             Pallas one-pass norm kernel      ``LlamaConfig.fused_ops``: kernel on TPU
 fused_rms_norm_residual    + residual-add fold              or under ``interpret``; reference impl
-fused_qk_rope              one kernel for q AND k           elsewhere (same custom VJP both ways,
-fused_swiglu               silu(gate)*up, no temp           so the train path may fuse too)
+fused_swiglu               silu(gate)*up, no temp           elsewhere (same custom VJP both ways,
+                                                            so the train path may fuse too)
+fused_qk_rope              ``rtpu_fused_qk_rope``: q AND k  ``models/llama.py``'s whole-sequence,
+                           rotated in one call on dense     no-cache block (the train step, forward
+                           [rows, heads x head size]        and backward, behind the tp ring too): on
+                           lanes, cos and sin once a row    the TPU where a device's share of q and k
+                           block, its VJP the same kernel   is whole lane tiles of heads
+                           at negated positions; under a    (``qk_rope_on_mesh_fits``: backend, mesh
+                           mesh of several devices inside   and shapes, no option), ``apply_rope``
+                           a ``shard_map`` manual over      elsewhere; the cache paths only under
+                           every axis, like the flash       ``LlamaConfig.fused_ops``
+                           kernels
 =========================  ===============================  =========================================
 
 Every dispatcher asks ``jax.default_backend() == "tpu"``, and on the TPU
@@ -146,6 +156,7 @@ from ray_tpu.ops.fused import (
     fused_rms_norm,
     fused_rms_norm_residual,
     fused_swiglu,
+    qk_rope_on_mesh_fits,
     swiglu_reference,
 )
 from ray_tpu.ops.mla_decode import (
@@ -170,6 +181,7 @@ __all__ = [
     "fused_rms_norm",
     "fused_rms_norm_residual",
     "fused_swiglu",
+    "qk_rope_on_mesh_fits",
     "mla_decode_attention",
     "mla_decode_attention_reference",
     "mla_step_rows",

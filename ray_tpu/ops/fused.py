@@ -13,7 +13,9 @@ pass over its operands in VMEM:
   rms_norm(x)`` pair reads/writes ``x`` once.
 - ``fused_qk_rope``           — one kernel rotates BOTH the q and k
   projection outputs, computing the cos/sin tables once per position
-  (the unfused path recomputes them per tensor).
+  (the unfused path recomputes them per tensor). The one of the four
+  that a cell runs: `models/llama.py`'s whole-sequence block takes it by
+  what it observes, under a multi-device mesh too (`fused_qk_rope`).
 - ``fused_swiglu``            — ``silu(gate) * up`` in fp32 without a
   materialized intermediate.
 
@@ -24,7 +26,7 @@ CPU — the test suite checks kernel-vs-reference equivalence that way)
 and the reference elsewhere. Every op carries a custom VJP (backward in
 plain jnp, checked against autodiff of the reference) so the TRAINING
 path can use the fused forward under ``jax.checkpoint``; models opt in
-via ``LlamaConfig.fused_ops``.
+via ``LlamaConfig.fused_ops`` (rope's VJP is its kernel again).
 
 Kernel-body discipline (now ENFORCED by jax-lint's
 ``pallas-shape-rules`` — ``python -m ray_tpu.devtools.lint --family
@@ -42,17 +44,19 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.norms import rms_norm as rms_norm_reference
 from ray_tpu.ops.rotary import apply_rope as apply_rope_reference
 from ray_tpu.ops.rotary import rope_frequencies
 
-_ROW_BLOCKS = (128, 64, 32, 16, 8)
+_ROW_BLOCKS = (256, 128, 64, 32, 16, 8)
 _COL_BLOCKS = (1024, 512, 256, 128)
 
 
@@ -235,19 +239,39 @@ def _rope_kernel(pos_ref, inv_ref, q_ref, k_ref, oq_ref, ok_ref, *,
         out[...] = (x * c + partner * s).astype(out.dtype)
 
 
-def _rope_impl(q, k, positions, theta, interpret):
+def _rope_table_lanes(d: int) -> int:
+    """Lanes of the in-kernel cos/sin table: one whole lane tile of
+    heads."""
+    return d * 128 // math.gcd(d, 128)
+
+
+def qk_rope_kernel_fits(rows: int, nq: int, nk: int, d: int) -> bool:
+    """Whether ``rows`` tokens of q [rows, nq] and k [rows, nk] (a
+    row's heads of size ``d`` side by side) are the kernel's dense
+    operands: both widths whole lane tiles of heads, so that no lane of
+    a block is padding and the table tiles out lane-aligned, and rows
+    that divide into sublane-aligned blocks. `models/llama.py` takes the
+    kernel for its whole-sequence blocks where this holds for a device's
+    share (`qk_rope_on_mesh_fits`) and `apply_rope` elsewhere."""
+    w = _rope_table_lanes(d)
+    return rows % 8 == 0 and nq % w == 0 and nk % w == 0
+
+
+def _rope_impl(q, k, positions, d, theta, interpret):
     import jax.experimental.pallas as pl
 
-    b, s, h, d = q.shape
-    nq, nk = h * d, k.shape[2] * d
+    b, s, nq = q.shape
+    nk = k.shape[2]
     rows = b * s
-    # Cap the f32 working tile at 512 KiB: the kernel holds a handful
-    # of [bn, nq] temporaries in VMEM beside the pipelined blocks.
-    bn = _row_block(rows, cap=max(8, (1 << 17) // nq))
-    # Lanes of the in-kernel cos/sin table: one whole lane tile of
-    # heads (lcm(d, 128)) when both widths are multiples of it — tiling
-    # it out is then a lane-aligned concatenate — else one head.
-    w = d * 128 // math.gcd(d, 128)
+    # Cap the f32 working tile at 1 MiB: the kernel holds a handful of
+    # [bn, nq] temporaries in VMEM beside the pipelined blocks (256 rows
+    # at the train cell's 1,024 lanes: 0.73 ms a call of 32,768 rows on a
+    # v5e where 128 rows take 0.78, PERF.md section 6, PR 63).
+    bn = _row_block(rows, cap=max(8, (1 << 18) // nq))
+    # One whole lane tile of heads (lcm(d, 128)) when both widths are
+    # multiples of it — tiling it out is then a lane-aligned
+    # concatenate — else one head.
+    w = _rope_table_lanes(d)
     if nq % w or nk % w:
         w = d
     # The same frequencies as the reference, tiled over the heads of
@@ -267,44 +291,98 @@ def _rope_impl(q, k, positions, theta, interpret):
         interpret=interpret,
         name="rtpu_fused_qk_rope",
         metadata={"kernel": "rtpu_fused_qk_rope"},
-    )(jnp.broadcast_to(positions, (b, s)).reshape(rows, 1),
-      inv, q.reshape(rows, nq), k.reshape(rows, nk))
+    )(positions.reshape(rows, 1), inv, q.reshape(rows, nq),
+      k.reshape(rows, nk))
     return oq.reshape(q.shape), ok.reshape(k.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _rope_qk_p(q, k, positions, theta, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _rope_qk_p(q, k, positions, d, theta, interpret):
+    """q [B, S, H·d] and k [B, S, KH·d], a row's heads side by side."""
     if not _use_kernel(interpret):
-        return (apply_rope_reference(q, positions, theta),
-                apply_rope_reference(k, positions, theta))
-    return _rope_impl(q, k, positions, theta, interpret)
+        return tuple(
+            apply_rope_reference(x.reshape(*x.shape[:2], -1, d), positions,
+                                 theta).reshape(x.shape) for x in (q, k))
+    return _rope_impl(q, k, positions, d, theta, interpret)
 
 
-def _rope_qk_fwd(q, k, positions, theta, interpret):
-    return _rope_qk_p(q, k, positions, theta, interpret), (positions,)
+def _rope_qk_fwd(q, k, positions, d, theta, interpret):
+    return _rope_qk_p(q, k, positions, d, theta, interpret), (positions,)
 
 
-def _rope_qk_bwd(theta, interpret, res, gs):
+def _rope_qk_bwd(d, theta, interpret, res, gs):
     # Rotation is orthogonal: the VJP rotates the cotangents by -angle,
     # i.e. the same kernel with negated positions.
     (positions,) = res
     gq, gk = gs
-    dq, dk = _rope_qk_p(gq, gk, -positions, theta, interpret)
+    dq, dk = _rope_qk_p(gq, gk, -positions, d, theta, interpret)
     dpos = np.zeros(positions.shape, jax.dtypes.float0)
     return dq, dk, dpos
 
 
 _rope_qk_p.defvjp(_rope_qk_fwd, _rope_qk_bwd)
 
+# A whole-sequence q, k and their positions over the repo's mesh: batch
+# over the data axes, rows over ``sp``, a row's heads over ``tp``.
+_ROPE_ROWS = P(("dp", "fsdp"), "sp")
+_ROPE_FLAT = P(("dp", "fsdp"), "sp", "tp")
+
+
+def qk_rope_on_mesh_fits(batch: int, seq: int, heads: int, kv_heads: int,
+                         d: int, mesh: Optional[Mesh]) -> bool:
+    """`qk_rope_kernel_fits` for a device's share of q [batch, seq,
+    heads·d] and k [.., kv_heads·d] as `fused_qk_rope` splits them over
+    ``mesh`` (every device its own rows and heads; none, or one device:
+    the whole)."""
+    sizes = dict(mesh.shape) if mesh is not None and mesh.size > 1 else {}
+    data = sizes.get("dp", 1) * sizes.get("fsdp", 1)
+    sp, tp = sizes.get("sp", 1), sizes.get("tp", 1)
+    if batch % data or seq % sp or heads % tp or kv_heads % tp:
+        return False
+    return qk_rope_kernel_fits(batch // data * (seq // sp),
+                               heads // tp * d, kv_heads // tp * d, d)
+
 
 def fused_qk_rope(q: jnp.ndarray, k: jnp.ndarray, positions: jnp.ndarray,
-                  theta: float = 500000.0, *, interpret: bool = False):
+                  theta: float = 500000.0, *, head_dim: Optional[int] = None,
+                  interpret: bool = False, mesh: Optional[Mesh] = None):
     """Rotate the q AND k projection outputs in one kernel: q [B,S,H,D],
-    k [B,S,KH,D], positions [B,S] int. The cos/sin tables are computed
-    once per position and lane tile (the unfused path recomputes them
-    per tensor and head). Returns ``(q_rot, k_rot)``; matches two
-    ``ops.rotary.apply_rope`` calls."""
-    return _rope_qk_p(q, k, positions, float(theta), bool(interpret))
+    k [B,S,KH,D], positions [B,S] int; or, with ``head_dim`` given, q
+    [B,S,H·D] and k [B,S,KH·D] with a row's heads side by side, which is
+    what the kernel works on (the 4-D form is reshaped to it: on the
+    chip a pass over the array where D is half a lane tile). The cos/sin
+    tables are computed once per position and lane tile (the unfused
+    path recomputes them per tensor and head). Returns ``(q_rot,
+    k_rot)`` in the operands' form; matches two
+    ``ops.rotary.apply_rope`` calls, and its VJP is the same kernel at
+    negated positions.
+
+    Mosaic kernels cannot be auto-partitioned: on a ``mesh`` of more
+    than one device the call runs per shard under a `shard_map` that is
+    manual over every axis, the seam `ops/attention.py` gives the flash
+    kernels (rope treats rows and heads alike: no collective).
+
+    Where it runs: `models/llama.py`'s whole-sequence, no-cache block
+    (the train step's layer body, forward and backward, under the tp
+    ring too) takes it on the TPU wherever a device's share of q and k
+    is dense (`qk_rope_on_mesh_fits`), read off the backend, the mesh
+    and the shapes; the cache paths only under
+    ``LlamaConfig.fused_ops``."""
+    flat = head_dim is not None
+    d = head_dim if flat else q.shape[-1]
+    shapes = q.shape, k.shape
+    if not flat:
+        q, k = (x.reshape(*x.shape[:2], -1) for x in (q, k))
+    rope = lambda q, k, pos: _rope_qk_p(q, k, pos, int(d), float(theta),
+                                        bool(interpret))
+    if mesh is not None and mesh.size > 1:
+        rope = shard_map(
+            rope, mesh=mesh, in_specs=(_ROPE_FLAT, _ROPE_FLAT, _ROPE_ROWS),
+            out_specs=(_ROPE_FLAT, _ROPE_FLAT),
+            # pallas_call's output carries no varying-axes type.
+            check_vma=False)
+    q, k = rope(q, k, jnp.broadcast_to(positions, q.shape[:2]))
+    return q.reshape(shapes[0]), k.reshape(shapes[1])
 
 
 # ---------------------------------------------------------------- SwiGLU

@@ -27,9 +27,18 @@ A device meets the shards in RING order (its own, then its left
 neighbour's, ...), which is another order on every device. Putting the
 products back into sequence order is a pass over the block's largest
 arrays (measured on a v5e: 10 % of a train step when every group did
-it), so what treats rows alike is applied shard by shard behind the
-product (``rowwise``), a group's results can go to `matmul_scatter` in
-ring order as they are, and only attention gets the true order.
+it), so what treats rows alike can be applied shard by shard behind
+the product (``rowwise``: SwiGLU), a group's results can go to
+`matmul_scatter` in ring order as they are, and only attention gets the
+true order. Behind the product is not free by itself: rotary embedding
+as a ``rowwise`` of plain XLA cost q and k ten times their bytes' time
+at head size 64 (half a lane tile: widened, split, joined), and the
+chip showed it cheaper to write q's and k's products with a row's heads
+side by side, let the ring assemble them (0.24 ms an array of 67 MB)
+and rotate both in ONE Pallas call on the assembled sequence (0.35 ms;
+`models/llama.py` ``_rope_kernel``, PERF.md section 6, PR 63): a
+Mosaic call cannot be traced inside the ring's partly manual
+`shard_map`, so it sits in one of its own, manual over every axis.
 
 `ring_size` says whether a caller's shapes engage it, from the ambient
 mesh alone: there is no option to set.
@@ -172,8 +181,10 @@ def gather_matmul(eqn: str, h, ws: Sequence, *,
     ``rowwise(products, *rows_of_row_args) -> tuple`` is applied to each
     shard's products as they are made (rotary embedding, an activation:
     anything that treats rows alike), with the same rows of each of
-    ``row_args`` [B, S, ...]; fused behind the product it costs no pass
-    of its own, which it does behind the assembled sequence.
+    ``row_args`` [B, S, ...]: where XLA fuses it with the product's
+    neighbours (SwiGLU) it costs no pass of its own; rotary embedding
+    did, several (see the module's note), and left for a kernel behind
+    the assembled sequence.
 
     ``in_sequence=False`` leaves each result as its ``tp`` row blocks in
     RING order (block t on device i holds the rows of shard (i - t) %

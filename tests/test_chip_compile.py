@@ -491,7 +491,8 @@ def _fsdp2_tp2_loss_and_grad(topo, cfg, batch):
     return c, shapes
 
 
-# policy: (Mosaic kernels, ring hops) a layer body pair. "nothing":
+# policy: (Mosaic kernels, rope's calls among them, ring hops) a layer
+# body pair. "nothing":
 # forward, the remat's forward again, dq and dkv; 4 hops forward
 # (q/k/v, wo, gate/up, w_down) + 7 backward, the remat's first three
 # again and the four transposes (one travelling copy serves q, k and v
@@ -499,8 +500,55 @@ def _fsdp2_tp2_loss_and_grad(topo, cfg, batch):
 # kernel's output and its statistic: the backward runs the forward
 # kernel no second time, and of the remat's q/k/v ring v's product
 # alone (the hop stays: the transposed products need the other
-# shard's rows of the normed stream, which nobody kept).
-_REMAT_PROGRAMS = {"attention": (3, 11), "nothing": (4, 11)}
+# shard's rows of the normed stream, which nobody kept). Rope's kernel
+# (`rtpu_fused_qk_rope`, q and k in one call) runs forward and backward
+# under either policy, and in the remat's forward too where q and k
+# are not kept: 3 + 2 and 4 + 3.
+_REMAT_PROGRAMS = {"attention": (5, 2, 11), "nothing": (7, 3, 11)}
+
+
+def _assert_no_pass_around_the_rope_kernel(text: str, layer_bodies, cfg):
+    """A layer body holds, for rope, the kernel's calls and nothing of
+    `apply_rope`: no cos or sin table, no float32 copy of a device's q
+    or k (as [B, S, H, D], its half sequence or its halves of a head),
+    no bf16 halves joined in a pass of their own. The products are
+    written with a row's heads side by side and reach the forward call
+    through the ring's assembly alone (its [B, S, H·D] as it is: no
+    copy, no reshape that moves a byte); the calls' results leave flat
+    too, which is what the policy keeps."""
+    assert not re.search(r"\b(cosine|sine)\(", text)
+    b, half = BATCH // 2, cfg.head_dim // 2
+    heads = f"({cfg.n_heads // 2}|{cfg.n_kv_heads // 2})"
+    widened = re.compile(
+        rf"= \(?(f32\[{b},(2048|1024),{heads},({cfg.head_dim}|{half})\]"
+        rf"|bf16\[{b},(2048|1024),{heads},{half}\])")
+    calls = []
+    for lines in layer_bodies:
+        for line in lines:
+            assert not widened.search(line), line
+        by_name = {m.group(1): l for l in lines
+                   if (m := re.match(r"\s*%([\w.\-]+) = ", l))}
+        for i, line in enumerate(lines):
+            m = re.match(r"\s*%rtpu_fused_qk_rope[\w.]* = \((\S+), (\S+)\) "
+                         r"custom-call\(([^)]*)\)", line)
+            if not m:
+                continue
+            # (The kernel's metadata is printed over three lines.)
+            line = " ".join(lines[i:i + 3])
+            rows = b * SEQ
+            assert m.group(1).startswith(
+                f"bf16[{rows},{cfg.n_heads // 2 * cfg.head_dim}]"), line
+            assert m.group(2).startswith(
+                f"bf16[{rows},{cfg.n_kv_heads // 2 * cfg.head_dim}]"), line
+            operands = [by_name[o.strip().lstrip("%")]
+                        for o in m.group(3).split(",")]
+            calls.append(("transpose(jvp" in line, operands[2:]))
+    # (`ConcatBitcast`: the compiler's own prefetch of an operand into
+    # the other memory space, pieces of it under way at a time.)
+    forward = [o for backward, ops in calls if not backward for o in ops]
+    assert forward and all(" bitcast(" in o or "ConcatBitcast" in o
+                           for o in forward), "\n".join(forward)
+    return len(calls)
 
 
 @pytest.mark.parametrize("policy", _REMAT_PROGRAMS)
@@ -515,13 +563,16 @@ def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip,
     tensor-parallel sums are ring hops of half the residual stream, each
     with a product between its start and its done, and no blocking
     collective of the whole stream is left in a layer body. So is what
-    the remat policy keeps: the kernels and hops left in the backward."""
+    the remat policy keeps: the kernels and hops left in the backward.
+    And where rope runs: in `rtpu_fused_qk_rope` behind the ring, chosen
+    by what the code observes, with no pass of `apply_rope` around it."""
     assert llama.LlamaConfig().remat_policy == "attention"
     cfg = dataclasses.replace(_CFG_1B, n_layers=2, remat_policy=policy)
-    kernels, ring_hops = _REMAT_PROGRAMS[policy]
+    kernels, rope_calls, ring_hops = _REMAT_PROGRAMS[policy]
     c, shapes = _fsdp2_tp2_loss_and_grad(topo, cfg, BATCH)
     text = c.as_text()
     assert text.count("tpu_custom_call") == kernels
+    assert _names_kernel(c, "rtpu_fused_qk_rope")
     for collective in ("all-gather", "all-reduce"):
         assert re.search(rf"\b{collective}(-start)?\(", text), collective
     # Each device holds a quarter of the weights, not the model.
@@ -534,7 +585,7 @@ def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip,
                 for c in comps if any(" convolution(" in l for l in c)}
     stream = f"bf16[{BATCH // 2},{SEQ},{cfg.d_model}]"   # a device's batch
     hop = f"bf16[{BATCH // 2},{SEQ // 2},{cfg.d_model}]"
-    hops = 0
+    hops, layer_bodies = 0, []
     for lines in comps:
         started = {}
         for i, line in enumerate(lines):
@@ -554,11 +605,14 @@ def test_fsdp2_tp2_loss_and_grad_compile_on_described_mesh(topo, chip,
         if not any("collective-permute-start(" in l for l in lines):
             continue
         # A layer body: the whole stream crosses no link in one piece.
+        layer_bodies.append(lines)
         for line in lines:
             assert not re.search(
                 rf"= {re.escape(stream)}\S* (all-reduce|all-gather)"
                 r"(-start)?\(", line), line
     assert hops == text.count("collective-permute-start(") == ring_hops
+    assert _assert_no_pass_around_the_rope_kernel(
+        text, layer_bodies, cfg) == rope_calls
     # The embedding is looked up through its vocab shards, never gathered.
     assert not re.search(
         rf"= bf16\[{cfg.vocab_size},{cfg.d_model}\]\S* all-gather", text)

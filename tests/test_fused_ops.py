@@ -167,13 +167,145 @@ def test_fused_swiglu_grad_matches_autodiff():
                                    rtol=1e-5, atol=1e-5)
 
 
+# --------------------------------- rope at a device's share of the cell
+
+# `smollm2.sft.fsdp2tp2` on one device: 16 heads of 64 of q and of k
+# (1,024 dense lanes each), rows cut down from 16 x 2,048.
+_SHARE = (2, 256, 16, 64)
+_POSITIONS = {
+    "plain": lambda b, s: jnp.broadcast_to(jnp.arange(s), (b, s)),
+    # A packed batch: every row starts elsewhere.
+    "offset": lambda b, s: (jnp.arange(s)[None, :]
+                            + 1000 * jnp.arange(1, b + 1)[:, None]),
+}
+
+
+def _share_operands():
+    return (_randn(30, _SHARE, jnp.bfloat16), _randn(31, _SHARE, jnp.bfloat16))
+
+
+def _ulp_close(got, ref):
+    """To bf16's last place: float32 inside, one rounding at the end,
+    where the two may fall on either side of a tie."""
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=2 ** -7, atol=2 ** -9)
+
+
+@pytest.mark.parametrize("flat", [False, True], ids=["heads", "flat"])
+@pytest.mark.parametrize("positions", sorted(_POSITIONS))
+def test_fused_qk_rope_at_a_devices_share_of_the_train_cell(positions, flat):
+    """bf16 q and k at the train cell's real widths, as `[B, S, H, D]`
+    and as the `[B, S, H·D]` that `models/llama.py` hands over: the
+    kernel's outputs against `apply_rope`."""
+    from ray_tpu.ops.fused import qk_rope_kernel_fits
+
+    b, s, h, d = _SHARE
+    assert qk_rope_kernel_fits(b * s, h * d, h * d, d)
+    q, k = _share_operands()
+    pos = _POSITIONS[positions](b, s)
+    if flat:
+        qr, kr = fused_qk_rope(q.reshape(b, s, -1), k.reshape(b, s, -1), pos,
+                               130000.0, head_dim=d, interpret=True)
+        assert qr.shape == kr.shape == (b, s, h * d)
+        qr, kr = qr.reshape(_SHARE), kr.reshape(_SHARE)
+    else:
+        qr, kr = fused_qk_rope(q, k, pos, 130000.0, interpret=True)
+    assert qr.dtype == kr.dtype == jnp.bfloat16
+    _ulp_close(qr, apply_rope(q, pos, 130000.0))
+    _ulp_close(kr, apply_rope(k, pos, 130000.0))
+
+
+@pytest.mark.parametrize("positions", sorted(_POSITIONS))
+def test_fused_qk_rope_vjp_at_a_devices_share_of_the_train_cell(positions):
+    """The VJP (the same kernel at negated positions) against autodiff
+    of `apply_rope`, bf16 cotangents."""
+    b, s, h, d = _SHARE
+    q, k = _share_operands()
+    gq, gk = _randn(32, _SHARE, jnp.bfloat16), _randn(33, _SHARE, jnp.bfloat16)
+    pos = _POSITIONS[positions](b, s)
+    _, vjp = jax.vjp(lambda q, k: fused_qk_rope(q, k, pos, 130000.0,
+                                                interpret=True), q, k)
+    _, ref = jax.vjp(lambda q, k: (apply_rope(q, pos, 130000.0),
+                                   apply_rope(k, pos, 130000.0)), q, k)
+    for got, want in zip(vjp((gq, gk)), ref((gq, gk))):
+        assert got.dtype == jnp.bfloat16
+        _ulp_close(got, want)
+
+
+@pytest.mark.parametrize("rows,nq,nk,d,fits", [
+    (32768, 1024, 1024, 64, True),     # the train cell's share
+    (8192, 2048, 512, 64, True),       # Llama-3 1B, grouped keys
+    (1024, 4096, 1024, 128, True),     # Mistral's / Llama-3 8B's heads
+    (16384, 1024, 64, 64, False),      # one key head: half a lane tile
+    (24, 64, 32, 16, False),           # the CPU suite's toy widths
+    (100, 1024, 1024, 64, False),      # rows that no block divides
+    (2048, 960, 960, 96, False),       # 384 lanes hold whole heads of 96
+    (2048, 1536, 384, 96, True),
+])
+def test_qk_rope_kernel_fits_whole_lane_tiles_of_heads(rows, nq, nk, d, fits):
+    from ray_tpu.ops.fused import qk_rope_kernel_fits
+
+    assert qk_rope_kernel_fits(rows, nq, nk, d) is fits
+
+
+def _mesh_loss_and_grads(cfg, mesh, params, tokens):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.mesh import mesh_context, param_shardings
+
+    def loss(p, t):
+        return llama.loss_fn(p, t, cfg, mesh=mesh)[0]
+
+    with mesh_context(mesh):
+        p = jax.device_put(params, param_shardings(
+            mesh, llama.param_logical_axes(cfg)))
+        t = jax.device_put(tokens, NamedSharding(
+            mesh, P(("dp", "fsdp"), "sp")))
+        jaxpr = str(jax.make_jaxpr(jax.value_and_grad(loss))(p, t))
+        return jax.jit(jax.value_and_grad(loss))(p, t), jaxpr
+
+
+def test_rope_kernel_under_the_fsdp2_tp2_mesh_matches_the_twin():
+    """The sharded seam numerically, CPU mesh fsdp 2 x tp 2 with the tp
+    ring ON: the step's loss and gradients with the kernel (interpreted:
+    a device's share is 2 heads of 64 of q and of k, one lane tile)
+    against `apply_rope` as the ring's rowwise. The kernel is chosen by
+    what the code observes (the interpreter asked for by the tests' own
+    hook, the mesh, the shapes), and the ring's hops are the same."""
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.mesh import mesh_2d
+
+    cfg = llama.tiny_config(d_model=256, n_heads=4, n_kv_heads=4, d_ff=256,
+                            remat=True, max_seq_len=64)
+    mesh = mesh_2d(4, tp=2, devices=jax.devices()[:4])
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
+                                cfg.vocab_size)
+    (l0, g0), twin = _mesh_loss_and_grads(cfg, mesh, params, tokens)
+    (l1, g1), kernel = _mesh_loss_and_grads(
+        dataclasses.replace(cfg, interpret_kernels=True), mesh, params,
+        tokens)
+    # Forward and backward of each of the 2 layers' scan body.
+    assert "rtpu_fused_qk_rope" not in twin
+    assert kernel.count("name=rtpu_fused_qk_rope") == 2
+    assert kernel.count("ppermute") == twin.count("ppermute") > 0
+    np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=1e-6)
+
+
 # ----------------------------------------------------- model dispatch
 
 
 def test_llama_fused_forward_matches_unfused():
-    """`LlamaConfig.fused_ops="interpret"` routes the WHOLE block through
-    the fused kernels; logits must match the unfused model exactly on
-    f32 (identical math, one pass)."""
+    """`LlamaConfig.fused_ops="interpret"` routes the block's norms and
+    SwiGLU through the fused kernels (its whole-sequence rope is chosen
+    by what the code observes: the twin at these toy widths); logits
+    must match the unfused model exactly on f32 (identical math, one
+    pass)."""
     from ray_tpu.models import llama
 
     cfg = llama.tiny_config()
@@ -234,3 +366,48 @@ def test_llama_fused_train_step_grads_match():
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=1e-5)
+
+
+# The three programs of the Mistral cells (`mistral7b.chat.steady`,
+# `.chat.flood`, `.doc.steady`: widths of benchmark/configs/
+# mistral-7b-v0.3-l16.json, 2 of its scanned layers) as PR 62 traced
+# them: sha256 of the jaxpr's text, first 16 hex digits. The kernel that
+# the whole-sequence block takes by what it observes (PR 63) is for the
+# path WITHOUT a cache; with one, `_block` and the step do what they
+# did, and every serving check pins their logits to these programs.
+_CACHE_PROGRAMS = {
+    "prefill_1x1024": "2f74a7e3f4e790e6",
+    "paired_prefill_2x512": "3ae4b53871b6753d",
+    "step_32x1": "43e0ab1248b93fb6",
+}
+
+
+@pytest.mark.parametrize("program", sorted(_CACHE_PROGRAMS))
+def test_llama_cache_paths_trace_the_programs_they_did(program):
+    import functools
+    import hashlib
+
+    from ray_tpu.models import llama
+
+    cfg = llama.LlamaConfig(vocab_size=32768, d_model=4096, n_layers=2,
+                            n_heads=32, n_kv_heads=8, d_ff=14336,
+                            max_seq_len=1024, rope_theta=1e6)
+    params = jax.eval_shape(functools.partial(llama.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    cache = lambda slots: jax.eval_shape(
+        lambda: llama.init_kv_cache(cfg, slots, 1024))
+    fn, args = {
+        "prefill_1x1024": (llama.forward_last_with_cache,
+                           (ints(1, 1024), cache(1), ints(), ints())),
+        "paired_prefill_2x512": (llama.forward_last_rows_with_cache,
+                                 (ints(2, 512), cache(2), ints(2), ints(2))),
+        "step_32x1": (llama.decode_step_with_cache,
+                      (ints(32, 1), cache(32), ints(32))),
+    }[program]
+    jaxpr = str(jax.make_jaxpr(lambda p, *a: fn(p, *a, cfg))(params, *args))
+    assert "rtpu_fused_qk_rope" not in jaxpr
+    assert (hashlib.sha256(jaxpr.encode()).hexdigest()[:16]
+            == _CACHE_PROGRAMS[program]), (
+        f"{program} traces another program than PR 62's: a change to the "
+        "cache paths moves the three Mistral cells")
