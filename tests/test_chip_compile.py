@@ -1920,3 +1920,90 @@ def test_xing_mhc_check_programs_rewrite_the_engines_own_cache(chip):
         assert mixes["y"].shape == (5, 2, width, 3584)
         assert seen["mhc_end"]["streams"].shape == (width, 4, 3584)
 
+
+
+# ------------------------------------------------- the looped family
+
+def _ouro_args(chip, slots=8, rows=512):
+    """Ouro-2.6B's widths (benchmark/configs/ouro-2.6b.json) at 2 layers
+    — the layer body is scanned, so its HLO is the 48-layer one's — with
+    the cell's 8 slots of 512 rows and all four passes."""
+    from ray_tpu.models import ouro
+    from ray_tpu.serve.engine.decode_loop import DecodeLoop
+
+    cfg = ouro.OuroConfig(n_layers=2)
+    params = _abstract(chip, functools.partial(ouro.init_params, cfg),
+                       jax.random.PRNGKey(0))
+    cache = _abstract(chip, lambda: ouro.init_kv_cache(cfg, slots, rows))
+    assert cache["k"].shape == (8, slots, 16, rows, 128)
+    return cfg, DecodeLoop(cfg, max_len=rows, chunk=8), params, cache
+
+
+def _moves_of(text: str, params) -> list:
+    """Copies whose result is one of the blocks' matrices (a layer's
+    ``[1, ..]`` or the stack's): a weight MOVED in HBM where a product
+    should read it where it lies. (A fusion whose root is a layer's
+    slice into ``S(1)`` is the compiler's prefetch of that matrix into
+    fast memory: its one read, not a copy.)"""
+    shapes = {tuple(a.shape[1:]) for a in params["blocks"].values()
+              if a.ndim == 3}
+    moved = re.compile(r"= bf16\[(?:\d+,)?(\d+),(\d+)\]\S* copy\(")
+    return [line.strip()[:120] for line in text.splitlines()
+            for m in [moved.search(line)]
+            if m and (int(m.group(1)), int(m.group(2))) in shapes]
+
+
+def test_ouro_decode_chunk_reads_the_stack_four_times_and_copies_it_never(
+        chip):
+    """The looped step through the engine's own `decode_chunk`: four
+    scans over the layers inside the scan over the chunk's steps, the
+    decode-attention kernel once a scan body under its name, the
+    192-entry cache aliased and never copied, and NO matrix of the
+    stack copied or sliced out on its own (`models/common.py`
+    ``_layer_of``'s trap, PR 33; the q, k, v stacks stored output-major
+    are read where they lie: stored by head the compiler re-laid all
+    three out once a program)."""
+    cfg, loop, params, cache = _ouro_args(chip)
+    c = _lower_decode_chunk(chip, loop, params, cache, 8)
+    text = c.as_text()
+    assert text.count(" while(") == 1 + cfg.n_loops
+    assert text.count("%rtpu_decode_attention.") >= cfg.n_loops
+    _assert_cache_in_place(c, cache)
+    # Three stacks of 2 layers re-laid out would be 50 MB held.
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 24
+    assert _moves_of(text, params) == []
+    vec = _sds(chip, (8,), jnp.int32)
+    out = jax.eval_shape(
+        loop.decode_chunk, params, cache, _sds(chip, (8, 1), jnp.int32),
+        vec, vec, vec, _sds(chip, (8,), jnp.bool_))
+    assert len(out) == 8 and set(out[7]) == {
+        "decode_attn_rows", "decode_attn_rows_streamed", "loop_passes",
+        "loop_layer_steps"}
+
+
+@pytest.mark.parametrize("bucket", [64, 256])
+def test_ouro_prefills_write_each_pass_once_and_copy_no_weight(chip, bucket):
+    """The tick's prefill and the check's donating twin at the cell's
+    smallest and largest bucket: one token (or one row of logits and
+    what the check reads) out, the cache aliased, no matrix of the
+    stack moved; what they hold beside the cache is the slot's rows
+    (`in_slot`: 0.03 GB at 2 layers, 0.8 at 48) and the new rows."""
+    cfg, loop, params, cache = _ouro_args(chip)
+    scalar = _sds(chip, (), jnp.int32)
+    args = (params, cache, _sds(chip, (1, bucket), jnp.int32), scalar,
+            scalar, scalar)
+    for program in (loop.prefill_inplace, loop.prefill_last_inplace):
+        c = program.lower(*args).compile()
+        text = c.as_text()
+        assert text.count(" while(") == cfg.n_loops
+        mem = c.memory_analysis()
+        assert mem.alias_size_in_bytes >= 2 * cache["k"].size * 2
+        assert mem.temp_size_in_bytes < 2 ** 28
+        assert _moves_of(text, params) == []
+    token, _, counters = jax.eval_shape(loop.prefill_inplace, *args)
+    assert (token.shape, token.dtype) == ((1,), jnp.int32)
+    assert set(counters) == {"loop_prefill_passes"}
+    logits, _, _, seen = jax.eval_shape(loop.prefill_last_inplace, *args)
+    assert logits.shape == (1, cfg.vocab_size)
+    assert seen["gates"].shape == (cfg.n_loops, 1, bucket)
+    assert seen["blocks"]["handed"].shape == (cfg.n_entries, 1, 2048)

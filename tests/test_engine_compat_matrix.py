@@ -193,6 +193,13 @@ def _family_cfg(family):
 
     if family == "llama":
         return _tiny_llama_128()
+    if family == "ouro":
+        # Rows and no slot state, like llama: not a row of FAMILIES.
+        from ray_tpu.models import ouro
+
+        return ouro.tiny_config(vocab_size=64, d_model=24, n_heads=2,
+                                n_kv_heads=2, head_dim=12, d_ff=32,
+                                n_layers=2, n_loops=3)
     return dataclasses.replace(FAMILIES[family](), max_seq_len=128)
 
 
@@ -272,7 +279,7 @@ FIRST_WAVE = [ANCHOR, ([4] * 3, 5), ([5] * 3, 13), ([6] * 3, 7), (LONG, 9)]
 SECOND_WAVE = [(LONG, 11), ([7] * 3, 6), ([8] * 5, 2)]
 CHURN_CASES = [("llama", 2), ("llama", 3), ("llama", 4),
                ("olmo_hybrid", 2), ("minicpm_sala", 3), ("zaya", 3),
-               ("granite_hybrid", 2), ("kimi_linear", 2)]
+               ("granite_hybrid", 2), ("kimi_linear", 2), ("ouro", 2)]
 
 
 @pytest.mark.parametrize("family,max_batch", CHURN_CASES)

@@ -108,6 +108,7 @@ SERVED = {
                                  "rtpu_mla_decode_attention"},
     "xing4.0-29b-a4b-ep8": {"rtpu_mhc_pre", "rtpu_mhc_post",
                             "rtpu_mla_decode_attention"},
+    "ouro-2.6b": {"rtpu_decode_attention"},
 }
 
 
